@@ -25,6 +25,9 @@ REPS = int(sys.argv[2]) if len(sys.argv) > 2 else 50
 
 
 def main():
+    from mmlspark_tpu.core.device import configure_compile_cache
+
+    configure_compile_cache()
     params = init_resnet(variant="resnet50", num_classes=1000)
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.normal(size=(BATCH, 3, 224, 224)).astype(np.float32))

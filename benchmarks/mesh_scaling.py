@@ -41,6 +41,10 @@ def main():
 
     force_platform("cpu", min_devices=8)
 
+    from mmlspark_tpu.core.device import configure_compile_cache
+
+    configure_compile_cache()
+
     import jax
     import numpy as np
 

@@ -47,6 +47,10 @@ def make_corpus(n, seed=0):
 
 
 def main():
+    from mmlspark_tpu.core.device import configure_compile_cache
+
+    configure_compile_cache()
+
     from mmlspark_tpu.data.table import Table
     from mmlspark_tpu.vw import VowpalWabbitClassifier, VowpalWabbitFeaturizer
 

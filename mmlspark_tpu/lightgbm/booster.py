@@ -202,8 +202,7 @@ class Booster:
         chunk = _predict_chunk_rows(*pc.feats.shape, extra_row_bytes=extra)
         outs = []
         # device-resident constants built ONCE — a jnp.asarray per chunk
-        # would re-upload every tree table each iteration (transfers are
-        # the fixed cost on remote-attached chips)
+        # would re-upload every tree table each iteration
         cargs = (
             jnp.asarray(pc.feats), jnp.asarray(pc.thrs),
             jnp.asarray(pc.nanl), jnp.asarray(pc.zm),
@@ -572,12 +571,20 @@ def _leaf_paths(b: "Booster", t: int) -> "PathConsts":
     )
 
 
+def _node_features(X, feats):
+    """(N, T, I): each row's value of every internal node's split feature.
+    ONE gather with the 2-D index — the flat gather + reshape spelling,
+    ``take(X, feats.reshape(-1), axis=1).reshape(n, t, i)``, is miscompiled
+    by XLA:TPU (libtpu 0.0.34) when the two fuse: at T*I = 300 every
+    element came back wrong for 48k-70k rows per dispatch, so a 50k-row
+    predict returned one constant (PERF.md, bring-up). ``chip_smoke.py``
+    phase 2 walks the trees on the host to catch a recurrence."""
+    return jnp.take(X, feats, axis=1)
+
+
 def _path_match(X, feats, thrs, nanl, zm, P, plen):
     """(N, T, L) one-hot leaf membership per tree."""
-    x = jnp.take(X, feats.reshape(-1), axis=1)
-    n = X.shape[0]
-    t, i = feats.shape
-    x = x.reshape(n, t, i)
+    x = _node_features(X, feats)
     # missing (NaN — and 0.0 at zero_as_missing nodes) routes per the
     # node's nan_left flag; pads are always-left
     miss = jnp.isnan(x) | (zm[None] & (jnp.abs(x) <= K_ZERO_THRESHOLD))
@@ -620,10 +627,9 @@ def _path_match_cat_gather(X, feats, thrs, nanl, zm, P, plen, iscat, catm):
     (T, I, Bc) mask tables. ~Two orders of magnitude slower than the
     matmul kernel below (docs/perf_histogram.md round 5) — used only when
     the dense (T*I, Fc*Bc) mask matrix would exceed its size gate."""
-    x = jnp.take(X, feats.reshape(-1), axis=1)
+    x = _node_features(X, feats)
     n = X.shape[0]
     t, i = feats.shape
-    x = x.reshape(n, t, i)
     miss = jnp.isnan(x) | (zm[None] & (jnp.abs(x) <= K_ZERO_THRESHOLD))
     d_num = jnp.where(miss, nanl[None], x <= thrs[None])
     bc = catm.shape[-1]
@@ -678,10 +684,9 @@ def _path_match_cat(X, feats, thrs, nanl, zm, P, plen, iscat, cfeats, cm):
     ``cm`` (T*I, Fc*Bc) built by ``_cat_paths``. Gather formulations of
     this lookup (3-axis batched or flattened) measured 300-450x slower
     than the numeric compare path on TPU (r5)."""
-    x = jnp.take(X, feats.reshape(-1), axis=1)
+    x = _node_features(X, feats)
     n = X.shape[0]
     t, i = feats.shape
-    x = x.reshape(n, t, i)
     miss = jnp.isnan(x) | (zm[None] & (jnp.abs(x) <= K_ZERO_THRESHOLD))
     d_num = jnp.where(miss, nanl[None], x <= thrs[None])
     fc = cfeats.shape[0]

@@ -34,7 +34,6 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from mmlspark_tpu.ops.ring_attention import attention_reference
-from mmlspark_tpu.ops.shmap import shard_map
 from mmlspark_tpu.parallel.mesh import AXIS_SEQ
 
 
@@ -82,7 +81,7 @@ def a2a_attention(
     from mmlspark_tpu.parallel.mesh import AXIS_DATA
 
     spec = P(AXIS_DATA if int(mesh.shape.get(AXIS_DATA, 1)) > 1 else None, AXIS_SEQ)
-    shard = shard_map(
+    shard = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(spec, spec, spec),

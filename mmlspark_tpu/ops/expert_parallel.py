@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from mmlspark_tpu.ops.shmap import shard_map
 from mmlspark_tpu.parallel.mesh import AXIS_EXPERT
 
 
@@ -76,7 +75,7 @@ def moe_apply(
         out = expert_fn(params_one, x_l) * mask * chosen_l
         return lax.psum(out, AXIS_EXPERT)
 
-    return shard_map(
+    return jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(
@@ -167,7 +166,7 @@ def moe_apply_a2a(
         y = back[safe_slot] * keep[:, None] * chosen_l
         return y
 
-    return shard_map(
+    return jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from mmlspark_tpu.ops.shmap import shard_map
 from mmlspark_tpu.parallel.mesh import AXIS_PIPE
 
 
@@ -94,7 +93,7 @@ def pipeline_apply(
         return lax.psum(outputs, AXIS_PIPE)
 
     # strip the stage axis onto the mesh; microbatches replicated
-    out = shard_map(
+    out = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(

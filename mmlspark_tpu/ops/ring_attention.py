@@ -30,7 +30,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from mmlspark_tpu.ops.shmap import shard_map
 from mmlspark_tpu.parallel.mesh import AXIS_SEQ
 
 
@@ -142,7 +141,7 @@ def ring_attention(
     # batch rides the data axis simultaneously (attention is batch-local),
     # so a data x seq mesh uses both without gathers
     spec = P(AXIS_DATA if int(mesh.shape.get(AXIS_DATA, 1)) > 1 else None, AXIS_SEQ)
-    shard = shard_map(
+    shard = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(spec, spec, spec),

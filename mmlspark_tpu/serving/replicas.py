@@ -559,6 +559,9 @@ def _main(argv: List[str]) -> int:
     parser.add_argument("--replica", required=True, metavar="WORKDIR")
     parser.add_argument("index", type=int)
     args = parser.parse_args(argv)
+    from mmlspark_tpu.core.device import configure_compile_cache
+
+    configure_compile_cache()  # replicas of one fleet share compiled programs
     return _replica_main(args.replica, args.index)
 
 

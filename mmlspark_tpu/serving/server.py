@@ -224,6 +224,11 @@ class _BatchLoop:
             "serving_apply_latency_seconds",
             "Model apply time per micro-batch",
         )
+        self._reg_retries = reg.counter(
+            "serving_retries_total",
+            "Requests re-enqueued after their micro-batch failed "
+            "(task-retry re-hydration)",
+        )
         self._reg_expired = reg.counter(
             "serving_expired_total",
             "Requests dropped before model apply (deadline expired or "
@@ -457,6 +462,7 @@ class _BatchLoop:
             failed = [r for r in unanswered if r.retries >= self.max_retries]
             for r in retryable:
                 r.retries += 1
+                self._reg_retries.inc()
                 self.queue.put(r)
             err = json.dumps({"error": str(e)[:500]}).encode("utf-8")
             for r in failed:

@@ -37,7 +37,6 @@ from mmlspark_tpu.core.utils import StopWatch
 from mmlspark_tpu.data.sparse import SparseBatch, column_to_batch, dense_to_batch
 from mmlspark_tpu.data.table import Table
 from mmlspark_tpu.ops.hashing import mask_bits, murmur32_bytes
-from mmlspark_tpu.ops.shmap import shard_map
 
 #: VW's implicit constant (bias) feature, hashed from the literal "Constant".
 CONSTANT_FEATURE = b"Constant"
@@ -457,7 +456,7 @@ def train_linear(
                 jnp.zeros(dim, dtype=jnp.float32),
             )
         else:
-            shard = shard_map(
+            shard = jax.shard_map(
                 fit_fn,
                 mesh=mesh,
                 in_specs=(P("data"), P("data"), P("data"), P("data"), P(), P()),

@@ -405,8 +405,7 @@ class TestLeafBatchRatio:
 
 class TestScanSegmentation:
     """The one-dispatch scanned fit splits into equal segments when a single
-    device program would run past the remote-attach watchdog
-    (MMLSPARK_TPU_SCAN_ROW_ITERS); margins thread between dispatches, so
+    device program would run for minutes (MMLSPARK_TPU_SCAN_ROW_ITERS); margins thread between dispatches, so
     results must be BIT-identical to the unsegmented scan — including GOSS,
     whose per-iteration rng folds on the GLOBAL iteration id."""
 

@@ -39,7 +39,6 @@ def test_mesh_config_resolution():
 def test_psum_over_mesh():
     from jax.sharding import PartitionSpec as P
 
-    from mmlspark_tpu.ops.shmap import shard_map
 
     mesh = make_mesh()
     x = jnp.arange(8.0)
@@ -48,7 +47,7 @@ def test_psum_over_mesh():
         return jax.lax.psum(x, "data")
 
     out = jax.jit(
-        shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P())
+        jax.shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P())
     )(x)
     assert float(out[0]) == 28.0
 

@@ -74,7 +74,11 @@ class TestDeviceProfiler:
         row = prof.roofline()[0]
         assert row["name"] == "matmul"
         assert row["achieved_flops_per_s"] > 0
-        assert row["bound"] in ("compute", "memory")
+        # a rig with no peak-table row argues against no machine balance
+        if device_peaks().known:
+            assert row["bound"] in ("compute", "memory")
+        else:
+            assert row["bound"] == "unknown"
 
     def test_memory_stats_absent_on_cpu_backend(self):
         prof, _ = _fresh_profiler()
