@@ -47,14 +47,28 @@ def get_logger(name: str = "mmlspark_tpu") -> logging.Logger:
 
 
 @contextlib.contextmanager
-def profile_trace(log_dir: str) -> Iterator[None]:
+def profile_trace(
+    log_dir: str, host_tracer_level: Optional[int] = None
+) -> Iterator[float]:
     """Capture a jax.profiler (xprof) device trace into ``log_dir`` for
-    TensorBoard's profile plugin."""
+    TensorBoard's profile plugin. Yields the session's ``t0``: the
+    ``time.monotonic()`` instant the trace's own clock reads zero at, which
+    ``Tracer.trace_events(t0)`` rebases the program's spans onto.
+
+    ``host_tracer_level`` is the runtime's (default: JAX's own, 2). At 0 the
+    trace holds no host events at all — the level for a job that moves
+    gigabytes, where the runtime's host threads would log every transfer
+    chunk — and the spans from ``trace_events`` are then the only names the
+    host side has."""
     import jax
 
-    jax.profiler.start_trace(log_dir)
+    options = jax.profiler.ProfileOptions()
+    if host_tracer_level is not None:
+        options.host_tracer_level = host_tracer_level
+    jax.profiler.start_trace(log_dir, profiler_options=options)
+    t0 = time.monotonic()
     try:
-        yield
+        yield t0
     finally:
         jax.profiler.stop_trace()
 

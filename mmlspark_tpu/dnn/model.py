@@ -31,6 +31,7 @@ import numpy as np
 from mmlspark_tpu.core.params import Param, gt, to_bool, to_int, to_str
 from mmlspark_tpu.core.pipeline import Model
 from mmlspark_tpu.data.table import Table
+from mmlspark_tpu.observability.tracing import get_tracer
 
 
 def _stack_batch(col: np.ndarray, pad_to: int, dtype: Any) -> np.ndarray:
@@ -272,63 +273,88 @@ class DNNModel(Model):
         return jax.jit(run), mesh, place_params
 
     def transform(self, table: Table) -> Table:
+        """Spans (``observability/tracing``; under ``ServingServer``'s batch
+        loop they join the request's trace): ``dnn.transform`` around the
+        call, ``dnn.place_params``, then per batch ``dnn.stack`` (rows to one
+        padded host batch), ``dnn.dispatch`` (input transfer and enqueue;
+        on a call's first batch also the trace and lowering) and
+        ``dnn.fetch`` (it owns the wait on the forward), and ``dnn.assemble``
+        for the output columns. Byte tags come from shapes."""
         import jax
 
-        feeds: Dict[str, str] = self.getFeedDict()
-        fetches: Dict[str, str] = self.getFetchDict()
-        if not feeds or not fetches:
-            raise ValueError("feedDict and fetchDict must both be set")
-        batch_size = self.getBatchSize()
-        if self.getShardOverMesh():
-            from mmlspark_tpu.parallel.mesh import make_mesh
+        tracer = get_tracer()
+        with tracer.span("dnn.transform", rows=table.num_rows) as whole:
+            feeds: Dict[str, str] = self.getFeedDict()
+            fetches: Dict[str, str] = self.getFetchDict()
+            if not feeds or not fetches:
+                raise ValueError("feedDict and fetchDict must both be set")
+            batch_size = self.getBatchSize()
+            if self.getShardOverMesh():
+                from mmlspark_tpu.parallel.mesh import make_mesh
 
-            n_dev = make_mesh(self.getMeshConfig()).shape.get("data", 1)
-            batch_size = max(batch_size, n_dev)
-            batch_size += (-batch_size) % n_dev
-        if self.getPipelineStageFn() is not None:
-            # GPipe schedule splits each batch into numMicrobatches
-            m = self.getNumMicrobatches()
-            batch_size = max(batch_size, m)
-            batch_size += (-batch_size) % m
-        dtype = np.dtype(self.getInputDtype())
-        n = table.num_rows
-        fn, _, place_params = self._jitted()
-        # Pin weights on device ONCE, with their final shardings when the
-        # mesh is in play: numpy param leaves would re-transfer (and sharded
-        # ones re-broadcast) on every batch dispatch.
-        import jax.numpy as jnp
-
-        if place_params is not None:
-            params = place_params(self.getModelParams())
-        else:
-            params = jax.tree.map(jnp.asarray, self.getModelParams())
-
-        out_cols: Dict[str, List[np.ndarray]] = {name: [] for name in fetches}
-        bounds = (
-            [(lo, min(lo + batch_size, n)) for lo in range(0, n, batch_size)]
-            if self.getMiniBatcher()
-            else [(0, n)]
-        )
-        for lo, hi in bounds:
-            pad_to = batch_size if self.getMiniBatcher() else n
+                n_dev = make_mesh(self.getMeshConfig()).shape.get("data", 1)
+                batch_size = max(batch_size, n_dev)
+                batch_size += (-batch_size) % n_dev
             if self.getPipelineStageFn() is not None:
-                # GPipe needs batch % microbatches == 0 even un-minibatched
-                pad_to += (-pad_to) % self.getNumMicrobatches()
-            inputs = {
-                model_in: _stack_batch(table.column(col)[lo:hi], pad_to, dtype)
-                for model_in, col in feeds.items()
-            }
-            outputs = fn(params, inputs)
-            if not isinstance(outputs, dict):
-                outputs = {"output": outputs}
-            for col_name, model_out in fetches.items():
-                if model_out not in outputs:
-                    raise KeyError(
-                        f"model returned {sorted(outputs)}, no output {model_out!r}"
-                    )
-                arr = np.asarray(jax.device_get(outputs[model_out]))[: hi - lo]
-                out_cols[col_name].append(arr)
-        result = table
-        for col_name, parts in out_cols.items():
-            result = result.with_column(col_name, np.concatenate(parts))
-        return result
+                # GPipe schedule splits each batch into numMicrobatches
+                m = self.getNumMicrobatches()
+                batch_size = max(batch_size, m)
+                batch_size += (-batch_size) % m
+            dtype = np.dtype(self.getInputDtype())
+            n = table.num_rows
+            fn, _, place_params = self._jitted()
+            # Pin weights on device ONCE, with their final shardings when the
+            # mesh is in play: numpy param leaves would re-transfer (and sharded
+            # ones re-broadcast) on every batch dispatch.
+            import jax.numpy as jnp
+
+            with tracer.span("dnn.place_params", bytes=sum(
+                int(getattr(leaf, "nbytes", 0))
+                for leaf in jax.tree.leaves(self.getModelParams())
+            )):
+                if place_params is not None:
+                    params = place_params(self.getModelParams())
+                else:
+                    params = jax.tree.map(jnp.asarray, self.getModelParams())
+
+            out_cols: Dict[str, List[np.ndarray]] = {name: [] for name in fetches}
+            bounds = (
+                [(lo, min(lo + batch_size, n)) for lo in range(0, n, batch_size)]
+                if self.getMiniBatcher()
+                else [(0, n)]
+            )
+            whole.tags["batches"] = len(bounds)
+            for lo, hi in bounds:
+                pad_to = batch_size if self.getMiniBatcher() else n
+                if self.getPipelineStageFn() is not None:
+                    # GPipe needs batch % microbatches == 0 even un-minibatched
+                    pad_to += (-pad_to) % self.getNumMicrobatches()
+                with tracer.span("dnn.stack", pad_rows=pad_to - (hi - lo)) as sp:
+                    inputs = {
+                        model_in: _stack_batch(table.column(col)[lo:hi], pad_to, dtype)
+                        for model_in, col in feeds.items()
+                    }
+                    fed = sp.tags["bytes"] = sum(a.nbytes for a in inputs.values())
+                with tracer.span("dnn.dispatch", bytes=fed):
+                    outputs = fn(params, inputs)
+                with tracer.span("dnn.fetch") as sp:
+                    if not isinstance(outputs, dict):
+                        outputs = {"output": outputs}
+                    fetched = 0
+                    for col_name, model_out in fetches.items():
+                        if model_out not in outputs:
+                            raise KeyError(
+                                f"model returned {sorted(outputs)}, no output {model_out!r}"
+                            )
+                        arr = np.asarray(jax.device_get(outputs[model_out]))
+                        fetched += arr.nbytes
+                        out_cols[col_name].append(arr[: hi - lo])
+                    sp.tags["bytes"] = fetched
+            with tracer.span("dnn.assemble") as sp:
+                result, copied = table, 0
+                for col_name, parts in out_cols.items():
+                    column = np.concatenate(parts)
+                    copied += column.nbytes
+                    result = result.with_column(col_name, column)
+                sp.tags["bytes"] = copied
+            return result

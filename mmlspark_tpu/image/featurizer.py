@@ -19,6 +19,7 @@ from mmlspark_tpu.core.pipeline import Model
 from mmlspark_tpu.data.table import Table
 from mmlspark_tpu.dnn.model import DNNModel
 from mmlspark_tpu.image.transforms import ImageTransformer
+from mmlspark_tpu.observability.tracing import get_tracer
 
 
 class ImageFeaturizer(Model):
@@ -61,44 +62,50 @@ class ImageFeaturizer(Model):
         return fn
 
     def transform(self, table: Table) -> Table:
-        params = self.getModelParams()
-        if params is None:
-            raise ValueError("modelParams must be set (see mmlspark_tpu.models)")
-        work = table
-        image_col = self.getInputCol()
-        if self.getAutoResize():
-            resized_col = "__resized__"
-            work = ImageTransformer(
-                inputCol=image_col,
-                outputCol=resized_col,
-                toFloat=True,
-                stages=[
-                    {
-                        "op": "ResizeImage",
-                        "height": self.getInputHeight(),
-                        "width": self.getInputWidth(),
-                    }
-                ],
-            ).transform(work)
-            image_col = resized_col
+        """One ``image.featurize`` span (``observability/tracing``) roots the
+        call's trace: the resize stage's ``image.*`` spans and the batched
+        forward's ``dnn.*`` spans are its descendants."""
+        with get_tracer().span(
+            "image.featurize", rows=table.num_rows, batch_size=self.getBatchSize()
+        ):
+            params = self.getModelParams()
+            if params is None:
+                raise ValueError("modelParams must be set (see mmlspark_tpu.models)")
+            work = table
+            image_col = self.getInputCol()
+            if self.getAutoResize():
+                resized_col = "__resized__"
+                work = ImageTransformer(
+                    inputCol=image_col,
+                    outputCol=resized_col,
+                    toFloat=True,
+                    stages=[
+                        {
+                            "op": "ResizeImage",
+                            "height": self.getInputHeight(),
+                            "width": self.getInputWidth(),
+                        }
+                    ],
+                ).transform(work)
+                image_col = resized_col
 
-        backbone = self._backbone()
-        cut = self.getCutOutputLayers()
-        scale = float(self.getScale())
+            backbone = self._backbone()
+            cut = self.getCutOutputLayers()
+            scale = float(self.getScale())
 
-        def apply_fn(p, inputs):
-            x = inputs["input"].astype("float32") * scale
-            x = x.transpose(0, 3, 1, 2)  # NHWC -> NCHW
-            return {"output": backbone(p, x, cut)}
+            def apply_fn(p, inputs):
+                x = inputs["input"].astype("float32") * scale
+                x = x.transpose(0, 3, 1, 2)  # NHWC -> NCHW
+                return {"output": backbone(p, x, cut)}
 
-        dnn = DNNModel(
-            applyFn=apply_fn,
-            modelParams=params,
-            feedDict={"input": image_col},
-            fetchDict={self.getOutputCol(): "output"},
-            batchSize=self.getBatchSize(),
-        )
-        out = dnn.transform(work)
-        if image_col != self.getInputCol():
-            out = out.drop(image_col)
-        return out
+            dnn = DNNModel(
+                applyFn=apply_fn,
+                modelParams=params,
+                feedDict={"input": image_col},
+                fetchDict={self.getOutputCol(): "output"},
+                batchSize=self.getBatchSize(),
+            )
+            out = dnn.transform(work)
+            if image_col != self.getInputCol():
+                out = out.drop(image_col)
+            return out

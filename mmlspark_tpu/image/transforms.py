@@ -21,6 +21,7 @@ import numpy as np
 from mmlspark_tpu.core.params import HasInputCol, HasOutputCol, Param, to_bool, to_str
 from mmlspark_tpu.core.pipeline import Transformer
 from mmlspark_tpu.data.table import Table
+from mmlspark_tpu.observability.tracing import get_tracer
 
 
 def _ensure_nhwc(batch: Any) -> Any:
@@ -226,27 +227,49 @@ class ImageTransformer(HasInputCol, HasOutputCol, Transformer):
         return run
 
     def transform(self, table: Table) -> Table:
+        """Spans (``observability/tracing``): ``image.transform`` around the
+        whole stage; per shape group ``image.stack`` (rows to one host
+        batch), ``image.apply_fetch`` (upload, the stage program, download:
+        it owns the wait on the device) and ``image.assemble`` (clip/round
+        and the per-row scatter); one more ``image.assemble`` around the
+        output column. Byte tags come from shapes."""
         import jax
 
-        col = table.column(self.getInputCol())
-        run = self._pipeline()
-        images = [np.asarray(im) for im in col]
-        # Group equal-shape images into device batches: one compile per
-        # distinct input shape, one program execution per group.
-        by_shape: Dict[Tuple[int, ...], List[int]] = {}
-        for i, im in enumerate(images):
-            by_shape.setdefault(im.shape, []).append(i)
-        out: List[Any] = [None] * len(images)
-        for shape, idxs in by_shape.items():
-            batch = _ensure_nhwc(np.stack([images[i] for i in idxs]))
-            result = np.asarray(jax.device_get(run(batch)))
-            if not self.getToFloat():
-                result = np.clip(np.rint(result), 0, 255).astype(np.uint8)
-            if result.shape[-1] == 1 and len(shape) == 2:
-                result = result[..., 0]
-            for j, i in enumerate(idxs):
-                out[i] = result[j]
-        return table.with_column(self.getOutputCol(), out)
+        tracer = get_tracer()
+        with tracer.span("image.transform", rows=table.num_rows) as whole:
+            col = table.column(self.getInputCol())
+            run = self._pipeline()
+            images = [np.asarray(im) for im in col]
+            # Group equal-shape images into device batches: one compile per
+            # distinct input shape, one program execution per group.
+            by_shape: Dict[Tuple[int, ...], List[int]] = {}
+            for i, im in enumerate(images):
+                by_shape.setdefault(im.shape, []).append(i)
+            whole.tags["groups"] = len(by_shape)
+            out: List[Any] = [None] * len(images)
+            to_float = self.getToFloat()
+            for shape, idxs in by_shape.items():
+                with tracer.span("image.stack") as sp:
+                    batch = _ensure_nhwc(np.stack([images[i] for i in idxs]))
+                    sp.tags["bytes"] = batch.nbytes
+                with tracer.span("image.apply_fetch", bytes_up=batch.nbytes) as sp:
+                    result = np.asarray(jax.device_get(run(batch)))
+                    sp.tags["bytes_down"] = result.nbytes
+                with tracer.span("image.assemble") as sp:
+                    if not to_float:
+                        result = np.clip(np.rint(result), 0, 255).astype(np.uint8)
+                    if result.shape[-1] == 1 and len(shape) == 2:
+                        result = result[..., 0]
+                    for j, i in enumerate(idxs):
+                        out[i] = result[j]  # a view: the scatter copies nothing
+                    sp.tags["bytes"] = 0 if to_float else result.nbytes
+            with tracer.span("image.assemble") as sp:
+                done = table.with_column(self.getOutputCol(), out)
+                column = done.column(self.getOutputCol())
+                # equal shapes densify into one array (a copy of every
+                # row); mixed shapes stay an object column of the views
+                sp.tags["bytes"] = 0 if column.dtype == object else column.nbytes
+            return done
 
 
 class ImageSetAugmenter(HasInputCol, HasOutputCol, Transformer):

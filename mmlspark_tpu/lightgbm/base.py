@@ -39,6 +39,7 @@ from mmlspark_tpu.data.table import Table
 from mmlspark_tpu.lightgbm.binning import BinMapper, bin_dataset
 from mmlspark_tpu.lightgbm.booster import Booster
 from mmlspark_tpu.lightgbm.train import TrainOptions, TrainResult, train
+from mmlspark_tpu.observability.tracing import get_tracer
 
 
 class LightGBMParams(
@@ -383,38 +384,41 @@ class LightGBMBase(LightGBMParams, Estimator):
             max_conflict_rate=self.getMaxConflictRate(),
         )
         from mmlspark_tpu import runtime
+        from mmlspark_tpu.native import native_available
 
         ambient = runtime.current_policy()
-        if ambient is None and self.getNumExecutors() <= 0:
-            return bin_dataset(X, **kwargs)
-        from mmlspark_tpu.lightgbm.binning import bin_dataset_partitioned
-
-        pol = ambient or runtime.SchedulerPolicy(
-            max_workers=self.getNumExecutors(), seed=self.getSeed()
-        )
-        from mmlspark_tpu.observability.tracing import get_tracer
-
-        # durable binning: under MMLSPARK_TPU_CHECKPOINT_DIR each
-        # partition's binned block checkpoints as it completes, so a
-        # killed fit rerun with the same params + data resumes with zero
-        # re-execution of finished partitions
-        journal_root = journal_key = None
-        ckpt_root = runtime.default_checkpoint_dir()
-        if ckpt_root is not None:
-            import os
-
-            journal_root = os.path.join(ckpt_root, "binning")
-            journal_key = self._checkpoint_key(X, kwargs)
-        self._runtime_metrics = runtime.RuntimeMetrics()
+        inline = ambient is None and self.getNumExecutors() <= 0
+        # exactly one span a fit, whichever branch bins
         with get_tracer().span(
-            "lightgbm.binning", rows=int(getattr(X, "shape", (0,))[0])
+            "lightgbm.binning", rows=int(getattr(X, "shape", (0,))[0]),
+            path="partitioned" if not inline
+            else "native" if native_available() else "numpy",
         ):
+            if inline:
+                return bin_dataset(X, **kwargs)
+            from mmlspark_tpu.lightgbm.binning import bin_dataset_partitioned
+
+            pol = ambient or runtime.SchedulerPolicy(
+                max_workers=self.getNumExecutors(), seed=self.getSeed()
+            )
+            # durable binning: under MMLSPARK_TPU_CHECKPOINT_DIR each
+            # partition's binned block checkpoints as it completes, so a
+            # killed fit rerun with the same params + data resumes with zero
+            # re-execution of finished partitions
+            journal_root = journal_key = None
+            ckpt_root = runtime.default_checkpoint_dir()
+            if ckpt_root is not None:
+                import os
+
+                journal_root = os.path.join(ckpt_root, "binning")
+                journal_key = self._checkpoint_key(X, kwargs)
+            self._runtime_metrics = runtime.RuntimeMetrics()
             bins, mapper = bin_dataset_partitioned(
                 X, policy=pol, metrics=self._runtime_metrics,
                 journal_root=journal_root, journal_key=journal_key, **kwargs
             )
-        self._runtime_metrics.log(prefix="binning: ")
-        return bins, mapper
+            self._runtime_metrics.log(prefix="binning: ")
+            return bins, mapper
 
     def _checkpoint_key(self, X, bin_kwargs: dict) -> str:
         """Identity of one durable fit: estimator class + binning params +
@@ -431,53 +435,82 @@ class LightGBMBase(LightGBMParams, Estimator):
         return "-".join(parts)
 
     def _fit(self, table: Table) -> "LightGBMModelBase":
-        # Validation split by indicator column (LightGBMBase.scala:196-197).
-        valid_table = None
-        if self.isSet("validationIndicatorCol"):
-            ind = np.asarray(table.column(self.getValidationIndicatorCol()), dtype=bool)
-            valid_table, table = table.filter(ind), table.filter(~ind)
+        """One ``lightgbm.fit`` span (``observability/tracing``) roots the
+        fit's trace; its children are ``lightgbm.prepare`` (Table to float
+        arrays, options, slots), ``lightgbm.binning`` (:meth:`_bin_dataset`),
+        the four that :func:`~mmlspark_tpu.lightgbm.train.train` opens
+        (``upload``, ``program``, ``u_build``, ``boost``) and
+        ``lightgbm.pack`` (the Booster's arrays in ``train``, the model and
+        its commit here). How many there are depends on the fit's shape,
+        never on ``numIterations``."""
+        tracer = get_tracer()
+        # The root takes the manual form: in the ring, ambient for the fit
+        # (so every span below is its child), but not mirrored into a
+        # profiler session. A trace reduction names an idle gap by the
+        # annotation that overlaps it most, and an annotation around the
+        # whole fit would own every gap its children are there to name.
+        root = tracer.start_span("lightgbm.fit", iterations=self.getNumIterations())
+        status = "ok"
+        try:
+            with tracer.attach(root):
+                return self._fit_traced(table, root)
+        except BaseException as e:
+            status = type(e).__name__
+            raise
+        finally:
+            tracer.finish(root, status=status)
 
-        warm = self.getModelString()
-        prev = Booster.from_string(warm) if warm else None
-        # Warm start: pin sparse extraction to the previous booster's feature
-        # count so its trees never gather past the new batch's explicit width.
-        X, y, w, init = self._prepare(
-            table, num_features=prev.num_features if prev else 0
-        )
-        w = self._adjust_weights(y, w)
-        num_class = self._num_classes(y)
-        opts = self._make_options(num_class)
+    def _fit_traced(self, table: Table, root) -> "LightGBMModelBase":
+        tracer = get_tracer()
+        with tracer.span("lightgbm.prepare", rows=table.num_rows):
+            # Validation split by indicator column (LightGBMBase.scala:196-197).
+            valid_table = None
+            if self.isSet("validationIndicatorCol"):
+                ind = np.asarray(table.column(self.getValidationIndicatorCol()), dtype=bool)
+                valid_table, table = table.filter(ind), table.filter(~ind)
 
-        # Feature slot names: slotNames overrides the generated f0..fN
-        # (LightGBMParams slotNames) and is the namespace categorical names
-        # resolve against.
-        num_features = X.shape[1] if hasattr(X, "shape") else X.num_features
-        slot_names = self.getSlotNames() or []
-        if slot_names and len(slot_names) != num_features:
-            raise ValueError(
-                f"slotNames has {len(slot_names)} entries for "
-                f"{num_features} features"
+            warm = self.getModelString()
+            prev = Booster.from_string(warm) if warm else None
+            # Warm start: pin sparse extraction to the previous booster's feature
+            # count so its trees never gather past the new batch's explicit width.
+            X, y, w, init = self._prepare(
+                table, num_features=prev.num_features if prev else 0
             )
-        feature_names = list(slot_names) or [f"f{i}" for i in range(num_features)]
+            w = self._adjust_weights(y, w)
+            num_class = self._num_classes(y)
+            opts = self._make_options(num_class)
 
-        # Categorical slot resolution (LightGBMBase.scala:148-156): indexes
-        # union names resolved against the feature slot names.
-        cat_slots = set(self.getCategoricalSlotIndexes() or [])
-        names = self.getCategoricalSlotNames() or []
-        bad = sorted(i for i in cat_slots if not (0 <= i < num_features))
-        if bad:
-            raise ValueError(
-                f"categoricalSlotIndexes out of range for {num_features} "
-                f"features: {bad}"
-            )
-        if names:
-            name_to_idx = {nm: i for i, nm in enumerate(feature_names)}
-            for nm in names:
-                if nm not in name_to_idx:
-                    raise ValueError(
-                        f"categoricalSlotNames: unknown feature name {nm!r}"
-                    )
-                cat_slots.add(name_to_idx[nm])
+            # Feature slot names: slotNames overrides the generated f0..fN
+            # (LightGBMParams slotNames) and is the namespace categorical names
+            # resolve against.
+            num_features = X.shape[1] if hasattr(X, "shape") else X.num_features
+            slot_names = self.getSlotNames() or []
+            if slot_names and len(slot_names) != num_features:
+                raise ValueError(
+                    f"slotNames has {len(slot_names)} entries for "
+                    f"{num_features} features"
+                )
+            feature_names = list(slot_names) or [f"f{i}" for i in range(num_features)]
+
+            # Categorical slot resolution (LightGBMBase.scala:148-156): indexes
+            # union names resolved against the feature slot names.
+            cat_slots = set(self.getCategoricalSlotIndexes() or [])
+            names = self.getCategoricalSlotNames() or []
+            bad = sorted(i for i in cat_slots if not (0 <= i < num_features))
+            if bad:
+                raise ValueError(
+                    f"categoricalSlotIndexes out of range for {num_features} "
+                    f"features: {bad}"
+                )
+            if names:
+                name_to_idx = {nm: i for i, nm in enumerate(feature_names)}
+                for nm in names:
+                    if nm not in name_to_idx:
+                        raise ValueError(
+                            f"categoricalSlotNames: unknown feature name {nm!r}"
+                        )
+                    cat_slots.add(name_to_idx[nm])
+        root.tags.update(rows=int(len(y)), features=int(num_features))
 
         bins, mapper = self._bin_dataset(X, opts, cat_slots)
         valid_sets = []
@@ -513,39 +546,42 @@ class LightGBMBase(LightGBMParams, Estimator):
                 valid_sets=valid_sets, mapper=mapper, mesh=mesh,
                 feature_names=feature_names, callbacks=self.callbacks,
             )
-        model = self._make_model(result)
-        model.parent = self
-        # per-iteration metric histories (valid sets + 'training' when
-        # isProvideTrainingMetric) — transient, like the reference's
-        # delegate-observed metrics
-        model._train_evals = result.evals
-        from mmlspark_tpu.observability.events import ModelCommitted, get_bus
+        with tracer.span(
+            "lightgbm.pack", trees=int(result.booster.num_trees)
+        ):
+            model = self._make_model(result)
+            model.parent = self
+            # per-iteration metric histories (valid sets + 'training' when
+            # isProvideTrainingMetric) — transient, like the reference's
+            # delegate-observed metrics
+            model._train_evals = result.evals
+            from mmlspark_tpu.observability.events import ModelCommitted, get_bus
 
-        # durable model commit: atomic-rename versioned write under the
-        # checkpoint root, so a warm-restarting server's recovery scan
-        # (ModelStore.latest) never observes a torn model file
-        version = None
-        from mmlspark_tpu.runtime.journal import ModelStore, default_checkpoint_dir
+            # durable model commit: atomic-rename versioned write under the
+            # checkpoint root, so a warm-restarting server's recovery scan
+            # (ModelStore.latest) never observes a torn model file
+            version = None
+            from mmlspark_tpu.runtime.journal import ModelStore, default_checkpoint_dir
 
-        ckpt_root = default_checkpoint_dir()
-        if ckpt_root is not None:
-            import os
+            ckpt_root = default_checkpoint_dir()
+            if ckpt_root is not None:
+                import os
 
-            store = ModelStore(os.path.join(ckpt_root, "models"))
-            version = store.commit(
-                model.get_model_string(), name=type(model).__name__.lower()
-            )
-        bus = get_bus()
-        if bus.active:
-            detail = (
-                f"{result.booster.num_trees} trees"
-                if getattr(result, "booster", None) is not None else ""
-            )
-            if version is not None:
-                detail = f"{detail} v{version}".strip()
-            bus.publish(ModelCommitted(
-                model=type(model).__name__, detail=detail,
-            ))
+                store = ModelStore(os.path.join(ckpt_root, "models"))
+                version = store.commit(
+                    model.get_model_string(), name=type(model).__name__.lower()
+                )
+            bus = get_bus()
+            if bus.active:
+                detail = (
+                    f"{result.booster.num_trees} trees"
+                    if getattr(result, "booster", None) is not None else ""
+                )
+                if version is not None:
+                    detail = f"{detail} v{version}".strip()
+                bus.publish(ModelCommitted(
+                    model=type(model).__name__, detail=detail,
+                ))
         return model
 
     def _fit_process_group(

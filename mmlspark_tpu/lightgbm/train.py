@@ -49,6 +49,7 @@ from jax import lax
 from mmlspark_tpu.core.device import on_tpu
 from mmlspark_tpu.lightgbm.binning import BinMapper
 from mmlspark_tpu.observability.profiler import get_profiler
+from mmlspark_tpu.observability.tracing import get_tracer
 from mmlspark_tpu.lightgbm.booster import Booster
 from mmlspark_tpu.lightgbm.objectives import (
     METRICS,
@@ -232,6 +233,7 @@ def _cat_static_maps(
     return cat_idx, is_cat, inv, onehot
 
 
+@jax.named_scope("split_search")
 def _split_search(
     hist: jax.Array,  # (k, F, B, 3)
     totals: jax.Array,  # (k, 3) exact per-node [sum_g, sum_h, count]
@@ -717,19 +719,20 @@ def _build_tree_depthwise(
         # space (histograms are expanded before the search); under bundling
         # the row's value gathers from the feature's packed column and
         # decodes back to an original bin before the compare.
-        row_f = feat_lv[-1][local]
-        row_b = bin_lv[-1][local]
-        row_c = rconsts[0][row_f] if rconsts is not None else row_f
-        x_bin = jnp.take_along_axis(bins, row_c[:, None], axis=1)[:, 0]
-        if rconsts is not None:
-            x_bin = _orig_bins(x_bin, row_f, rconsts)
-        go_right = x_bin > row_b
-        if has_cat:
-            ic = iscat_lv[-1][local]
-            cm = catmask_lv[-1].reshape(-1)[local * b + x_bin.astype(jnp.int32)]
-            go_right = jnp.where(ic, ~cm, go_right)
-        go_right = go_right.astype(jnp.int32)
-        node = 2 * node + 1 + go_right
+        with jax.named_scope("route"):
+            row_f = feat_lv[-1][local]
+            row_b = bin_lv[-1][local]
+            row_c = rconsts[0][row_f] if rconsts is not None else row_f
+            x_bin = jnp.take_along_axis(bins, row_c[:, None], axis=1)[:, 0]
+            if rconsts is not None:
+                x_bin = _orig_bins(x_bin, row_f, rconsts)
+            go_right = x_bin > row_b
+            if has_cat:
+                ic = iscat_lv[-1][local]
+                cm = catmask_lv[-1].reshape(-1)[local * b + x_bin.astype(jnp.int32)]
+                go_right = jnp.where(ic, ~cm, go_right)
+            go_right = go_right.astype(jnp.int32)
+            node = 2 * node + 1 + go_right
 
         inherited = jnp.stack(
             [
@@ -1009,52 +1012,53 @@ def _build_tree_leafwise(
         # 2j + went_right without), k·(invalid) elsewhere — the panel
         # histogram drops out-of-range keys, so the key IS the in-leaf mask
         # and grad/hess need no masking pass.
-        node = st["node"]
-        new_node = node
-        key = jnp.full(n, 2 * k, jnp.int32)
-        in_set = None
-        if u_cat is not None:
-            # Categorical membership for ALL k leaves as one MXU matmul
-            # against the CATEGORICAL rows of the fit-resident one-hot U
-            # (streams ~Σ cat widths per pass, not K_pad); the per-leaf
-            # gather fallback below serves the no-U paths (mesh, CPU).
-            from mmlspark_tpu.ops.u_histogram import membership_matmul
+        with jax.named_scope("route"):
+            node = st["node"]
+            new_node = node
+            key = jnp.full(n, 2 * k, jnp.int32)
+            in_set = None
+            if u_cat is not None:
+                # Categorical membership for ALL k leaves as one MXU matmul
+                # against the CATEGORICAL rows of the fit-resident one-hot U
+                # (streams ~Σ cat widths per pass, not K_pad); the per-leaf
+                # gather fallback below serves the no-U paths (mesh, CPU).
+                from mmlspark_tpu.ops.u_histogram import membership_matmul
 
-            in_set = membership_matmul(u_cat, fr_dev, lrow_dev, sf, scm, n)
-        # One (N, k) gather for all k split columns — k separate lane-axis
-        # dynamic slices each paid their own relayout (measured ~2 ms/tree
-        # at k=16); jnp.take batches them into a single op. Under bundling
-        # the gather targets the packed columns and decodes to original
-        # bins for the whole (N, k) block at once.
-        if rconsts is not None:
-            cols = jnp.take(bins, rconsts[0][sf], axis=1)  # (N, k) packed
-            cols = _orig_bins(cols, sf, rconsts)
-        else:
-            cols = jnp.take(bins, sf, axis=1)  # (N, k)
-        for jj in range(k):
-            colj = cols[:, jj]
-            in_j = (node == top_l[jj]) & can[jj]
-            right_j = colj > sb[jj]
-            if has_cat:
-                # categorical: LEFT iff the row's bin is in the split set
-                right_j = jnp.where(
-                    sic[jj],
-                    ~in_set[jj]
-                    if in_set is not None
-                    else ~scm[jj][colj.astype(jnp.int32)],
-                    right_j,
-                )
-            new_node = jnp.where(
-                in_j, jnp.where(right_j, rslot[jj], lslot[jj]), new_node
-            )
-            if use_sub:
-                # key rows landing in the SMALLER child (right when
-                # small_r, else left) — the built child of split jj
-                key = jnp.where(
-                    in_j & (right_j == small_r[jj]), jj, key
-                )
+                in_set = membership_matmul(u_cat, fr_dev, lrow_dev, sf, scm, n)
+            # One (N, k) gather for all k split columns — k separate lane-axis
+            # dynamic slices each paid their own relayout (measured ~2 ms/tree
+            # at k=16); jnp.take batches them into a single op. Under bundling
+            # the gather targets the packed columns and decodes to original
+            # bins for the whole (N, k) block at once.
+            if rconsts is not None:
+                cols = jnp.take(bins, rconsts[0][sf], axis=1)  # (N, k) packed
+                cols = _orig_bins(cols, sf, rconsts)
             else:
-                key = jnp.where(in_j, 2 * jj + right_j.astype(jnp.int32), key)
+                cols = jnp.take(bins, sf, axis=1)  # (N, k)
+            for jj in range(k):
+                colj = cols[:, jj]
+                in_j = (node == top_l[jj]) & can[jj]
+                right_j = colj > sb[jj]
+                if has_cat:
+                    # categorical: LEFT iff the row's bin is in the split set
+                    right_j = jnp.where(
+                        sic[jj],
+                        ~in_set[jj]
+                        if in_set is not None
+                        else ~scm[jj][colj.astype(jnp.int32)],
+                        right_j,
+                    )
+                new_node = jnp.where(
+                    in_j, jnp.where(right_j, rslot[jj], lslot[jj]), new_node
+                )
+                if use_sub:
+                    # key rows landing in the SMALLER child (right when
+                    # small_r, else left) — the built child of split jj
+                    key = jnp.where(
+                        in_j & (right_j == small_r[jj]), jj, key
+                    )
+                else:
+                    key = jnp.where(in_j, 2 * jj + right_j.astype(jnp.int32), key)
 
         if use_sub:
             # Build the smaller child in PACKED space, derive the sibling
@@ -1247,7 +1251,8 @@ def _make_step(
     }
 
     def step(bins, y, w, margins, edges, bag_mask, feature_mask, it, lr=None, u=None):
-        grad, hess = objective.grad_hess(margins, y, w, **obj_kwargs)  # (N, C)
+        with jax.named_scope("grad_hess"):
+            grad, hess = objective.grad_hess(margins, y, w, **obj_kwargs)  # (N, C)
 
         if opts.boosting_type == "goss":
             # Gradient-based One-Side Sampling: keep the top_rate fraction of
@@ -1353,8 +1358,9 @@ def _make_step(
             # the final booster's leaf values are averaged post-hoc.
             return tree, margins
         # margins update: row_leaf (C, N) slots into leaf_val (C, M)
-        contrib = jnp.take_along_axis(tree.leaf_val, tree.row_leaf, axis=1).T  # (N, C)
-        return tree, margins + contrib
+        with jax.named_scope("margin_update"):
+            contrib = jnp.take_along_axis(tree.leaf_val, tree.row_leaf, axis=1).T  # (N, C)
+            return tree, margins + contrib
 
     return step
 
@@ -1735,353 +1741,300 @@ def train(
         init_score = np.zeros(num_classes, dtype=np.float32)
         margins0 = np.asarray(init_margins, dtype=np.float32).reshape(n, num_classes)
 
-    # Device placement; shard rows over the mesh data axis when given.
-    # Rows are padded to a multiple of the data-axis size; padding rides along
-    # with zero weight/count so it never influences histograms or stats — the
-    # "empty partition sends ignore" analogue (LightGBMUtils.scala:144-161).
-    pad = 0
-    sh_bins = None
-    if mesh is not None:
-        from mmlspark_tpu.parallel.mesh import (
-            AXIS_MODEL,
-            data_sharding,
-            feature_parallel_sharding,
-            pad_to_multiple,
-            replicated,
-        )
+    tracer = get_tracer()
+    # ``lightgbm.upload`` is the host's share of placement: padding, casts
+    # and the hand-off of each host array. The transfers themselves run on
+    # (a 28 MB bins matrix: 15 ms here, 0.29 s on the wire, measured on a
+    # v5e, PERF.md) and are waited for by the first program that reads
+    # them, inside ``lightgbm.boost``.
+    with tracer.span("lightgbm.upload") as up_span:
+        # Device placement; shard rows over the mesh data axis when given.
+        # Rows are padded to a multiple of the data-axis size; padding rides along
+        # with zero weight/count so it never influences histograms or stats — the
+        # "empty partition sends ignore" analogue (LightGBMUtils.scala:144-161).
+        pad = 0
+        sh_bins = None
+        up_bytes = 0
 
-        shard_n = int(mesh.shape["data"])
-        padded_n, pad = pad_to_multiple(n, shard_n)
+        def host(a):
+            """A host array on its way to the device, counted for the tag."""
+            nonlocal up_bytes
+            up_bytes += a.nbytes
+            return a
+
+        if mesh is not None:
+            from mmlspark_tpu.parallel.mesh import (
+                AXIS_MODEL,
+                data_sharding,
+                feature_parallel_sharding,
+                pad_to_multiple,
+                replicated,
+            )
+
+            shard_n = int(mesh.shape["data"])
+            padded_n, pad = pad_to_multiple(n, shard_n)
+            if pad:
+                bins = np.concatenate([bins, np.zeros((pad, f), dtype=bins.dtype)])
+                y_np = np.concatenate([y_np, np.zeros(pad, dtype=np.float32)])
+                w = np.concatenate([w, np.zeros(pad, dtype=np.float32)])
+                margins0 = np.concatenate(
+                    [margins0, np.zeros((pad, num_classes), dtype=margins0.dtype)]
+                )
+            sh_rows = data_sharding(mesh)
+            sh_rep = replicated(mesh)
+            model_size = int(mesh.shape.get(AXIS_MODEL, 1))
+            if model_size > 1 and f % model_size == 0 and bundle is None:
+                # feature parallel: bins vertically partitioned over the model
+                # axis (LightGBM's feature_parallel layout); XLA partitions the
+                # histogram build/split search and inserts the best-split
+                # argmax collectives across model shards itself. (Indivisible
+                # feature counts stay row-sharded/replicated over model.)
+                sh_bins = feature_parallel_sharding(mesh)
+            put_rows = lambda a: jax.device_put(a, sh_rows)
+            put_rep = lambda a: jax.device_put(a, sh_rep)
+        else:
+            put_rows = put_rep = jnp.asarray
+        presence = np.ones(n + pad, dtype=np.float32)
         if pad:
-            bins = np.concatenate([bins, np.zeros((pad, f), dtype=bins.dtype)])
-            y_np = np.concatenate([y_np, np.zeros(pad, dtype=np.float32)])
-            w = np.concatenate([w, np.zeros(pad, dtype=np.float32)])
-            margins0 = np.concatenate(
-                [margins0, np.zeros((pad, num_classes), dtype=margins0.dtype)]
-            )
-        sh_rows = data_sharding(mesh)
-        sh_rep = replicated(mesh)
-        model_size = int(mesh.shape.get(AXIS_MODEL, 1))
-        if model_size > 1 and f % model_size == 0 and bundle is None:
-            # feature parallel: bins vertically partitioned over the model
-            # axis (LightGBM's feature_parallel layout); XLA partitions the
-            # histogram build/split search and inserts the best-split
-            # argmax collectives across model shards itself. (Indivisible
-            # feature counts stay row-sharded/replicated over model.)
-            sh_bins = feature_parallel_sharding(mesh)
-        put_rows = lambda a: jax.device_put(a, sh_rows)
-        put_rep = lambda a: jax.device_put(a, sh_rep)
-    else:
-        put_rows = put_rep = jnp.asarray
-    presence = np.ones(n + pad, dtype=np.float32)
-    if pad:
-        presence[n:] = 0.0
+            presence[n:] = 0.0
 
-    if mapper is not None:
-        edges = np.where(np.isfinite(mapper.edges), mapper.edges, np.float32(np.finfo(np.float32).max))
-    else:
-        edges = np.zeros((f, 1))
-    edges_dev = put_rep(edges.astype(np.float32))
+        if mapper is not None:
+            edges = np.where(np.isfinite(mapper.edges), mapper.edges, np.float32(np.finfo(np.float32).max))
+        else:
+            edges = np.zeros((f, 1))
+        edges_dev = put_rep(host(edges.astype(np.float32)))
 
-    def dev_rows(a):
-        """Re-shard a device-created array onto the row sharding (device-to-
-        device; no host wire traffic)."""
-        return jax.device_put(a, sh_rows) if mesh is not None else a
+        def dev_rows(a):
+            """Re-shard a device-created array onto the row sharding (device-to-
+            device; no host wire traffic)."""
+            return jax.device_put(a, sh_rows) if mesh is not None else a
 
-    # Ship bins as uint8 when they fit (4x less host->device traffic);
-    # consumers compare/gather fine on uint8 and the histogram kernels
-    # upcast per-tile. Device-RESIDENT bins (bin_dataset_to_device's
-    # overlapped streaming upload) skip the put entirely.
-    put_bins = (lambda a: jax.device_put(a, sh_bins)) if sh_bins is not None else put_rows
-    if isinstance(bins, jax.Array) and mesh is None:
-        bins_dev = bins
-    elif num_bins <= 256:
-        # uint8 inputs (incl. out-of-core memmaps) upload as-is — no host
-        # copy; device_put streams straight from the mapping
-        b8 = np.asarray(bins) if not isinstance(bins, np.ndarray) else bins
-        b8 = b8 if b8.dtype == np.uint8 else b8.astype(np.uint8)
-        bins_dev = put_bins(np.ascontiguousarray(b8))
-    else:
-        bins_dev = put_bins(np.asarray(bins, dtype=np.int32))
-    # Integer-valued labels (binary/multiclass/count targets) ride the wire
-    # as uint8 and upcast on device — 4x less of the per-fit transfer cost.
-    if y_np.size and np.all(np.mod(y_np, 1) == 0) and np.all((y_np >= 0) & (y_np <= 255)):
-        y_dev = put_rows(y_np.astype(np.uint8)).astype(jnp.float32)
-    else:
-        y_dev = put_rows(y_np)
-    # Constant-valued operands are created ON device instead of uploaded.
-    if w_is_default:
-        w_dev = dev_rows(jnp.ones(n + pad, jnp.float32))
-    else:
-        w_dev = put_rows(w)
-    if init_margins is None:
-        margins = dev_rows(
-            jnp.asarray(init_score, dtype=jnp.float32)[None, :]
-            * jnp.ones((n + pad, 1), jnp.float32)
-        )
-    else:
-        margins = put_rows(margins0.astype(np.float32))
-
-    # U histogram path (ops/u_histogram.py): single-device fits whose packed
-    # one-hot fits the HBM budget contract each pass against a fit-resident
-    # U instead of rebuilding the one-hot (measured 2.1x/pass on v5e).
-    # histogram_method='u' forces it (tests exercise it on CPU); the env
-    # knobs kill it or resize the budget without code changes.
-    import os as _os
-
-    u_spec = None
-    u_budget = 0  # the in-force U HBM budget; the OOM ladder halves it
-    if (
-        mesh is None
-        and opts.tree_learner != "voting_parallel"
-        and num_bins <= 256
-        and _os.environ.get("MMLSPARK_TPU_NO_U") != "1"
-        and (
-            opts.histogram_method == "u"
-            or (
-                opts.histogram_method in (None, "pallas")
-                and on_tpu()
-            )
-        )
-    ):
-        from mmlspark_tpu.ops.u_histogram import (
-            chunked_u_spec,
-            make_u_spec,
-            num_u_chunks,
-            u_bytes,
-        )
-
-        if bundle is not None:
-            # U laid out over the PACKED columns — K = Σ bundle widths is
-            # the whole point: fewer one-hot rows to re-stream per pass.
-            cand = make_u_spec(
-                bundle.num_bins, f, [int(wd) for wd in bundle.widths]
+        # Ship bins as uint8 when they fit (4x less host->device traffic);
+        # consumers compare/gather fine on uint8 and the histogram kernels
+        # upcast per-tile. Device-RESIDENT bins (bin_dataset_to_device's
+        # overlapped streaming upload) skip the put entirely.
+        put_bins = (lambda a: jax.device_put(a, sh_bins)) if sh_bins is not None else put_rows
+        if isinstance(bins, jax.Array) and mesh is None:
+            bins_dev = bins
+        elif num_bins <= 256:
+            # uint8 inputs (incl. out-of-core memmaps) upload as-is — no host
+            # copy; device_put streams straight from the mapping
+            b8 = np.asarray(bins) if not isinstance(bins, np.ndarray) else bins
+            b8 = b8 if b8.dtype == np.uint8 else b8.astype(np.uint8)
+            bins_dev = put_bins(host(np.ascontiguousarray(b8)))
+        else:
+            bins_dev = put_bins(host(np.asarray(bins, dtype=np.int32)))
+        # Integer-valued labels (binary/multiclass/count targets) ride the wire
+        # as uint8 and upcast on device — 4x less of the per-fit transfer cost.
+        if y_np.size and np.all(np.mod(y_np, 1) == 0) and np.all((y_np >= 0) & (y_np <= 255)):
+            y_dev = put_rows(host(y_np.astype(np.uint8))).astype(jnp.float32)
+        else:
+            y_dev = put_rows(host(y_np))
+        # Constant-valued operands are created ON device instead of uploaded.
+        if w_is_default:
+            w_dev = dev_rows(jnp.ones(n + pad, jnp.float32))
+        else:
+            w_dev = put_rows(host(w))
+        if init_margins is None:
+            margins = dev_rows(
+                jnp.asarray(init_score, dtype=jnp.float32)[None, :]
+                * jnp.ones((n + pad, 1), jnp.float32)
             )
         else:
-            per_feature = None if mapper is None else [int(x) for x in mapper.num_bins]
-            cand = make_u_spec(num_bins, f, per_feature)
-        try:
-            budget = int(_os.environ.get("MMLSPARK_TPU_U_BUDGET", str(8 << 30)))
-        except ValueError:
+            margins = put_rows(host(margins0.astype(np.float32)))
+        up_span.tags["bytes"] = up_bytes
+
+    with tracer.span("lightgbm.program") as prog_span:
+        # U histogram path (ops/u_histogram.py): single-device fits whose packed
+        # one-hot fits the HBM budget contract each pass against a fit-resident
+        # U instead of rebuilding the one-hot (measured 2.1x/pass on v5e).
+        # histogram_method='u' forces it (tests exercise it on CPU); the env
+        # knobs kill it or resize the budget without code changes.
+        import os as _os
+
+        u_spec = None
+        u_budget = 0  # the in-force U HBM budget; the OOM ladder halves it
+        if (
+            mesh is None
+            and opts.tree_learner != "voting_parallel"
+            and num_bins <= 256
+            and _os.environ.get("MMLSPARK_TPU_NO_U") != "1"
+            and (
+                opts.histogram_method == "u"
+                or (
+                    opts.histogram_method in (None, "pallas")
+                    and on_tpu()
+                )
+            )
+        ):
+            from mmlspark_tpu.ops.u_histogram import (
+                chunked_u_spec,
+                make_u_spec,
+                num_u_chunks,
+                u_bytes,
+            )
+
+            if bundle is not None:
+                # U laid out over the PACKED columns — K = Σ bundle widths is
+                # the whole point: fewer one-hot rows to re-stream per pass.
+                cand = make_u_spec(
+                    bundle.num_bins, f, [int(wd) for wd in bundle.widths]
+                )
+            else:
+                per_feature = None if mapper is None else [int(x) for x in mapper.num_bins]
+                cand = make_u_spec(num_bins, f, per_feature)
+            try:
+                budget = int(_os.environ.get("MMLSPARK_TPU_U_BUDGET", str(8 << 30)))
+            except ValueError:
+                from mmlspark_tpu.core.profiling import get_logger
+
+                get_logger("mmlspark_tpu.lightgbm").warning(
+                    "MMLSPARK_TPU_U_BUDGET=%r is not an integer byte count; "
+                    "using the default 8 GB budget",
+                    _os.environ["MMLSPARK_TPU_U_BUDGET"],
+                )
+                budget = 8 << 30
+            if u_bytes(n + pad, cand) > budget:
+                # Over budget: stream the pass in row chunks instead of
+                # abandoning the MXU path wholesale (the pre-chunking behavior
+                # was an all-or-nothing cliff: one row past the budget and the
+                # whole fit fell back to the compare-built kernels).
+                cand = chunked_u_spec(n + pad, cand, budget)
+            u_spec = cand
+            u_budget = budget
+            if u_spec.chunk_rows:
+                chunks = num_u_chunks(n + pad, u_spec)
+                from mmlspark_tpu.core.profiling import get_logger
+
+                get_logger("mmlspark_tpu.lightgbm").info(
+                    "U one-hot (%.1f GB) exceeds MMLSPARK_TPU_U_BUDGET (%.1f GB);"
+                    " streaming each histogram pass in %d row chunks of %d",
+                    u_bytes(n + pad, dataclasses.replace(u_spec, chunk_rows=0))
+                    / 1e9,
+                    budget / 1e9, chunks, u_spec.chunk_rows,
+                )
+                from mmlspark_tpu.observability.events import (
+                    HistogramChunked,
+                    get_bus,
+                )
+
+                bus = get_bus()
+                if bus.active:
+                    from mmlspark_tpu.ops.u_histogram import histogram_acc_dtype
+
+                    # quant may still fall back below (row cap); mirror that
+                    # predicate so the event records the dtype actually used
+                    _ck_quant = opts.use_quantized_grad and (
+                        n + pad <= min((1 << 31) // 127, 1 << 24)
+                    )
+                    _ck_dt = jnp.dtype(histogram_acc_dtype(n + pad, _ck_quant))
+                    _ck_3k = 3 * max(1, min(opts.leaf_batch, opts.num_leaves - 1))
+                    bus.publish(HistogramChunked(
+                        rows=n + pad, k_packed=u_spec.k_pad,
+                        chunk_rows=u_spec.chunk_rows, num_chunks=chunks,
+                        budget_bytes=budget,
+                        acc_dtype=_ck_dt.name,
+                        bytes_saved=u_spec.k_pad * _ck_3k
+                        * (4 - _ck_dt.itemsize),
+                    ))
+
+        if opts.use_quantized_grad:
+            reason = None
+            if u_spec is None:
+                reason = (
+                    "the precomputed-U histogram path is inactive (non-TPU "
+                    "backend without histogram_method='u', mesh/voting "
+                    "parallelism, num_bins > 256, or U over the HBM budget)"
+                )
+            elif n + pad > min((1 << 31) // 127, 1 << 24):
+                # Two ceilings, enforce the tighter (2^24): s8 x s8 sums
+                # accumulate in int32 (|sum| <= 127 * rows wraps past
+                # 2^31/127 ~= 16.9M rows), and the f32 count channel loses
+                # integer exactness above 2^24 — the "counts stay exact"
+                # contract in _split_search holds only below it.
+                reason = (
+                    f"{n + pad} rows exceeds the quantized-path cap "
+                    "min(2^31/127, 2^24) = 2^24 (f32 count exactness / int32 "
+                    "histogram accumulator)"
+                )
+            if reason is not None:
+                from mmlspark_tpu.core.profiling import get_logger
+
+                get_logger("mmlspark_tpu.lightgbm").warning(
+                    "use_quantized_grad requested but %s; training with exact "
+                    "bf16 stats instead", reason,
+                )
+                opts = dataclasses.replace(opts, use_quantized_grad=False)
+
+        if (
+            opts.use_quantized_grad
+            and u_spec is not None
+            and opts.growth == "depthwise"
+            and opts.depth >= 7
+        ):
+            # The U panel packs 3 stat planes per frontier node into 128
+            # slots, so levels with > 42 nodes (2^6 = 64 at level 6, reached
+            # once depth >= 7) can't ride the quantized U kernel; _hist_fn
+            # drops those levels to the exact histogram path. Surface the
+            # per-level degrade once per fit instead of silently.
             from mmlspark_tpu.core.profiling import get_logger
 
             get_logger("mmlspark_tpu.lightgbm").warning(
-                "MMLSPARK_TPU_U_BUDGET=%r is not an integer byte count; "
-                "using the default 8 GB budget",
-                _os.environ["MMLSPARK_TPU_U_BUDGET"],
+                "use_quantized_grad with depthwise growth and depth %d: levels "
+                "deeper than 5 have > 42 frontier nodes and exceed the 128-slot "
+                "U panel budget (3 stats x nodes), so those levels fall back to "
+                "exact (non-quantized) histograms per level",
+                opts.depth,
             )
-            budget = 8 << 30
-        if u_bytes(n + pad, cand) > budget:
-            # Over budget: stream the pass in row chunks instead of
-            # abandoning the MXU path wholesale (the pre-chunking behavior
-            # was an all-or-nothing cliff: one row past the budget and the
-            # whole fit fell back to the compare-built kernels).
-            cand = chunked_u_spec(n + pad, cand, budget)
-        u_spec = cand
-        u_budget = budget
-        if u_spec.chunk_rows:
-            chunks = num_u_chunks(n + pad, u_spec)
-            from mmlspark_tpu.core.profiling import get_logger
 
-            get_logger("mmlspark_tpu.lightgbm").info(
-                "U one-hot (%.1f GB) exceeds MMLSPARK_TPU_U_BUDGET (%.1f GB);"
-                " streaming each histogram pass in %d row chunks of %d",
-                u_bytes(n + pad, dataclasses.replace(u_spec, chunk_rows=0))
-                / 1e9,
-                budget / 1e9, chunks, u_spec.chunk_rows,
-            )
+        if opts.growth == "leafwise" and opts.histogram_subtraction:
+            # Mirror _build_tree_leafwise's use_sub gate so the event reports
+            # the path the trace will actually take (static predicate).
             from mmlspark_tpu.observability.events import (
-                HistogramChunked,
+                HistogramSubtracted,
                 get_bus,
             )
+            from mmlspark_tpu.ops.u_histogram import histogram_acc_dtype
 
+            _sb_cols = len(bundle.widths) if bundle is not None else f
+            _sb_bins = bundle.num_bins if bundle is not None else num_bins
+            _sb_quant = opts.use_quantized_grad and u_spec is not None
+            _sb_dt = jnp.dtype(histogram_acc_dtype(n + pad, _sb_quant))
+            _sb_m = 2 * opts.num_leaves - 1
+            _sb_cache = (
+                max(1, opts.num_class) * _sb_m * _sb_cols * _sb_bins * 3
+                * _sb_dt.itemsize
+            )
             bus = get_bus()
-            if bus.active:
-                from mmlspark_tpu.ops.u_histogram import histogram_acc_dtype
-
-                # quant may still fall back below (row cap); mirror that
-                # predicate so the event records the dtype actually used
-                _ck_quant = opts.use_quantized_grad and (
-                    n + pad <= min((1 << 31) // 127, 1 << 24)
-                )
-                _ck_dt = jnp.dtype(histogram_acc_dtype(n + pad, _ck_quant))
-                _ck_3k = 3 * max(1, min(opts.leaf_batch, opts.num_leaves - 1))
-                bus.publish(HistogramChunked(
-                    rows=n + pad, k_packed=u_spec.k_pad,
-                    chunk_rows=u_spec.chunk_rows, num_chunks=chunks,
-                    budget_bytes=budget,
-                    acc_dtype=_ck_dt.name,
-                    bytes_saved=u_spec.k_pad * _ck_3k
-                    * (4 - _ck_dt.itemsize),
+            if (
+                bus.active
+                and _sb_cache <= (256 << 20)
+                and opts.tree_learner != "voting_parallel"
+            ):
+                bus.publish(HistogramSubtracted(
+                    rows=n + pad, num_leaves=opts.num_leaves,
+                    packed_columns=_sb_cols, packed_bins=_sb_bins,
+                    acc_dtype=_sb_dt.name, cache_bytes=_sb_cache,
+                    bytes_saved_per_tree=(opts.num_leaves - 1) * _sb_cols
+                    * _sb_bins * 3 * _sb_dt.itemsize,
                 ))
 
-    if opts.use_quantized_grad:
-        reason = None
-        if u_spec is None:
-            reason = (
-                "the precomputed-U histogram path is inactive (non-TPU "
-                "backend without histogram_method='u', mesh/voting "
-                "parallelism, num_bins > 256, or U over the HBM budget)"
-            )
-        elif n + pad > min((1 << 31) // 127, 1 << 24):
-            # Two ceilings, enforce the tighter (2^24): s8 x s8 sums
-            # accumulate in int32 (|sum| <= 127 * rows wraps past
-            # 2^31/127 ~= 16.9M rows), and the f32 count channel loses
-            # integer exactness above 2^24 — the "counts stay exact"
-            # contract in _split_search holds only below it.
-            reason = (
-                f"{n + pad} rows exceeds the quantized-path cap "
-                "min(2^31/127, 2^24) = 2^24 (f32 count exactness / int32 "
-                "histogram accumulator)"
-            )
-        if reason is not None:
-            from mmlspark_tpu.core.profiling import get_logger
-
-            get_logger("mmlspark_tpu.lightgbm").warning(
-                "use_quantized_grad requested but %s; training with exact "
-                "bf16 stats instead", reason,
-            )
-            opts = dataclasses.replace(opts, use_quantized_grad=False)
-
-    if (
-        opts.use_quantized_grad
-        and u_spec is not None
-        and opts.growth == "depthwise"
-        and opts.depth >= 7
-    ):
-        # The U panel packs 3 stat planes per frontier node into 128
-        # slots, so levels with > 42 nodes (2^6 = 64 at level 6, reached
-        # once depth >= 7) can't ride the quantized U kernel; _hist_fn
-        # drops those levels to the exact histogram path. Surface the
-        # per-level degrade once per fit instead of silently.
-        from mmlspark_tpu.core.profiling import get_logger
-
-        get_logger("mmlspark_tpu.lightgbm").warning(
-            "use_quantized_grad with depthwise growth and depth %d: levels "
-            "deeper than 5 have > 42 frontier nodes and exceed the 128-slot "
-            "U panel budget (3 stats x nodes), so those levels fall back to "
-            "exact (non-quantized) histograms per level",
-            opts.depth,
-        )
-
-    if opts.growth == "leafwise" and opts.histogram_subtraction:
-        # Mirror _build_tree_leafwise's use_sub gate so the event reports
-        # the path the trace will actually take (static predicate).
-        from mmlspark_tpu.observability.events import (
-            HistogramSubtracted,
-            get_bus,
-        )
-        from mmlspark_tpu.ops.u_histogram import histogram_acc_dtype
-
-        _sb_cols = len(bundle.widths) if bundle is not None else f
-        _sb_bins = bundle.num_bins if bundle is not None else num_bins
-        _sb_quant = opts.use_quantized_grad and u_spec is not None
-        _sb_dt = jnp.dtype(histogram_acc_dtype(n + pad, _sb_quant))
-        _sb_m = 2 * opts.num_leaves - 1
-        _sb_cache = (
-            max(1, opts.num_class) * _sb_m * _sb_cols * _sb_bins * 3
-            * _sb_dt.itemsize
-        )
-        bus = get_bus()
-        if (
-            bus.active
-            and _sb_cache <= (256 << 20)
-            and opts.tree_learner != "voting_parallel"
-        ):
-            bus.publish(HistogramSubtracted(
-                rows=n + pad, num_leaves=opts.num_leaves,
-                packed_columns=_sb_cols, packed_bins=_sb_bins,
-                acc_dtype=_sb_dt.name, cache_bytes=_sb_cache,
-                bytes_saved_per_tree=(opts.num_leaves - 1) * _sb_cols
-                * _sb_bins * 3 * _sb_dt.itemsize,
-            ))
-
-    okey = (_opts_key(opts), num_bins, mesh, u_spec, bundle, objective.cache_token)
-    if opts.boosting_type == "goss":
-        okey = okey + (n,)  # GOSS bakes the unpadded row count into the program
-    _prof = get_profiler()
-    _prof_on = _prof.active
-    if hist_reduce is not None:
-        # the reduce hook closes over a live socket group — never share a
-        # compiled program holding it across fits. The profiler wrap times
-        # the host-side collective per call, splitting each iteration into
-        # histogram-build (device) vs allreduce (wire) time.
-        if _prof_on:
-            hist_reduce = _prof.wrap_host(hist_reduce, "gbdt.hist_allreduce")
-        step_raw = _make_step(
-            opts, objective, num_bins, mesh, n_real=n, u_spec=u_spec,
-            hist_reduce=hist_reduce, bundle=bundle,
-        )
-        step = jax.jit(step_raw, donate_argnums=(3,))
-    else:
-        step_raw = _cached_program(
-            ("step_raw", okey),
-            lambda: _make_step(
-                opts, objective, num_bins, mesh, n_real=n, u_spec=u_spec,
-                bundle=bundle,
-            ),
-        )
-        step = _cached_program(
-            ("step_jit", okey), lambda: jax.jit(step_raw, donate_argnums=(3,))
-        )
-    u_builder = None
-    if u_spec is not None:
-        if u_spec.chunk_rows:
-            # chunked pass consumes a (num_chunks, F, chunk) bins stack
-            # laid out once per fit, not the resident one-hot
-            from mmlspark_tpu.ops.u_histogram import prepare_chunked_bins
-
-            u_builder = partial(prepare_chunked_bins, spec=u_spec)
-        else:
-            from mmlspark_tpu.ops.u_histogram import build_u
-
-            u_builder = partial(build_u, spec=u_spec)
-    valid_update = _cached_program(
-        ("valid_update", opts.routing_steps, bundle),
-        lambda: _make_valid_update(opts.routing_steps, bundle),
-    )
-
-    # -- RESOURCE_EXHAUSTED degradation ladder (docs/resilience.md) ----------
-    # An HBM OOM during a histogram dispatch is retryable at a reduced
-    # footprint: halve the in-memory U budget (floor 1 MiB), re-derive the
-    # chunked-U spec, rebuild the step program, and re-run the SAME
-    # iteration. Chunked and resident passes are bit-exact, so the final
-    # model text matches an undisturbed run byte for byte. The last rung —
-    # a smaller ``leaf_batch`` — changes split-scheduling and is left to
-    # the caller (it trades reproducibility for survival).
-    from mmlspark_tpu.runtime.faults import (
-        current_faults as _current_faults,
-        is_oom_error as _is_oom,
-    )
-
-    _fault_plan = _current_faults()
-    _oom_retry_cap = 8
-
-    def _degrade_for_oom(err, stage, iteration, retries) -> bool:
-        """Walk one rung down the ladder; True when the caller may retry."""
-        nonlocal u_spec, u_budget, okey, step_raw, step, u_builder
-        if u_spec is None:
-            return False  # no U path active: nothing to shrink in-loop
-        new_budget = max(u_budget // 2, 1 << 20)
-        if new_budget == u_budget and u_spec.chunk_rows:
-            return False  # floor reached; the OOM is genuine scarcity
-        u_budget = new_budget
-        from mmlspark_tpu.ops.u_histogram import (
-            build_u,
-            chunked_u_spec,
-            prepare_chunked_bins,
-        )
-
-        u_spec = chunked_u_spec(
-            n + pad, dataclasses.replace(u_spec, chunk_rows=0), u_budget
-        )
-        okey = (
-            _opts_key(opts), num_bins, mesh, u_spec, bundle,
-            objective.cache_token,
-        )
+        okey = (_opts_key(opts), num_bins, mesh, u_spec, bundle, objective.cache_token)
         if opts.boosting_type == "goss":
-            okey = okey + (n,)
+            okey = okey + (n,)  # GOSS bakes the unpadded row count into the program
+        _prof = get_profiler()
+        _prof_on = _prof.active
+        # whether this fit's step program was already built by an earlier fit
+        prog_span.tags["cache_hit"] = (
+            hist_reduce is None and ("step_jit", okey) in _PROGRAM_CACHE
+        )
         if hist_reduce is not None:
+            # the reduce hook closes over a live socket group — never share a
+            # compiled program holding it across fits. The profiler wrap times
+            # the host-side collective per call, splitting each iteration into
+            # histogram-build (device) vs allreduce (wire) time.
+            if _prof_on:
+                hist_reduce = _prof.wrap_host(hist_reduce, "gbdt.hist_allreduce")
             step_raw = _make_step(
                 opts, objective, num_bins, mesh, n_real=n, u_spec=u_spec,
                 hist_reduce=hist_reduce, bundle=bundle,
@@ -2096,480 +2049,566 @@ def train(
                 ),
             )
             step = _cached_program(
-                ("step_jit", okey),
-                lambda: jax.jit(step_raw, donate_argnums=(3,)),
+                ("step_jit", okey), lambda: jax.jit(step_raw, donate_argnums=(3,))
             )
-        u_builder = (
-            partial(prepare_chunked_bins, spec=u_spec) if u_spec.chunk_rows
-            else partial(build_u, spec=u_spec)
-        )
-        from mmlspark_tpu.core.profiling import get_logger
+        u_builder = None
+        if u_spec is not None:
+            if u_spec.chunk_rows:
+                # chunked pass consumes a (num_chunks, F, chunk) bins stack
+                # laid out once per fit, not the resident one-hot
+                from mmlspark_tpu.ops.u_histogram import prepare_chunked_bins
 
-        get_logger("mmlspark_tpu.lightgbm").warning(
-            "histogram %s dispatch hit RESOURCE_EXHAUSTED at iteration %d "
-            "(%s); degrading: U budget -> %d bytes, chunk_rows -> %d, "
-            "retry %d",
-            stage, iteration, str(err)[:120], u_budget, u_spec.chunk_rows,
-            retries,
-        )
-        from mmlspark_tpu.observability.events import (
-            HistogramDegraded,
-            MemoryPressure,
-            get_bus,
-        )
-
-        bus = get_bus()
-        if bus.active:
-            bus.publish(MemoryPressure(
-                source="device", level="critical", used_bytes=0.0,
-                limit_bytes=0.0, detail=str(err)[:200],
-            ))
-            bus.publish(HistogramDegraded(
-                rows=n + pad, budget_bytes=u_budget,
-                chunk_rows=u_spec.chunk_rows, stage=stage,
-                iteration=int(iteration), retries=int(retries),
-            ))
-        return True
-
-    valid_sets = list(valid_sets or [])
-    valid_state = []
-    for name, bv, yv, wv in valid_sets:
-        wv = np.ones(len(yv), dtype=np.float32) if wv is None else np.asarray(wv, np.float32)
-        mv = np.broadcast_to(init_score[None, :], (len(yv), num_classes)).copy()
-        valid_state.append(
-            {
-                "name": name,
-                "bins": jnp.asarray(np.asarray(bv, dtype=np.int32)),
-                "y": np.asarray(yv, dtype=np.float32),
-                "w": wv,
-                "margins": jnp.asarray(mv.astype(np.float32)),
-            }
-        )
-
-    metric = opts.metric or objective.default_metric
-    higher_better = metric_higher_is_better(metric)
-    evals: Dict[str, Dict[str, List[float]]] = {
-        vs["name"]: {metric: []} for vs in valid_state
-    }
-    if opts.provide_training_metric:
-        evals["training"] = {metric: []}
-
-    rng = np.random.default_rng(opts.seed)
-    num_bag = max(1, int(round(n * opts.bagging_fraction)))
-    num_feat = max(1, int(round(f_feat * opts.feature_fraction)))
-
-    from mmlspark_tpu.lightgbm.callbacks import (
-        CallbackEnv,
-        _has_iteration_hooks,
-        _lr_schedule,
-    )
-
-    callbacks = list(callbacks or [])
-    lr_all = _lr_schedule(callbacks, opts.learning_rate, opts.num_iterations)
-    iteration_hooks = _has_iteration_hooks(callbacks)
-
-    def _cb_env(it: int) -> "CallbackEnv":
-        lr_it = float(lr_all[it]) if (lr_all is not None and it < len(lr_all)) \
-            else opts.learning_rate
-        return CallbackEnv(
-            iteration=it, num_iterations=opts.num_iterations,
-            learning_rate=lr_it, evals=evals,
-        )
-
-    for cb in callbacks:
-        cb.before_training(_cb_env(0))
-
-    trees: List[TreeArrays] = []
-    best_score = -np.inf if higher_better else np.inf
-    best_iter = 0
-    stale = 0
-
-    # Device-resident inputs are uploaded once and only re-uploaded when
-    # bagging/feature-fraction actually resamples, and per-tree outputs stay
-    # on device until one bulk fetch after the loop, so an iteration costs
-    # no host<->device round-trip of its own.
-    # presence mask built on device (zeroed pad tail) — no upload
-    bag_dev = dev_rows(
-        jnp.ones(n + pad, jnp.float32)
-        if pad == 0
-        else jnp.ones(n + pad, jnp.float32).at[n:].set(0.0)
-    )
-    fm_ones_dev = put_rep(np.ones(f_feat, dtype=np.float32))
-
-    # Fast path: no per-iteration host decisions (no valid-set metrics, no
-    # mesh special-casing) — run every boosting iteration in ONE device
-    # program via lax.scan. Per-iteration masks come from the same
-    # _mask_schedule as the loop path, so semantics (bagging schedule,
-    # feature sampling, rng stream order) are identical.
-    stacked_trees = None
-    schedule = _mask_schedule(
-        opts, rng, n, pad, num_bag, num_feat, f_feat, presence, y=y_np
-    )
-    bag_resampling = _bagging_active(opts)
-    # The scan path materializes an (iterations, N) uint8 bagging-mask array
-    # on device when bagging resamples; gate it so a huge fit (e.g. 10M rows
-    # x 1000 iters = 10 GB) falls back to the loop path, which re-uploads
-    # only on resample.
-    bag_stack_ok = (
-        not bag_resampling or opts.num_iterations * (n + pad) <= (512 << 20)
-    )
-    if (
-        mesh is None
-        and not valid_state
-        and not iteration_hooks  # per-iteration delegates need the loop path
-        and bag_stack_ok
-        and opts.num_iterations > 0
-        and opts.boosting_type != "dart"  # dart drops trees per host decision
-        and not opts.provide_training_metric  # needs per-iteration margins
-        and hist_reduce is None  # process fits need per-iteration control
-        and iteration_hook is None
-        and start_iteration == 0
-    ):
-        bag_list, fm_list = [], []
-        for bag_np, _, fm_np in schedule:
-            bag_list.append(bag_np)
-            fm_list.append(fm_np if fm_np is not None else np.ones(f_feat, np.float32))
-        if bag_resampling:
-            # uint8 on the wire (masks are 0/1; 4x less than f32); cast per
-            # scan step
-            bag_arg = jnp.asarray(np.stack(bag_list).astype(np.uint8))
-        else:
-            bag_arg = bag_dev  # (N,) closed over inside the program
-        fm_all = jnp.asarray(np.stack(fm_list))
-        per_iter_lr = lr_all is not None
-        lr_arg = jnp.asarray(lr_all) if per_iter_lr else fm_all  # unused placeholder
-        runner = _cached_program(
-            ("scan", okey, bag_resampling, per_iter_lr),
-            lambda: _make_scan_steps(
-                step_raw, per_iter_bag=bag_resampling, per_iter_lr=per_iter_lr,
-                with_u=u_builder is not None,
-            ),
-        )
-        # fit-resident U: built ONCE here, shared by every segment below
-        u_dev_scan = jnp.int32(0)  # unused placeholder when no U path
-        if u_builder is not None:
-            u_jit = _cached_program(
-                ("u_build_jit", u_spec), lambda: jax.jit(u_builder)
-            )
-            u_dev_scan = u_jit(bins_dev)
-        # Segment the one-dispatch fit so no single device program runs for
-        # minutes. Observed on an earlier v5e host: a 4M-row x 100-iteration
-        # scan (~90 s on-device) reproducibly killed the TPU worker, while
-        # 4M x 50 and 2M x 100 (~50 s) ran fine. Whether the current machine
-        # still needs this is an open ROADMAP question; the bound is
-        # MMLSPARK_TPU_SCAN_ROW_ITERS. Equal-length segments share one compiled program; margins thread
-        # between dispatches, so results are identical to the single scan.
-        row_iters = n * max(1, opts.num_iterations) * max(1, num_classes)
-        budget = int(_os.environ.get("MMLSPARK_TPU_SCAN_ROW_ITERS", 200_000_000))
-        nseg = max(1, -(-row_iters // budget))
-        # prefer a divisor of the iteration count close to nseg: equal
-        # segment lengths mean ONE compiled shape instead of two
-        for cand in range(nseg, min(nseg + 3, max(1, opts.num_iterations)) + 1):
-            if opts.num_iterations % cand == 0:
-                nseg = cand
-                break
-        seg = -(-opts.num_iterations // nseg)
-        parts = []
-        for s0 in range(0, opts.num_iterations, seg):
-            s1 = min(s0 + seg, opts.num_iterations)
-            # margins is donated into the runner; a degraded retry of
-            # this segment needs the pre-dispatch value back, so keep a
-            # host snapshot (segments are rare — usually one per fit)
-            margins_before = np.asarray(margins)
-            oom_retries = 0
-            while True:
-                try:
-                    # injected OOM fires pre-dispatch (margins not donated
-                    # yet), so the degraded retry re-dispatches cleanly
-                    if _fault_plan is not None:
-                        _fault_plan.apply_on_histogram(s0, oom_retries)
-                    # profiling forces a per-segment sync (an honest device
-                    # window needs block_until_ready); the unprofiled fit
-                    # keeps the async dispatch pipeline.
-                    t_seg = time.perf_counter() if _prof_on else 0.0
-                    cache_before = (
-                        runner._cache_size() if _prof_on
-                        and hasattr(runner, "_cache_size") else None
-                    )
-                    margins, part = runner(
-                        bins_dev, y_dev, w_dev, margins, edges_dev,
-                        bag_arg[s0:s1] if bag_resampling else bag_arg,
-                        fm_all[s0:s1],
-                        lr_arg[s0:s1] if per_iter_lr else lr_arg,
-                        jnp.int32(s0),
-                        u_dev_scan,
-                    )
-                    if _prof_on:
-                        jax.block_until_ready((margins, part))
-                        dt = time.perf_counter() - t_seg
-                        compiled = (
-                            cache_before is not None
-                            and hasattr(runner, "_cache_size")
-                            and runner._cache_size() > cache_before
-                        )
-                        if compiled:
-                            _prof.note_compile("gbdt.scan", dt)
-                        else:
-                            _prof.note_cache_hit("gbdt.scan")
-                        _prof.note_execute("gbdt.scan", dt)
-                    break
-                except Exception as e:  # noqa: BLE001 - OOM-classified below
-                    if (
-                        not _is_oom(e)
-                        or oom_retries >= _oom_retry_cap
-                        or not _degrade_for_oom(e, "scan", s0, oom_retries + 1)
-                    ):
-                        raise
-                    oom_retries += 1
-                    # recreate the donated margins buffer and rebuild the
-                    # scan program + fit-resident U under the new spec
-                    margins = jnp.asarray(margins_before)
-                    runner = _cached_program(
-                        ("scan", okey, bag_resampling, per_iter_lr),
-                        lambda: _make_scan_steps(
-                            step_raw, per_iter_bag=bag_resampling,
-                            per_iter_lr=per_iter_lr,
-                            with_u=u_builder is not None,
-                        ),
-                    )
-                    if u_builder is not None:
-                        u_jit = _cached_program(
-                            ("u_build_jit", u_spec), lambda: jax.jit(u_builder)
-                        )
-                        u_dev_scan = u_jit(bins_dev)
-            parts.append(part)
-        stacked_trees = (
-            parts[0]
-            if len(parts) == 1
-            else jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0), *parts)
-        )
-    else:
-        dart_rng = np.random.default_rng(opts.seed + 7919)
-        # loop path: the fit-resident U builds once, outside the loop
-        # (cached jitted builder — a fresh jax.jit per fit would retrace)
-        u_dev = None
-        if u_builder is not None:
-            u_jit = _cached_program(
-                ("u_build_jit", u_spec), lambda: jax.jit(u_builder)
-            )
-            u_dev = u_jit(bins_dev)
-        tree_contrib = _cached_program(
-            ("tree_contrib", opts.routing_steps, bundle),
-            lambda: _make_tree_contrib(opts.routing_steps, bundle),
-        )
-
-        def contrib_of(tr, bins_v):
-            return tree_contrib(
-                bins_v, tr.feat, tr.bin, tr.left, tr.right, tr.is_leaf,
-                tr.leaf_val, tr.cat_node, tr.cat_mask,
-            )
-
-        pending_bag = None
-        for it, (bag_np, bag_changed, fm_np) in enumerate(schedule):
-            if it < start_iteration:
-                # journal resume: consume the draw (rng stream stays
-                # aligned with an uninterrupted fit) without boosting
-                if bag_changed:
-                    pending_bag = bag_np
-                continue
-            if pending_bag is not None:
-                # the last skipped resample is the mask in force at k
-                if not bag_changed:
-                    bag_np, bag_changed = pending_bag, True
-                pending_bag = None
-            if bag_changed:
-                bag_dev = put_rows(bag_np)
-            fm_dev = put_rep(fm_np) if fm_np is not None else fm_ones_dev
-            for cb in callbacks:
-                cb.before_iteration(_cb_env(it))
-            # traced scalar (not a baked constant) so per-iteration LR values
-            # don't each recompile the step program
-            lr_it = jnp.float32(
-                lr_all[it] if lr_all is not None else opts.learning_rate
-            )
-
-            # dart: drop a random subset of existing trees from the margins
-            # the new tree fits against (each with prob drop_rate), then
-            # renormalize — new tree x 1/(k+1), dropped trees x k/(k+1)
-            # (the DART weight-shrinkage rule).
-            dropped = []
-            if opts.boosting_type == "dart" and trees:
-                dropped = list(np.nonzero(
-                    dart_rng.random(len(trees)) < opts.drop_rate
-                )[0])
-            if dropped:
-                c_d = contrib_of(trees[dropped[0]], bins_dev)
-                for di in dropped[1:]:
-                    c_d = c_d + contrib_of(trees[di], bins_dev)
-                margins_in = margins - c_d
+                u_builder = partial(prepare_chunked_bins, spec=u_spec)
             else:
-                margins_in = margins
+                from mmlspark_tpu.ops.u_histogram import build_u
 
-            # Injected OOM faults fire here, BEFORE dispatch, so margins_in
-            # has not been donated when the degraded retry re-dispatches.
-            # A real device OOM surfaces after donation; the retry is then
-            # best-effort (the allocator usually fails before consuming the
-            # donated buffer, but that is not contractual).
-            oom_retries = 0
-            while True:
-                try:
-                    if _fault_plan is not None:
-                        _fault_plan.apply_on_histogram(it, oom_retries)
-                    t_step = time.perf_counter() if _prof_on else 0.0
-                    step_cache_before = (
-                        step._cache_size() if _prof_on
-                        and hasattr(step, "_cache_size") else None
-                    )
-                    tree, new_margins = step(
-                        bins_dev, y_dev, w_dev, margins_in, edges_dev,
-                        bag_dev, fm_dev, jnp.int32(it), lr_it, u=u_dev,
-                    )
-                    break
-                except Exception as e:  # noqa: BLE001 - OOM-classified below
-                    if (
-                        not _is_oom(e)
-                        or oom_retries >= _oom_retry_cap
-                        or not _degrade_for_oom(e, "loop", it, oom_retries + 1)
-                    ):
-                        raise
-                    oom_retries += 1
-                    if u_builder is not None:
-                        u_jit = _cached_program(
-                            ("u_build_jit", u_spec), lambda: jax.jit(u_builder)
-                        )
-                        u_dev = u_jit(bins_dev)
+                u_builder = partial(build_u, spec=u_spec)
+        valid_update = _cached_program(
+            ("valid_update", opts.routing_steps, bundle),
+            lambda: _make_valid_update(opts.routing_steps, bundle),
+        )
 
-            if dropped:
-                k = len(dropped)
-                scale_new = 1.0 / (k + 1)
-                scale_drop = k / (k + 1)
-                # margins_in was donated into step — recover the unscaled
-                # new-tree contribution from the row->leaf map it computed
-                c_new = jnp.take_along_axis(tree.leaf_val, tree.row_leaf, axis=1).T
-                # valid-set deltas need the PRE-scaled dropped trees
-                for vs in valid_state:
-                    c_dv = contrib_of(trees[dropped[0]], vs["bins"])
-                    for di in dropped[1:]:
-                        c_dv = c_dv + contrib_of(trees[di], vs["bins"])
-                    c_newv = contrib_of(tree, vs["bins"])
-                    vs["margins"] = (
-                        vs["margins"] - c_dv * scale_new + c_newv * scale_new
-                    )
-                    vs["_updated"] = True
-                tree = tree._replace(leaf_val=tree.leaf_val * scale_new)
-                for di in dropped:
-                    trees[di] = trees[di]._replace(
-                        leaf_val=trees[di].leaf_val * scale_drop
-                    )
-                margins = margins - c_d * scale_new + c_new * scale_new
-            else:
-                margins = new_margins
-            # Synchronize each iteration on the mesh path: an unbounded async
-            # queue of collective programs can starve a device thread past the
-            # XLA rendezvous timeout (hard abort on the host-platform mesh),
-            # and per-iteration sync is the barrier-execution-mode semantics
-            # of the reference anyway (TrainUtils.scala:477-483).
-            jax.block_until_ready(margins)
-            if _prof_on:
-                # the per-iteration device window: step dispatch through
-                # the mesh sync above (dart host work rides along on the
-                # rare dropped-tree iterations)
-                dt = time.perf_counter() - t_step
-                compiled = (
-                    step_cache_before is not None
-                    and hasattr(step, "_cache_size")
-                    and step._cache_size() > step_cache_before
+        # -- RESOURCE_EXHAUSTED degradation ladder (docs/resilience.md) ----------
+        # An HBM OOM during a histogram dispatch is retryable at a reduced
+        # footprint: halve the in-memory U budget (floor 1 MiB), re-derive the
+        # chunked-U spec, rebuild the step program, and re-run the SAME
+        # iteration. Chunked and resident passes are bit-exact, so the final
+        # model text matches an undisturbed run byte for byte. The last rung —
+        # a smaller ``leaf_batch`` — changes split-scheduling and is left to
+        # the caller (it trades reproducibility for survival).
+        from mmlspark_tpu.runtime.faults import (
+            current_faults as _current_faults,
+            is_oom_error as _is_oom,
+        )
+
+        _fault_plan = _current_faults()
+        _oom_retry_cap = 8
+
+        def _degrade_for_oom(err, stage, iteration, retries) -> bool:
+            """Walk one rung down the ladder; True when the caller may retry."""
+            nonlocal u_spec, u_budget, okey, step_raw, step, u_builder
+            if u_spec is None:
+                return False  # no U path active: nothing to shrink in-loop
+            new_budget = max(u_budget // 2, 1 << 20)
+            if new_budget == u_budget and u_spec.chunk_rows:
+                return False  # floor reached; the OOM is genuine scarcity
+            u_budget = new_budget
+            from mmlspark_tpu.ops.u_histogram import (
+                build_u,
+                chunked_u_spec,
+                prepare_chunked_bins,
+            )
+
+            u_spec = chunked_u_spec(
+                n + pad, dataclasses.replace(u_spec, chunk_rows=0), u_budget
+            )
+            okey = (
+                _opts_key(opts), num_bins, mesh, u_spec, bundle,
+                objective.cache_token,
+            )
+            if opts.boosting_type == "goss":
+                okey = okey + (n,)
+            if hist_reduce is not None:
+                step_raw = _make_step(
+                    opts, objective, num_bins, mesh, n_real=n, u_spec=u_spec,
+                    hist_reduce=hist_reduce, bundle=bundle,
                 )
-                if compiled:
-                    _prof.note_compile("gbdt.step", dt)
-                else:
-                    _prof.note_cache_hit("gbdt.step")
-                _prof.note_execute("gbdt.step", dt)
-            # drop row_leaf, a (C, N) buffer per tree, before retaining
-            trees.append(tree._replace(row_leaf=None))
-            if iteration_hook is not None:
-                # the commit point: the iteration's tree is final and its
-                # margins applied — procfit journals it here
-                iteration_hook(it, trees[-1])
+                step = jax.jit(step_raw, donate_argnums=(3,))
+            else:
+                step_raw = _cached_program(
+                    ("step_raw", okey),
+                    lambda: _make_step(
+                        opts, objective, num_bins, mesh, n_real=n, u_spec=u_spec,
+                        bundle=bundle,
+                    ),
+                )
+                step = _cached_program(
+                    ("step_jit", okey),
+                    lambda: jax.jit(step_raw, donate_argnums=(3,)),
+                )
+            u_builder = (
+                partial(prepare_chunked_bins, spec=u_spec) if u_spec.chunk_rows
+                else partial(build_u, spec=u_spec)
+            )
+            from mmlspark_tpu.core.profiling import get_logger
 
-            if opts.provide_training_metric:
-                # isProvideTrainingMetric: train-set metric per iteration
-                # (a device fetch per round — opt-in, loop path only)
-                evals["training"][metric].append(_evaluate(
-                    metric, opts.objective, y_np[:n], np.asarray(margins)[:n],
-                    w[:n], opts.alpha,
+            get_logger("mmlspark_tpu.lightgbm").warning(
+                "histogram %s dispatch hit RESOURCE_EXHAUSTED at iteration %d "
+                "(%s); degrading: U budget -> %d bytes, chunk_rows -> %d, "
+                "retry %d",
+                stage, iteration, str(err)[:120], u_budget, u_spec.chunk_rows,
+                retries,
+            )
+            from mmlspark_tpu.observability.events import (
+                HistogramDegraded,
+                MemoryPressure,
+                get_bus,
+            )
+
+            bus = get_bus()
+            if bus.active:
+                bus.publish(MemoryPressure(
+                    source="device", level="critical", used_bytes=0.0,
+                    limit_bytes=0.0, detail=str(err)[:200],
                 ))
+                bus.publish(HistogramDegraded(
+                    rows=n + pad, budget_bytes=u_budget,
+                    chunk_rows=u_spec.chunk_rows, stage=stage,
+                    iteration=int(iteration), retries=int(retries),
+                ))
+            return True
 
-            improved_any = False
-            for vs in valid_state:
-                if vs.pop("_updated", False):
-                    pass  # dart already applied this round's delta
-                else:
-                    vs["margins"] = valid_update(vs["bins"], vs["margins"], tree)
-                score = _evaluate(
-                    metric, opts.objective, vs["y"], np.asarray(vs["margins"]),
-                    vs["w"], opts.alpha,
+        valid_sets = list(valid_sets or [])
+        valid_state = []
+        for name, bv, yv, wv in valid_sets:
+            wv = np.ones(len(yv), dtype=np.float32) if wv is None else np.asarray(wv, np.float32)
+            mv = np.broadcast_to(init_score[None, :], (len(yv), num_classes)).copy()
+            valid_state.append(
+                {
+                    "name": name,
+                    "bins": jnp.asarray(np.asarray(bv, dtype=np.int32)),
+                    "y": np.asarray(yv, dtype=np.float32),
+                    "w": wv,
+                    "margins": jnp.asarray(mv.astype(np.float32)),
+                }
+            )
+
+        metric = opts.metric or objective.default_metric
+        higher_better = metric_higher_is_better(metric)
+        evals: Dict[str, Dict[str, List[float]]] = {
+            vs["name"]: {metric: []} for vs in valid_state
+        }
+        if opts.provide_training_metric:
+            evals["training"] = {metric: []}
+
+        rng = np.random.default_rng(opts.seed)
+        num_bag = max(1, int(round(n * opts.bagging_fraction)))
+        num_feat = max(1, int(round(f_feat * opts.feature_fraction)))
+
+        from mmlspark_tpu.lightgbm.callbacks import (
+            CallbackEnv,
+            _has_iteration_hooks,
+            _lr_schedule,
+        )
+
+        callbacks = list(callbacks or [])
+        lr_all = _lr_schedule(callbacks, opts.learning_rate, opts.num_iterations)
+        iteration_hooks = _has_iteration_hooks(callbacks)
+
+        def _cb_env(it: int) -> "CallbackEnv":
+            lr_it = float(lr_all[it]) if (lr_all is not None and it < len(lr_all)) \
+                else opts.learning_rate
+            return CallbackEnv(
+                iteration=it, num_iterations=opts.num_iterations,
+                learning_rate=lr_it, evals=evals,
+            )
+
+        for cb in callbacks:
+            cb.before_training(_cb_env(0))
+
+        trees: List[TreeArrays] = []
+        best_score = -np.inf if higher_better else np.inf
+        best_iter = 0
+        stale = 0
+
+        # Device-resident inputs are uploaded once and only re-uploaded when
+        # bagging/feature-fraction actually resamples, and per-tree outputs stay
+        # on device until one bulk fetch after the loop, so an iteration costs
+        # no host<->device round-trip of its own.
+        # presence mask built on device (zeroed pad tail) — no upload
+        bag_dev = dev_rows(
+            jnp.ones(n + pad, jnp.float32)
+            if pad == 0
+            else jnp.ones(n + pad, jnp.float32).at[n:].set(0.0)
+        )
+        fm_ones_dev = put_rep(np.ones(f_feat, dtype=np.float32))
+
+        # Fast path: no per-iteration host decisions (no valid-set metrics, no
+        # mesh special-casing) — run every boosting iteration in ONE device
+        # program via lax.scan. Per-iteration masks come from the same
+        # _mask_schedule as the loop path, so semantics (bagging schedule,
+        # feature sampling, rng stream order) are identical.
+        stacked_trees = None
+        schedule = _mask_schedule(
+            opts, rng, n, pad, num_bag, num_feat, f_feat, presence, y=y_np
+        )
+        bag_resampling = _bagging_active(opts)
+        # The scan path materializes an (iterations, N) uint8 bagging-mask array
+        # on device when bagging resamples; gate it so a huge fit (e.g. 10M rows
+        # x 1000 iters = 10 GB) falls back to the loop path, which re-uploads
+        # only on resample.
+        bag_stack_ok = (
+            not bag_resampling or opts.num_iterations * (n + pad) <= (512 << 20)
+        )
+        scan_path = (
+            mesh is None
+            and not valid_state
+            and not iteration_hooks  # per-iteration delegates need the loop path
+            and bag_stack_ok
+            and opts.num_iterations > 0
+            and opts.boosting_type != "dart"  # dart drops trees per host decision
+            and not opts.provide_training_metric  # needs per-iteration margins
+            and hist_reduce is None  # process fits need per-iteration control
+            and iteration_hook is None
+            and start_iteration == 0
+        )
+        if scan_path:
+            bag_list, fm_list = [], []
+            for bag_np, _, fm_np in schedule:
+                bag_list.append(bag_np)
+                fm_list.append(fm_np if fm_np is not None else np.ones(f_feat, np.float32))
+            if bag_resampling:
+                # uint8 on the wire (masks are 0/1; 4x less than f32); cast per
+                # scan step
+                bag_arg = jnp.asarray(np.stack(bag_list).astype(np.uint8))
+            else:
+                bag_arg = bag_dev  # (N,) closed over inside the program
+            fm_all = jnp.asarray(np.stack(fm_list))
+            per_iter_lr = lr_all is not None
+            lr_arg = jnp.asarray(lr_all) if per_iter_lr else fm_all  # unused placeholder
+            runner = _cached_program(
+                ("scan", okey, bag_resampling, per_iter_lr),
+                lambda: _make_scan_steps(
+                    step_raw, per_iter_bag=bag_resampling, per_iter_lr=per_iter_lr,
+                    with_u=u_builder is not None,
+                ),
+            )
+        else:
+            dart_rng = np.random.default_rng(opts.seed + 7919)
+            tree_contrib = _cached_program(
+                ("tree_contrib", opts.routing_steps, bundle),
+                lambda: _make_tree_contrib(opts.routing_steps, bundle),
+            )
+
+            def contrib_of(tr, bins_v):
+                return tree_contrib(
+                    bins_v, tr.feat, tr.bin, tr.left, tr.right, tr.is_leaf,
+                    tr.leaf_val, tr.cat_node, tr.cat_mask,
                 )
-                evals[vs["name"]][metric].append(score)
-                # best-so-far from the true score (TrainUtils.scala:276-315);
-                # the first finite eval improves on the ±inf sentinel
-                # naturally, and a NaN score never registers as an improvement.
-                delta = (score - best_score) if higher_better else (best_score - score)
-                if delta > opts.improvement_tolerance:
-                    best_score, best_iter, improved_any = score, it + 1, True
-            stop_requested = False
-            for cb in callbacks:
-                if cb.after_iteration(_cb_env(it)):
-                    stop_requested = True
-            if stop_requested:
-                break
-            if valid_state and opts.early_stopping_round > 0:
-                stale = 0 if improved_any else stale + 1
-                if stale >= opts.early_stopping_round:
+
+    def build_u_dev():
+        """The fit-resident U (or the chunked bins stack): one dispatch,
+        again only after an OOM degraded the spec. The build's device time
+        is waited for by whatever reads its result: ``lightgbm.boost``."""
+        from mmlspark_tpu.ops.u_histogram import num_u_chunks, u_bytes
+
+        chunks = num_u_chunks(n + pad, u_spec)
+        u_jit = _cached_program(
+            ("u_build_jit", u_spec), lambda: jax.jit(u_builder)
+        )
+        with tracer.span(
+            "lightgbm.u_build", chunks=chunks,
+            u_bytes=chunks * u_spec.chunk_rows * f if u_spec.chunk_rows
+            else u_bytes(n + pad, u_spec),
+        ):
+            return u_jit(bins_dev)
+
+    # built ONCE per fit, shared by every segment or iteration below
+    u_dev = build_u_dev() if u_builder is not None else None
+    # ``lightgbm.boost`` runs from the first dispatch until the trees are on
+    # the host: the dispatches return at once, so the span's time is the
+    # device's, waited for in _fetch_trees (and per iteration on the loop
+    # path). Nothing in it is recorded per iteration.
+    with tracer.span(
+        "lightgbm.boost", iterations=opts.num_iterations - start_iteration
+    ) as boost_span:
+        if scan_path:
+            no_u = jnp.int32(0)  # unused placeholder when no U path
+            # Segment the one-dispatch fit so no single device program runs for
+            # minutes. Observed on an earlier v5e host: a 4M-row x 100-iteration
+            # scan (~90 s on-device) reproducibly killed the TPU worker, while
+            # 4M x 50 and 2M x 100 (~50 s) ran fine. Whether the current machine
+            # still needs this is an open ROADMAP question; the bound is
+            # MMLSPARK_TPU_SCAN_ROW_ITERS. Equal-length segments share one compiled program; margins thread
+            # between dispatches, so results are identical to the single scan.
+            row_iters = n * max(1, opts.num_iterations) * max(1, num_classes)
+            budget = int(_os.environ.get("MMLSPARK_TPU_SCAN_ROW_ITERS", 200_000_000))
+            nseg = max(1, -(-row_iters // budget))
+            # prefer a divisor of the iteration count close to nseg: equal
+            # segment lengths mean ONE compiled shape instead of two
+            for cand in range(nseg, min(nseg + 3, max(1, opts.num_iterations)) + 1):
+                if opts.num_iterations % cand == 0:
+                    nseg = cand
                     break
+            seg = -(-opts.num_iterations // nseg)
+            boost_span.tags["segments"] = nseg
+            parts = []
+            for s0 in range(0, opts.num_iterations, seg):
+                s1 = min(s0 + seg, opts.num_iterations)
+                # margins is donated into the runner; a degraded retry of
+                # this segment needs the pre-dispatch value back, so keep a
+                # host snapshot (segments are rare — usually one per fit)
+                margins_before = np.asarray(margins)
+                oom_retries = 0
+                while True:
+                    try:
+                        # injected OOM fires pre-dispatch (margins not donated
+                        # yet), so the degraded retry re-dispatches cleanly
+                        if _fault_plan is not None:
+                            _fault_plan.apply_on_histogram(s0, oom_retries)
+                        # profiling forces a per-segment sync (an honest device
+                        # window needs block_until_ready); the unprofiled fit
+                        # keeps the async dispatch pipeline.
+                        t_seg = time.perf_counter() if _prof_on else 0.0
+                        cache_before = (
+                            runner._cache_size() if _prof_on
+                            and hasattr(runner, "_cache_size") else None
+                        )
+                        margins, part = runner(
+                            bins_dev, y_dev, w_dev, margins, edges_dev,
+                            bag_arg[s0:s1] if bag_resampling else bag_arg,
+                            fm_all[s0:s1],
+                            lr_arg[s0:s1] if per_iter_lr else lr_arg,
+                            jnp.int32(s0),
+                            no_u if u_dev is None else u_dev,
+                        )
+                        if _prof_on:
+                            jax.block_until_ready((margins, part))
+                            dt = time.perf_counter() - t_seg
+                            compiled = (
+                                cache_before is not None
+                                and hasattr(runner, "_cache_size")
+                                and runner._cache_size() > cache_before
+                            )
+                            if compiled:
+                                _prof.note_compile("gbdt.scan", dt)
+                            else:
+                                _prof.note_cache_hit("gbdt.scan")
+                            _prof.note_execute("gbdt.scan", dt)
+                        break
+                    except Exception as e:  # noqa: BLE001 - OOM-classified below
+                        if (
+                            not _is_oom(e)
+                            or oom_retries >= _oom_retry_cap
+                            or not _degrade_for_oom(e, "scan", s0, oom_retries + 1)
+                        ):
+                            raise
+                        oom_retries += 1
+                        # recreate the donated margins buffer and rebuild the
+                        # scan program + fit-resident U under the new spec
+                        margins = jnp.asarray(margins_before)
+                        runner = _cached_program(
+                            ("scan", okey, bag_resampling, per_iter_lr),
+                            lambda: _make_scan_steps(
+                                step_raw, per_iter_bag=bag_resampling,
+                                per_iter_lr=per_iter_lr,
+                                with_u=u_builder is not None,
+                            ),
+                        )
+                        if u_builder is not None:
+                            u_dev = build_u_dev()
+                parts.append(part)
+            stacked_trees = (
+                parts[0]
+                if len(parts) == 1
+                else jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0), *parts)
+            )
+        else:
+            boost_span.tags["segments"] = 0  # the loop path: a dispatch an iteration
+            pending_bag = None
+            for it, (bag_np, bag_changed, fm_np) in enumerate(schedule):
+                if it < start_iteration:
+                    # journal resume: consume the draw (rng stream stays
+                    # aligned with an uninterrupted fit) without boosting
+                    if bag_changed:
+                        pending_bag = bag_np
+                    continue
+                if pending_bag is not None:
+                    # the last skipped resample is the mask in force at k
+                    if not bag_changed:
+                        bag_np, bag_changed = pending_bag, True
+                    pending_bag = None
+                if bag_changed:
+                    bag_dev = put_rows(bag_np)
+                fm_dev = put_rep(fm_np) if fm_np is not None else fm_ones_dev
+                for cb in callbacks:
+                    cb.before_iteration(_cb_env(it))
+                # traced scalar (not a baked constant) so per-iteration LR values
+                # don't each recompile the step program
+                lr_it = jnp.float32(
+                    lr_all[it] if lr_all is not None else opts.learning_rate
+                )
 
-    # scan path: all iterations ran inside one program (trees list unused)
-    iters_done = opts.num_iterations if stacked_trees is not None else len(trees)
-    for cb in callbacks:
-        cb.after_training(_cb_env(max(0, iters_done - 1)))
+                # dart: drop a random subset of existing trees from the margins
+                # the new tree fits against (each with prob drop_rate), then
+                # renormalize — new tree x 1/(k+1), dropped trees x k/(k+1)
+                # (the DART weight-shrinkage rule).
+                dropped = []
+                if opts.boosting_type == "dart" and trees:
+                    dropped = list(np.nonzero(
+                        dart_rng.random(len(trees)) < opts.drop_rate
+                    )[0])
+                if dropped:
+                    c_d = contrib_of(trees[dropped[0]], bins_dev)
+                    for di in dropped[1:]:
+                        c_d = c_d + contrib_of(trees[di], bins_dev)
+                    margins_in = margins - c_d
+                else:
+                    margins_in = margins
 
-    if opts.verbosity >= 1:
-        import logging as _logging
+                # Injected OOM faults fire here, BEFORE dispatch, so margins_in
+                # has not been donated when the degraded retry re-dispatches.
+                # A real device OOM surfaces after donation; the retry is then
+                # best-effort (the allocator usually fails before consuming the
+                # donated buffer, but that is not contractual).
+                oom_retries = 0
+                while True:
+                    try:
+                        if _fault_plan is not None:
+                            _fault_plan.apply_on_histogram(it, oom_retries)
+                        t_step = time.perf_counter() if _prof_on else 0.0
+                        step_cache_before = (
+                            step._cache_size() if _prof_on
+                            and hasattr(step, "_cache_size") else None
+                        )
+                        tree, new_margins = step(
+                            bins_dev, y_dev, w_dev, margins_in, edges_dev,
+                            bag_dev, fm_dev, jnp.int32(it), lr_it, u=u_dev,
+                        )
+                        break
+                    except Exception as e:  # noqa: BLE001 - OOM-classified below
+                        if (
+                            not _is_oom(e)
+                            or oom_retries >= _oom_retry_cap
+                            or not _degrade_for_oom(e, "loop", it, oom_retries + 1)
+                        ):
+                            raise
+                        oom_retries += 1
+                        if u_builder is not None:
+                            u_dev = build_u_dev()
 
-        from mmlspark_tpu.core.profiling import get_logger
-
-        logger = get_logger("mmlspark_tpu.lightgbm")
-        # verbosity is an explicit request for output — lift the level floor
-        # for THIS summary only, restoring the configured level after
-        root_logger = _logging.getLogger("mmlspark_tpu")
-        prev_level = root_logger.level
-        if root_logger.getEffectiveLevel() > _logging.INFO:
-            root_logger.setLevel(_logging.INFO)
-        try:
-            for name, metrics in evals.items():
-                for mname, scores in metrics.items():
-                    if not scores:
-                        continue
-                    arr = np.asarray(scores, dtype=np.float64)
-                    if np.isnan(arr).all():
-                        logger.info("valid %s %s: all evals NaN", name, mname)
-                        continue
-                    best_i = int(
-                        np.nanargmax(arr) if higher_better else np.nanargmin(arr)
+                if dropped:
+                    k = len(dropped)
+                    scale_new = 1.0 / (k + 1)
+                    scale_drop = k / (k + 1)
+                    # margins_in was donated into step — recover the unscaled
+                    # new-tree contribution from the row->leaf map it computed
+                    c_new = jnp.take_along_axis(tree.leaf_val, tree.row_leaf, axis=1).T
+                    # valid-set deltas need the PRE-scaled dropped trees
+                    for vs in valid_state:
+                        c_dv = contrib_of(trees[dropped[0]], vs["bins"])
+                        for di in dropped[1:]:
+                            c_dv = c_dv + contrib_of(trees[di], vs["bins"])
+                        c_newv = contrib_of(tree, vs["bins"])
+                        vs["margins"] = (
+                            vs["margins"] - c_dv * scale_new + c_newv * scale_new
+                        )
+                        vs["_updated"] = True
+                    tree = tree._replace(leaf_val=tree.leaf_val * scale_new)
+                    for di in dropped:
+                        trees[di] = trees[di]._replace(
+                            leaf_val=trees[di].leaf_val * scale_drop
+                        )
+                    margins = margins - c_d * scale_new + c_new * scale_new
+                else:
+                    margins = new_margins
+                # Synchronize each iteration on the mesh path: an unbounded async
+                # queue of collective programs can starve a device thread past the
+                # XLA rendezvous timeout (hard abort on the host-platform mesh),
+                # and per-iteration sync is the barrier-execution-mode semantics
+                # of the reference anyway (TrainUtils.scala:477-483).
+                jax.block_until_ready(margins)
+                if _prof_on:
+                    # the per-iteration device window: step dispatch through
+                    # the mesh sync above (dart host work rides along on the
+                    # rare dropped-tree iterations)
+                    dt = time.perf_counter() - t_step
+                    compiled = (
+                        step_cache_before is not None
+                        and hasattr(step, "_cache_size")
+                        and step._cache_size() > step_cache_before
                     )
-                    logger.info(
-                        "valid %s %s: last=%.6f best=%.6f@%d",
-                        name, mname, scores[-1], arr[best_i], best_i + 1,
-                    )
-        finally:
-            root_logger.setLevel(prev_level)
+                    if compiled:
+                        _prof.note_compile("gbdt.step", dt)
+                    else:
+                        _prof.note_cache_hit("gbdt.step")
+                    _prof.note_execute("gbdt.step", dt)
+                # drop row_leaf, a (C, N) buffer per tree, before retaining
+                trees.append(tree._replace(row_leaf=None))
+                if iteration_hook is not None:
+                    # the commit point: the iteration's tree is final and its
+                    # margins applied — procfit journals it here
+                    iteration_hook(it, trees[-1])
 
-    booster = _pack_booster(
-        trees, stacked_trees, opts, num_classes, init_score, mapper,
-        feature_names,
-        best_iteration=best_iter
-        if (valid_state and opts.early_stopping_round > 0) else -1,
-    )
+                if opts.provide_training_metric:
+                    # isProvideTrainingMetric: train-set metric per iteration
+                    # (a device fetch per round — opt-in, loop path only)
+                    evals["training"][metric].append(_evaluate(
+                        metric, opts.objective, y_np[:n], np.asarray(margins)[:n],
+                        w[:n], opts.alpha,
+                    ))
+
+                improved_any = False
+                for vs in valid_state:
+                    if vs.pop("_updated", False):
+                        pass  # dart already applied this round's delta
+                    else:
+                        vs["margins"] = valid_update(vs["bins"], vs["margins"], tree)
+                    score = _evaluate(
+                        metric, opts.objective, vs["y"], np.asarray(vs["margins"]),
+                        vs["w"], opts.alpha,
+                    )
+                    evals[vs["name"]][metric].append(score)
+                    # best-so-far from the true score (TrainUtils.scala:276-315);
+                    # the first finite eval improves on the ±inf sentinel
+                    # naturally, and a NaN score never registers as an improvement.
+                    delta = (score - best_score) if higher_better else (best_score - score)
+                    if delta > opts.improvement_tolerance:
+                        best_score, best_iter, improved_any = score, it + 1, True
+                stop_requested = False
+                for cb in callbacks:
+                    if cb.after_iteration(_cb_env(it)):
+                        stop_requested = True
+                if stop_requested:
+                    break
+                if valid_state and opts.early_stopping_round > 0:
+                    stale = 0 if improved_any else stale + 1
+                    if stale >= opts.early_stopping_round:
+                        break
+
+        # scan path: all iterations ran inside one program (trees list unused)
+        iters_done = opts.num_iterations if stacked_trees is not None else len(trees)
+        for cb in callbacks:
+            cb.after_training(_cb_env(max(0, iters_done - 1)))
+
+        if opts.verbosity >= 1:
+            import logging as _logging
+
+            from mmlspark_tpu.core.profiling import get_logger
+
+            logger = get_logger("mmlspark_tpu.lightgbm")
+            # verbosity is an explicit request for output — lift the level floor
+            # for THIS summary only, restoring the configured level after
+            root_logger = _logging.getLogger("mmlspark_tpu")
+            prev_level = root_logger.level
+            if root_logger.getEffectiveLevel() > _logging.INFO:
+                root_logger.setLevel(_logging.INFO)
+            try:
+                for name, metrics in evals.items():
+                    for mname, scores in metrics.items():
+                        if not scores:
+                            continue
+                        arr = np.asarray(scores, dtype=np.float64)
+                        if np.isnan(arr).all():
+                            logger.info("valid %s %s: all evals NaN", name, mname)
+                            continue
+                        best_i = int(
+                            np.nanargmax(arr) if higher_better else np.nanargmin(arr)
+                        )
+                        logger.info(
+                            "valid %s %s: last=%.6f best=%.6f@%d",
+                            name, mname, scores[-1], arr[best_i], best_i + 1,
+                        )
+            finally:
+                root_logger.setLevel(prev_level)
+
+        fetched = _fetch_trees(trees, stacked_trees, opts, num_classes)
+    with tracer.span("lightgbm.pack", trees=iters_done * num_classes):
+        booster = _assemble_booster(
+            fetched, opts, num_classes, init_score, mapper, feature_names,
+            best_iteration=best_iter
+            if (valid_state and opts.early_stopping_round > 0) else -1,
+        )
     return TrainResult(booster=booster, evals=evals, best_iteration=best_iter)
 
 
@@ -2807,17 +2846,35 @@ def _pack_booster(
     factored so the process-parallel fit (``procfit.py``) can rebuild the
     identical booster from journal-restored trees. Accepts either a list
     of per-iteration :class:`TreeArrays` (loop path / journal restore) or
-    a scan-stacked TreeArrays pytree."""
+    a scan-stacked TreeArrays pytree. Two halves, so that train() can end
+    its ``lightgbm.boost`` span where the device's work has reached the
+    host: :func:`_fetch_trees`, then :func:`_assemble_booster`."""
+    return _assemble_booster(
+        _fetch_trees(trees, stacked_trees, opts, num_classes),
+        opts, num_classes, init_score, mapper, feature_names, best_iteration,
+    )
+
+
+_FIELDS = (
+    "feat", "bin", "thr", "left", "right", "is_leaf", "leaf_val", "cover", "gain",
+)
+
+
+def _fetch_trees(
+    trees: Optional[List[TreeArrays]],
+    stacked_trees: Optional[TreeArrays],
+    opts: TrainOptions,
+    num_classes: int,
+) -> Tuple[np.ndarray, Optional[np.ndarray], Optional[np.ndarray]]:
+    """Device to host: (packed (9, T*C, M) float32 tree fields, categorical
+    node flags, categorical masks). The first fetch waits for every
+    dispatch still in flight."""
     t = opts.num_iterations if stacked_trees is not None else len(trees)
     m = opts.num_nodes
 
     # ONE device-side pack + ONE fetch for all tree fields: every int/bool
     # field's values fit float32 exactly (slot ids < 2^24), so the 9 fields
     # ride a single (9, T*C, M) f32 wire transfer instead of 9 round-trips.
-    _FIELDS = (
-        "feat", "bin", "thr", "left", "right", "is_leaf", "leaf_val", "cover", "gain",
-    )
-
     def _field_dev(field):
         if stacked_trees is not None:
             dev = getattr(stacked_trees, field)  # (T, C, M)
@@ -2826,9 +2883,6 @@ def _pack_booster(
         return dev.reshape(t * num_classes, m).astype(jnp.float32)
 
     packed = np.asarray(jnp.stack([_field_dev(fld) for fld in _FIELDS]))
-
-    def stack(field, dtype):
-        return packed[_FIELDS.index(field)].astype(dtype)
 
     # Categorical split arrays ride separate (small) transfers: the bool
     # mask matrix does not fit the homogeneous f32 pack.
@@ -2846,6 +2900,24 @@ def _pack_booster(
             )
         cat_nodes_np = np.asarray(cn_dev).astype(bool)
         cat_masks_np = np.asarray(cm_dev.astype(jnp.uint8)).astype(bool)
+    return packed, cat_nodes_np, cat_masks_np
+
+
+def _assemble_booster(
+    fetched: Tuple[np.ndarray, Optional[np.ndarray], Optional[np.ndarray]],
+    opts: TrainOptions,
+    num_classes: int,
+    init_score: np.ndarray,
+    mapper: Optional[BinMapper],
+    feature_names: Optional[List[str]] = None,
+    best_iteration: int = -1,
+) -> Booster:
+    """Host only: the fetched tree fields to one :class:`Booster`."""
+    packed, cat_nodes_np, cat_masks_np = fetched
+    t = packed.shape[1] // num_classes
+
+    def stack(field, dtype):
+        return packed[_FIELDS.index(field)].astype(dtype)
 
     left = stack("left", np.int32)
     right = stack("right", np.int32)

@@ -151,20 +151,26 @@ def resnet_apply(params: Dict[str, Any], x, cut: int = 0, dtype: Any = None):
     if dtype is not None:
         x = x.astype(dtype)
     stride = 1 if small_inputs else 2
-    x = jax.nn.relu(_bn(_conv(x, params["stem"]["conv"], stride), params["stem"]["bn"]))
-    if not small_inputs:
-        x = lax.reduce_window(
-            x, -np.inf, lax.max, (1, 1, 3, 3), (1, 1, 2, 2),
-            ((0, 0), (0, 0), (1, 1), (1, 1)),
+    # the scope names are what an xprof trace groups the forward's ops by
+    with jax.named_scope("resnet_stem"):
+        x = jax.nn.relu(
+            _bn(_conv(x, params["stem"]["conv"], stride), params["stem"]["bn"])
         )
+        if not small_inputs:
+            x = lax.reduce_window(
+                x, -np.inf, lax.max, (1, 1, 3, 3), (1, 1, 2, 2),
+                ((0, 0), (0, 0), (1, 1), (1, 1)),
+            )
     for stage_i, stage in enumerate(params["stages"]):
-        for block_i, block in enumerate(stage):
-            s = 2 if (stage_i > 0 and block_i == 0) else 1
-            x = _block(x, block, s, bottleneck)
+        with jax.named_scope(f"resnet_stage{stage_i + 1}"):
+            for block_i, block in enumerate(stage):
+                s = 2 if (stage_i > 0 and block_i == 0) else 1
+                x = _block(x, block, s, bottleneck)
     if cut >= 2:
         return x
-    feats = x.mean(axis=(2, 3))
-    if cut >= 1:
-        return feats
-    fc = params["fc"]
-    return feats @ fc["w"].astype(feats.dtype).T + fc["b"].astype(feats.dtype)
+    with jax.named_scope("head"):
+        feats = x.mean(axis=(2, 3))
+        if cut >= 1:
+            return feats
+        fc = params["fc"]
+        return feats @ fc["w"].astype(feats.dtype).T + fc["b"].astype(feats.dtype)

@@ -26,9 +26,11 @@ module is that layer:
   :func:`mmlspark_tpu.core.profiling.annotate`, so an active xprof
   device trace shows the same names as the exported span tree.
 
-Finished spans accumulate in a bounded ring (default 4096) and export
-to JSON via :meth:`Tracer.export`. When the event bus has listeners
-(``MMLSPARK_TPU_EVENT_LOG`` set), every finished span is also published
+Finished spans accumulate in a bounded ring (default 4096);
+:meth:`Tracer.export` gives them as JSON-able records and
+:meth:`Tracer.trace_events` on the clock of a profiler session. When the
+event bus has listeners (``MMLSPARK_TPU_EVENT_LOG`` set), every finished
+span is also published
 as a :class:`~mmlspark_tpu.observability.events.SpanRecorded` event, so
 the per-process event-log segments carry the span stream the history
 server's cross-process waterfall is rebuilt from.
@@ -40,10 +42,9 @@ import collections
 import contextlib
 import contextvars
 import dataclasses
-import json
 import threading
 import time
-from typing import Any, Dict, Iterator, List, Optional
+from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 
 @dataclasses.dataclass
@@ -287,18 +288,10 @@ class Tracer:
         finally:
             self._current.reset(token)
 
-    @contextlib.contextmanager
-    def _annotate(self, name: str) -> Iterator[None]:
+    def _annotate(self, name: str):
         if not self._xprof:
-            yield
-            return
-        try:
-            from mmlspark_tpu.core.profiling import annotate
-        except ImportError:  # pragma: no cover - jax is a hard dep in practice
-            yield
-            return
-        with annotate(name):
-            yield
+            return contextlib.nullcontext()
+        return _annotation()(name)
 
     # -- export --------------------------------------------------------------
 
@@ -313,8 +306,23 @@ class Tracer:
             if trace_id is None or s.trace_id == trace_id
         ]
 
-    def to_json(self, trace_id: Optional[str] = None) -> str:
-        return json.dumps(self.export(trace_id), indent=2)
+    def trace_events(self, t0: float) -> List[Tuple[str, float, float]]:
+        """Finished leaf spans as ``(name, start_ns, duration_ns)`` on the
+        clock of a profiler session that started at ``t0`` (what
+        :func:`mmlspark_tpu.core.profiling.profile_trace` yields): the
+        host-event tuples a trace reduction takes, so the idle gaps of a
+        trace recorded without host events still get an owner's name. A
+        span with children is left out, because a reduction names a gap by
+        the event that overlaps it most and a parent overlaps whatever its
+        children do; so are spans that ended before the session."""
+        with self._lock:
+            spans = list(self._finished)
+        parents = {s.parent_id for s in spans}
+        return [
+            (s.name, (s.start - t0) * 1e9, (s.end - s.start) * 1e9)
+            for s in spans
+            if s.end >= t0 and s.span_id not in parents
+        ]
 
     def span_tree(self, trace_id: str) -> Dict[str, Any]:
         """One trace as a nested dict (children under "children"), the
@@ -333,6 +341,22 @@ class Tracer:
     def clear(self) -> None:
         with self._lock:
             self._finished.clear()
+
+
+_ANNOTATE = None
+
+
+def _annotation():
+    # cached like core/pipeline._tracer: every span enters one, and a span
+    # must not pay import-machinery cost
+    global _ANNOTATE
+    if _ANNOTATE is None:
+        try:
+            from mmlspark_tpu.core.profiling import annotate
+        except ImportError:  # pragma: no cover - jax is a hard dep in practice
+            annotate = contextlib.nullcontext
+        _ANNOTATE = annotate
+    return _ANNOTATE
 
 
 _TRACER = Tracer()

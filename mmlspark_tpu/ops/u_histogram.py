@@ -168,6 +168,7 @@ def _col_maps_cached(spec: USpec) -> Tuple[np.ndarray, np.ndarray]:
     return feat, local
 
 
+@jax.named_scope("u_build")
 def build_u(bins: jax.Array, spec: USpec, dtype=jnp.int8) -> jax.Array:
     """(K_pad, N_pad) TRANSPOSED one-hot of the packed bin ids — ONE compare
     pass's worth of VPU work (~120 ms at 400k x 28 x 256), paid once per
@@ -407,6 +408,7 @@ def _fused_panel_dot(
     )(aux, u)
 
 
+@jax.named_scope("hist_pass")
 def build_histograms_u(
     u: jax.Array,  # (K_pad, N_pad) int8 from build_u
     grad: jax.Array,  # (N,) — ignored when stats is given
@@ -545,6 +547,7 @@ def dequant_hist(h: jax.Array, scales: jax.Array) -> jax.Array:
     return h.astype(jnp.float32) * scales
 
 
+@jax.named_scope("hist_pass")
 def build_histograms_u_chunked(
     bins_chunks: jax.Array,  # (m, F, chunk) uint8 from prepare_chunked_bins
     grad: jax.Array,  # (N,) — ignored when stats is given
