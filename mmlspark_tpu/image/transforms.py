@@ -14,6 +14,7 @@ Flip codes follow OpenCV: 0 = vertical (x-axis), 1 = horizontal, -1 = both.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Dict, List, Tuple
 
 import numpy as np
@@ -207,7 +208,18 @@ class ImageTransformer(HasInputCol, HasOutputCol, Transformer):
 
     # -- execution -----------------------------------------------------------
 
-    def _pipeline(self) -> Callable:
+    def _pipeline(self) -> Tuple[Callable, Callable]:
+        """``(stages, run)``: the stages as one NHWC -> NHWC function (the
+        result's shape is read off it) and, jitted, the program over one
+        shape group, ``run(flat, shape) -> flat result``. The program takes
+        and returns ``(rows, H * W * C)`` and reshapes to NHWC inside, so
+        that what crosses the host boundary is in the host's row order: the
+        TPU keeps a 4-D image batch with the batch dimension minor-most and
+        ``device_get`` hands a device layout back as strides, so a 4-D
+        result arrives with every image scattered across the whole buffer
+        and the first reader of its rows pays a strided gather (3.7 GB at
+        0.17 GB/s: PERF.md, PR 26). Where the stages change no shape the
+        reshapes cancel and the program moves nothing."""
         import jax
 
         ops = []
@@ -217,28 +229,33 @@ class ImageTransformer(HasInputCol, HasOutputCol, Transformer):
                 raise ValueError(f"unknown image op {op_name!r}; have {sorted(_OPS)}")
             ops.append(_OPS[op_name](stage))
 
-        @jax.jit
-        def run(batch):
+        def stages(batch):
             x = batch.astype("float32")
             for op in ops:
                 x = op(x)
             return x
 
-        return run
+        @functools.partial(jax.jit, static_argnums=1)
+        def run(flat, shape):
+            return stages(flat.reshape(shape)).reshape(shape[0], -1)
+
+        return stages, run
 
     def transform(self, table: Table) -> Table:
         """Spans (``observability/tracing``): ``image.transform`` around the
         whole stage; per shape group ``image.stack`` (rows to one host
         batch), ``image.apply_fetch`` (upload, the stage program, download:
-        it owns the wait on the device) and ``image.assemble`` (clip/round
-        and the per-row scatter); one more ``image.assemble`` around the
-        output column. Byte tags come from shapes."""
+        it owns the wait on the device) and ``image.assemble`` (the fetch
+        as NHWC, clip/round of the uint8 path, gray squeeze); one more
+        ``image.assemble`` around the output column (``_image_column``).
+        An ``image.assemble``'s ``bytes`` is what it copied. Byte tags come
+        from shapes."""
         import jax
 
         tracer = get_tracer()
         with tracer.span("image.transform", rows=table.num_rows) as whole:
             col = table.column(self.getInputCol())
-            run = self._pipeline()
+            stages, run = self._pipeline()
             images = [np.asarray(im) for im in col]
             # Group equal-shape images into device batches: one compile per
             # distinct input shape, one program execution per group.
@@ -246,30 +263,59 @@ class ImageTransformer(HasInputCol, HasOutputCol, Transformer):
             for i, im in enumerate(images):
                 by_shape.setdefault(im.shape, []).append(i)
             whole.tags["groups"] = len(by_shape)
-            out: List[Any] = [None] * len(images)
+            groups: List[Tuple[List[int], np.ndarray]] = []
             to_float = self.getToFloat()
             for shape, idxs in by_shape.items():
                 with tracer.span("image.stack") as sp:
                     batch = _ensure_nhwc(np.stack([images[i] for i in idxs]))
                     sp.tags["bytes"] = batch.nbytes
                 with tracer.span("image.apply_fetch", bytes_up=batch.nbytes) as sp:
-                    result = np.asarray(jax.device_get(run(batch)))
-                    sp.tags["bytes_down"] = result.nbytes
+                    out_shape = jax.eval_shape(stages, batch).shape
+                    flat = np.asarray(jax.device_get(
+                        run(batch.reshape(len(idxs), -1), batch.shape)))
+                    sp.tags["bytes_down"] = flat.nbytes
                 with tracer.span("image.assemble") as sp:
+                    # the fetch is in row order unless the device kept this
+                    # 2-D shape column-major (it does where that pads less);
+                    # then one gather here instead of one in every reader
+                    copied = 0 if flat.flags.c_contiguous else flat.nbytes
+                    result = np.ascontiguousarray(flat).reshape(out_shape)
                     if not to_float:
                         result = np.clip(np.rint(result), 0, 255).astype(np.uint8)
+                        copied += result.nbytes
                     if result.shape[-1] == 1 and len(shape) == 2:
                         result = result[..., 0]
-                    for j, i in enumerate(idxs):
-                        out[i] = result[j]  # a view: the scatter copies nothing
-                    sp.tags["bytes"] = 0 if to_float else result.nbytes
+                    sp.tags["bytes"] = copied
+                groups.append((idxs, result))
             with tracer.span("image.assemble") as sp:
-                done = table.with_column(self.getOutputCol(), out)
-                column = done.column(self.getOutputCol())
-                # equal shapes densify into one array (a copy of every
-                # row); mixed shapes stay an object column of the views
-                sp.tags["bytes"] = 0 if column.dtype == object else column.nbytes
-            return done
+                column, sp.tags["bytes"] = _image_column(groups, len(images))
+                return table.with_column(self.getOutputCol(), column)
+
+
+def _image_column(
+    groups: List[Tuple[List[int], np.ndarray]], n: int
+) -> Tuple[np.ndarray, int]:
+    """The output column over ``n`` rows from each shape group's (row
+    indices, fetched result), and the bytes it copied. One group holds every
+    row in order, so its result is the column as fetched (possibly a
+    read-only view of the device's buffer: a Table's columns are immutable).
+    Groups whose results share a shape are written once into one dense
+    array, rows in input order. Otherwise an object column of row views."""
+    if not groups:
+        return np.empty(0), 0
+    if len(groups) == 1:
+        return groups[0][1], 0
+    if len({result.shape[1:] for _, result in groups}) == 1:
+        first = groups[0][1]
+        dense = np.empty((n,) + first.shape[1:], dtype=first.dtype)
+        for idxs, result in groups:
+            dense[idxs] = result
+        return dense, dense.nbytes
+    rows = np.empty(n, dtype=object)
+    for idxs, result in groups:
+        for j, i in enumerate(idxs):
+            rows[i] = result[j]
+    return rows, 0
 
 
 class ImageSetAugmenter(HasInputCol, HasOutputCol, Transformer):

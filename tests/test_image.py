@@ -100,6 +100,73 @@ def test_mixed_shapes_grouped(rng):
     assert all(im.shape == (8, 8, 3) for im in out["out"])
 
 
+# How ImageTransformer builds its output column from what its program
+# returned (one case each): input shapes, toFloat, stages, the column's kind.
+COLUMN_CASES = {
+    "one_group_float": ([(8, 6, 3)] * 4, True, lambda t: t.resize(4, 5), "dense"),
+    "one_group_uint8": ([(8, 6, 3)] * 4, False, lambda t: t.resize(4, 5), "dense"),
+    "interleaved_sizes_resized_float": (
+        [(s, s, 3) for s in (8, 12, 8, 12, 8)], True, lambda t: t.resize(4, 5), "dense"),
+    "interleaved_sizes_resized_uint8": (
+        [(s, s, 3) for s in (8, 12, 8, 12, 8)], False, lambda t: t.resize(4, 5), "dense"),
+    "mixed_results_sharing_their_first_dimension": (
+        [(8, 4, 3), (8, 6, 3), (8, 4, 3)], False, lambda t: t.flip(1), "object"),
+    "mixed_results": ([(8, 8, 3), (12, 12, 3), (8, 8, 3)], True, lambda t: t.flip(0), "object"),
+    "gray_keeps_its_squeeze": ([(8, 6)] * 3, False, lambda t: t.flip(1), "dense"),
+    "gray_interleaved_sizes_resized": (
+        [(8, 6), (12, 6), (8, 6)], False, lambda t: t.resize(4, 5), "dense"),
+    "empty_table": ([], False, lambda t: t.flip(1), "empty"),
+}
+
+
+@pytest.mark.parametrize("case", list(COLUMN_CASES))
+def test_output_column_is_built_from_the_fetched_results(case):
+    from mmlspark_tpu.observability.tracing import get_tracer
+
+    shapes, to_float, stages, kind = COLUMN_CASES[case]
+    rng = np.random.default_rng(7)
+    images = np.empty(len(shapes), dtype=object)
+    for i, shape in enumerate(shapes):
+        # its own fill value and its own noise: row i can only be image i
+        images[i] = (20 * i + rng.integers(0, 20, size=shape)).astype(np.uint8)
+
+    def stage():
+        return stages(ImageTransformer(inputCol="image", outputCol="out", toFloat=to_float))
+
+    tracer = get_tracer()
+    tracer.clear()
+    out = stage().transform(Table({"image": images}))
+    assembled = [s for s in tracer.export() if s["name"] == "image.assemble"]
+    column = out["out"]
+    assert out.num_rows == len(shapes) and isinstance(column, np.ndarray)
+    if kind == "empty":
+        assert column.shape == (0,) and column.dtype == np.float64  # as before
+        assert [s["tags"] for s in assembled] == [{"bytes": 0}]
+        return
+    alone = []
+    for i in range(len(shapes)):
+        one = np.empty(1, dtype=object)
+        one[0] = images[i]
+        alone.append(stage().transform(Table({"image": one}))["out"][0])
+    dtype = np.float32 if to_float else np.uint8
+    if kind == "dense":
+        assert column.dtype == dtype and column.flags.c_contiguous
+        assert column.shape == (len(shapes),) + alone[0].shape
+        assert column.ndim == 1 + len(shapes[0])  # (n, H, W, C); gray (n, H, W)
+    else:
+        assert column.dtype == object and column.shape == (len(shapes),)
+        assert [im.shape for im in column] == list(shapes)
+    for i, want in enumerate(alone):
+        assert column[i].dtype == dtype
+        np.testing.assert_array_equal(column[i], want)
+    # one image.assemble per shape group, then the column's: the only copy
+    # it may make is the one scatter into a dense column of several groups
+    groups = len(set(shapes))
+    assert len(assembled) == groups + 1
+    scattered = kind == "dense" and groups > 1
+    assert assembled[-1]["tags"] == {"bytes": column.nbytes if scattered else 0}
+
+
 def test_augmenter(image_table):
     out = ImageSetAugmenter(inputCol="image", outputCol="image").transform(image_table)
     assert out.num_rows == 6

@@ -176,8 +176,9 @@ def test_featurize_records_three_spans_a_batch(images, batch):
     assert tags("image.transform") == [{"rows": images, "groups": 1}]
     assert tags("image.stack") == [{"bytes": uint8}]
     assert tags("image.apply_fetch") == [{"bytes_up": uint8, "bytes_down": resized}]
-    # float output: the scatter hands out views; the column densifies (one copy)
-    assert tags("image.assemble") == [{"bytes": 0}, {"bytes": resized}]
+    # float output, one shape group: no clip/round, and the fetched result
+    # is handed over as the column (no copy)
+    assert tags("image.assemble") == [{"bytes": 0}, {"bytes": 0}]
     assert tags("dnn.transform") == [{"rows": images, "batches": batches}]
     import jax
 
