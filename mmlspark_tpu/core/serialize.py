@@ -55,12 +55,18 @@ def _write_pytree(value: Any, path: str) -> None:
     import jax
 
     leaves, treedef = jax.tree_util.tree_flatten(value)
+    leaves = [np.asarray(l) for l in leaves]  # one fetch a leaf
     np.savez(
         os.path.join(path, "leaves.npz"),
-        **{f"leaf_{i}": np.asarray(l) for i, l in enumerate(leaves)},
+        **{f"leaf_{i}": l for i, l in enumerate(leaves)},
     )
     with open(os.path.join(path, "treedef.pkl"), "wb") as f:
         pickle.dump(treedef, f)
+    # npz keeps an extension dtype (bfloat16 weights) only as raw 2-byte voids
+    extended = {str(i): str(l.dtype) for i, l in enumerate(leaves) if l.dtype.kind == "V"}
+    if extended:
+        with open(os.path.join(path, "extended_dtypes.json"), "w") as f:
+            json.dump(extended, f)
 
 
 def _read_pytree(path: str) -> Any:
@@ -68,6 +74,13 @@ def _read_pytree(path: str) -> Any:
 
     with np.load(os.path.join(path, "leaves.npz"), allow_pickle=True) as z:
         leaves = [z[f"leaf_{i}"] for i in range(len(z.files))]
+    extended = os.path.join(path, "extended_dtypes.json")
+    if os.path.exists(extended):
+        import ml_dtypes
+
+        with open(extended) as f:
+            for i, name in json.load(f).items():
+                leaves[int(i)] = leaves[int(i)].view(getattr(ml_dtypes, name))
     with open(os.path.join(path, "treedef.pkl"), "rb") as f:
         treedef = pickle.load(f)
     return jax.tree_util.tree_unflatten(treedef, leaves)

@@ -8,6 +8,7 @@ from mmlspark_tpu.featurize.indexers import (
     ValueIndexer,
     ValueIndexerModel,
 )
+from mmlspark_tpu.featurize.lm import LMFeaturizer
 from mmlspark_tpu.featurize.text import (
     MultiNGram,
     PageSplitter,
@@ -22,6 +23,7 @@ __all__ = [
     "DataConversion",
     "Featurize",
     "IndexToValue",
+    "LMFeaturizer",
     "MultiNGram",
     "PageSplitter",
     "TextFeaturizer",

@@ -1,0 +1,78 @@
+"""LMFeaturizer — score or featurize a column of token rows with a decoder.
+
+The text sibling of :class:`mmlspark_tpu.image.ImageFeaturizer`: a language
+model (default family: :mod:`mmlspark_tpu.models.afmoe`) applied to whole
+sequences by :class:`DNNModel` in fixed-shape device batches, features and
+last-position logits out, for a downstream learner. No generation loop, no
+cache across calls.
+"""
+
+from __future__ import annotations
+
+import numpy as np
+
+from mmlspark_tpu.core.params import Param, gt, to_int, to_str
+from mmlspark_tpu.core.pipeline import Model
+from mmlspark_tpu.data.table import Table
+from mmlspark_tpu.dnn.model import DNNModel
+from mmlspark_tpu.observability.tracing import get_tracer
+
+_LOAD = "expert_load"
+
+
+class LMFeaturizer(Model):
+    """Apply a decoder to a column of int32 token rows of one length."""
+
+    inputCol = Param("Column of token-id rows, all of one length", default="tokens", converter=to_str)
+    outputCols = Param(
+        "model output ('hidden': last position after the final norm, 'logits': "
+        "last-position logits, 'expert_load': tokens of the row each expert "
+        "received, per expert layer) -> output column",
+        default={"hidden": "features"},
+    )
+    modelParams = Param(
+        "Decoder parameter pytree (mmlspark_tpu.models.afmoe.init_afmoe format)",
+        default=None, is_complex=True,
+    )
+    modelConfig = Param("Decoder configuration (see mmlspark_tpu.models.afmoe)", default=None)
+    batchSize = Param("Rows per device batch", default=4, converter=to_int, validator=gt(0))
+
+    def transform(self, table: Table) -> Table:
+        """One ``lm.featurize`` span roots the call's trace, the batched
+        forward's ``dnn.*`` spans beneath it; ``lm.route_stats`` then sums the
+        fetched expert loads per dispatch (``observability/tracing``)."""
+        from mmlspark_tpu.models.afmoe import afmoe_apply
+
+        params, config = self.getModelParams(), self.getModelConfig()
+        if params is None or config is None:
+            raise ValueError("modelParams and modelConfig must be set (see mmlspark_tpu.models.afmoe)")
+        outputs = dict(self.getOutputCols())
+        unknown = set(outputs) - {"hidden", "logits", _LOAD}
+        if unknown or not outputs:
+            raise ValueError(f"outputCols maps hidden / logits / expert_load to columns (got {sorted(outputs)})")
+        batch = self.getBatchSize()
+        tokens = len(table.column(self.getInputCol())[0])
+        with get_tracer().span(
+            "lm.featurize", rows=table.num_rows, tokens=tokens, batch_size=batch,
+            layers=config["layers"], experts=config["num_experts"],
+        ):
+            load_col = outputs.get(_LOAD, "__expert_load__")  # fetched always: route_stats reads it
+            dnn = DNNModel(
+                applyFn=lambda p, inputs: afmoe_apply(p, inputs["input"], config),
+                modelParams=params,
+                feedDict={"input": self.getInputCol()},
+                fetchDict={**{col: out for out, col in outputs.items()}, load_col: _LOAD},
+                batchSize=batch,
+                inputDtype="int32",
+            )
+            out = dnn.transform(table)
+            with get_tracer().span("lm.route_stats") as sp:
+                # (rows, expert layers, experts) -> per dispatch (the rows of one batch)
+                load = np.asarray(out[load_col], np.int64)
+                per_dispatch = np.add.reduceat(load, np.arange(0, len(load), batch), axis=0)
+                sp.tags["load_peak"] = int(per_dispatch.max(axis=-1).sum())
+                sp.tags["load_mean"] = float(per_dispatch.mean(axis=-1).sum())
+                sp.tags["tokens_routed"] = int(load.sum())
+            if _LOAD not in outputs:
+                out = out.drop(load_col)
+            return out

@@ -6,6 +6,7 @@ weights are produced by training or loaded from checkpoints via
 :mod:`mmlspark_tpu.downloader`.
 """
 
+from mmlspark_tpu.models.afmoe import afmoe_apply, init_afmoe
 from mmlspark_tpu.models.resnet import init_resnet, resnet_apply
 from mmlspark_tpu.models.zoo import (
     load_zoo_params,
@@ -16,6 +17,6 @@ from mmlspark_tpu.models.zoo import (
 )
 
 __all__ = [
-    "init_resnet", "resnet_apply", "publish_model", "load_zoo_params",
+    "init_resnet", "resnet_apply", "init_afmoe", "afmoe_apply", "publish_model", "load_zoo_params",
     "params_to_bytes", "params_from_bytes", "train_resnet_classifier",
 ]

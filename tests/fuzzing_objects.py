@@ -398,6 +398,28 @@ def _make_test_objects() -> Dict[str, Callable[[], TestObject]]:
 
     add("mmlspark_tpu.image.featurizer.ImageFeaturizer", image_featurizer)
 
+    def lm_featurizer():
+        import jax
+
+        from mmlspark_tpu.featurize import LMFeaturizer
+        from mmlspark_tpu.models import init_afmoe
+
+        config = dict(
+            hidden_size=32, num_attention_heads=2, num_key_value_heads=1, head_dim=16,
+            intermediate_size=48, moe_intermediate_size=16, num_experts=4,
+            num_experts_per_tok=2, num_dense_layers=1, sliding_window=4, rope_theta=10000,
+            rms_norm_eps=1e-5, route_scale=2.0, vocab_size=64, layers=3, interpret=True,
+            layer_types=["sliding_attention", "full_attention", "sliding_attention"],
+        )
+        params = jax.tree.map(np.asarray, init_afmoe(jax.random.PRNGKey(0), config))
+        tokens = _rng(3).integers(0, 64, size=(3, 12)).astype(np.int32)
+        return TestObject(
+            LMFeaturizer(modelParams=params, modelConfig=config, batchSize=2),
+            Table({"id": np.arange(3), "tokens": tokens}),
+        )
+
+    add("mmlspark_tpu.featurize.lm.LMFeaturizer", lm_featurizer)
+
     def superpixel():
         from mmlspark_tpu.lime import SuperpixelTransformer
 

@@ -1,0 +1,244 @@
+"""The ``afmoe`` decoder on the deep path, at a small size on the CPU:
+``LMFeaturizer`` through ``DNNModel.transform`` against the benchmark's
+plain reference, the blocked attention against dense masked attention, the
+sorted top-k experts against every expert under a mask, and the layer kinds
+of the benchmark's cut."""
+
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from chipbench.reference import afmoe as ref
+from mmlspark_tpu.data.table import Table
+from mmlspark_tpu.featurize.lm import LMFeaturizer
+from mmlspark_tpu.models.afmoe import afmoe_apply, init_afmoe, layer_kinds
+from mmlspark_tpu.observability.tracing import get_tracer
+from mmlspark_tpu.ops.attention import blocked_attention
+from mmlspark_tpu.ops.expert_parallel import moe_topk
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+KINDS = ["sliding_attention"] * 3 + ["full_attention"]
+SMALL = dict(
+    hidden_size=64, num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+    intermediate_size=96, moe_intermediate_size=32, num_experts=8, num_experts_per_tok=2,
+    num_shared_experts=1, num_dense_layers=2, sliding_window=8, rope_theta=10000,
+    rms_norm_eps=1e-5, route_scale=2.826, vocab_size=512, layer_types=KINDS * 2, layers=6,
+    interpret=True,  # the attention kernel, on a backend that is no TPU
+)
+ALL_OUTPUTS = {"hidden": "h", "logits": "l", "expert_load": "e"}
+
+
+def _tokens(seed, rows=5, length=50):
+    return np.random.default_rng(seed).integers(0, SMALL["vocab_size"], size=(rows, length)).astype(np.int32)
+
+
+@pytest.fixture(scope="module")
+def params():
+    return init_afmoe(jax.random.PRNGKey(7), SMALL)
+
+
+# -- the model, through the stage ---------------------------------------------
+
+@pytest.mark.parametrize("seed,batch", [(0, 2), (1, 5), (2, 3)])
+def test_featurizer_agrees_with_the_reference_on_all_three_outputs(params, seed, batch):
+    tokens = _tokens(seed)
+    out = LMFeaturizer(outputCols=ALL_OUTPUTS, modelParams=params, modelConfig=SMALL,
+                       batchSize=batch).transform(Table({"tokens": tokens}))
+    want = ref.forward(params, tokens, SMALL)
+    assert out["h"].shape == (5, 64) and out["l"].shape == (5, 512) and out["e"].shape == (5, 4, 8)
+    assert out["h"].dtype == np.float32 and out["l"].dtype == np.float32 and out["e"].dtype == np.int32
+    assert ref.relative_gaps(out["h"], want["hidden"]).max() < 0.03
+    assert ref.relative_gaps(out["l"], want["logits"]).max() < 0.03
+    assert ref.load_gaps(out["e"], want["expert_load"], 50 * 2).max() <= 0.02
+    # no token is dropped: every expert layer of every row routed S x k
+    assert (out["e"].sum(axis=-1) == 50 * 2).all()
+
+
+@pytest.mark.parametrize("fault", ref.FAULTS)
+def test_each_planted_fault_moves_the_reference_far_from_the_program(params, fault):
+    tokens = _tokens(3)
+    got = jax.jit(lambda p, x: afmoe_apply(p, x, SMALL))(params, tokens)
+    wrong = ref.forward(params, tokens, SMALL, fault=fault)
+    assert ref.relative_gaps(got["hidden"], wrong["hidden"]).min() > 0.1
+
+
+def test_an_unknown_fault_is_an_error(params):
+    with pytest.raises(ValueError, match="unknown fault"):
+        ref.forward(params, _tokens(0, rows=1), SMALL, fault="typo")
+
+
+def test_the_reference_imports_nothing_of_the_program():
+    with open(os.path.join(ROOT, "chipbench", "reference", "afmoe.py")) as f:
+        assert "mmlspark_tpu" not in f.read().split('"""', 2)[2]
+
+
+def test_narrower_product_inputs_are_a_different_result(params):
+    tokens = _tokens(4)
+    stated = jax.jit(lambda p, x: afmoe_apply(p, x, SMALL))(params, tokens)
+    again = jax.jit(lambda p, x: afmoe_apply(p, x, dict(SMALL, product_dtype="bfloat16")))(params, tokens)
+    low = jax.jit(lambda p, x: afmoe_apply(p, x, dict(SMALL, product_dtype="float8_e4m3fn")))(params, tokens)
+    assert np.array_equal(stated["hidden"], again["hidden"])
+    assert 0.02 < ref.relative_gaps(low["hidden"], stated["hidden"]).min()
+
+
+def test_outputs_follow_output_cols_and_the_load_column_is_dropped_again(params):
+    table = Table({"tokens": _tokens(0), "id": np.arange(5)})
+    out = LMFeaturizer(modelParams=params, modelConfig=SMALL, batchSize=4).transform(table)
+    assert set(out.columns) == {"tokens", "id", "features"}
+    with pytest.raises(ValueError, match="outputCols"):
+        LMFeaturizer(outputCols={"probs": "p"}, modelParams=params, modelConfig=SMALL).transform(table)
+    with pytest.raises(ValueError, match="modelParams and modelConfig"):
+        LMFeaturizer(modelParams=params).transform(table)
+
+
+def test_spans_of_a_transform(params):
+    tracer = get_tracer()
+    tracer.clear()
+    out = LMFeaturizer(outputCols=ALL_OUTPUTS, modelParams=params, modelConfig=SMALL,
+                       batchSize=2).transform(Table({"tokens": _tokens(5)}))
+    spans = {s["name"]: s for s in tracer.export()}
+    root = spans["lm.featurize"]
+    assert root["tags"] == {"rows": 5, "tokens": 50, "batch_size": 2, "layers": 6, "experts": 8}
+    assert spans["dnn.transform"]["parent_id"] == root["span_id"]
+    stats = spans["lm.route_stats"]["tags"]
+    per_dispatch = np.add.reduceat(out["e"].astype(np.int64), [0, 2, 4], axis=0)
+    assert stats["load_peak"] == per_dispatch.max(axis=-1).sum()
+    assert stats["load_mean"] == pytest.approx(5 * 4 * 50 * 2 / 8)
+    assert stats["tokens_routed"] == 5 * 4 * 50 * 2
+    assert stats["load_peak"] >= stats["load_mean"]
+
+
+def test_named_scopes_are_in_the_lowered_program(params):
+    text = jax.jit(lambda p, x: afmoe_apply(p, x, SMALL)).lower(params, _tokens(0)).as_text(debug_info=True)
+    for scope in ("attn_window", "attn_full", "moe_route", "moe_experts", "lm_head"):
+        assert scope in text, scope
+
+
+def test_weights_are_bfloat16_on_the_device_and_come_from_the_key(params):
+    leaves = jax.tree.leaves(params)
+    assert all(isinstance(a, jax.Array) for a in leaves)
+    assert {str(a.dtype) for a in leaves} == {"bfloat16", "float32"}
+    assert params["moe"]["router_bias"].dtype == jnp.float32  # the one float32 buffer
+    assert params["moe"]["e_gate"].shape == (4, 8, 64, 32) and params["dense"]["w_up"].shape == (2, 64, 96)
+    again = init_afmoe(jax.random.PRNGKey(7), SMALL)
+    other = init_afmoe(jax.random.PRNGKey(8), SMALL)
+    assert np.array_equal(params["head"], again["head"]) and not np.array_equal(params["head"], other["head"])
+
+
+# -- the benchmark's cut ------------------------------------------------------
+
+def test_layer_kinds_of_the_cut_are_the_published_lists_first_six():
+    with open(os.path.join(ROOT, "chipbench", "configs", "trinity-mini.json")) as f:
+        spec = json.load(f)
+    config = spec["params"]
+    assert config["layers"] == 6 and spec["num_hidden_layers"] == 32 and len(config["layer_types"]) == 32
+    dense, moe = layer_kinds(config)
+    assert [k == "sliding_attention" for k in config["layer_types"][:6]] == dense + moe
+    assert dense == [True, True] and moe == [True, False, True, True]  # one whole period, three to one
+    assert [(s, i) for s, i, _ in ref.layer_kinds(config)] == [
+        ("dense", 0), ("dense", 1), ("moe", 0), ("moe", 1), ("moe", 2), ("moe", 3)]
+    assert [sl for _, _, sl in ref.layer_kinds(config)] == dense + moe
+
+
+# -- blocked attention --------------------------------------------------------
+
+def _dense_attention(q, k, v, window):
+    B, S, H, d = q.shape
+    G = H // k.shape[2]
+    q, k, v = (np.asarray(a, np.float64) for a in (q, k, v))
+    i, j = np.arange(S)[:, None], np.arange(S)[None, :]
+    seen = (j <= i) if window is None else (j <= i) & (j > i - window)
+    out = np.zeros_like(q)
+    for h in range(H):
+        scores = np.einsum("bsd,btd->bst", q[:, :, h], k[:, :, h // G]) / np.sqrt(d)
+        scores = np.where(seen, scores, -np.inf)
+        weights = np.exp(scores - scores.max(axis=-1, keepdims=True))
+        out[:, :, h] = np.einsum("bst,btd->bsd", weights / weights.sum(axis=-1, keepdims=True), v[:, :, h // G])
+    return out
+
+
+@pytest.mark.parametrize("window", [None, 1, 7, 16, 40, 1000])
+@pytest.mark.parametrize("length,block", [(50, 16), (64, 16), (9, 512)])
+def test_blocked_attention_against_dense_masked_attention(window, length, block):
+    rng = np.random.default_rng(length)
+    q = jnp.asarray(rng.normal(size=(2, length, 4, 8)), jnp.float32)
+    k, v = (jnp.asarray(rng.normal(size=(2, length, 2, 8)), jnp.float32) for _ in range(2))
+    got = jax.jit(lambda *a: blocked_attention(*a, window=window, block=block, interpret=True))(q, k, v)
+    assert got.shape == q.shape and got.dtype == q.dtype
+    np.testing.assert_allclose(got, _dense_attention(q, k, v, window), atol=2e-5)
+
+
+def test_blocked_attention_in_bfloat16():
+    rng = np.random.default_rng(0)
+    q = jnp.asarray(rng.normal(size=(1, 40, 4, 8)), jnp.bfloat16)
+    k, v = (jnp.asarray(rng.normal(size=(1, 40, 2, 8)), jnp.bfloat16) for _ in range(2))
+    for window in (5, None):
+        got = blocked_attention(q, k, v, window=window, block=16, interpret=True)
+        assert got.dtype == jnp.bfloat16
+        np.testing.assert_allclose(np.asarray(got, np.float64), _dense_attention(q, k, v, window), atol=0.03)
+
+
+def test_blocked_attention_refuses_heads_that_do_not_group():
+    q = jnp.zeros((1, 8, 3, 4))
+    with pytest.raises(ValueError, match="grouped heads"):
+        blocked_attention(q, jnp.zeros((1, 8, 2, 4)), jnp.zeros((1, 8, 2, 4)))
+    with pytest.raises(ValueError, match="whole query blocks"):
+        blocked_attention(jnp.zeros((1, 300, 2, 4)), *[jnp.zeros((1, 300, 2, 4))] * 2, block=200)
+
+
+# -- top-k experts ------------------------------------------------------------
+
+def _every_expert_masked(x, choose, weigh, experts, k, scale):
+    x, choose, weigh = (np.asarray(a, np.float64) for a in (x, choose, weigh))
+    chosen = np.argsort(-choose, axis=1, kind="stable")[:, :k]
+    picked = np.take_along_axis(weigh, chosen, axis=1)
+    weights = picked / picked.sum(axis=1, keepdims=True) * scale
+    y = np.zeros_like(x)
+    for e in range(choose.shape[1]):
+        gate, up, down = (np.asarray(experts[n][e], np.float64) for n in ("gate", "up", "down"))
+        a = x @ gate
+        out = (a / (1 + np.exp(-a)) * (x @ up)) @ down
+        y += (weights * (chosen == e)).sum(axis=1)[:, None] * out
+    return y, chosen
+
+
+@pytest.mark.parametrize("k", [1, 2, 3])
+def test_moe_topk_against_every_expert_under_a_mask(k):
+    """Expert 5 gets no token, expert 2 gets half of them (every even
+    token's first choice), and nothing is dropped."""
+    rng = np.random.default_rng(k)
+    T, D, F, E = 64, 16, 8, 6
+    x = jnp.asarray(rng.normal(size=(T, D)), jnp.float32)
+    experts = {"gate": jnp.asarray(rng.normal(size=(E, D, F)) / 4, jnp.float32),
+               "up": jnp.asarray(rng.normal(size=(E, D, F)) / 4, jnp.float32),
+               "down": jnp.asarray(rng.normal(size=(E, F, D)) / 3, jnp.float32)}
+    weigh = rng.uniform(0.1, 0.9, size=(T, E))
+    bias = np.zeros((T, E))
+    bias[:, 5] = -10.0  # never chosen
+    bias[::2, 2] = 10.0  # always chosen by every other token
+    bias[1::2, 2] = -10.0
+    choose = jnp.asarray(weigh + bias, jnp.float32)
+    y, chosen = jax.jit(lambda *a: moe_topk(*a, k, 2.5))(x, choose, jnp.asarray(weigh, jnp.float32), experts)
+    want, want_chosen = _every_expert_masked(x, choose, weigh, experts, k, 2.5)
+    load = np.bincount(np.asarray(chosen).ravel(), minlength=E)
+    assert load[5] == 0 and load[2] == T // 2 and load.sum() == T * k
+    assert np.array_equal(np.sort(chosen, axis=1), np.sort(want_chosen, axis=1))
+    assert y.dtype == jnp.float32 and chosen.dtype == jnp.int32
+    np.testing.assert_allclose(y, want, rtol=2e-4, atol=2e-5)
+
+
+def test_moe_topk_when_one_expert_takes_every_token():
+    rng = np.random.default_rng(0)
+    T, D, F, E = 32, 8, 4, 4
+    x = jnp.asarray(rng.normal(size=(T, D)), jnp.float32)
+    experts = {n: jnp.asarray(rng.normal(size=s), jnp.float32)
+               for n, s in (("gate", (E, D, F)), ("up", (E, D, F)), ("down", (E, F, D)))}
+    scores = jnp.zeros((T, E)).at[:, 3].set(1.0)
+    y, chosen = moe_topk(x, scores, scores, experts, 1, 1.0)
+    want, _ = _every_expert_masked(x, scores, scores, experts, 1, 1.0)
+    assert (np.asarray(chosen) == 3).all()
+    np.testing.assert_allclose(y, want, rtol=2e-4, atol=2e-5)

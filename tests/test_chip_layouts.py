@@ -44,3 +44,61 @@ def test_resize_program_crosses_the_host_boundary_in_row_order(one_chip, side_in
     memory = compiled.memory_analysis()
     assert memory.output_size_in_bytes == shape[0] * 224 * 224 * 3 * 4
     assert (memory.temp_size_in_bytes > 0) == temporaries
+
+
+def _compile_off(fn, *shapes):
+    """Compile with the persistent cache off: an entry written for a
+    described device cannot be read back without one."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return jax.jit(fn).lower(*shapes).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", before)
+        compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("window", [2048, None], ids=["window", "full"])
+def test_blocked_attention_at_the_published_widths_never_holds_whole_scores(one_chip, window):
+    """One dispatch of the language-model cell: 4 rows of 8,192 tokens, 32
+    query heads over 4 key/value heads of 128. Whole float32 scores would
+    be 4 x 32 x 8,192^2 x 4 B = 32 GiB; the kernel (it compiles as written:
+    a ``tpu_custom_call``) needs only the transposed copies of its operands
+    (PERF.md, PR 27)."""
+    import jax
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.ops.attention import blocked_attention
+
+    q = jax.ShapeDtypeStruct((4, 8192, 32, 128), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((4, 8192, 4, 128), jnp.bfloat16, sharding=one_chip)
+    compiled = _compile_off(lambda q, k, v: blocked_attention(q, k, v, window=window), q, kv, kv)
+    memory = compiled.memory_analysis()
+    assert memory.output_size_in_bytes == 4 * 8192 * 32 * 128 * 2
+    assert memory.temp_size_in_bytes < 2**30 and "tpu_custom_call" in compiled.as_text()
+
+
+def test_topk_experts_at_the_published_widths_use_the_grouped_product(one_chip):
+    """32,768 tokens, 128 experts of width 1,024, top-8: XLA:TPU lowers
+    ``lax.ragged_dot`` to its own grouped kernel (a ``tpu_custom_call``),
+    not to 128 masked dense products."""
+    import jax
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.ops.expert_parallel import moe_topk
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    experts = {"gate": spec((128, 2048, 1024)), "up": spec((128, 2048, 1024)),
+               "down": spec((128, 1024, 2048))}
+    scores = spec((32768, 128), jnp.float32)
+    compiled = _compile_off(lambda x, s, e: moe_topk(x, s, s, e, 8, 2.826),
+                            spec((32768, 2048)), scores, experts)
+    text = compiled.as_text()
+    assert text.count("ragged-dot") >= 3 and "tpu_custom_call" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 6 * 2**30

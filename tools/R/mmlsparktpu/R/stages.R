@@ -847,6 +847,24 @@ mt_value_indexer_model <- function(...) {
   mt_stage("mmlspark_tpu.featurize.indexers", "ValueIndexerModel", ...)
 }
 
+#' LMFeaturizer (Model)
+#'
+#' Apply a decoder to a column of int32 token rows of one length.
+#'
+#' @section Parameters:
+#' \itemize{
+#'   \item \code{batchSize}: Rows per device batch (default 4)
+#'   \item \code{inputCol}: Column of token-id rows, all of one length (default 'tokens')
+#'   \item \code{modelConfig}: Decoder configuration (see mmlspark_tpu.models.afmoe) (default None)
+#'   \item \code{modelParams}: Decoder parameter pytree (mmlspark_tpu.models.afmoe.init_afmoe format) (default None)
+#'   \item \code{outputCols}: model output ('hidden': last position after the final norm, 'logits': last-position logits, 'expert_load': tokens of the row each expert received, per expert layer) -> output column (default {'hidden': 'features'})
+#' }
+#' @param ... named params forwarded to the Python constructor
+#' @export
+mt_lm_featurizer <- function(...) {
+  mt_stage("mmlspark_tpu.featurize.lm", "LMFeaturizer", ...)
+}
+
 #' MultiNGram (Transformer)
 #'
 #' All n-grams for several lengths at once (``featurize/text/MultiNGram.scala:24``). Input: token-list column.
