@@ -15,7 +15,8 @@ Re-design of ``CNTKModel`` (``cntk/CNTKModel.scala:145-531``) for TPU:
   (``CNTKModel.scala:225-367``); the single-input/single-output convenience
   setters mirror ``setInputCol``/``setOutputCol``;
 - input coercion float/double/vector (``CNTKModel.scala:417-460``) becomes
-  dtype casting on the padded host batch.
+  a cast while the host batch is written, and no copy at all where a dense
+  column's slice already is the batch (``_stack_batch``).
 
 Optionally shards each batch over the mesh ``data`` axis — the reference's
 per-partition embarrassing parallelism (``CNTKModelUtils.applyModel``,
@@ -35,12 +36,25 @@ from mmlspark_tpu.observability.tracing import get_tracer
 
 
 def _stack_batch(col: np.ndarray, pad_to: int, dtype: Any) -> np.ndarray:
-    """Rows of a column -> one padded [pad_to, ...] device-ready batch."""
-    rows = [np.asarray(v) for v in col]
-    batch = np.stack(rows).astype(dtype)
-    if len(rows) < pad_to:
-        pad = np.zeros((pad_to - len(rows),) + batch.shape[1:], dtype=batch.dtype)
-        batch = np.concatenate([batch, pad])
+    """A column's slice of rows -> the [pad_to, ...] batch of ``dtype`` the
+    program is fed, copying only where the slice itself forces a copy.
+
+    A dense slice that already is that batch (``pad_to`` rows, ``dtype``,
+    C-contiguous) is returned as it is: a view of an immutable ``Table``
+    column, possibly read-only, so nothing may write into a batch and the
+    program must not donate its inputs. Any other dense slice (short of
+    ``pad_to``, another dtype, strided) is written once into a fresh batch:
+    the assignment casts, the tail rows are zero. An object column's rows
+    (ragged tables, lists of arrays) are stacked first, which raises on rows
+    of unequal shape."""
+    if col.dtype == object:
+        col = np.stack([np.asarray(v) for v in col])
+    rows = len(col)
+    if rows == pad_to and col.dtype == dtype and col.flags.c_contiguous:
+        return col
+    batch = np.empty((pad_to,) + col.shape[1:], dtype=dtype)
+    batch[:rows] = col
+    batch[rows:] = 0
     return batch
 
 
@@ -275,8 +289,10 @@ class DNNModel(Model):
     def transform(self, table: Table) -> Table:
         """Spans (``observability/tracing``; under ``ServingServer``'s batch
         loop they join the request's trace): ``dnn.transform`` around the
-        call, ``dnn.place_params``, then per batch ``dnn.stack`` (rows to one
-        padded host batch), ``dnn.dispatch`` (input transfer and enqueue;
+        call, ``dnn.place_params``, then per batch ``dnn.stack`` (the
+        column's slice as one padded host batch; its ``bytes`` is what it
+        copied, 0 where the slice is the batch), ``dnn.dispatch`` (input
+        transfer and enqueue, ``bytes`` what the program is fed;
         on a call's first batch also the trace and lowering) and
         ``dnn.fetch`` (it owns the wait on the forward), and ``dnn.assemble``
         for the output columns. Byte tags come from shapes."""
@@ -330,11 +346,14 @@ class DNNModel(Model):
                     # GPipe needs batch % microbatches == 0 even un-minibatched
                     pad_to += (-pad_to) % self.getNumMicrobatches()
                 with tracer.span("dnn.stack", pad_rows=pad_to - (hi - lo)) as sp:
-                    inputs = {
-                        model_in: _stack_batch(table.column(col)[lo:hi], pad_to, dtype)
-                        for model_in, col in feeds.items()
-                    }
-                    fed = sp.tags["bytes"] = sum(a.nbytes for a in inputs.values())
+                    inputs, copied = {}, 0
+                    for model_in, col in feeds.items():
+                        rows = table.column(col)[lo:hi]
+                        batch = inputs[model_in] = _stack_batch(rows, pad_to, dtype)
+                        if batch is not rows:
+                            copied += batch.nbytes
+                    sp.tags["bytes"] = copied
+                    fed = sum(a.nbytes for a in inputs.values())
                 with tracer.span("dnn.dispatch", bytes=fed):
                     outputs = fn(params, inputs)
                 with tracer.span("dnn.fetch") as sp:

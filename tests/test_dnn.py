@@ -99,6 +99,106 @@ def test_dnn_model_transform_batched():
     np.testing.assert_allclose(out["scores"], expected, rtol=1e-4, atol=1e-5)
 
 
+def _stack_batch_reference(col, pad_to, dtype):
+    """``_stack_batch`` as it was before it learned to hand over a view: the
+    plain reference (a list of rows, ``np.stack``, ``astype``, zero pad)."""
+    rows = [np.asarray(v) for v in col]
+    batch = np.stack(rows).astype(dtype)
+    if len(rows) < pad_to:
+        pad = np.zeros((pad_to - len(rows),) + batch.shape[1:], dtype=batch.dtype)
+        batch = np.concatenate([batch, pad])
+    return batch
+
+
+def _object_column(rows):
+    col = np.empty(len(rows), dtype=object)
+    for i, row in enumerate(rows):
+        col[i] = row
+    return col
+
+
+def _readonly(a):
+    a.flags.writeable = False
+    return a
+
+
+_IMAGES = np.random.default_rng(5).standard_normal((12, 4, 4, 3))
+_IMAGES32 = _IMAGES.astype(np.float32)
+_PAD_TO = 4
+_STACK_CASES = {
+    # name: (column, the batch's rows, dtype, how the batch is made)
+    "dense_full": (_IMAGES32, slice(4, 8), "float32", "view"),
+    "dense_full_readonly": (_readonly(_IMAGES32.copy()), slice(0, 4), "float32", "view"),
+    "dense_padded": (_IMAGES32, slice(8, 11), "float32", "copy"),
+    "dense_float64_to_float32": (_IMAGES, slice(0, 4), "float32", "copy"),
+    "dense_int32_tokens": (np.arange(96, dtype=np.int32).reshape(8, 12), slice(4, 8), "int32", "view"),
+    "dense_scalars_padded": (np.arange(6.0), slice(4, 6), "float32", "copy"),
+    "dense_strided": (_IMAGES32, slice(0, 8, 2), "float32", "copy"),
+    "dense_transposed_rows": (_IMAGES32.transpose(0, 3, 1, 2), slice(0, 4), "float32", "copy"),
+    "object_equal_rows": (_object_column(list(_IMAGES32)), slice(0, 4), "float32", "copy"),
+    "object_padded_cast": (_object_column(list(_IMAGES)), slice(9, 12), "float32", "copy"),
+    "object_unequal_rows": (
+        _object_column([np.zeros((2, 3)), np.zeros((3, 3)), np.zeros((2, 3))]), slice(0, 3), "float32", "raises"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_STACK_CASES))
+def test_stack_batch_equals_the_row_by_row_reference(case):
+    """Every path of ``_stack_batch`` gives the old implementation's batch
+    byte for byte; a dense, contiguous, equal-dtype full slice is handed
+    over as a view (also of a read-only column), anything else is a fresh
+    writable batch."""
+    from mmlspark_tpu.dnn.model import _stack_batch
+
+    column, which, dtype, how = _STACK_CASES[case]
+    rows, dtype = column[which], np.dtype(dtype)
+    if how == "raises":
+        with pytest.raises(ValueError):
+            _stack_batch_reference(rows, _PAD_TO, dtype)
+        with pytest.raises(ValueError):
+            _stack_batch(rows, _PAD_TO, dtype)
+        return
+    want = _stack_batch_reference(rows, _PAD_TO, dtype)
+    before = np.array(rows, copy=True)
+    got = _stack_batch(rows, _PAD_TO, dtype)
+    assert (got.shape, got.dtype) == (want.shape, want.dtype)
+    assert len(got) == _PAD_TO and got.dtype == dtype
+    assert got.flags.c_contiguous
+    assert got.tobytes() == want.tobytes()
+    if how == "view":
+        assert got is rows and np.shares_memory(got, column)
+    else:
+        assert not np.shares_memory(got, column) and got.flags.writeable
+        if column.dtype != object:
+            np.testing.assert_array_equal(rows, before)  # the column is not written
+
+
+def test_dnn_model_same_features_from_dense_and_object_columns():
+    """The view path (full batches of a read-only dense float32 column), the
+    one-copy path (its padded last batch) and the stacked path (the same rows
+    as an object column) feed the program the same bytes."""
+    from mmlspark_tpu.observability.tracing import get_tracer
+
+    rng = np.random.default_rng(7)
+    dense = _readonly(rng.standard_normal((11, 6)).astype(np.float32))
+    weights = rng.standard_normal((6, 3)).astype(np.float32)
+    model = DNNModel(
+        applyFn=lambda params, inputs: {"output": inputs["x"] @ params["w"]},
+        modelParams={"w": weights}, feedDict={"x": "x"},
+        fetchDict={"y": "output"}, batchSize=4,
+    )
+    tracer = get_tracer()
+    tracer.clear()
+    from_dense = model.transform(Table({"x": dense}))["y"]
+    from_rows = model.transform(Table({"x": _object_column(list(dense))}))["y"]
+    assert from_dense.shape == (11, 3)
+    assert from_dense.tobytes() == from_rows.tobytes()
+    np.testing.assert_allclose(from_dense, dense @ weights, rtol=1e-5, atol=1e-6)
+    copied = [s["tags"]["bytes"] for s in tracer.export() if s["name"] == "dnn.stack"]
+    batch = 4 * 6 * 4
+    assert copied == [0, 0, batch] + [batch] * 3
+
+
 def test_dnn_model_sharded(mesh8):
     fn = lambda params, inputs: {"output": inputs["x"] * params["scale"]}
     n = 40
