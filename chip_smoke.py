@@ -437,7 +437,6 @@ def phase_kernels(sz: Sizes, out: dict):
         build_histograms_pallas,
         build_histograms_panel_pallas,
     )
-    from mmlspark_tpu.ops import u_histogram
     from mmlspark_tpu.ops.u_histogram import make_u_spec, stat_rows_quant
 
     n, f, b, nodes = sz.kernel_rows, sz.kernel_features, MAX_BIN + 1, 8
@@ -498,22 +497,6 @@ def phase_kernels(sz: Sizes, out: dict):
         np.asarray(got).astype(np.int64), want, err_msg="bin_scatter quant"
     )
     out["build_histograms_bin_scatter_quant"] = f"{got.dtype} sums exact"
-
-    # the fused panel+dot pass sits behind MMLSPARK_TPU_U_FUSED inside
-    # build_histograms_u and only ever runs on the chip; flip the module
-    # switch that variable sets, for this one call
-    if sz.interpret:
-        out["_fused_panel_dot"] = "needs the chip (tests interpret it)"
-        return
-    u = jax.jit(lambda x: u_histogram.build_u(x, spec))(bins)
-    switch, u_histogram._FUSED = u_histogram._FUSED, True
-    try:
-        fused = jax.jit(lambda *a: u_histogram.build_histograms_u(
-            *a, nodes, spec
-        ))(u, g, h, c, node8)
-    finally:
-        u_histogram._FUSED = switch
-    check("_fused_panel_dot", fused, ref8)
 
 
 def _shard_probe(rows: int):

@@ -96,24 +96,6 @@ class DNNModel(Model):
     shardOverMesh = Param(
         "Shard each batch over the mesh 'data' axis", default=False, converter=to_bool
     )
-    pipelineStageFn = Param(
-        "Pipeline mode: jittable (stage_params, h) -> h applying ONE stage; "
-        "modelParams must carry a leading stage axis sharded over the mesh "
-        "'pipe' axis (GPipe microbatch schedule, ops/pipeline_parallel.py)",
-        default=None, is_complex=True,
-    )
-    numMicrobatches = Param(
-        "Pipeline mode: microbatches per batch (bubble fraction "
-        "(p-1)/(m+p-1))",
-        default=4, converter=to_int, validator=gt(0),
-    )
-    expertFn = Param(
-        "MoE mode: jittable (expert_params, x) -> y applying ONE expert; "
-        "modelParams must be {'experts': pytree with leading E axis, "
-        "'gate': (D, E) array} — top-1 masked-dense dispatch over the mesh "
-        "'expert' axis (ops/expert_parallel.py)",
-        default=None, is_complex=True,
-    )
 
     # -- convenience single input/output API (CNTKModel.scala:302-367) -------
 
@@ -136,26 +118,17 @@ class DNNModel(Model):
     # -- evaluation ----------------------------------------------------------
 
     def _jitted(self):
+        """``(fn, place)``: the jitted program, and the function that puts
+        ``modelParams`` on the device once a call. ``place`` leaves a leaf the
+        caller already committed (a ``jax.Array`` on its mesh) where it is:
+        an ``applyFn`` that runs its own parallel op (``ops/pipeline_parallel``,
+        ``ops/expert_parallel``) is handed parameters placed for it."""
         import jax
-
-        modes = [
-            name for name, v in [
-                ("applyFn", self.getApplyFn()),
-                ("pipelineStageFn", self.getPipelineStageFn()),
-                ("expertFn", self.getExpertFn()),
-            ] if v is not None
-        ]
-        if len(modes) != 1:
-            raise ValueError(
-                "exactly one of applyFn / pipelineStageFn / expertFn must be "
-                f"set (got {modes or 'none'})"
-            )
-        if self.getPipelineStageFn() is not None:
-            return self._jitted_pipeline()
-        if self.getExpertFn() is not None:
-            return self._jitted_moe()
+        import jax.numpy as jnp
 
         apply_fn = self.getApplyFn()
+        if apply_fn is None:
+            raise ValueError("applyFn must be set")
         if self.getShardOverMesh():
             from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -211,80 +184,8 @@ class DNNModel(Model):
                 }
                 return apply_fn(params, inputs)
 
-            return jax.jit(run), mesh, place_params
-        return jax.jit(apply_fn), None, None
-
-    def _single_feed(self, inputs: Dict[str, Any]):
-        if len(inputs) != 1:
-            raise ValueError(
-                "pipeline/MoE modes take exactly one feed column "
-                f"(got {sorted(inputs)})"
-            )
-        return next(iter(inputs.values()))
-
-    def _jitted_pipeline(self):
-        """Pipeline mode: the batch flows through p stages, one per device
-        on the mesh 'pipe' axis (GPipe microbatch schedule); falls back to a
-        sequential stage scan when the pipe axis is 1."""
-        import jax
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        from mmlspark_tpu.ops.pipeline_parallel import pipeline_apply
-        from mmlspark_tpu.parallel.mesh import AXIS_PIPE, make_mesh
-
-        stage_fn = self.getPipelineStageFn()
-        m = self.getNumMicrobatches()
-        mesh = make_mesh(self.getMeshConfig())
-        staged = NamedSharding(mesh, P(AXIS_PIPE))
-
-        def place_params(params):
-            # leading (stage) axis onto the pipe mesh axis, once
-            return jax.tree.map(lambda a: jax.device_put(a, staged), params)
-
-        def run(params, inputs):
-            x = self._single_feed(inputs)
-            return {"output": pipeline_apply(stage_fn, params, x, mesh, m)}
-
-        return jax.jit(run), mesh, place_params
-
-    def _jitted_moe(self):
-        """MoE mode: top-1 gated experts, one per device on the mesh
-        'expert' axis (masked-dense dispatch + psum combine); sequential
-        expert scan when the expert axis is 1."""
-        import jax
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        from mmlspark_tpu.ops.expert_parallel import moe_apply
-        from mmlspark_tpu.parallel.mesh import AXIS_EXPERT, make_mesh
-
-        expert_fn = self.getExpertFn()
-        mesh = make_mesh(self.getMeshConfig())
-        exp_sh = NamedSharding(mesh, P(AXIS_EXPERT))
-        rep = NamedSharding(mesh, P())
-
-        def place_params(params):
-            if not isinstance(params, dict) or "experts" not in params or "gate" not in params:
-                raise ValueError(
-                    "MoE mode needs modelParams = {'experts': pytree with a "
-                    "leading expert axis, 'gate': (D, E) array}"
-                )
-            return {
-                "experts": jax.tree.map(
-                    lambda a: jax.device_put(a, exp_sh), params["experts"]
-                ),
-                "gate": jax.device_put(params["gate"], rep),
-            }
-
-        def run(params, inputs):
-            x = self._single_feed(inputs)
-            gate_logits = x @ params["gate"]
-            return {
-                "output": moe_apply(
-                    expert_fn, params["experts"], x, gate_logits, mesh
-                )
-            }
-
-        return jax.jit(run), mesh, place_params
+            return jax.jit(run), place_params
+        return jax.jit(apply_fn), lambda params: jax.tree.map(jnp.asarray, params)
 
     def transform(self, table: Table) -> Table:
         """Spans (``observability/tracing``; under ``ServingServer``'s batch
@@ -311,27 +212,17 @@ class DNNModel(Model):
                 n_dev = make_mesh(self.getMeshConfig()).shape.get("data", 1)
                 batch_size = max(batch_size, n_dev)
                 batch_size += (-batch_size) % n_dev
-            if self.getPipelineStageFn() is not None:
-                # GPipe schedule splits each batch into numMicrobatches
-                m = self.getNumMicrobatches()
-                batch_size = max(batch_size, m)
-                batch_size += (-batch_size) % m
             dtype = np.dtype(self.getInputDtype())
             n = table.num_rows
-            fn, _, place_params = self._jitted()
+            fn, place = self._jitted()
             # Pin weights on device ONCE, with their final shardings when the
             # mesh is in play: numpy param leaves would re-transfer (and sharded
             # ones re-broadcast) on every batch dispatch.
-            import jax.numpy as jnp
-
             with tracer.span("dnn.place_params", bytes=sum(
                 int(getattr(leaf, "nbytes", 0))
                 for leaf in jax.tree.leaves(self.getModelParams())
             )):
-                if place_params is not None:
-                    params = place_params(self.getModelParams())
-                else:
-                    params = jax.tree.map(jnp.asarray, self.getModelParams())
+                params = place(self.getModelParams())
 
             out_cols: Dict[str, List[np.ndarray]] = {name: [] for name in fetches}
             bounds = (
@@ -342,9 +233,6 @@ class DNNModel(Model):
             whole.tags["batches"] = len(bounds)
             for lo, hi in bounds:
                 pad_to = batch_size if self.getMiniBatcher() else n
-                if self.getPipelineStageFn() is not None:
-                    # GPipe needs batch % microbatches == 0 even un-minibatched
-                    pad_to += (-pad_to) % self.getNumMicrobatches()
                 with tracer.span("dnn.stack", pad_rows=pad_to - (hi - lo)) as sp:
                     inputs, copied = {}, 0
                     for model_in, col in feeds.items():

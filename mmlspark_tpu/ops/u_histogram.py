@@ -43,7 +43,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import os
 from typing import Optional, Tuple
 
 import jax
@@ -51,18 +50,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from mmlspark_tpu.core.device import on_tpu
-
 _LANE = 128
 _N_ALIGN = 512  # row padding granularity (lane-dim alignment for U tiles)
-
-
-# Fused Pallas panel+dot pass (MMLSPARK_TPU_U_FUSED=1 opts in). Default
-# OFF: measured ~2.5% SLOWER end-to-end than the two-op XLA formulation on
-# v5e (XLA's matmul pipeline beats the hand grid even though the fused
-# kernel saves the panel's HBM round-trip) — kept env-gated for future
-# toolchains and as the correctness-tested template for the fusion.
-_FUSED = os.environ.get("MMLSPARK_TPU_U_FUSED", "0") == "1"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -332,82 +321,6 @@ def histogram_acc_dtype(n_rows: int, quant: bool):
     return jnp.int32
 
 
-def k_pad_fits_vmem(k_pad: int) -> bool:
-    """Fused-pass VMEM gate: 2 U blocks (k_pad x 512 s8) + accumulator
-    (k_pad x 128 s32) must sit comfortably in VMEM (~24 MB budget)."""
-    return k_pad * (2 * _N_ALIGN + 4 * _LANE) <= (24 << 20)
-
-
-def _fused_panel_dot(
-    u: jax.Array,  # (K_pad, N_pad) int8
-    aux: jax.Array,  # (8, N_pad) f32: rows [g, h, c, node, 0, 0, 0, 0]
-    k: int,
-    quant: bool,
-    interpret: bool = False,
-) -> jax.Array:
-    """One Pallas pass fusing the panel build into the U contraction.
-
-    The two-op XLA formulation materializes the (3k, N) panel to HBM
-    behind an optimization barrier (without it XLA re-fuses the build into
-    the dot's rhs load and recomputes it per K-tile — measured 2x slower).
-    This kernel gets the best of both: each N-tile's panel is built ONCE
-    in VMEM from the node keys + stat rows and consumed immediately by the
-    MXU, so the pass streams exactly U + 32 f32 bytes/row of aux — no
-    panel round-trip, no per-K-tile recompute. The output block
-    (K_pad, 128) stays VMEM-resident across the whole N grid and
-    accumulates (int32 exact for the quantized path, f32 otherwise).
-
-    Panel row j carries stat j//k for rows whose node key equals j%k —
-    the same (3k, N) layout the XLA path uses, padded to the full 128-lane
-    group (rows 3k..127 are zero; callers slice)."""
-    k_pad, n_pad = u.shape
-    tn = _N_ALIGN
-    out_dtype = jnp.int32 if quant else jnp.float32
-
-    def kern(aux_ref, u_ref, out_ref):
-        from jax.experimental import pallas as pl  # local: optional dep path
-
-        j = lax.broadcasted_iota(jnp.int32, (_LANE, tn), 0)
-        leaf = (j % k).astype(jnp.float32)
-        sidx = j // k
-        g, h, c = aux_ref[0:1, :], aux_ref[1:2, :], aux_ref[2:3, :]
-        nodev = aux_ref[3:4, :]
-        val = jnp.where(sidx == 0, g, jnp.where(sidx == 1, h, c))
-        panel = jnp.where((nodev == leaf) & (j < 3 * k), val, 0.0)  # (128, tn)
-        if quant:
-            acc = lax.dot_general(
-                u_ref[...], panel.astype(jnp.int8),
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.int32,
-            )
-        else:
-            acc = lax.dot_general(
-                u_ref[...].astype(jnp.bfloat16), panel.astype(jnp.bfloat16),
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-
-        @pl.when(pl.program_id(0) == 0)
-        def _init():
-            out_ref[...] = jnp.zeros_like(out_ref)
-
-        out_ref[...] += acc
-
-    from jax.experimental import pallas as pl
-
-    return pl.pallas_call(
-        kern,
-        grid=(n_pad // tn,),
-        in_specs=[
-            pl.BlockSpec((8, tn), lambda i: (0, i)),
-            pl.BlockSpec((k_pad, tn), lambda i: (0, i)),
-        ],
-        out_specs=pl.BlockSpec((k_pad, _LANE), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((k_pad, _LANE), out_dtype),
-        interpret=interpret,
-    )(aux, u)
-
-
 @jax.named_scope("hist_pass")
 def build_histograms_u(
     u: jax.Array,  # (K_pad, N_pad) int8 from build_u
@@ -448,41 +361,17 @@ def build_histograms_u(
     if stats is None:
         stats = stat_rows(grad, hess, count)
 
-    # VMEM residency: two double-buffered U blocks + the accumulator block
-    # ≈ k_pad * 1.5 KB; gate well under v5e's VMEM so wide-K datasets
-    # (thousands of packed bins) fall back to the two-op XLA pass.
-    if (
-        _FUSED
-        and k_pad_fits_vmem(u.shape[0])
-        and on_tpu()
-    ):
-        # Fused Pallas pass: panel built per N-tile in VMEM, no HBM
-        # round-trip (docstring of _fused_panel_dot).
-        aux = jnp.concatenate(
-            [
-                stats.astype(jnp.float32),  # quantized values are small ints
-                node.astype(jnp.float32)[None, :],
-                jnp.zeros((4, n), jnp.float32),
-            ]
-        )
-        if n_pad != n:
-            # pad node lane with -1 (matches no leaf); stat lanes with 0
-            aux = jnp.pad(aux, ((0, 0), (0, n_pad - n)))
-            aux = aux.at[3, n:].set(-1.0)
-        packed = _fused_panel_dot(u, aux, k, quant=scales is not None)
-        packed = packed[:, : 3 * k]
+    panel_t = _stat_panel_t(stats, node, k, n_pad)
+    if scales is not None:
+        packed = lax.dot_general(
+            u, panel_t,
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.int32,
+        )  # (K_pad, 3k) exact int sums of quantized stats
     else:
-        panel_t = _stat_panel_t(stats, node, k, n_pad)
-        if scales is not None:
-            packed = lax.dot_general(
-                u, panel_t,
-                (((1,), (1,)), ((), ())), preferred_element_type=jnp.int32,
-            )  # (K_pad, 3k) exact int sums of quantized stats
-        else:
-            packed = lax.dot_general(
-                u.astype(jnp.bfloat16), panel_t,
-                (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
-            )  # (K_pad, 3k)
+        packed = lax.dot_general(
+            u.astype(jnp.bfloat16), panel_t,
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        )  # (K_pad, 3k)
 
     if scales is not None and not dequant:
         # narrow to the statically overflow-free accumulator width (exact:

@@ -215,6 +215,36 @@ def test_dnn_model_sharded(mesh8):
     np.testing.assert_allclose(out["y"], np.arange(n) * 3.0)
 
 
+def test_place_leaves_a_caller_committed_leaf_where_it_is(mesh8):
+    """An ``applyFn`` that runs its own parallel op is handed parameters the
+    caller placed on the mesh: the single-device ``place`` returns such a leaf
+    as the same object, and puts a numpy leaf on the device."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    sharded = jax.device_put(
+        np.arange(16, dtype=np.float32).reshape(8, 2), NamedSharding(mesh8, P("data"))
+    )
+    model = DNNModel(
+        applyFn=lambda p, i: i["x"], modelParams={"w": sharded, "b": np.ones(2)}
+    )
+    _, place = model._jitted()
+    placed = place(model.getModelParams())
+    assert placed["w"] is sharded
+    assert isinstance(placed["b"], jax.Array)
+
+
+def test_dnn_model_declares_exactly_its_ten_params():
+    from mmlspark_tpu.core.params import Param
+
+    declared = {k for k, v in vars(DNNModel).items() if isinstance(v, Param)}
+    assert declared == {
+        "applyFn", "modelParams", "feedDict", "fetchDict", "batchSize",
+        "miniBatcher", "inputDtype", "paramShardings", "meshConfig",
+        "shardOverMesh",
+    }
+
+
 def test_dnn_model_single_io_convenience():
     fn = lambda params, inputs: inputs["input"] + 1.0
     model = (

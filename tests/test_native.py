@@ -26,6 +26,12 @@ def _pack(tokens, encoding="utf-8"):
     return np.frombuffer(b"".join(bs), dtype=np.uint8), starts, lens
 
 
+#: Read while the file is collected, before ``built_library`` can build
+#: anything: only ``conftest.pytest_configure`` can have made this true on a
+#: fresh checkout.
+_LOADED_AT_COLLECTION = native_mod.load_library() is not None
+
+
 @pytest.fixture(scope="module", autouse=True)
 def built_library():
     if not native_available():
@@ -33,6 +39,15 @@ def built_library():
             pytest.skip("no native toolchain in this environment")
         build()
     assert native_available()
+
+
+def test_library_loaded_at_collection_time():
+    """The property the ``native`` cases of ``test_hashing_batch.py`` rest on:
+    with a toolchain, the library is there before any test file runs, whichever
+    file a worker is handed first."""
+    if native_mod.native_disabled():
+        pytest.skip("MMLSPARK_TPU_NATIVE turns the library off")
+    assert _LOADED_AT_COLLECTION
 
 
 def _numpy_apply_bins(X, mapper):

@@ -146,35 +146,65 @@ def test_expert_count_mismatch_raises():
         moe_apply(_expert_fn, (ws, bs), x, gates, mesh)
 
 
+def _placed(tree, mesh, axis):
+    """The caller's half of the contract: leading axis onto the mesh axis,
+    committed, before the parameters are handed to ``DNNModel``."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    return jax.device_put(tree, NamedSharding(mesh, P(axis)))
+
+
+def _pipeline_model(params, mesh, microbatches, **kw):
+    from mmlspark_tpu.dnn import DNNModel
+
+    return DNNModel(
+        applyFn=lambda p, i: {
+            "output": pipeline_apply(_stage_fn, p, i["x"], mesh, microbatches)
+        },
+        modelParams=_placed(params, mesh, "pipe"),
+        feedDict={"x": "f"},
+        fetchDict={"y": "output"},
+        **kw,
+    )
+
+
+def _moe_model(experts, gate, mesh, expert_fn, **kw):
+    from mmlspark_tpu.dnn import DNNModel
+
+    def apply(p, i):
+        x = i["x"]
+        return {"output": moe_apply(expert_fn, p["experts"], x, x @ p["gate"], mesh)}
+
+    return DNNModel(
+        applyFn=apply,
+        modelParams={"experts": _placed(experts, mesh, "expert"), "gate": gate},
+        feedDict={"x": "f"},
+        fetchDict={"y": "output"},
+        **kw,
+    )
+
+
 class TestDNNModelConsumers:
     """The pipe/expert ops behind the PUBLIC DNNModel API — a user-facing
-    transform engages the axes, not just the raw ops."""
+    transform engages the axes through an ``applyFn`` that calls the op, with
+    parameters the caller placed on the mesh."""
 
     def test_pipeline_mode_through_dnnmodel(self):
         from mmlspark_tpu.data.table import Table
-        from mmlspark_tpu.dnn import DNNModel
 
         rng = np.random.default_rng(0)
         d, n, p = 8, 24, 4
         params = _stack_params(rng, p, d)
         X = rng.normal(size=(n, d)).astype(np.float32)
+        mesh = make_mesh(MeshConfig(data=2, pipe=p))
 
-        out = DNNModel(
-            pipelineStageFn=_stage_fn,
-            modelParams=params,
-            feedDict={"x": "f"},
-            fetchDict={"y": "output"},
-            batchSize=8,
-            numMicrobatches=2,
-            meshConfig=MeshConfig(data=2, pipe=p),
-        ).transform(Table({"f": X}))
+        out = _pipeline_model(params, mesh, 2, batchSize=8).transform(Table({"f": X}))
 
         want = np.asarray(_sequential(params, jnp.asarray(X)))
         np.testing.assert_allclose(out.column("y"), want, rtol=2e-4, atol=2e-5)
 
     def test_moe_mode_through_dnnmodel(self):
         from mmlspark_tpu.data.table import Table
-        from mmlspark_tpu.dnn import DNNModel
 
         rng = np.random.default_rng(1)
         d, n, e = 8, 30, 8
@@ -184,19 +214,15 @@ class TestDNNModelConsumers:
         )
         gate = jnp.asarray(rng.normal(size=(d, e)), jnp.float32)
         X = rng.normal(size=(n, d)).astype(np.float32)
+        mesh = make_mesh(MeshConfig(data=1, expert=e))
 
         def expert_fn(params, x):
             w, b = params
             return jnp.tanh(x @ w + b)
 
-        out = DNNModel(
-            expertFn=expert_fn,
-            modelParams={"experts": experts, "gate": gate},
-            feedDict={"x": "f"},
-            fetchDict={"y": "output"},
-            batchSize=10,
-            meshConfig=MeshConfig(data=1, expert=e),
-        ).transform(Table({"f": X}))
+        out = _moe_model(experts, gate, mesh, expert_fn, batchSize=10).transform(
+            Table({"f": X})
+        )
 
         # reference: dense per-token top-1 expert
         logits = X @ np.asarray(gate)
@@ -209,50 +235,51 @@ class TestDNNModelConsumers:
             want[i] = np.tanh(X[i] @ w_ + b_) * probs[i, assign[i]]
         np.testing.assert_allclose(out.column("y"), want, rtol=2e-4, atol=2e-5)
 
-    def test_mode_exclusivity_raises(self):
+    def test_without_apply_fn_raises(self):
+        from mmlspark_tpu.data.table import Table
         from mmlspark_tpu.dnn import DNNModel
 
-        with pytest.raises(ValueError, match="exactly one of"):
-            DNNModel(
-                applyFn=lambda p, i: i,
-                pipelineStageFn=_stage_fn,
-                feedDict={"x": "f"},
-                fetchDict={"y": "output"},
-            )._jitted()
-        with pytest.raises(ValueError, match="exactly one of"):
-            DNNModel(feedDict={"x": "f"}, fetchDict={"y": "output"})._jitted()
+        m = DNNModel(feedDict={"x": "f"}, fetchDict={"y": "output"})
+        with pytest.raises(ValueError, match="applyFn must be set"):
+            m._jitted()
+        with pytest.raises(ValueError, match="applyFn must be set"):
+            m.transform(Table({"f": np.zeros((2, 3), np.float32)}))
 
-    def test_moe_params_shape_validated(self):
-        from mmlspark_tpu.dnn import DNNModel
+    def test_wrong_expert_count_raises_through_transform(self):
+        """Eight experts on a four-way expert axis: the op's own error comes
+        out of ``transform``; the class validates nothing of a mode."""
+        from mmlspark_tpu.data.table import Table
 
-        m = DNNModel(
-            expertFn=lambda p, x: x,
-            modelParams={"gate": np.zeros((4, 2))},  # missing 'experts'
-            feedDict={"x": "f"},
-            fetchDict={"y": "output"},
+        rng = np.random.default_rng(6)
+        experts = (
+            jnp.asarray(rng.normal(size=(8, 8, 8)), jnp.float32),
+            jnp.asarray(rng.normal(size=(8, 8)), jnp.float32),
         )
-        _, _, place = m._jitted()
-        with pytest.raises(ValueError, match="experts"):
-            place(m.getModelParams())
+        gate = jnp.asarray(rng.normal(size=(8, 8)), jnp.float32)
+        mesh = make_mesh(MeshConfig(data=1, expert=4), devices=jax.devices()[:4])
+        m = _moe_model(experts, gate, mesh, _expert_fn, batchSize=8)
+        with pytest.raises(ValueError, match="one expert per device"):
+            m.transform(Table({"f": np.zeros((8, 8), np.float32)}))
 
 
-def test_pipeline_mode_unbatched_pads_to_microbatches():
-    """miniBatcher=False with a row count not divisible by numMicrobatches
-    must pad internally instead of raising."""
+def test_pipeline_unbatched_batch_must_divide_microbatches():
+    """``miniBatcher=False`` feeds the table's rows as one batch, as they are:
+    a count the microbatches do not divide raises the op's error through
+    ``transform``, and one they divide equals the sequential stack."""
     from mmlspark_tpu.data.table import Table
-    from mmlspark_tpu.dnn import DNNModel
 
     rng = np.random.default_rng(2)
     d, p = 8, 4
     params = _stack_params(rng, p, d)
+    mesh = make_mesh(MeshConfig(data=2, pipe=p))
+    m = _pipeline_model(params, mesh, p, miniBatcher=False)
+
     X = rng.normal(size=(10, d)).astype(np.float32)  # 10 % 4 != 0
-    out = DNNModel(
-        pipelineStageFn=_stage_fn,
-        modelParams=params,
-        feedDict={"x": "f"}, fetchDict={"y": "output"},
-        miniBatcher=False, numMicrobatches=p,
-        meshConfig=MeshConfig(data=2, pipe=p),
-    ).transform(Table({"f": X}))
+    with pytest.raises(ValueError, match="not divisible"):
+        m.transform(Table({"f": X}))
+
+    X = rng.normal(size=(12, d)).astype(np.float32)
+    out = m.transform(Table({"f": X}))
     want = np.asarray(_sequential(params, jnp.asarray(X)))
     np.testing.assert_allclose(out.column("y"), want, rtol=2e-4, atol=2e-5)
 

@@ -433,65 +433,6 @@ class TestChunkedU:
         assert abs(a - ar) < 0.002, (a, ar)
 
 
-class TestFusedPanelDot:
-    """The opt-in Pallas fusion (MMLSPARK_TPU_U_FUSED) must match the
-    two-op XLA formulation bit-for-bit on the quant path and to bf16
-    precision on the exact path (same precision model)."""
-
-    @pytest.mark.parametrize("quant", [False, True])
-    def test_matches_xla_path(self, quant):
-        import jax
-
-        from mmlspark_tpu.ops.u_histogram import (
-            _fused_panel_dot,
-            stat_rows_quant,
-        )
-
-        widths, f, b, bins, g, h, c, node = _mixed_case(seed=5, n=1024)
-        k = 4
-        spec = make_u_spec(b, f, per_feature=widths)
-        u = build_u(jnp.asarray(bins), spec)
-        if quant:
-            stats, scales = stat_rows_quant(
-                jnp.asarray(g), jnp.asarray(h), jnp.asarray(c),
-                jax.random.PRNGKey(3),
-            )
-        else:
-            stats = stat_rows(jnp.asarray(g), jnp.asarray(h), jnp.asarray(c))
-        n = bins.shape[0]
-        aux = jnp.concatenate([
-            stats.astype(jnp.float32),
-            jnp.asarray(node, jnp.float32)[None, :],
-            jnp.zeros((4, n), jnp.float32),
-        ])
-        pad = u.shape[1] - n
-        if pad:
-            aux = jnp.pad(aux, ((0, 0), (0, pad)))
-            aux = aux.at[3, n:].set(-1.0)
-        fused = np.asarray(
-            _fused_panel_dot(u, aux, k, quant=quant, interpret=True)
-        )[:, : 3 * k]
-        # XLA reference: the in-module non-fused branch
-        key = jnp.tile(jnp.arange(k, dtype=jnp.int32), 3)[:, None]
-        mask_t = key == jnp.asarray(node, jnp.int32)[None, :]
-        if quant:
-            panel = jnp.where(mask_t, jnp.repeat(stats, k, axis=0), jnp.int8(0))
-            if pad:
-                panel = jnp.pad(panel, ((0, 0), (0, pad)))
-            ref = np.asarray(jnp.einsum(
-                "kn,pn->kp", u.astype(jnp.int32), panel.astype(jnp.int32)))
-            np.testing.assert_array_equal(fused, ref)
-        else:
-            panel = jnp.where(mask_t, jnp.repeat(stats, k, axis=0), jnp.bfloat16(0))
-            if pad:
-                panel = jnp.pad(panel, ((0, 0), (0, pad)))
-            ref = np.asarray(jax.lax.dot_general(
-                u.astype(jnp.bfloat16), panel,
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32))
-            np.testing.assert_allclose(fused, ref, rtol=1e-5, atol=1e-3)
-
-
 class TestAccumulatorDtype:
     """Deterministic overflow promotion for narrow histogram accumulators:
     f32 on the exact path; on the quant path the narrowest signed int whose

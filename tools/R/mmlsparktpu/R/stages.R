@@ -683,16 +683,13 @@ mt_transformer <- function(...) {
 #' \itemize{
 #'   \item \code{applyFn}: Jittable (params, {name: array}) -> {name: array} | array (default None)
 #'   \item \code{batchSize}: Rows per device batch (static shape; last batch padded) (default 64)
-#'   \item \code{expertFn}: MoE mode: jittable (expert_params, x) -> y applying ONE expert; modelParams must be {'experts': pytree with leading E axis, 'gate': (D, E) array} — top-1 masked-dense dispatch over the mesh 'expert' axis (ops/expert_parallel.py) (default None)
 #'   \item \code{feedDict}: model input name -> feature column name (default {})
 #'   \item \code{fetchDict}: output column name -> model output name (default {})
 #'   \item \code{inputDtype}: Cast inputs to this dtype (default 'float32')
 #'   \item \code{meshConfig}: MeshConfig for shardOverMesh (None = all devices on the data axis) (default None)
 #'   \item \code{miniBatcher}: Batch rows before eval (CNTKModel batches by default) (default True)
 #'   \item \code{modelParams}: Model parameter pytree (default None)
-#'   \item \code{numMicrobatches}: Pipeline mode: microbatches per batch (bubble fraction (p-1)/(m+p-1)) (default 4)
 #'   \item \code{paramShardings}: Tensor-parallel map: param key -> axis index sharded over the mesh 'model' axis (None = fully replicated params) (default None)
-#'   \item \code{pipelineStageFn}: Pipeline mode: jittable (stage_params, h) -> h applying ONE stage; modelParams must carry a leading stage axis sharded over the mesh 'pipe' axis (GPipe microbatch schedule, ops/pipeline_parallel.py) (default None)
 #'   \item \code{shardOverMesh}: Shard each batch over the mesh 'data' axis (default False)
 #' }
 #' @param ... named params forwarded to the Python constructor
