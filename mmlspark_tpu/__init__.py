@@ -70,8 +70,9 @@ def clear_compiled_caches() -> None:
 
     Long-lived processes that fit many differently-shaped models — test
     harnesses, notebook sessions, serving workers cycling models —
-    accumulate compiled XLA executables: the boosting-step cache
-    (``lightgbm.train._PROGRAM_CACHE``), module-level jitted predict
+    accumulate compiled XLA executables: the package's program cache
+    (``core.device.cached_program``: the boosting step, the deep path's
+    ``applyFn`` and image-stage programs), module-level jitted predict
     kernels, and JAX's own pjit caches. XLA:CPU tolerates only so much of
     this in one process (an upstream compiler crash reproduces after
     several hundred accumulated compilations — see
@@ -82,9 +83,9 @@ def clear_compiled_caches() -> None:
 
     import jax
 
-    from mmlspark_tpu.lightgbm import train as _train
+    from mmlspark_tpu.core import device as _device
 
-    _train._PROGRAM_CACHE.clear()
+    _device._PROGRAM_CACHE.clear()
     jax.clear_caches()
     gc.collect()
 

@@ -5,7 +5,9 @@ Re-design of ``CNTKModel`` (``cntk/CNTKModel.scala:145-531``) for TPU:
 - the serialized CNTK ``Function`` broadcast to executors becomes a jittable
   ``applyFn(params, inputs) -> outputs`` plus a ``params`` pytree placed on
   device once per transform (the ``rebroadcastCNTKModel`` analogue,
-  ``CNTKModel.scala:411-413``);
+  ``CNTKModel.scala:411-413``) and passed as an argument; the program is
+  built once a process for each ``applyFn`` object (``_jitted``) and a later
+  ``transform``, of this instance or another, finds it;
 - mini-batching is ON by default (reference wraps with
   ``FixedMiniBatchTransformer(batchSize=10)`` then ``FlattenBatch``,
   ``CNTKModel.scala:374,496-528``) — here every batch is right-padded to a
@@ -29,6 +31,7 @@ from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
+from mmlspark_tpu.core.device import cached_program, frozen, programs_built
 from mmlspark_tpu.core.params import Param, gt, to_bool, to_int, to_str
 from mmlspark_tpu.core.pipeline import Model
 from mmlspark_tpu.data.table import Table
@@ -56,6 +59,51 @@ def _stack_batch(col: np.ndarray, pad_to: int, dtype: Any) -> np.ndarray:
     batch[:rows] = col
     batch[rows:] = 0
     return batch
+
+
+def _place_on_device(params):
+    """One device: numpy leaves go up, a ``jax.Array`` stays where it is."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.tree.map(jnp.asarray, params)
+
+
+def _mesh_program(apply_fn: Callable, mesh: Any, tp: Dict[str, int]):
+    """``(fn, place)`` under ``shardOverMesh``: each batch sharded over the
+    mesh ``data`` axis, parameters replicated or, where ``tp`` names their
+    key, sharded over ``model`` on that axis."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    batch_sharding = NamedSharding(mesh, P("data"))
+    replicated = NamedSharding(mesh, P())
+
+    def shard_for(key, value):
+        if key in tp:
+            spec = [None] * np.ndim(value)
+            spec[tp[key]] = "model"
+            return NamedSharding(mesh, P(*spec))
+        return replicated
+
+    def place_params(params):
+        """Commit weights to their FINAL shardings once, outside the
+        compiled call — so the in-program device_put is a no-op
+        rather than a per-batch broadcast/reshard over ICI."""
+        if isinstance(params, dict):
+            return {
+                k: jax.device_put(v, shard_for(k, v))
+                for k, v in params.items()
+            }
+        return jax.device_put(params, replicated)
+
+    def run(params, inputs):
+        inputs = {
+            k: jax.device_put(v, batch_sharding) for k, v in inputs.items()
+        }
+        return apply_fn(params, inputs)
+
+    return jax.jit(run), place_params
 
 
 class DNNModel(Model):
@@ -122,70 +170,53 @@ class DNNModel(Model):
         ``modelParams`` on the device once a call. ``place`` leaves a leaf the
         caller already committed (a ``jax.Array`` on its mesh) where it is:
         an ``applyFn`` that runs its own parallel op (``ops/pipeline_parallel``,
-        ``ops/expert_parallel``) is handed parameters placed for it."""
+        ``ops/expert_parallel``) is handed parameters placed for it.
+
+        Both come from ``core.device.cached_program``, keyed on everything
+        the traced function sees besides its arguments: the ``applyFn`` object
+        (two closures of one source are two keys; a ``functools.partial`` is
+        its own, a bound method its instance's) and, under ``shardOverMesh``,
+        the mesh and ``paramShardings`` by content. No weight is in a key or
+        in what is cached, so one program serves every ``modelParams``; the
+        cache keeps ``applyFn`` alive until it is evicted, so an ``applyFn``
+        should take its weights from ``params``, not close over them."""
         import jax
-        import jax.numpy as jnp
 
         apply_fn = self.getApplyFn()
         if apply_fn is None:
             raise ValueError("applyFn must be set")
-        if self.getShardOverMesh():
-            from jax.sharding import NamedSharding, PartitionSpec as P
+        if not self.getShardOverMesh():
+            return cached_program(
+                ("dnn", apply_fn), lambda: (jax.jit(apply_fn), _place_on_device)
+            )
+        from mmlspark_tpu.parallel.mesh import make_mesh
 
-            from mmlspark_tpu.parallel.mesh import make_mesh
-
-            mesh_config = self.getMeshConfig()
-            mesh = make_mesh(mesh_config)
-            batch_sharding = NamedSharding(mesh, P("data"))
-            replicated = NamedSharding(mesh, P())
-            # Tensor parallelism: paramShardings maps a param-pytree key to
-            # the axis index sharded over the mesh "model" axis (e.g. the
-            # output-features dim of a Linear weight). XLA then partitions
-            # the matmuls and inserts the all-gather/reduce-scatter
-            # collectives (the TP recipe: annotate shardings, let GSPMD
-            # place the collectives).
-            tp: Dict[str, int] = self.getParamShardings() or {}
-            if tp and not isinstance(self.getModelParams(), dict):
+        mesh = make_mesh(self.getMeshConfig())
+        # Tensor parallelism: paramShardings maps a param-pytree key to
+        # the axis index sharded over the mesh "model" axis (e.g. the
+        # output-features dim of a Linear weight). XLA then partitions
+        # the matmuls and inserts the all-gather/reduce-scatter
+        # collectives (the TP recipe: annotate shardings, let GSPMD
+        # place the collectives).
+        tp: Dict[str, int] = dict(self.getParamShardings() or {})
+        if tp and not isinstance(self.getModelParams(), dict):
+            raise ValueError(
+                "paramShardings requires modelParams to be a flat dict "
+                f"of arrays (got {type(self.getModelParams()).__name__})"
+            )
+        for key, axis in tp.items():
+            val = self.getModelParams().get(key)
+            if val is None:
+                raise ValueError(f"paramShardings key {key!r} not in modelParams")
+            if np.ndim(val) <= axis:
                 raise ValueError(
-                    "paramShardings requires modelParams to be a flat dict "
-                    f"of arrays (got {type(self.getModelParams()).__name__})"
+                    f"paramShardings[{key!r}]={axis} out of range for a "
+                    f"{np.ndim(val)}-d param"
                 )
-            for key, axis in tp.items():
-                val = self.getModelParams().get(key)
-                if val is None:
-                    raise ValueError(f"paramShardings key {key!r} not in modelParams")
-                if np.ndim(val) <= axis:
-                    raise ValueError(
-                        f"paramShardings[{key!r}]={axis} out of range for a "
-                        f"{np.ndim(val)}-d param"
-                    )
-
-            def shard_for(key, value):
-                if key in tp:
-                    spec = [None] * np.ndim(value)
-                    spec[tp[key]] = "model"
-                    return NamedSharding(mesh, P(*spec))
-                return replicated
-
-            def place_params(params):
-                """Commit weights to their FINAL shardings once, outside the
-                compiled call — so the in-program device_put is a no-op
-                rather than a per-batch broadcast/reshard over ICI."""
-                if isinstance(params, dict):
-                    return {
-                        k: jax.device_put(v, shard_for(k, v))
-                        for k, v in params.items()
-                    }
-                return jax.device_put(params, replicated)
-
-            def run(params, inputs):
-                inputs = {
-                    k: jax.device_put(v, batch_sharding) for k, v in inputs.items()
-                }
-                return apply_fn(params, inputs)
-
-            return jax.jit(run), place_params
-        return jax.jit(apply_fn), lambda params: jax.tree.map(jnp.asarray, params)
+        return cached_program(
+            ("dnn.mesh", apply_fn, mesh, frozen(tp)),
+            lambda: _mesh_program(apply_fn, mesh, tp),
+        )
 
     def transform(self, table: Table) -> Table:
         """Spans (``observability/tracing``; under ``ServingServer``'s batch
@@ -193,8 +224,10 @@ class DNNModel(Model):
         call, ``dnn.place_params``, then per batch ``dnn.stack`` (the
         column's slice as one padded host batch; its ``bytes`` is what it
         copied, 0 where the slice is the batch), ``dnn.dispatch`` (input
-        transfer and enqueue, ``bytes`` what the program is fed;
-        on a call's first batch also the trace and lowering) and
+        transfer and enqueue, ``bytes`` what the program is fed; where
+        the call had to build its program (``dnn.transform``'s
+        ``programs_built`` 1, else 0: ``_jitted``) or meets a new batch
+        shape, its first batch also holds the trace and lowering) and
         ``dnn.fetch`` (it owns the wait on the forward), and ``dnn.assemble``
         for the output columns. Byte tags come from shapes."""
         import jax
@@ -214,7 +247,9 @@ class DNNModel(Model):
                 batch_size += (-batch_size) % n_dev
             dtype = np.dtype(self.getInputDtype())
             n = table.num_rows
+            built_before = programs_built()
             fn, place = self._jitted()
+            whole.tags["programs_built"] = programs_built() - built_before
             # Pin weights on device ONCE, with their final shardings when the
             # mesh is in play: numpy param leaves would re-transfer (and sharded
             # ones re-broadcast) on every batch dispatch.

@@ -3,14 +3,19 @@
 The text sibling of :class:`mmlspark_tpu.image.ImageFeaturizer`: a language
 model (default family: :mod:`mmlspark_tpu.models.afmoe`) applied to whole
 sequences by :class:`DNNModel` in fixed-shape device batches, features and
-last-position logits out, for a downstream learner. No generation loop, no
-cache across calls.
+last-position logits out, for a downstream learner. No generation loop and
+no key/value cache: every call runs whole sequences. The program itself is
+built once a process for each decoder configuration (by content) and found
+again by later calls.
 """
 
 from __future__ import annotations
 
+import copy
+
 import numpy as np
 
+from mmlspark_tpu.core.device import cached_program, frozen
 from mmlspark_tpu.core.params import Param, gt, to_int, to_str
 from mmlspark_tpu.core.pipeline import Model
 from mmlspark_tpu.data.table import Table
@@ -18,6 +23,21 @@ from mmlspark_tpu.dnn.model import DNNModel
 from mmlspark_tpu.observability.tracing import get_tracer
 
 _LOAD = "expert_load"
+
+
+def _apply_fn(config: dict):
+    """The ``applyFn`` handed to :class:`DNNModel`: one function object a
+    process for a configuration's content (a fresh ``dict`` of equal content
+    is the same key), so that a later ``transform`` finds its program."""
+    from mmlspark_tpu.models.afmoe import afmoe_apply
+
+    def make():
+        # a later trace (another batch shape) must read what the key says,
+        # whatever the caller has done to its dict since
+        own = copy.deepcopy(config)
+        return lambda p, inputs: afmoe_apply(p, inputs["input"], own)
+
+    return cached_program(("lm.featurizer", frozen(config)), make)
 
 
 class LMFeaturizer(Model):
@@ -41,8 +61,6 @@ class LMFeaturizer(Model):
         """One ``lm.featurize`` span roots the call's trace, the batched
         forward's ``dnn.*`` spans beneath it; ``lm.route_stats`` then sums the
         fetched expert loads per dispatch (``observability/tracing``)."""
-        from mmlspark_tpu.models.afmoe import afmoe_apply
-
         params, config = self.getModelParams(), self.getModelConfig()
         if params is None or config is None:
             raise ValueError("modelParams and modelConfig must be set (see mmlspark_tpu.models.afmoe)")
@@ -58,7 +76,7 @@ class LMFeaturizer(Model):
         ):
             load_col = outputs.get(_LOAD, "__expert_load__")  # fetched always: route_stats reads it
             dnn = DNNModel(
-                applyFn=lambda p, inputs: afmoe_apply(p, inputs["input"], config),
+                applyFn=_apply_fn(config),
                 modelParams=params,
                 feedDict={"input": self.getInputCol()},
                 fetchDict={**{col: out for out, col in outputs.items()}, load_col: _LOAD},

@@ -5,7 +5,8 @@ downloaded CNTK model, cuts ``cutOutputLayers`` layers off the top, and
 prepends resize/unroll. Here the backbone is a native JAX network (default:
 the :mod:`mmlspark_tpu.models.resnet` zoo) and the whole chain — resize →
 normalize → NCHW layout → backbone forward with ``cut`` — jits into one XLA
-program executed in fixed-shape device batches by :class:`DNNModel`.
+program executed in fixed-shape device batches by :class:`DNNModel`, built
+once a process for each (backbone, ``cutOutputLayers``, ``scale``).
 """
 
 from __future__ import annotations
@@ -14,12 +15,29 @@ from typing import Any, Dict, Optional
 
 import numpy as np
 
+from mmlspark_tpu.core.device import cached_program
 from mmlspark_tpu.core.params import Param, gt, to_bool, to_int, to_str
 from mmlspark_tpu.core.pipeline import Model
 from mmlspark_tpu.data.table import Table
 from mmlspark_tpu.dnn.model import DNNModel
 from mmlspark_tpu.image.transforms import ImageTransformer
 from mmlspark_tpu.observability.tracing import get_tracer
+
+
+def _apply_fn(backbone, cut: int, scale: float):
+    """The ``applyFn`` handed to :class:`DNNModel`: one function object a
+    process for everything it captures, so that a later ``transform``, of
+    any featurizer of this definition, finds the program built for it."""
+
+    def make():
+        def apply_fn(p, inputs):
+            x = inputs["input"].astype("float32") * scale
+            x = x.transpose(0, 3, 1, 2)  # NHWC -> NCHW
+            return {"output": backbone(p, x, cut)}
+
+        return apply_fn
+
+    return cached_program(("image.featurizer", backbone, cut, scale), make)
 
 
 class ImageFeaturizer(Model):
@@ -89,14 +107,9 @@ class ImageFeaturizer(Model):
                 ).transform(work)
                 image_col = resized_col
 
-            backbone = self._backbone()
-            cut = self.getCutOutputLayers()
-            scale = float(self.getScale())
-
-            def apply_fn(p, inputs):
-                x = inputs["input"].astype("float32") * scale
-                x = x.transpose(0, 3, 1, 2)  # NHWC -> NCHW
-                return {"output": backbone(p, x, cut)}
+            apply_fn = _apply_fn(
+                self._backbone(), self.getCutOutputLayers(), float(self.getScale())
+            )
 
             dnn = DNNModel(
                 applyFn=apply_fn,

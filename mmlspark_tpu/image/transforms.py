@@ -14,11 +14,13 @@ Flip codes follow OpenCV: 0 = vertical (x-axis), 1 = horizontal, -1 = both.
 
 from __future__ import annotations
 
+import copy
 import functools
 from typing import Any, Callable, Dict, List, Tuple
 
 import numpy as np
 
+from mmlspark_tpu.core.device import cached_program, frozen, programs_built
 from mmlspark_tpu.core.params import HasInputCol, HasOutputCol, Param, to_bool, to_str
 from mmlspark_tpu.core.pipeline import Transformer
 from mmlspark_tpu.data.table import Table
@@ -159,6 +161,32 @@ _OPS: Dict[str, Callable[[Dict[str, Any]], Callable]] = {
 }
 
 
+def _build_pipeline(stage_list: List[Dict[str, Any]]):
+    """What :meth:`ImageTransformer._pipeline` caches for one stage list."""
+    import jax
+
+    ops = []
+    # an op reads its dict when it is traced, and a later trace (another
+    # shape group) must read what the key says
+    for stage in copy.deepcopy(stage_list):
+        op_name = stage["op"]
+        if op_name not in _OPS:
+            raise ValueError(f"unknown image op {op_name!r}; have {sorted(_OPS)}")
+        ops.append(_OPS[op_name](stage))
+
+    def stages(batch):
+        x = batch.astype("float32")
+        for op in ops:
+            x = op(x)
+        return x
+
+    @functools.partial(jax.jit, static_argnums=1)
+    def run(flat, shape):
+        return stages(flat.reshape(shape)).reshape(shape[0], -1)
+
+    return stages, run, {}
+
+
 class ImageTransformer(HasInputCol, HasOutputCol, Transformer):
     """Applies a list of image stages to an image column."""
 
@@ -208,42 +236,32 @@ class ImageTransformer(HasInputCol, HasOutputCol, Transformer):
 
     # -- execution -----------------------------------------------------------
 
-    def _pipeline(self) -> Tuple[Callable, Callable]:
-        """``(stages, run)``: the stages as one NHWC -> NHWC function (the
-        result's shape is read off it) and, jitted, the program over one
-        shape group, ``run(flat, shape) -> flat result``. The program takes
-        and returns ``(rows, H * W * C)`` and reshapes to NHWC inside, so
-        that what crosses the host boundary is in the host's row order: the
+    def _pipeline(self) -> Tuple[Callable, Callable, Dict[Tuple[int, ...], Tuple[int, ...]]]:
+        """``(stages, run, shapes)``, built once a process for a stage list's
+        content (``core.device.cached_program``; a fresh list of equal dicts
+        finds it): the stages as one NHWC -> NHWC function, the jitted
+        program over one shape group, ``run(flat, shape) -> flat result``,
+        and the result shape ``stages`` gave each batch shape seen so far
+        (so ``jax.eval_shape`` traces them once a shape, not once a call).
+        The program takes and returns ``(rows, H * W * C)`` and reshapes to
+        NHWC inside, so that what crosses the host boundary is in the host's
+        row order: the
         TPU keeps a 4-D image batch with the batch dimension minor-most and
         ``device_get`` hands a device layout back as strides, so a 4-D
         result arrives with every image scattered across the whole buffer
         and the first reader of its rows pays a strided gather (3.7 GB at
         0.17 GB/s: PERF.md, PR 26). Where the stages change no shape the
         reshapes cancel and the program moves nothing."""
-        import jax
-
-        ops = []
-        for stage in self.getStages():
-            op_name = stage["op"]
-            if op_name not in _OPS:
-                raise ValueError(f"unknown image op {op_name!r}; have {sorted(_OPS)}")
-            ops.append(_OPS[op_name](stage))
-
-        def stages(batch):
-            x = batch.astype("float32")
-            for op in ops:
-                x = op(x)
-            return x
-
-        @functools.partial(jax.jit, static_argnums=1)
-        def run(flat, shape):
-            return stages(flat.reshape(shape)).reshape(shape[0], -1)
-
-        return stages, run
+        stage_list = self.getStages()
+        return cached_program(
+            ("image.pipeline", frozen(stage_list)), lambda: _build_pipeline(stage_list)
+        )
 
     def transform(self, table: Table) -> Table:
         """Spans (``observability/tracing``): ``image.transform`` around the
-        whole stage; per shape group ``image.stack`` (rows to one host
+        whole stage (``programs_built``: 1 where this call had to build the
+        stage program, 0 where an earlier call had); per shape group
+        ``image.stack`` (rows to one host
         batch), ``image.apply_fetch`` (upload, the stage program, download:
         it owns the wait on the device) and ``image.assemble`` (the fetch
         as NHWC, clip/round of the uint8 path, gray squeeze); one more
@@ -255,7 +273,9 @@ class ImageTransformer(HasInputCol, HasOutputCol, Transformer):
         tracer = get_tracer()
         with tracer.span("image.transform", rows=table.num_rows) as whole:
             col = table.column(self.getInputCol())
-            stages, run = self._pipeline()
+            built_before = programs_built()
+            stages, run, shapes = self._pipeline()
+            whole.tags["programs_built"] = programs_built() - built_before
             images = [np.asarray(im) for im in col]
             # Group equal-shape images into device batches: one compile per
             # distinct input shape, one program execution per group.
@@ -270,7 +290,9 @@ class ImageTransformer(HasInputCol, HasOutputCol, Transformer):
                     batch = _ensure_nhwc(np.stack([images[i] for i in idxs]))
                     sp.tags["bytes"] = batch.nbytes
                 with tracer.span("image.apply_fetch", bytes_up=batch.nbytes) as sp:
-                    out_shape = jax.eval_shape(stages, batch).shape
+                    out_shape = shapes.get(batch.shape)
+                    if out_shape is None:
+                        out_shape = shapes[batch.shape] = jax.eval_shape(stages, batch).shape
                     flat = np.asarray(jax.device_get(
                         run(batch.reshape(len(idxs), -1), batch.shape)))
                     sp.tags["bytes_down"] = flat.nbytes

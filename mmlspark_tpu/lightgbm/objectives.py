@@ -28,7 +28,7 @@ class Objective:
     init_score: Callable[..., np.ndarray]
     default_metric: str
     # Distinguishes data-specific objective INSTANCES sharing a name in the
-    # jitted-program cache (train._PROGRAM_CACHE keys on this): the registry
+    # jitted-program cache (core.device.cached_program keys on this): the registry
     # singletons use None; per-fit objectives (lambdarank closes over the
     # query-group structure) must carry a unique token or a later fit with
     # identical TrainOptions silently reuses the first fit's closure.

@@ -34,7 +34,6 @@ reduces only the top-K-voted features' histograms — see ``ops/voting.py``.
 
 from __future__ import annotations
 
-import collections
 import dataclasses
 import math
 import time
@@ -46,7 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from mmlspark_tpu.core.device import on_tpu
+from mmlspark_tpu.core.device import cached_program, on_tpu, programs_built
 from mmlspark_tpu.lightgbm.binning import BinMapper
 from mmlspark_tpu.observability.profiler import get_profiler
 from mmlspark_tpu.observability.tracing import get_tracer
@@ -1365,34 +1364,6 @@ def _make_step(
     return step
 
 
-# Jitted-program cache shared across train() calls. A fit's programs are
-# fully determined by (options, bin count, mesh, scan-vs-loop shape); without
-# this cache every fit would rebuild its closures and re-trace/lower the
-# whole boosting program — several seconds of host work that dwarfs the
-# actual device time on warm fits (jit re-specializes per input shape
-# underneath each cached callable, so shapes need not be part of the key).
-# LRU-bounded so hyperparameter sweeps (every combo is a distinct key) don't
-# grow compiled executables without limit; 256 entries ≈ 64 configs in
-# flight, far beyond a CV fold x param-grid working set.
-_PROGRAM_CACHE: "collections.OrderedDict[Any, Any]" = collections.OrderedDict()
-_PROGRAM_CACHE_SIZE = 256
-
-
-def _cached_program(key, make):
-    fn = _PROGRAM_CACHE.get(key)
-    hit = fn is not None
-    if fn is None:
-        fn = _PROGRAM_CACHE[key] = make()
-        if len(_PROGRAM_CACHE) > _PROGRAM_CACHE_SIZE:
-            _PROGRAM_CACHE.popitem(last=False)
-    else:
-        _PROGRAM_CACHE.move_to_end(key)
-    prof = get_profiler()
-    if prof.active:
-        prof.note_program_cache(hit=hit, size=len(_PROGRAM_CACHE))
-    return fn
-
-
 def _opts_key(opts: "TrainOptions"):
     return dataclasses.astuple(opts)
 
@@ -2024,10 +1995,7 @@ def train(
             okey = okey + (n,)  # GOSS bakes the unpadded row count into the program
         _prof = get_profiler()
         _prof_on = _prof.active
-        # whether this fit's step program was already built by an earlier fit
-        prog_span.tags["cache_hit"] = (
-            hist_reduce is None and ("step_jit", okey) in _PROGRAM_CACHE
-        )
+        built_before = programs_built()
         if hist_reduce is not None:
             # the reduce hook closes over a live socket group — never share a
             # compiled program holding it across fits. The profiler wrap times
@@ -2041,16 +2009,20 @@ def train(
             )
             step = jax.jit(step_raw, donate_argnums=(3,))
         else:
-            step_raw = _cached_program(
+            step_raw = cached_program(
                 ("step_raw", okey),
                 lambda: _make_step(
                     opts, objective, num_bins, mesh, n_real=n, u_spec=u_spec,
                     bundle=bundle,
                 ),
             )
-            step = _cached_program(
+            step = cached_program(
                 ("step_jit", okey), lambda: jax.jit(step_raw, donate_argnums=(3,))
             )
+        # whether this fit's step program was already built by an earlier fit
+        prog_span.tags["cache_hit"] = (
+            hist_reduce is None and programs_built() == built_before
+        )
         u_builder = None
         if u_spec is not None:
             if u_spec.chunk_rows:
@@ -2063,7 +2035,7 @@ def train(
                 from mmlspark_tpu.ops.u_histogram import build_u
 
                 u_builder = partial(build_u, spec=u_spec)
-        valid_update = _cached_program(
+        valid_update = cached_program(
             ("valid_update", opts.routing_steps, bundle),
             lambda: _make_valid_update(opts.routing_steps, bundle),
         )
@@ -2115,14 +2087,14 @@ def train(
                 )
                 step = jax.jit(step_raw, donate_argnums=(3,))
             else:
-                step_raw = _cached_program(
+                step_raw = cached_program(
                     ("step_raw", okey),
                     lambda: _make_step(
                         opts, objective, num_bins, mesh, n_real=n, u_spec=u_spec,
                         bundle=bundle,
                     ),
                 )
-                step = _cached_program(
+                step = cached_program(
                     ("step_jit", okey),
                     lambda: jax.jit(step_raw, donate_argnums=(3,)),
                 )
@@ -2266,7 +2238,7 @@ def train(
             fm_all = jnp.asarray(np.stack(fm_list))
             per_iter_lr = lr_all is not None
             lr_arg = jnp.asarray(lr_all) if per_iter_lr else fm_all  # unused placeholder
-            runner = _cached_program(
+            runner = cached_program(
                 ("scan", okey, bag_resampling, per_iter_lr),
                 lambda: _make_scan_steps(
                     step_raw, per_iter_bag=bag_resampling, per_iter_lr=per_iter_lr,
@@ -2275,7 +2247,7 @@ def train(
             )
         else:
             dart_rng = np.random.default_rng(opts.seed + 7919)
-            tree_contrib = _cached_program(
+            tree_contrib = cached_program(
                 ("tree_contrib", opts.routing_steps, bundle),
                 lambda: _make_tree_contrib(opts.routing_steps, bundle),
             )
@@ -2293,7 +2265,7 @@ def train(
         from mmlspark_tpu.ops.u_histogram import num_u_chunks, u_bytes
 
         chunks = num_u_chunks(n + pad, u_spec)
-        u_jit = _cached_program(
+        u_jit = cached_program(
             ("u_build_jit", u_spec), lambda: jax.jit(u_builder)
         )
         with tracer.span(
@@ -2387,7 +2359,7 @@ def train(
                         # recreate the donated margins buffer and rebuild the
                         # scan program + fit-resident U under the new spec
                         margins = jnp.asarray(margins_before)
-                        runner = _cached_program(
+                        runner = cached_program(
                             ("scan", okey, bag_resampling, per_iter_lr),
                             lambda: _make_scan_steps(
                                 step_raw, per_iter_bag=bag_resampling,
@@ -2782,14 +2754,14 @@ def train_many(
             objective.cache_token)
     if base.boosting_type == "goss":
         okey = okey + (n,)  # GOSS bakes the unpadded row count
-    step_raw = _cached_program(
+    step_raw = cached_program(
         ("step_raw_many", okey),
         lambda: _make_step(
             base, objective, num_bins, None, n_real=n, u_spec=None,
             bundle=bundle,
         ),
     )
-    runner = _cached_program(
+    runner = cached_program(
         ("scan_many", okey, any_bag),
         lambda: _make_scan_steps_many(step_raw, per_iter_bag=any_bag),
     )

@@ -365,9 +365,10 @@ class DeviceProfiler:
             self._reg_compiles.labels(fn=name).inc(int(compiles))
 
     def note_program_cache(self, hit: bool, size: int) -> None:
-        """Accounting for callers that manage their own compiled-program
-        cache (the GBDT fit's LRU of jitted step/scan programs): hit/miss
-        counters plus a live size gauge."""
+        """Accounting for the package's program cache
+        (``core.device.cached_program``: the GBDT fit's jitted step/scan
+        programs and the deep path's): hit/miss counters plus a live size
+        gauge."""
         reg = self.registry
         if hit:
             reg.counter(
@@ -381,7 +382,7 @@ class DeviceProfiler:
             ).inc()
         reg.gauge(
             "profiler_program_cache_size",
-            "Compiled programs resident in the fit program cache",
+            "Compiled programs resident in the program cache",
         ).set(size)
 
     @contextmanager

@@ -24,6 +24,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Dict, List, Optional, Tuple
 
+from mmlspark_tpu.core.device import frozen
 from mmlspark_tpu.core.pipeline import Estimator
 
 #: Estimator param names the GBDT batched core vmaps over (traced lanes).
@@ -44,19 +45,6 @@ VW_VMAPPED = frozenset({"learningRate", "powerT", "l1", "l2"})
 #: (``--learning_rate 0.1`` wins over ``learningRate``), breaking the
 #: per-candidate stacks. Candidates carrying them fall back to singleton.
 _VW_ARG_CONFLICTS = frozenset({"learning_rate", "power_t", "l1", "l2"})
-
-
-def _freeze(value: Any):
-    """Hashable stand-in for a param value (bucket keys live in sets)."""
-    if isinstance(value, (list, tuple)):
-        return tuple(_freeze(v) for v in value)
-    if isinstance(value, dict):
-        return tuple(sorted((k, _freeze(v)) for k, v in value.items()))
-    try:
-        hash(value)
-        return value
-    except TypeError:
-        return repr(value)
 
 
 @dataclasses.dataclass
@@ -128,7 +116,7 @@ def _bucket_key(cand: Estimator, kind: str):
     the key (classifier vs regressor = different objective/kernel)."""
     vmapped = GBDT_VMAPPED if kind == "gbdt" else VW_VMAPPED
     statics = frozenset(
-        (name, _freeze(value))
+        (name, frozen(value))
         for name, value in cand.extractParamMap().items()
         if name not in vmapped
     )

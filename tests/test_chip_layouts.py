@@ -35,7 +35,7 @@ def test_resize_program_crosses_the_host_boundary_in_row_order(one_chip, side_in
     from mmlspark_tpu.image import ImageTransformer
 
     shape = (512, side_in, side_in, 3)
-    _, run = ImageTransformer(toFloat=True).resize(224, 224)._pipeline()
+    _, run, _ = ImageTransformer(toFloat=True).resize(224, 224)._pipeline()
     flat = jax.ShapeDtypeStruct((shape[0], int(np.prod(shape[1:]))), np.uint8, sharding=one_chip)
     compiled = run.lower(flat, shape).compile()
     (taken,), _ = compiled.input_formats

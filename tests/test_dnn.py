@@ -307,3 +307,264 @@ def test_onnx_gate():
     if not onnx_import.onnx_available():
         with pytest.raises(ImportError):
             onnx_import.from_onnx("/tmp/nope.onnx")
+
+
+# -- the program is built once a process (core.device.cached_program) ----------
+
+def _counting_fn():
+    """An ``applyFn`` whose Python body notes every trace of it."""
+    traces = []
+
+    def fn(params, inputs):
+        traces.append(1)
+        return {"output": inputs["x"] * params["scale"] + params["shift"]}
+
+    return fn, traces
+
+
+def _model(fn, scale=3.0, shift=0.0, **params):
+    return DNNModel(
+        applyFn=fn, modelParams={"scale": np.float32(scale), "shift": np.float32(shift)},
+        feedDict={"x": "x"}, fetchDict={"y": "output"}, batchSize=8, **params,
+    )
+
+
+def _transform(model, table):
+    """(the output column, the ``dnn.transform`` span's ``programs_built``)."""
+    from mmlspark_tpu.observability.tracing import get_tracer
+
+    tracer = get_tracer()
+    tracer.clear()
+    out = np.asarray(model.transform(table)["y"])
+    (built,) = [s["tags"]["programs_built"] for s in tracer.export() if s["name"] == "dnn.transform"]
+    return out, built
+
+
+_X = np.arange(20, dtype=np.float32)
+
+
+class _Scaler:
+    def __init__(self, traces):
+        self.traces = traces
+
+    def apply(self, params, inputs):
+        self.traces.append(1)
+        return {"output": inputs["x"] * params["scale"] + params["shift"]}
+
+
+
+def _apply_fn_pairs():
+    """name -> () -> (the two models' applyFns, the trace list they share),
+    and how many programs the second model has to build."""
+    import functools
+
+    def plain():
+        fn, traces = _counting_fn()
+        return (fn, fn), traces
+
+    def partial_object():
+        fn, traces = _counting_fn()
+        bound = functools.partial(fn)
+        return (bound, bound), traces
+
+    def bound_method_read_twice():
+        scaler = _Scaler([])
+        return (scaler.apply, scaler.apply), scaler.traces  # two method objects, equal
+
+    def two_closures_of_one_source():
+        traces = []
+
+        def make():
+            def fn(params, inputs):
+                traces.append(1)
+                return {"output": inputs["x"] * params["scale"] + params["shift"]}
+            return fn
+
+        return (make(), make()), traces
+
+    def two_partials_of_one_function():
+        fn, traces = _counting_fn()
+        return (functools.partial(fn), functools.partial(fn)), traces
+
+    return {
+        "one_function": (plain, 0), "one_partial": (partial_object, 0),
+        "one_bound_method": (bound_method_read_twice, 0),
+        "two_closures": (two_closures_of_one_source, 1),
+        "two_partials": (two_partials_of_one_function, 1),
+    }
+
+
+@pytest.mark.parametrize("case", sorted(_apply_fn_pairs()))
+def test_two_models_of_one_apply_fn_build_one_program(case):
+    """The key is the ``applyFn`` object: a second ``DNNModel`` with the same
+    one finds the first's program (traced once, ``programs_built`` 0) and runs
+    it over its own weights; another object of the same source is another
+    key, as it is to JAX."""
+    make, second_builds = _apply_fn_pairs()[case]
+    (first, second), traces = make()
+    table = Table({"x": _X})
+    out, built = _transform(_model(first, scale=3.0), table)
+    assert built == 1 and len(traces) == 1
+    np.testing.assert_array_equal(out, _X * 3.0)
+    out, built = _transform(_model(second, scale=-2.0, shift=1.0), table)  # other weights
+    assert built == second_builds and len(traces) == 1 + second_builds
+    np.testing.assert_array_equal(out, _X * -2.0 + 1.0)
+    # the same instance again, a table of another length: the batch shape is the same
+    out, built = _transform(_model(second, scale=0.5), Table({"x": _X[:11]}))
+    assert built == 0 and len(traces) == 1 + second_builds
+    np.testing.assert_array_equal(out, _X[:11] * 0.5)
+
+
+def test_a_new_batch_shape_retraces_under_the_cached_program():
+    fn, traces = _counting_fn()
+    table = Table({"x": _X})
+    _transform(_model(fn), table)
+    out, built = _transform(_model(fn).setBatchSize(5), table)
+    assert built == 0 and len(traces) == 2  # jit re-specialises; nothing was rebuilt
+    np.testing.assert_array_equal(out, _X * 3.0)
+
+
+_MESH_KEYS = {
+    # name: (params of the second model, programs it builds)
+    "same": ({}, 0),
+    "mesh_config": ({"meshConfig": "model2"}, 1),
+    "param_shardings": ({"paramShardings": {"w": 1}}, 1),
+    "param_shardings_equal_content": ({"paramShardings": {"w": 0}}, 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_MESH_KEYS))
+def test_under_a_mesh_the_key_holds_the_mesh_and_the_shardings(mesh8, case):
+    """``run`` and ``place`` close over the mesh and ``paramShardings``: a
+    change in either is a program of its own, and each places the weights as
+    its own value demands."""
+    import jax
+
+    from mmlspark_tpu.parallel.mesh import MeshConfig
+
+    traces = []
+
+    def fn(params, inputs):
+        traces.append(1)
+        return {"output": inputs["x"] @ params["w"]}
+
+    w = np.arange(32, dtype=np.float32).reshape(4, 8)
+    x = np.random.default_rng(0).standard_normal((16, 4)).astype(np.float32)
+
+    def model(**params):
+        if params.get("meshConfig") == "model2":
+            params["meshConfig"] = MeshConfig(data=4, model=2)
+        params.setdefault("paramShardings", {"w": 0})
+        return DNNModel(applyFn=fn, modelParams={"w": w}, feedDict={"x": "x"},
+                        fetchDict={"y": "output"}, batchSize=8, shardOverMesh=True, **params)
+
+    def run(m):
+        return _transform(m, Table({"x": x}))
+
+    out, built = run(model())
+    assert built == 1
+    np.testing.assert_allclose(out, x @ w, rtol=1e-5)
+    overrides, builds = _MESH_KEYS[case]
+    second = model(**overrides)
+    out, built = run(second)
+    assert built == builds
+    np.testing.assert_allclose(out, x @ w, rtol=1e-5)
+    _, place = second._jitted()
+    placed = place({"w": w})["w"]
+    axis = second.getParamShardings()["w"]
+    spec = [None, None]
+    spec[axis] = "model"
+    assert tuple(placed.sharding.spec) == tuple(spec)
+    model_axis = (second.getMeshConfig() or MeshConfig()).resolve(len(jax.devices()))["model"]
+    assert placed.sharding.mesh.shape["model"] == model_axis
+
+
+def test_the_lru_evicts_at_its_bound_and_a_rebuilt_program_gives_the_same_bytes(monkeypatch):
+    from mmlspark_tpu.core import device
+
+    monkeypatch.setattr(device, "_PROGRAM_CACHE_SIZE", 2)
+    device._PROGRAM_CACHE.clear()
+    fns = [_counting_fn() for _ in range(3)]
+    table = Table({"x": _X})
+    first, built = _transform(_model(fns[0][0]), table)
+    assert built == 1
+    for fn, _ in fns[1:]:
+        assert _transform(_model(fn), table)[1] == 1
+    assert len(device._PROGRAM_CACHE) == 2
+    assert ("dnn", fns[0][0]) not in device._PROGRAM_CACHE  # the oldest went
+    assert _transform(_model(fns[2][0]), table)[1] == 0  # the newest stayed
+    again, built = _transform(_model(fns[0][0]), table)
+    assert built == 1 and again.tobytes() == first.tobytes()
+    assert len(device._PROGRAM_CACHE) == 2
+
+
+def test_two_threads_that_transform_at_once_build_one_program():
+    """``ServingServer``'s batch loop and a user's thread may both call
+    ``transform``: the look-up is under a lock, so one of them builds and the
+    other finds, and each tag counts its own thread's builds only."""
+    import sys
+    import threading
+
+    from mmlspark_tpu.observability.tracing import get_tracer
+
+    fn, _ = _counting_fn()
+    table = Table({"x": _X})
+    workers, results = 8, {}
+    start = threading.Barrier(workers)
+
+    def work(i):
+        start.wait(timeout=30)
+        results[i] = np.asarray(_model(fn, scale=float(i)).transform(table)["y"])
+
+    tracer = get_tracer()
+    tracer.clear()
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=work, args=(i,)) for i in range(workers)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(interval)
+    built = [s["tags"]["programs_built"] for s in tracer.export() if s["name"] == "dnn.transform"]
+    assert sorted(built) == [0] * (workers - 1) + [1]
+    for i in range(workers):
+        np.testing.assert_array_equal(results[i], _X * float(i))
+
+
+def test_a_weight_is_in_no_key_and_in_nothing_cached():
+    """The cache must not pin weights: what it holds for a model is the
+    jitted ``applyFn`` and a placing function, neither of which references
+    ``modelParams``."""
+    import gc
+    import weakref
+
+    from mmlspark_tpu.core import device
+
+    fn = lambda params, inputs: {"output": inputs["x"] * params["scale"]}  # noqa: E731
+    scale = np.full((1,), 2.0, np.float32)
+    model = DNNModel(applyFn=fn, modelParams={"scale": scale}, feedDict={"x": "x"}, fetchDict={"y": "output"})
+    np.testing.assert_array_equal(np.asarray(model.transform(Table({"x": _X}))["y"]), _X * 2.0)
+    assert ("dnn", fn) in device._PROGRAM_CACHE
+    alive = weakref.ref(scale)
+    del scale, model
+    gc.collect()
+    assert alive() is None
+
+
+@pytest.mark.parametrize("value,same,other", [
+    ({"a": [1, 2], "b": {"c": "x"}}, {"b": {"c": "x"}, "a": [1, 2]}, {"a": [1, 3], "b": {"c": "x"}}),
+    ([{"op": "Flip", "flipCode": 1}], [{"flipCode": 1, "op": "Flip"}], [{"op": "Flip", "flipCode": 0}]),
+    ({"mean": np.array([1.0, 2.0])}, {"mean": np.array([1.0, 2.0])}, {"mean": np.array([1.0, 2.5])}),
+    ({"mean": np.zeros(2, np.float32)}, {"mean": np.zeros(2, np.float32)}, {"mean": np.zeros(2, np.float64)}),
+    ((1, "a"), [1, "a"], (1, "b")),
+    ({"s": {1, 2}}, {"s": {1, 2}}, {"s": {1, 3}}),
+], ids=["nested_dict", "stage_list", "array", "array_dtype", "tuple", "unhashable_leaf_by_repr"])
+def test_frozen_is_a_key_by_content(value, same, other):
+    from mmlspark_tpu.core.device import frozen
+
+    assert frozen(value) == frozen(same) and hash(frozen(value)) == hash(frozen(same))
+    assert frozen(value) != frozen(other)

@@ -189,3 +189,123 @@ def test_featurize_serialization(tmp_path):
 
     loaded = PipelineStage.load(str(tmp_path / "feat"))
     np.testing.assert_allclose(loaded.transform(t)["features"], model.transform(t)["features"])
+
+
+# -- LMFeaturizer: the decoder's program is built once a process ----------------
+
+_LM = dict(
+    hidden_size=32, num_attention_heads=2, num_key_value_heads=1, head_dim=16,
+    intermediate_size=48, moe_intermediate_size=16, num_experts=4, num_experts_per_tok=2,
+    num_shared_experts=1, num_dense_layers=1, sliding_window=8, rope_theta=10000,
+    rms_norm_eps=1e-5, route_scale=2.0, vocab_size=64,
+    layer_types=["sliding_attention", "full_attention"], layers=2,
+    interpret=True,  # the attention kernel, on a backend that is no TPU
+)
+_LM_OUTPUTS = {"hidden": "h", "logits": "l", "expert_load": "e"}
+_LM_TOKENS = np.random.default_rng(4).integers(0, 64, size=(5, 24)).astype(np.int32)
+
+
+def _lm_params(seed):
+    import jax
+
+    from mmlspark_tpu.models.afmoe import init_afmoe
+
+    return init_afmoe(jax.random.PRNGKey(seed), _LM)
+
+
+def _lm_transform(params, config, batch=4):
+    """(the three output columns, the ``dnn.transform`` span's ``programs_built``)."""
+    from mmlspark_tpu.featurize.lm import LMFeaturizer
+    from mmlspark_tpu.observability.tracing import get_tracer
+
+    tracer = get_tracer()
+    tracer.clear()
+    out = LMFeaturizer(outputCols=_LM_OUTPUTS, modelParams=params, modelConfig=config,
+                       batchSize=batch).transform(Table({"tokens": _LM_TOKENS}))
+    (built,) = [s["tags"]["programs_built"] for s in tracer.export() if s["name"] == "dnn.transform"]
+    return {name: np.asarray(out[col]) for name, col in _LM_OUTPUTS.items()}, built
+
+
+def _lm_direct(params, config):
+    """The decoder called with no stage around it, rows padded as the stage pads."""
+    import jax
+
+    from mmlspark_tpu.models import afmoe
+
+    apply = getattr(afmoe.afmoe_apply, "uncounted", afmoe.afmoe_apply)  # see counted_decoder
+    forward = jax.jit(lambda p, t: apply(p, t, config))
+    batches = np.concatenate([_LM_TOKENS, np.zeros((3, 24), np.int32)]).reshape(2, 4, 24)
+    out = [forward(params, batch) for batch in batches]
+    return {k: np.concatenate([np.asarray(o[k]) for o in out])[:5] for k in _LM_OUTPUTS}
+
+
+@pytest.fixture()
+def counted_decoder(monkeypatch):
+    """Every trace of ``afmoe_apply`` through a featurizer built after this."""
+    from mmlspark_tpu.models import afmoe
+
+    traces, apply = [], afmoe.afmoe_apply
+
+    def counting(params, tokens, config):
+        traces.append(dict(config))
+        return apply(params, tokens, config)
+
+    counting.uncounted = apply
+    monkeypatch.setattr(afmoe, "afmoe_apply", counting)
+    return traces
+
+
+def test_two_lm_featurizers_of_equal_configuration_trace_the_decoder_once(counted_decoder):
+    config = dict(_LM, note="traced once")  # a key no layer reads: this test's own program
+    first, second = _lm_params(1), _lm_params(2)
+    out, built = _lm_transform(first, dict(config))
+    assert built == 1 and len(counted_decoder) == 1
+    want = _lm_direct(first, config)
+    for name in _LM_OUTPUTS:
+        np.testing.assert_array_equal(out[name], want[name])
+    # a fresh dict of equal content, a fresh list in it, another featurizer, other weights
+    again = dict(reversed(list(config.items())), layer_types=list(config["layer_types"]))
+    out, built = _lm_transform(second, again)
+    assert built == 0 and len(counted_decoder) == 1
+    want = _lm_direct(second, config)
+    assert not np.array_equal(out["hidden"], _lm_transform(first, dict(config))[0]["hidden"])
+    for name in _LM_OUTPUTS:
+        np.testing.assert_array_equal(out[name], want[name])
+    assert len(counted_decoder) == 1
+
+
+@pytest.mark.parametrize("field,value", [
+    ("sliding_window", 4), ("route_scale", 1.0), ("layer_types", ["full_attention"] * 2),
+    ("product_dtype", "float8_e4m3fn"),
+])
+def test_each_field_of_the_decoder_configuration_is_in_the_key(counted_decoder, field, value):
+    base = dict(_LM, note="one field changed")
+    params = _lm_params(3)
+    plain, _ = _lm_transform(params, dict(base))
+    traced = len(counted_decoder)
+    changed = dict(base, **{field: value})
+    out, built = _lm_transform(params, changed)
+    assert built == 1 and len(counted_decoder) == traced + 1
+    assert counted_decoder[-1][field] == value
+    want = _lm_direct(params, changed)
+    for name in _LM_OUTPUTS:
+        np.testing.assert_array_equal(out[name], want[name])
+    assert not np.array_equal(out["hidden"], plain["hidden"])
+    # and the first configuration still finds its own
+    out, built = _lm_transform(params, dict(base))
+    assert built == 0 and np.array_equal(out["hidden"], plain["hidden"])
+
+
+def test_a_configuration_changed_in_place_after_a_call_is_another_key(counted_decoder):
+    """The cached function reads its own copy of the configuration, so a
+    later trace under it (another batch shape) sees what its key says."""
+    config = dict(_LM, note="changed in place", layer_types=list(_LM["layer_types"]))
+    params = _lm_params(5)
+    first, built = _lm_transform(params, config)
+    config["layer_types"][1] = "sliding_attention"  # the caller edits the list it passed
+    edited, built = _lm_transform(params, config)
+    assert built == 1 and not np.array_equal(edited["hidden"], first["hidden"])
+    original = dict(config, layer_types=list(_LM["layer_types"]))
+    out, built = _lm_transform(params, original, batch=5)  # found, and traced anew for the shape
+    assert built == 0 and counted_decoder[-1]["layer_types"] == _LM["layer_types"]
+    np.testing.assert_allclose(out["hidden"], first["hidden"], rtol=2e-2, atol=2e-2)

@@ -235,3 +235,197 @@ def test_read_zip(tmp_path):
     assert t.num_rows == 2
     assert any(p.endswith("!a.txt") for p in t["path"])
     assert b"beta" in list(t["bytes"])
+
+
+# -- programs are built once a process (core.device.cached_program) ------------
+
+def _built(job, *names):
+    """(what ``job`` returned, {span name: its ``programs_built``})."""
+    from mmlspark_tpu.observability.tracing import get_tracer
+
+    tracer = get_tracer()
+    tracer.clear()
+    out = job()
+    return out, {s["name"]: s["tags"]["programs_built"] for s in tracer.export() if s["name"] in names}
+
+
+def _counting_backbone():
+    """A backbone whose Python body notes each trace and the ``cut`` it saw."""
+    cuts = []
+
+    def backbone(p, x, cut):
+        cuts.append(cut)
+        pooled = x.mean(axis=(2, 3))  # (rows, channels), NCHW in
+        return pooled @ p["w"] + cut
+
+    return backbone, cuts
+
+
+def _pixels(rows=5, side=8):
+    images = np.random.default_rng(11).integers(0, 256, size=(rows, side, side, 3), dtype=np.uint8)
+    column = np.empty(rows, dtype=object)
+    for i in range(rows):
+        column[i] = images[i]
+    return images, Table({"image": column})
+
+
+def _featurizer(backbone, w, **params):
+    return ImageFeaturizer(applyFn=backbone, modelParams={"w": w}, inputHeight=8, inputWidth=8,
+                           batchSize=4, **params)
+
+
+_W = np.arange(6, dtype=np.float32).reshape(3, 2)
+
+
+def _expected(images, w, cut=1, scale=1.0 / 255.0):
+    return (images.astype(np.float32) * np.float32(scale)).mean(axis=(1, 2)) @ w + cut
+
+
+def test_two_featurizers_of_one_definition_trace_the_backbone_once():
+    backbone, cuts = _counting_backbone()
+    images, table = _pixels()
+    spans = ("image.transform", "dnn.transform")
+    out, built = _built(lambda: _featurizer(backbone, _W).transform(table)["features"], *spans)
+    assert built["dnn.transform"] == 1 and cuts == [1]
+    np.testing.assert_allclose(out, _expected(images, _W), rtol=1e-5)
+    # another featurizer object, other weights: the same program over them
+    out, built = _built(lambda: _featurizer(backbone, -_W).transform(table)["features"], *spans)
+    assert built == {"image.transform": 0, "dnn.transform": 0} and cuts == [1]
+    np.testing.assert_allclose(out, _expected(images, -_W), rtol=1e-5)
+
+
+_CAPTURED = {
+    # what the featurizer's applyFn closes over, each changed in turn:
+    # name -> (params of the second featurizer, what its output has to be)
+    "cutOutputLayers": ({"cutOutputLayers": 2}, lambda im: _expected(im, _W, cut=2)),
+    "scale": ({"scale": 0.5}, lambda im: _expected(im, _W, scale=0.5)),
+    "scale_given_as_an_int": ({"scale": 1}, lambda im: _expected(im, _W, scale=1.0)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_CAPTURED))
+def test_each_value_the_featurizers_apply_fn_captures_is_in_its_key(case):
+    backbone, cuts = _counting_backbone()
+    images, table = _pixels()
+    _featurizer(backbone, _W).transform(table)
+    params, expected = _CAPTURED[case]
+    out, built = _built(lambda: _featurizer(backbone, _W, **params).transform(table)["features"],
+                        "dnn.transform")
+    assert built == {"dnn.transform": 1} and len(cuts) == 2
+    np.testing.assert_allclose(out, expected(images), rtol=1e-5)
+    # and the first definition still finds its own
+    out, built = _built(lambda: _featurizer(backbone, _W).transform(table)["features"], "dnn.transform")
+    assert built == {"dnn.transform": 0} and len(cuts) == 2
+    np.testing.assert_allclose(out, _expected(images, _W), rtol=1e-5)
+
+
+def test_another_backbone_is_another_program():
+    first, first_cuts = _counting_backbone()
+    images, table = _pixels()
+    _featurizer(first, _W).transform(table)
+
+    def doubled(p, x, cut):
+        return 2.0 * first(p, x, cut)
+
+    out, built = _built(lambda: _featurizer(doubled, _W).transform(table)["features"], "dnn.transform")
+    assert built == {"dnn.transform": 1} and len(first_cuts) == 2
+    np.testing.assert_allclose(out, 2.0 * _expected(images, _W), rtol=1e-5)
+
+
+def _run_stages(table, stage_list):
+    """(the output column, ``image.transform``'s ``programs_built`` by name)."""
+    return _built(
+        lambda: ImageTransformer(inputCol="image", outputCol="out", stages=stage_list).transform(table)["out"],
+        "image.transform")
+
+
+def _stage_program_traces(monkeypatch):
+    """Count the traces of the flip op's body (every trace of the stage
+    function runs it once: ``eval_shape`` and the jitted program alike)."""
+    from mmlspark_tpu.image import transforms
+
+    traces = []
+    flip = transforms._op_flip
+
+    def counting(stage):
+        run = flip(stage)
+
+        def counted(x):
+            traces.append(x.shape)
+            return run(x)
+
+        return counted
+
+    monkeypatch.setitem(transforms._OPS, "Flip", counting)
+    return traces
+
+
+def test_a_fresh_stage_list_of_equal_content_finds_the_stage_program(monkeypatch):
+    traces = _stage_program_traces(monkeypatch)
+    images, table = _pixels()
+    stages = [{"op": "Flip", "flipCode": 0}, {"op": "Threshold", "threshold": 77.0, "maxVal": 200.0}]
+
+    want = np.where(images[:, ::-1].astype(np.float32) > 77.0, 200, 0).astype(np.uint8)
+    out, built = _run_stages(table, stages)
+    assert built == {"image.transform": 1}
+    assert traces == [(5, 8, 8, 3)] * 2  # the result's shape, then the program
+    np.testing.assert_array_equal(np.stack(list(out)), want)
+    out, built = _run_stages(table, [dict(reversed(list(s.items()))) for s in stages])  # equal dicts, made anew
+    assert built == {"image.transform": 0} and len(traces) == 2  # nor was the shape asked for again
+    np.testing.assert_array_equal(np.stack(list(out)), want)
+    # the fluent builders arrive at the same list
+    fluent = ImageTransformer(inputCol="image", outputCol="out").flip(0).threshold(77.0, 200.0)
+    assert _built(lambda: fluent.transform(table), "image.transform")[1] == {"image.transform": 0}
+    # a table of other rows is a shape not seen yet: traced, not rebuilt
+    _, fewer = _pixels(rows=3)
+    out, built = _built(lambda: fluent.transform(fewer)["out"], "image.transform")
+    assert built == {"image.transform": 0} and traces[2:] == [(3, 8, 8, 3)] * 2
+
+
+@pytest.mark.parametrize("change", ["a_value", "a_stage_more", "the_order"])
+def test_a_stage_list_that_differs_is_a_program_of_its_own(monkeypatch, change):
+    traces = _stage_program_traces(monkeypatch)
+    images, table = _pixels()
+    base = [{"op": "Flip", "flipCode": 0}, {"op": "Threshold", "threshold": 60.0, "maxVal": 255.0}]
+    changed, want = {
+        "a_value": ([{"op": "Flip", "flipCode": 1}, base[1]],
+                    lambda x: np.where(x[:, :, ::-1] > 60.0, 255, 0)),
+        "a_stage_more": (base + [{"op": "Flip", "flipCode": 1}],
+                         lambda x: np.where(x[:, ::-1, ::-1] > 60.0, 255, 0)),
+        "the_order": ([base[1], base[0]], lambda x: np.where(x > 60.0, 255, 0)[:, ::-1]),
+    }[change]
+
+    _run_stages(table, base)
+    before = len(traces)
+    out, built = _run_stages(table, changed)
+    assert built == {"image.transform": 1} and len(traces) > before
+    np.testing.assert_array_equal(np.stack(list(out)), want(images.astype(np.float32)).astype(np.uint8))
+
+
+def test_a_stage_dict_changed_in_place_after_a_call_is_another_key(monkeypatch):
+    """An op reads its dict while it is traced; the cached program holds a
+    copy, so a caller's later edit neither reaches the program built before
+    it nor is missed by the key."""
+    _stage_program_traces(monkeypatch)
+    images, table = _pixels()
+    stage = {"op": "Flip", "flipCode": 0, "note": "a key no op reads makes this list this test's own"}
+    transformer = ImageTransformer(inputCol="image", outputCol="out", stages=[stage])
+    out, _ = _built(lambda: transformer.transform(table)["out"], "image.transform")
+    np.testing.assert_array_equal(np.stack(list(out)), images[:, ::-1])
+    stage["flipCode"] = 1
+    out, built = _built(lambda: transformer.transform(table)["out"], "image.transform")
+    assert built == {"image.transform": 1}
+    np.testing.assert_array_equal(np.stack(list(out)), images[:, :, ::-1])
+    _, fewer = _pixels(rows=2)  # a new shape under the first program: its own copy of the dict
+    first = ImageTransformer(inputCol="image", outputCol="out", stages=[dict(stage, flipCode=0)])
+    out, built = _built(lambda: first.transform(fewer)["out"], "image.transform")
+    assert built == {"image.transform": 0}
+    np.testing.assert_array_equal(np.stack(list(out)), images[:2, ::-1])
+
+
+def test_an_unknown_op_raises_on_every_call():
+    _, table = _pixels()
+    bad = ImageTransformer(inputCol="image", outputCol="out", stages=[{"op": "Sharpen"}])
+    for _ in range(2):
+        with pytest.raises(ValueError, match="unknown image op 'Sharpen'"):
+            bad.transform(table)

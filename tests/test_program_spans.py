@@ -173,13 +173,17 @@ def test_featurize_records_three_spans_a_batch(images, batch):
 
     uint8, resized = images * 16 * 16 * 3, images * 16 * 16 * 3 * 4
     assert root["tags"] == {"rows": images, "batch_size": batch}
-    assert tags("image.transform") == [{"rows": images, "groups": 1}]
+    # programs_built: 1 in the module's first call of a definition, then 0
+    # (tests/test_dnn.py and tests/test_image.py hold the counts)
+    (stage,), (forward,) = tags("image.transform"), tags("dnn.transform")
+    assert stage.pop("programs_built") in (0, 1) and forward.pop("programs_built") in (0, 1)
+    assert stage == {"rows": images, "groups": 1}
     assert tags("image.stack") == [{"bytes": uint8}]
     assert tags("image.apply_fetch") == [{"bytes_up": uint8, "bytes_down": resized}]
     # float output, one shape group: no clip/round, and the fetched result
     # is handed over as the column (no copy)
     assert tags("image.assemble") == [{"bytes": 0}, {"bytes": 0}]
-    assert tags("dnn.transform") == [{"rows": images, "batches": batches}]
+    assert forward == {"rows": images, "batches": batches}
     import jax
 
     assert tags("dnn.place_params") == [{"bytes": sum(a.nbytes for a in jax.tree.leaves(params))}]
@@ -206,6 +210,7 @@ def test_mixed_shapes_record_a_stack_fetch_assemble_per_group():
     assert names == ["image.stack", "image.apply_fetch", "image.assemble"] * 2 + [
         "image.assemble", "image.transform"]
     whole = spans[-1]
+    assert whole["tags"].pop("programs_built") in (0, 1)
     assert whole["tags"] == {"rows": 5, "groups": 2}
     # uint8 out: the round trip's clip and cast is a copy; the object column is not
     assert [s["tags"]["bytes"] for s in spans if s["name"] == "image.assemble"] == [
