@@ -1,17 +1,20 @@
 """LMFeaturizer — score or featurize a column of token rows with a decoder.
 
 The text sibling of :class:`mmlspark_tpu.image.ImageFeaturizer`: a language
-model (default family: :mod:`mmlspark_tpu.models.afmoe`) applied to whole
-sequences by :class:`DNNModel` in fixed-shape device batches, features and
-last-position logits out, for a downstream learner. No generation loop and
-no key/value cache: every call runs whole sequences. The program itself is
-built once a process for each decoder configuration (by content) and found
-again by later calls.
+model applied to whole sequences by :class:`DNNModel` in fixed-shape device
+batches, features and last-position logits out, for a downstream learner.
+``modelConfig["model_type"]`` names the decoder family (:data:`FAMILIES`:
+``afmoe``, the default where the key is absent, :mod:`mmlspark_tpu.models.afmoe`;
+``joyai_llm_flash``, :mod:`mmlspark_tpu.models.mla_moe`). No generation loop
+and no key/value cache: every call runs whole sequences. The program itself
+is built once a process for each decoder configuration (by content) and
+found again by later calls.
 """
 
 from __future__ import annotations
 
 import copy
+import importlib
 
 import numpy as np
 
@@ -24,18 +27,36 @@ from mmlspark_tpu.observability.tracing import get_tracer
 
 _LOAD = "expert_load"
 
+# model_type -> (the family's module, its ``*_apply(params, tokens, config)``
+# there). The module's ``span_tags(config)`` says what ``lm.featurize`` tells
+# of a configuration of its family, so the key names stay with the family.
+FAMILIES = {
+    "afmoe": ("mmlspark_tpu.models.afmoe", "afmoe_apply"),
+    "joyai_llm_flash": ("mmlspark_tpu.models.mla_moe", "mla_moe_apply"),
+}
+
+
+def _family(config: dict):
+    """-> (``model_type``, the family's apply function, its span tags)."""
+    model_type = config.get("model_type", "afmoe")
+    if model_type not in FAMILIES:
+        raise ValueError(f"modelConfig['model_type'] {model_type!r}: one of {sorted(FAMILIES)}")
+    module, name = FAMILIES[model_type]
+    module = importlib.import_module(module)
+    return model_type, getattr(module, name), module.span_tags(config)
+
 
 def _apply_fn(config: dict):
     """The ``applyFn`` handed to :class:`DNNModel`: one function object a
     process for a configuration's content (a fresh ``dict`` of equal content
     is the same key), so that a later ``transform`` finds its program."""
-    from mmlspark_tpu.models.afmoe import afmoe_apply
+    _, apply, _ = _family(config)
 
     def make():
         # a later trace (another batch shape) must read what the key says,
         # whatever the caller has done to its dict since
         own = copy.deepcopy(config)
-        return lambda p, inputs: afmoe_apply(p, inputs["input"], own)
+        return lambda p, inputs: apply(p, inputs["input"], own)
 
     return cached_program(("lm.featurizer", frozen(config)), make)
 
@@ -51,10 +72,14 @@ class LMFeaturizer(Model):
         default={"hidden": "features"},
     )
     modelParams = Param(
-        "Decoder parameter pytree (mmlspark_tpu.models.afmoe.init_afmoe format)",
+        "Decoder parameter pytree (the family's init_* format: mmlspark_tpu.models.afmoe.init_afmoe, "
+        "mmlspark_tpu.models.mla_moe.init_mla_moe)",
         default=None, is_complex=True,
     )
-    modelConfig = Param("Decoder configuration (see mmlspark_tpu.models.afmoe)", default=None)
+    modelConfig = Param(
+        "Decoder configuration: the family's published config.json keys and 'layers'; "
+        "'model_type' names the family ('afmoe' where absent: mmlspark_tpu.models.afmoe; "
+        "'joyai_llm_flash': mmlspark_tpu.models.mla_moe)", default=None)
     batchSize = Param("Rows per device batch", default=4, converter=to_int, validator=gt(0))
 
     def transform(self, table: Table) -> Table:
@@ -63,16 +88,17 @@ class LMFeaturizer(Model):
         fetched expert loads per dispatch (``observability/tracing``)."""
         params, config = self.getModelParams(), self.getModelConfig()
         if params is None or config is None:
-            raise ValueError("modelParams and modelConfig must be set (see mmlspark_tpu.models.afmoe)")
+            raise ValueError("modelParams and modelConfig must be set (see mmlspark_tpu.models.afmoe, .mla_moe)")
         outputs = dict(self.getOutputCols())
         unknown = set(outputs) - {"hidden", "logits", _LOAD}
         if unknown or not outputs:
             raise ValueError(f"outputCols maps hidden / logits / expert_load to columns (got {sorted(outputs)})")
         batch = self.getBatchSize()
         tokens = len(table.column(self.getInputCol())[0])
+        model_type, _, tags = _family(config)
         with get_tracer().span(
             "lm.featurize", rows=table.num_rows, tokens=tokens, batch_size=batch,
-            layers=config["layers"], experts=config["num_experts"],
+            model_type=model_type, **tags,
         ):
             load_col = outputs.get(_LOAD, "__expert_load__")  # fetched always: route_stats reads it
             dnn = DNNModel(
@@ -91,6 +117,8 @@ class LMFeaturizer(Model):
                 sp.tags["load_peak"] = int(per_dispatch.max(axis=-1).sum())
                 sp.tags["load_mean"] = float(per_dispatch.mean(axis=-1).sum())
                 sp.tags["tokens_routed"] = int(load.sum())
+                sp.tags["experts_empty"] = int((per_dispatch == 0).sum())
+                sp.tags["expert_groups"] = int(per_dispatch.size)
             if _LOAD not in outputs:
                 out = out.drop(load_col)
             return out

@@ -1,0 +1,114 @@
+"""What the decoder families with routed experts share (:mod:`afmoe`,
+:mod:`mla_moe`): the stated precision of a norm and of a matrix product,
+SwiGLU, the routed-expert block, the head at a row's last position, and
+seeded weights made on the device a layer at a time.
+
+Precision, for every family here: weights and the residual stream bfloat16;
+every matrix product takes bfloat16 inputs and sums in float32; norms,
+softmax, rotary, router scores and the choice of experts are float32.
+``config["product_dtype"]`` rounds every product's inputs to a narrower
+dtype first (``float8_e4m3fn``, say), for measuring what that costs.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Any, Dict
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from mmlspark_tpu.ops.expert_parallel import moe_topk
+
+
+def norm(x, scale, eps):
+    x = x.astype(jnp.float32)
+    return x * lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * scale.astype(jnp.float32)
+
+
+def rounded(a, dtype):
+    """``a`` in bfloat16, rounded to ``dtype`` on the way if that is narrower."""
+    return a.astype(dtype).astype(jnp.bfloat16)
+
+
+def dot(x, w, dtype):
+    """Inputs rounded to ``dtype`` (bfloat16 as stated), float32 sums."""
+    return jnp.dot(rounded(x, dtype), rounded(w, dtype), preferred_element_type=jnp.float32)
+
+
+def swiglu(x, gate, up, down, dt):
+    inner = jax.nn.silu(dot(x, gate, dt)) * dot(x, up, dt)
+    return dot(inner, down, dt)
+
+
+def routed_experts(p, x, k: int, scale: float, dt):
+    """x: (rows, S, hidden). Sigmoid router scores; a token's ``k`` experts
+    are the largest of score + ``router_bias``, weighed by the scores alone
+    over their sum times ``scale`` (:func:`moe_topk`); one shared expert
+    beside them. -> (routed + shared (rows, S, hidden) float32, the tokens of
+    each row that each expert received (rows, experts))."""
+    B, S, D = x.shape
+    E = p["router"].shape[-1]
+    flat = rounded(x.reshape(B * S, D), dt)
+    with jax.named_scope("moe_route"):
+        scores = jax.nn.sigmoid(dot(flat, p["router"], dt))
+    with jax.named_scope("moe_experts"):
+        experts = {n: rounded(p["e_" + n], dt) for n in ("gate", "up", "down")}
+        routed, chosen = moe_topk(flat, scores + p["router_bias"], scores, experts, k, scale)
+        shared = swiglu(flat, p["s_gate"], p["s_up"], p["s_down"], dt)
+    load = (chosen.reshape(B, S * k, 1) == jnp.arange(E, dtype=jnp.int32)).sum(axis=1)
+    return (routed + shared).reshape(B, S, D), load.astype(jnp.int32)
+
+
+def last_position(params, h, eps, dt):
+    """-> (each row's last position after the final norm (rows, hidden),
+    the untied head applied to it (rows, vocabulary)), float32."""
+    with jax.named_scope("lm_head"):
+        hidden = norm(h[:, -1], params["final_norm"], eps)
+        return hidden, dot(hidden, params["head"], dt)
+
+
+def _init_layer(key, shapes):
+    out = {}
+    for k, (name, (shape, fan_in)) in zip(jax.random.split(key, len(shapes)), sorted(shapes.items())):
+        if name == "router_bias":  # a buffer in float32: it is added to float32 scores
+            out[name] = 0.1 * jax.random.normal(k, shape, jnp.float32)
+        elif fan_in is None:  # a norm's scale, drawn away from 1
+            out[name] = jax.random.uniform(k, shape, jnp.float32, 0.5, 1.5).astype(jnp.bfloat16)
+        else:
+            out[name] = (jax.random.normal(k, shape, jnp.float32) * fan_in ** -0.5).astype(jnp.bfloat16)
+    return out
+
+
+def init_decoder(key, config: Dict[str, Any], dense, moe):
+    """Seeded weights, made on the device in bfloat16 (nothing passes through
+    the host). ``dense`` and ``moe`` are each (``{name: (shape, fan-in or
+    None for a norm's scale)}`` of one layer, how many layers): the layers
+    of a kind live stacked on a leading axis, and one jitted call a layer
+    draws that layer and writes it into the donated stack, so nothing is
+    ever held twice. Matrices are normal with variance 1 / fan-in, the
+    embedding and the head 1 / hidden, norm scales uniform in [0.5, 1.5),
+    the router's balancing bias normal x 0.1 in float32."""
+    D, V = config["hidden_size"], config["vocab_size"]
+    k_embed, k_head, k_norm, k_dense, k_moe = jax.random.split(key, 5)
+    matrix = jax.jit(
+        lambda k, shape: (jax.random.normal(k, shape, jnp.float32) * D ** -0.5).astype(jnp.bfloat16),
+        static_argnums=1)
+    params = {
+        "embed": matrix(k_embed, (V, D)), "head": matrix(k_head, (D, V)),
+        "final_norm": jax.random.uniform(k_norm, (D,), jnp.float32, 0.5, 1.5).astype(jnp.bfloat16),
+    }
+    for name, k, (shapes, n) in (("dense", k_dense, dense), ("moe", k_moe, moe)):
+
+        @functools.partial(jax.jit, donate_argnums=0)
+        def write(stack, i, kk):
+            layer = _init_layer(kk, shapes)
+            return {m: lax.dynamic_update_index_in_dim(stack[m], layer[m], i, 0) for m in stack}
+
+        stack = {m: jnp.zeros((n,) + shape, jnp.float32 if m == "router_bias" else jnp.bfloat16)
+                 for m, (shape, _) in shapes.items()}
+        for i, kk in enumerate(jax.random.split(k, n)):
+            stack = write(stack, i, kk)
+        params[name] = stack
+    return params

@@ -420,6 +420,29 @@ def _make_test_objects() -> Dict[str, Callable[[], TestObject]]:
 
     add("mmlspark_tpu.featurize.lm.LMFeaturizer", lm_featurizer)
 
+    def lm_featurizer_latent():
+        import jax
+
+        from mmlspark_tpu.featurize import LMFeaturizer
+        from mmlspark_tpu.models import init_mla_moe
+
+        config = dict(
+            model_type="joyai_llm_flash", hidden_size=32, num_attention_heads=2, q_lora_rank=24,
+            kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8,
+            intermediate_size=48, moe_intermediate_size=16, n_routed_experts=4, n_shared_experts=1,
+            num_experts_per_tok=2, first_k_dense_replace=1, routed_scaling_factor=2.5,
+            rope_theta=32000000, rms_norm_eps=1e-6, vocab_size=64, layers=2, interpret=True,
+        )
+        params = jax.tree.map(np.asarray, init_mla_moe(jax.random.PRNGKey(0), config))
+        tokens = _rng(3).integers(0, 64, size=(3, 12)).astype(np.int32)
+        return TestObject(
+            LMFeaturizer(modelParams=params, modelConfig=config, batchSize=2),
+            Table({"id": np.arange(3), "tokens": tokens}),
+        )
+
+    # a second fixture of one class: the part after '#' names the variant
+    add("mmlspark_tpu.featurize.lm.LMFeaturizer#latent", lm_featurizer_latent)
+
     def superpixel():
         from mmlspark_tpu.lime import SuperpixelTransformer
 

@@ -102,7 +102,8 @@ def test_spans_of_a_transform(params):
                        batchSize=2).transform(Table({"tokens": _tokens(5)}))
     spans = {s["name"]: s for s in tracer.export()}
     root = spans["lm.featurize"]
-    assert root["tags"] == {"rows": 5, "tokens": 50, "batch_size": 2, "layers": 6, "experts": 8}
+    assert root["tags"] == {"rows": 5, "tokens": 50, "batch_size": 2, "layers": 6, "experts": 8,
+                            "model_type": "afmoe", "attention": "grouped"}
     assert spans["dnn.transform"]["parent_id"] == root["span_id"]
     stats = spans["lm.route_stats"]["tags"]
     per_dispatch = np.add.reduceat(out["e"].astype(np.int64), [0, 2, 4], axis=0)
@@ -110,6 +111,8 @@ def test_spans_of_a_transform(params):
     assert stats["load_mean"] == pytest.approx(5 * 4 * 50 * 2 / 8)
     assert stats["tokens_routed"] == 5 * 4 * 50 * 2
     assert stats["load_peak"] >= stats["load_mean"]
+    assert stats["expert_groups"] == 3 * 4 * 8  # dispatches x expert layers x experts
+    assert stats["experts_empty"] == (per_dispatch == 0).sum()
 
 
 def test_named_scopes_are_in_the_lowered_program(params):
@@ -152,7 +155,7 @@ def _dense_attention(q, k, v, window):
     q, k, v = (np.asarray(a, np.float64) for a in (q, k, v))
     i, j = np.arange(S)[:, None], np.arange(S)[None, :]
     seen = (j <= i) if window is None else (j <= i) & (j > i - window)
-    out = np.zeros_like(q)
+    out = np.zeros(q.shape[:3] + v.shape[3:])  # a value head may have another width than a key head
     for h in range(H):
         scores = np.einsum("bsd,btd->bst", q[:, :, h], k[:, :, h // G]) / np.sqrt(d)
         scores = np.where(seen, scores, -np.inf)
@@ -180,6 +183,18 @@ def test_blocked_attention_in_bfloat16():
         got = blocked_attention(q, k, v, window=window, block=16, interpret=True)
         assert got.dtype == jnp.bfloat16
         np.testing.assert_allclose(np.asarray(got, np.float64), _dense_attention(q, k, v, window), atol=0.03)
+
+
+@pytest.mark.parametrize("group", [1, 3, 4, 7])
+def test_blocked_attention_runs_every_head_group_at_the_default_block(group):
+    """300 positions pad to a key/value block of 384, three query blocks of
+    128, whatever the group: 28 heads over 4 (7) or 40 over 8 (5) are
+    published shapes."""
+    rng = np.random.default_rng(group)
+    q = jnp.asarray(rng.normal(size=(1, 300, 2 * group, 8)), jnp.float32)
+    k, v = (jnp.asarray(rng.normal(size=(1, 300, 2, 8)), jnp.float32) for _ in range(2))
+    got = jax.jit(lambda *a: blocked_attention(*a, interpret=True))(q, k, v)
+    np.testing.assert_allclose(got, _dense_attention(q, k, v, None), atol=2e-5)
 
 
 def test_blocked_attention_refuses_heads_that_do_not_group():

@@ -82,6 +82,55 @@ def test_blocked_attention_at_the_published_widths_never_holds_whole_scores(one_
     assert memory.temp_size_in_bytes < 2**30 and "tpu_custom_call" in compiled.as_text()
 
 
+def test_blocked_attention_at_the_latent_widths_compiles_with_a_group_of_one(one_chip):
+    """One dispatch of the latent-attention cell: 1 row of 16,384 tokens, 32
+    heads each with its own key of 192 (128 + the 64 rotary columns every
+    head shares) and value of 128. The 192-lane key tile compiles as written
+    (a ``tpu_custom_call``) and the kernel needs only the transposed copies
+    of its operands (PERF.md, PR 31)."""
+    import jax
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.ops.attention import blocked_attention
+
+    qk = jax.ShapeDtypeStruct((1, 16384, 32, 192), jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, 16384, 32, 128), jnp.bfloat16, sharding=one_chip)
+    compiled = _compile_off(lambda q, k, v: blocked_attention(q, k, v), qk, qk, v)
+    memory = compiled.memory_analysis()
+    assert memory.output_size_in_bytes == 16384 * 32 * 128 * 2
+    assert memory.temp_size_in_bytes < 2**30 and "tpu_custom_call" in compiled.as_text()
+
+
+def test_the_latent_decoder_at_the_published_widths_fits_beside_its_weights(one_chip):
+    """The whole program of ``joyai-llm-flash.score-16k``, one dispatch of
+    16,384 tokens: 10.35 GiB of weights leave 5.3 GiB of a v5e's 15.75 for
+    temporaries, and ``memory_stats`` on the chip does not count them, so
+    the compiler is the one that can say (2.47 GiB: PERF.md, PR 31). The
+    attention kernel and the three grouped expert products are one HLO name
+    a stack each, which the two roofline metrics read by."""
+    import json
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.models.mla_moe import init_mla_moe, mla_moe_apply
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs", "joyai-llm-flash.json")) as f:
+        config = json.load(f)["params"]
+    tree = jax.eval_shape(lambda k: init_mla_moe(k, config), jax.random.PRNGKey(0))
+    tree = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+    tokens = jax.ShapeDtypeStruct((1, 16384), jnp.int32, sharding=one_chip)
+    compiled = _compile_off(lambda p, x: mla_moe_apply(p, x, config), tree, tokens)
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes >= 11_116_285_952
+    assert memory.temp_size_in_bytes < 3.5 * 2**30
+    text = compiled.as_text()
+    assert len(set(re.findall(r"%(attn_full[\w.\-]*) =", text))) == 2  # the dense stack's and the expert stack's
+    assert len(set(re.findall(r"%(ragged-dot-none[\w.\-]*) =", text))) == 3
+
+
 def test_topk_experts_at_the_published_widths_use_the_grouped_product(one_chip):
     """32,768 tokens, 128 experts of width 1,024, top-8: XLA:TPU lowers
     ``lax.ragged_dot`` to its own grouped kernel (a ``tpu_custom_call``),
