@@ -27,7 +27,7 @@ per-partition embarrassing parallelism (``CNTKModelUtils.applyModel``,
 
 from __future__ import annotations
 
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Mapping, Optional, Tuple
 
 import numpy as np
 
@@ -59,6 +59,35 @@ def _stack_batch(col: np.ndarray, pad_to: int, dtype: Any) -> np.ndarray:
     batch[:rows] = col
     batch[rows:] = 0
     return batch
+
+
+def _build_device_batch():
+    """What :func:`_device_batch` caches: one jitted function for every
+    offset (``lo`` is an argument), retraced only for another column shape,
+    row count, batch shape or dtype."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    @functools.partial(jax.jit, static_argnums=(2, 3, 4))
+    def device_batch(col, lo, rows, batch_shape, dtype):
+        batch = lax.dynamic_slice_in_dim(col, lo, rows).astype(dtype)
+        if rows < batch_shape[0]:  # a short last batch: zero rows behind it
+            batch = jnp.pad(batch, [(0, batch_shape[0] - rows)] + [(0, 0)] * (batch.ndim - 1))
+        return batch.reshape(batch_shape)
+
+    return device_batch
+
+
+def _device_batch(col: Any, lo: int, hi: int, pad_to: int, row_shape: Tuple[int, ...], dtype: np.dtype):
+    """:func:`_stack_batch` for a column that lives on the device: rows
+    ``lo:hi`` of the ``jax.Array`` as the ``[pad_to, *row_shape]`` batch of
+    ``dtype``, sliced, cast, zero-padded and reshaped there by one small
+    program; nothing crosses the host boundary but ``lo``."""
+    program = cached_program(("dnn.device_batch",), _build_device_batch)
+    return program(col, np.int32(lo), hi - lo, (pad_to,) + tuple(row_shape), dtype)
 
 
 def _place_on_device(params):
@@ -224,12 +253,29 @@ class DNNModel(Model):
         call, ``dnn.place_params``, then per batch ``dnn.stack`` (the
         column's slice as one padded host batch; its ``bytes`` is what it
         copied, 0 where the slice is the batch), ``dnn.dispatch`` (input
-        transfer and enqueue, ``bytes`` what the program is fed; where
+        transfer and enqueue, ``bytes`` what crosses from the host: the
+        batches the program is fed; where
         the call had to build its program (``dnn.transform``'s
         ``programs_built`` 1, else 0: ``_jitted``) or meets a new batch
         shape, its first batch also holds the trace and lowering) and
         ``dnn.fetch`` (it owns the wait on the forward), and ``dnn.assemble``
         for the output columns. Byte tags come from shapes."""
+        return self._transform(table, {}, {})
+
+    def _transform(
+        self, table: Table, fed: Mapping[str, Any], row_shapes: Mapping[str, Tuple[int, ...]]
+    ) -> Table:
+        """:meth:`transform` with the columns of ``fed`` ({column name: its
+        ``table.num_rows`` rows}) standing where the table's would, so that
+        an earlier device stage can hand its result over without a
+        ``Table`` in between (``ImageFeaturizer``). The loop looks at what
+        it is handed: a numpy column is batched on the host as ever; a
+        ``jax.Array`` is batched where it lives (``_device_batch``), each row
+        reshaped to ``row_shapes[name]`` where that is given (a stage program
+        returns ``(rows, H*W*C)``). Such a batch records ``dnn.stack``
+        ``bytes`` 0 and adds nothing to ``dnn.dispatch``'s, and
+        ``dnn.transform``'s ``device_batches`` counts the batches whose every
+        fed column was sliced on the device."""
         import jax
 
         tracer = get_tracer()
@@ -266,18 +312,26 @@ class DNNModel(Model):
                 else [(0, n)]
             )
             whole.tags["batches"] = len(bounds)
+            whole.tags["device_batches"] = 0
             for lo, hi in bounds:
                 pad_to = batch_size if self.getMiniBatcher() else n
                 with tracer.span("dnn.stack", pad_rows=pad_to - (hi - lo)) as sp:
-                    inputs, copied = {}, 0
+                    inputs, copied, crossing, sliced = {}, 0, 0, 0
                     for model_in, col in feeds.items():
-                        rows = table.column(col)[lo:hi]
+                        column = fed[col] if col in fed else table.column(col)
+                        if isinstance(column, jax.Array):
+                            row_shape = row_shapes.get(col, column.shape[1:])
+                            inputs[model_in] = _device_batch(column, lo, hi, pad_to, row_shape, dtype)
+                            sliced += 1
+                            continue
+                        rows = column[lo:hi]
                         batch = inputs[model_in] = _stack_batch(rows, pad_to, dtype)
                         if batch is not rows:
                             copied += batch.nbytes
+                        crossing += batch.nbytes
                     sp.tags["bytes"] = copied
-                    fed = sum(a.nbytes for a in inputs.values())
-                with tracer.span("dnn.dispatch", bytes=fed):
+                    whole.tags["device_batches"] += sliced == len(feeds)
+                with tracer.span("dnn.dispatch", bytes=crossing):
                     outputs = fn(params, inputs)
                 with tracer.span("dnn.fetch") as sp:
                     if not isinstance(outputs, dict):

@@ -3,10 +3,18 @@
 Re-design of ``image/ImageFeaturizer.scala:40-86``: the reference wraps a
 downloaded CNTK model, cuts ``cutOutputLayers`` layers off the top, and
 prepends resize/unroll. Here the backbone is a native JAX network (default:
-the :mod:`mmlspark_tpu.models.resnet` zoo) and the whole chain — resize →
-normalize → NCHW layout → backbone forward with ``cut`` — jits into one XLA
-program executed in fixed-shape device batches by :class:`DNNModel`, built
-once a process for each (backbone, ``cutOutputLayers``, ``scale``).
+the :mod:`mmlspark_tpu.models.resnet` zoo) and the chain is two XLA
+programs with a device-resident table between them. The first is
+:class:`ImageTransformer`'s stage program (``autoResize``): one execution a
+shape group, uint8 rows up, ``(rows, H*W*C)`` float32 left on the device
+(``ImageTransformer._device_groups``; nothing is fetched). The second is
+normalize → NCHW layout → backbone forward with ``cut``, built once a process
+for each (backbone, ``cutOutputLayers``, ``scale``) and executed in
+fixed-shape batches by :class:`DNNModel`, whose batch loop is handed the
+first program's ``jax.Array`` as its fed column (``DNNModel._transform``) and
+slices each batch out of it on the device. The resized table is never a host
+column: what comes down is the feature rows. With ``autoResize=False`` the
+image column itself is fed, from the host, as any ``DNNModel`` column is.
 """
 
 from __future__ import annotations
@@ -89,36 +97,49 @@ class ImageFeaturizer(Model):
             params = self.getModelParams()
             if params is None:
                 raise ValueError("modelParams must be set (see mmlspark_tpu.models)")
-            work = table
-            image_col = self.getInputCol()
-            if self.getAutoResize():
-                resized_col = "__resized__"
-                work = ImageTransformer(
-                    inputCol=image_col,
-                    outputCol=resized_col,
-                    toFloat=True,
-                    stages=[
-                        {
-                            "op": "ResizeImage",
-                            "height": self.getInputHeight(),
-                            "width": self.getInputWidth(),
-                        }
-                    ],
-                ).transform(work)
-                image_col = resized_col
-
             apply_fn = _apply_fn(
                 self._backbone(), self.getCutOutputLayers(), float(self.getScale())
             )
+            in_col, out_col = self.getInputCol(), self.getOutputCol()
 
-            dnn = DNNModel(
-                applyFn=apply_fn,
-                modelParams=params,
-                feedDict={"input": image_col},
-                fetchDict={self.getOutputCol(): "output"},
-                batchSize=self.getBatchSize(),
-            )
-            out = dnn.transform(work)
-            if image_col != self.getInputCol():
-                out = out.drop(image_col)
-            return out
+            def forward(fed_col: str) -> DNNModel:
+                return DNNModel(
+                    applyFn=apply_fn,
+                    modelParams=params,
+                    feedDict={"input": fed_col},
+                    fetchDict={out_col: "output"},
+                    batchSize=self.getBatchSize(),
+                )
+
+            if not self.getAutoResize():
+                return forward(in_col).transform(table)
+            groups = ImageTransformer(
+                inputCol=in_col,
+                toFloat=True,
+                stages=[
+                    {
+                        "op": "ResizeImage",
+                        "height": self.getInputHeight(),
+                        "width": self.getInputWidth(),
+                    }
+                ],
+            )._device_groups(table)
+            if not groups:
+                raise ValueError("need at least one image to featurize")
+            resized_col = "__resized__"  # the name the forward is fed under; never a column
+            dnn = forward(resized_col)
+            if len(groups) == 1:  # every row, in order: the cell, any uniform column
+                _, shape, resized = groups[0]
+                return dnn._transform(table, {resized_col: resized}, {resized_col: shape[1:]})
+            # several input shapes: each group's rows go through on their
+            # own, and what is put back in input order is the feature rows
+            features = None
+            for idxs, shape, resized in groups:
+                rows = dnn._transform(
+                    table.select(in_col).take(idxs),
+                    {resized_col: resized}, {resized_col: shape[1:]},
+                )[out_col]
+                if features is None:
+                    features = np.empty((table.num_rows,) + rows.shape[1:], dtype=rows.dtype)
+                features[idxs] = rows
+            return table.with_column(out_col, features)

@@ -16,7 +16,7 @@ from __future__ import annotations
 
 import copy
 import functools
-from typing import Any, Callable, Dict, List, Tuple
+from typing import Any, Callable, Dict, Iterator, List, Tuple
 
 import numpy as np
 
@@ -257,6 +257,57 @@ class ImageTransformer(HasInputCol, HasOutputCol, Transformer):
             ("image.pipeline", frozen(stage_list)), lambda: _build_pipeline(stage_list)
         )
 
+    def _staged(self, table: Table, whole: Any, fetch: bool) -> Iterator[Tuple[List[int], Tuple[int, ...], Any]]:
+        """The stage up to and including its program, one shape group at a
+        time, under the caller's ``image.transform`` span ``whole``: ->
+        (the group's row indices, the shape its result has as a column,
+        ``(rows, H, W, C)`` or ``(rows, H, W)`` for gray rows, and the
+        result itself as the program returned it, ``(rows, H*W*C)`` float32).
+        With ``fetch`` the result is brought to the host inside
+        ``image.apply_fetch``, which then owns the wait on the device;
+        without, it stays the ``jax.Array`` the program returned, the span
+        holds the upload's and the program's enqueue and ``bytes_down`` is 0:
+        whoever reads the array first waits (``ImageFeaturizer`` hands it to
+        ``DNNModel``'s batch loop, so the first ``dnn.fetch`` does)."""
+        import jax
+
+        tracer = get_tracer()
+        col = table.column(self.getInputCol())
+        built_before = programs_built()
+        stages, run, shapes = self._pipeline()
+        whole.tags["programs_built"] = programs_built() - built_before
+        images = [np.asarray(im) for im in col]
+        # Group equal-shape images into device batches: one compile per
+        # distinct input shape, one program execution per group.
+        by_shape: Dict[Tuple[int, ...], List[int]] = {}
+        for i, im in enumerate(images):
+            by_shape.setdefault(im.shape, []).append(i)
+        whole.tags["groups"] = len(by_shape)
+        for shape, idxs in by_shape.items():
+            with tracer.span("image.stack") as sp:
+                batch = _ensure_nhwc(np.stack([images[i] for i in idxs]))
+                sp.tags["bytes"] = batch.nbytes
+            with tracer.span("image.apply_fetch", bytes_up=batch.nbytes, bytes_down=0) as sp:
+                out_shape = shapes.get(batch.shape)
+                if out_shape is None:
+                    out_shape = shapes[batch.shape] = jax.eval_shape(stages, batch).shape
+                flat = run(batch.reshape(len(idxs), -1), batch.shape)
+                if fetch:
+                    flat = np.asarray(jax.device_get(flat))
+                    sp.tags["bytes_down"] = flat.nbytes
+            if out_shape[-1] == 1 and len(shape) == 2:
+                out_shape = out_shape[:-1]  # gray rows came without a channel axis
+            yield idxs, out_shape, flat
+
+    def _device_groups(self, table: Table) -> List[Tuple[List[int], Tuple[int, ...], Any]]:
+        """``transform`` without its second half, for a device stage that
+        comes next: every shape group's (row indices, result shape, result)
+        of :meth:`_staged` with the result left on the device. Float32
+        whatever ``toFloat`` says: the uint8 path's clip and round are host
+        work of ``transform``."""
+        with get_tracer().span("image.transform", rows=table.num_rows) as whole:
+            return list(self._staged(table, whole, fetch=False))
+
     def transform(self, table: Table) -> Table:
         """Spans (``observability/tracing``): ``image.transform`` around the
         whole stage (``programs_built``: 1 where this call had to build the
@@ -268,34 +319,11 @@ class ImageTransformer(HasInputCol, HasOutputCol, Transformer):
         ``image.assemble`` around the output column (``_image_column``).
         An ``image.assemble``'s ``bytes`` is what it copied. Byte tags come
         from shapes."""
-        import jax
-
         tracer = get_tracer()
         with tracer.span("image.transform", rows=table.num_rows) as whole:
-            col = table.column(self.getInputCol())
-            built_before = programs_built()
-            stages, run, shapes = self._pipeline()
-            whole.tags["programs_built"] = programs_built() - built_before
-            images = [np.asarray(im) for im in col]
-            # Group equal-shape images into device batches: one compile per
-            # distinct input shape, one program execution per group.
-            by_shape: Dict[Tuple[int, ...], List[int]] = {}
-            for i, im in enumerate(images):
-                by_shape.setdefault(im.shape, []).append(i)
-            whole.tags["groups"] = len(by_shape)
             groups: List[Tuple[List[int], np.ndarray]] = []
             to_float = self.getToFloat()
-            for shape, idxs in by_shape.items():
-                with tracer.span("image.stack") as sp:
-                    batch = _ensure_nhwc(np.stack([images[i] for i in idxs]))
-                    sp.tags["bytes"] = batch.nbytes
-                with tracer.span("image.apply_fetch", bytes_up=batch.nbytes) as sp:
-                    out_shape = shapes.get(batch.shape)
-                    if out_shape is None:
-                        out_shape = shapes[batch.shape] = jax.eval_shape(stages, batch).shape
-                    flat = np.asarray(jax.device_get(
-                        run(batch.reshape(len(idxs), -1), batch.shape)))
-                    sp.tags["bytes_down"] = flat.nbytes
+            for idxs, out_shape, flat in self._staged(table, whole, fetch=True):
                 with tracer.span("image.assemble") as sp:
                     # the fetch is in row order unless the device kept this
                     # 2-D shape column-major (it does where that pads less);
@@ -305,12 +333,10 @@ class ImageTransformer(HasInputCol, HasOutputCol, Transformer):
                     if not to_float:
                         result = np.clip(np.rint(result), 0, 255).astype(np.uint8)
                         copied += result.nbytes
-                    if result.shape[-1] == 1 and len(shape) == 2:
-                        result = result[..., 0]
                     sp.tags["bytes"] = copied
                 groups.append((idxs, result))
             with tracer.span("image.assemble") as sp:
-                column, sp.tags["bytes"] = _image_column(groups, len(images))
+                column, sp.tags["bytes"] = _image_column(groups, table.num_rows)
                 return table.with_column(self.getOutputCol(), column)
 
 

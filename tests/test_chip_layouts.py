@@ -46,6 +46,31 @@ def test_resize_program_crosses_the_host_boundary_in_row_order(one_chip, side_in
     assert (memory.temp_size_in_bytes > 0) == temporaries
 
 
+@pytest.mark.parametrize("rows", [512, 300], ids=["full_batch", "short_last_batch"])
+def test_device_batch_slices_the_resident_table_into_the_forwards_layout(one_chip, rows):
+    """``ImageFeaturizer`` leaves the resized table on the device as the stage
+    program returned it, ``(6144, 150528)`` float32 row-major, and
+    ``DNNModel``'s batch loop cuts 512 rows out of it there. The slice
+    program's result is the 4-D batch in the layout the TPU gives a host
+    batch it is handed (batch minor-most), its temporaries are two copies of
+    one batch, and the table itself is not copied (PERF.md, PR 32)."""
+    import jax
+
+    from mmlspark_tpu.dnn.model import _build_device_batch
+
+    table = jax.ShapeDtypeStruct((6144, 224 * 224 * 3), np.float32, sharding=one_chip)
+    lo = jax.ShapeDtypeStruct((), np.int32, sharding=one_chip)
+    compiled = _build_device_batch().lower(
+        table, lo, rows, (512, 224, 224, 3), np.dtype("float32")).compile()
+    (taken, _), _ = compiled.input_formats
+    assert taken.layout.major_to_minor == (0, 1)
+    assert compiled.output_formats.layout.major_to_minor == (1, 3, 2, 0)
+    memory = compiled.memory_analysis()
+    batch = 512 * 224 * 224 * 3 * 4
+    assert memory.output_size_in_bytes == batch
+    assert memory.temp_size_in_bytes < 3 * batch
+
+
 def _compile_off(fn, *shapes):
     """Compile with the persistent cache off: an entry written for a
     described device cannot be read back without one."""

@@ -568,3 +568,109 @@ def test_frozen_is_a_key_by_content(value, same, other):
 
     assert frozen(value) == frozen(same) and hash(frozen(value)) == hash(frozen(same))
     assert frozen(value) != frozen(other)
+
+
+# -- a fed column that lives on the device (DNNModel._transform) ---------------
+
+def _spans_of(job):
+    from mmlspark_tpu.observability.tracing import get_tracer
+
+    tracer = get_tracer()
+    tracer.clear()
+    out = job()
+    return out, tracer.export()
+
+
+def _pair_model(**params):
+    """Two fed columns: rows of 6 floats and rows handed over flat."""
+    fn = lambda p, i: {"output": i["a"].sum(axis=(1, 2)) * p["scale"] + i["b"][:, 0]}
+    return DNNModel(
+        applyFn=fn, modelParams={"scale": np.float32(0.5)},
+        feedDict={"a": "a", "b": "b"}, fetchDict={"y": "output"}, batchSize=4, **params,
+    )
+
+
+_DEVICE_CASES = {
+    # name: (rows, miniBatcher, inputDtype, dtype of the column on the device, which columns are fed from it)
+    "full_batches": (12, True, "float32", np.float32, ("a", "b")),
+    "short_last_batch": (10, True, "float32", np.float32, ("a", "b")),
+    "fewer_rows_than_a_batch": (3, True, "float32", np.float32, ("a", "b")),
+    "one_batch_of_every_row": (10, False, "float32", np.float32, ("a", "b")),
+    "cast_on_the_device": (10, True, "float32", np.uint8, ("a", "b")),
+    "cast_to_a_narrower_dtype": (10, True, "bfloat16", np.float32, ("a", "b")),
+    "one_column_of_two_on_the_device": (10, True, "float32", np.float32, ("a",)),
+    "every_column_on_the_host": (10, True, "float32", np.float32, ()),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_DEVICE_CASES))
+def test_a_device_column_is_batched_where_it_lives_and_gives_the_host_columns_bits(case):
+    import jax
+    import jax.numpy as jnp
+
+    rows, mini, input_dtype, col_dtype, on_device = _DEVICE_CASES[case]
+    rng = np.random.default_rng(5)
+    host = {"a": rng.integers(0, 200, size=(rows, 2, 3)).astype(col_dtype),
+            "b": rng.integers(0, 200, size=(rows, 1)).astype(col_dtype)}
+    table = Table(host)
+    model = _pair_model(miniBatcher=mini, inputDtype=input_dtype)
+    want, host_spans = _spans_of(lambda: model.transform(table))
+    # "a" is handed over as a stage program would leave it: (rows, 2 * 3)
+    fed = {name: jnp.asarray(host[name].reshape(rows, -1)) for name in on_device}
+    got, spans = _spans_of(lambda: model._transform(table, fed, {"a": (2, 3)}))
+    assert got.columns == want.columns
+    assert got["y"].dtype == want["y"].dtype
+    np.testing.assert_array_equal(got["y"], want["y"])
+
+    def tags(recorded, name):
+        return [s["tags"] for s in recorded if s["name"] == name]
+
+    batches = -(-rows // 4) if mini else 1
+    all_on_device = len(on_device) == 2
+    (whole,), (host_whole,) = tags(spans, "dnn.transform"), tags(host_spans, "dnn.transform")
+    assert whole["batches"] == host_whole["batches"] == batches
+    assert whole["device_batches"] == (batches if all_on_device else 0)
+    assert host_whole["device_batches"] == 0
+    itemsize = np.dtype(input_dtype).itemsize
+    pad_to = 4 if mini else rows
+    crossing = sum(pad_to * width * itemsize for name, width in (("a", 6), ("b", 1))
+                   if name not in on_device)
+    assert [t["bytes"] for t in tags(spans, "dnn.dispatch")] == [crossing] * batches
+    assert [t["pad_rows"] for t in tags(spans, "dnn.stack")] == [
+        t["pad_rows"] for t in tags(host_spans, "dnn.stack")]
+    if all_on_device:
+        assert [t["bytes"] for t in tags(spans, "dnn.stack")] == [0] * batches
+    if not on_device:  # nothing fed from the device: today's tags, every one
+        for name in ("dnn.stack", "dnn.dispatch", "dnn.fetch", "dnn.assemble", "dnn.place_params"):
+            assert tags(spans, name) == tags(host_spans, name)
+    assert tags(spans, "dnn.fetch") == tags(host_spans, "dnn.fetch")
+    assert isinstance(got["y"], np.ndarray) and not isinstance(got["y"], jax.Array)
+
+
+@pytest.mark.parametrize("rows,programs", [(48, 1), (46, 2)], ids=["twelve_full", "a_short_twelfth"])
+def test_twelve_batches_of_a_device_column_build_one_slice_program(monkeypatch, rows, programs):
+    """The offset is an argument of the slice program, so every full batch
+    runs the one trace; a short last batch is one more (its row count)."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    import mmlspark_tpu
+
+    mmlspark_tpu.clear_compiled_caches()
+    traced = []
+    real = lax.dynamic_slice_in_dim
+    monkeypatch.setattr(lax, "dynamic_slice_in_dim",
+                        lambda *a, **kw: traced.append(1) or real(*a, **kw))
+    x = np.arange(rows * 3, dtype=np.float32).reshape(rows, 3)
+    model = DNNModel(applyFn=lambda p, i: i["x"] * 2.0, modelParams={},
+                     feedDict={"x": "x"}, fetchDict={"y": "output"}, batchSize=4)
+    out, spans = _spans_of(lambda: model._transform(Table({"x": x}), {"x": jnp.asarray(x)}, {}))
+    np.testing.assert_array_equal(out["y"], x * 2.0)
+    (whole,) = [s["tags"] for s in spans if s["name"] == "dnn.transform"]
+    assert whole["batches"] == whole["device_batches"] == 12
+    assert len(traced) == programs
+    # the call's own program is what programs_built counts, as before
+    assert whole["programs_built"] == 1
+    again, _ = _spans_of(lambda: model._transform(Table({"x": x}), {"x": jnp.asarray(x)}, {}))
+    assert len(traced) == programs
+    np.testing.assert_array_equal(again["y"], out["y"])

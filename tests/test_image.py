@@ -429,3 +429,131 @@ def test_an_unknown_op_raises_on_every_call():
     for _ in range(2):
         with pytest.raises(ValueError, match="unknown image op 'Sharpen'"):
             bad.transform(table)
+
+
+# -- the resized table stays on the device (ImageFeaturizer) -------------------
+
+def _every_pixel_backbone(p, x, cut):
+    """Features that read every pixel of an NCHW batch of any channel count."""
+    return x.mean(axis=1).reshape(x.shape[0], -1) @ p["w"] + cut
+
+
+# name: (row shapes in row order, batchSize, autoResize); the model input is 6 x 5
+_HANDOVER_CASES = {
+    "one_group_at_the_target_size": ([(6, 5, 3)] * 8, 4, True),
+    "one_group_that_needs_the_resize": ([(9, 7, 3)] * 8, 4, True),
+    "several_groups_interleaved": ([(9, 7, 3), (6, 5, 3), (12, 10, 3), (9, 7, 3), (6, 5, 3),
+                                    (9, 7, 3), (12, 10, 3)], 2, True),
+    "gray_images": ([(9, 7, 1)] * 5, 4, True),
+    "gray_images_of_two_sizes": ([(9, 7, 1), (6, 5, 1), (9, 7, 1)], 4, True),
+    "a_short_last_batch": ([(9, 7, 3)] * 10, 4, True),
+    "fewer_rows_than_a_batch": ([(6, 5, 3)] * 3, 8, True),
+    "no_auto_resize": ([(6, 5, 3)] * 7, 4, False),
+}
+
+
+def _handover_table(shapes):
+    rng = np.random.default_rng(13)
+    column = np.empty(len(shapes), dtype=object)
+    for i, shape in enumerate(shapes):
+        column[i] = rng.integers(0, 256, size=shape, dtype=np.uint8)
+    return Table({"id": np.arange(len(shapes)), "image": column})
+
+
+def _handover_featurizer(batch, resize):
+    w = np.random.default_rng(3).standard_normal((6 * 5, 4)).astype(np.float32)
+    return ImageFeaturizer(applyFn=_every_pixel_backbone, modelParams={"w": w}, inputHeight=6,
+                           inputWidth=5, batchSize=batch, autoResize=resize)
+
+
+@pytest.mark.parametrize("case", sorted(_HANDOVER_CASES))
+def test_featurizer_equals_the_public_stages_composed_over_a_host_table(case, monkeypatch):
+    """``ImageFeaturizer`` hands the stage program's result to the batch loop
+    on the device; the features are those of ``ImageTransformer.transform``
+    followed by ``DNNModel.transform`` over the host column, bit for bit, and
+    nothing the size of a resized image comes down in between."""
+    import jax
+
+    from mmlspark_tpu.dnn import DNNModel
+    from mmlspark_tpu.image.featurizer import _apply_fn
+    from mmlspark_tpu.observability.tracing import get_tracer
+
+    shapes, batch, resize = _HANDOVER_CASES[case]
+    table = _handover_table(shapes)
+    featurizer = _handover_featurizer(batch, resize)
+
+    staged = table
+    if resize:
+        staged = ImageTransformer(inputCol="image", outputCol="resized", toFloat=True).resize(
+            6, 5).transform(table)
+        assert isinstance(staged["resized"], np.ndarray) and staged["resized"].dtype == np.float32
+        assert staged["resized"].shape == (len(shapes), 6, 5, shapes[0][2])
+    want = DNNModel(
+        applyFn=_apply_fn(_every_pixel_backbone, 1, 1.0 / 255.0), modelParams=featurizer.getModelParams(),
+        feedDict={"input": "resized" if resize else "image"}, fetchDict={"features": "output"},
+        batchSize=batch,
+    ).transform(staged)["features"]
+
+    fetched = []
+    real_get = jax.device_get
+    monkeypatch.setattr(jax, "device_get", lambda x: fetched.append(np.shape(x)) or real_get(x))
+    tracer = get_tracer()
+    tracer.clear()
+    out = featurizer.transform(table)
+    spans = tracer.export()
+    got = out["features"]
+    assert out.columns == ["id", "image", "features"]  # __resized__ was never a column
+    assert isinstance(got, np.ndarray) and got.dtype == want.dtype and got.shape == (len(shapes), 4)
+    np.testing.assert_array_equal(got, want)
+    # only feature batches come down: the resized table is never fetched
+    assert fetched and all(shape == (batch, 4) for shape in fetched)
+
+    def tags(name):
+        return [s["tags"] for s in spans if s["name"] == name]
+
+    groups = len(set(shapes)) if resize else 0
+    assert [t["bytes_down"] for t in tags("image.apply_fetch")] == [0] * groups
+    assert not tags("image.assemble")
+    forwards = tags("dnn.transform")
+    assert len(forwards) == max(groups, 1)
+    assert sum(t["rows"] for t in forwards) == len(shapes)
+    for t in forwards:
+        assert t["device_batches"] == (t["batches"] if resize else 0)
+    assert all(t["bytes"] == 0 for t in tags("dnn.stack") + tags("dnn.dispatch")) == resize
+
+
+def test_a_table_without_rows_raises_as_the_forward_always_did():
+    with pytest.raises(ValueError, match="need at least one"):
+        _handover_featurizer(4, True).transform(_handover_table([]))
+    with pytest.raises(ValueError, match="need at least one"):
+        _handover_featurizer(4, False).transform(_handover_table([]))
+
+
+def test_the_stage_alone_still_returns_its_host_column():
+    from mmlspark_tpu.observability.tracing import get_tracer
+
+    table = _handover_table([(9, 7, 3)] * 4)
+    tracer = get_tracer()
+    tracer.clear()
+    out = ImageTransformer(inputCol="image", outputCol="out", toFloat=True).resize(6, 5).transform(table)
+    (span,) = [s["tags"] for s in tracer.export() if s["name"] == "image.apply_fetch"]
+    column = out["out"]
+    assert isinstance(column, np.ndarray) and column.dtype == np.float32
+    assert column.shape == (4, 6, 5, 3) and column.flags.c_contiguous
+    assert span == {"bytes_up": 4 * 9 * 7 * 3, "bytes_down": column.nbytes}
+
+
+def test_device_groups_are_the_stage_programs_own_arrays():
+    import jax
+
+    table = _handover_table([(9, 7, 3), (6, 5), (9, 7, 3), (6, 5)])
+    stage = ImageTransformer(inputCol="image", outputCol="out", toFloat=True).resize(6, 5)
+    host = stage.transform(table.take([0, 2]))["out"], stage.transform(table.take([1, 3]))["out"]
+    groups = stage._device_groups(table)
+    assert [idxs for idxs, _, _ in groups] == [[0, 2], [1, 3]]
+    # gray rows that came without a channel axis keep their squeeze
+    assert [shape for _, shape, _ in groups] == [(2, 6, 5, 3), (2, 6, 5)]
+    for (_, shape, flat), want in zip(groups, host):
+        assert isinstance(flat, jax.Array) and flat.dtype == np.float32
+        assert flat.shape == (2, int(np.prod(shape[1:])))
+        np.testing.assert_array_equal(np.asarray(flat).reshape(shape), want)

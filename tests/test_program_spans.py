@@ -138,61 +138,73 @@ def _image_table(images=10, side=16, seed=0):
     return Table({"image": column})
 
 
-def _featurizer(batch):
+def _featurizer(batch, **params_):
     from mmlspark_tpu.image import ImageFeaturizer
     from mmlspark_tpu.models import init_resnet
 
     params = init_resnet(seed=0, variant="resnet18", small_inputs=True)
-    return ImageFeaturizer(modelParams=params, inputHeight=16, inputWidth=16, batchSize=batch), params
+    return ImageFeaturizer(modelParams=params, inputHeight=16, inputWidth=16, batchSize=batch,
+                           **params_), params
 
 
+@pytest.mark.parametrize("resize", [True, False], ids=["resized_on_the_device", "fed_as_it_is"])
 @pytest.mark.parametrize("images,batch", [(10, 4), (8, 4), (3, 8)])
-def test_featurize_records_three_spans_a_batch(images, batch):
-    featurizer, params = _featurizer(batch)
+def test_featurize_records_three_spans_a_batch(images, batch, resize):
+    featurizer, params = _featurizer(batch, autoResize=resize)
     out, spans = _recorded(lambda: featurizer.transform(_image_table(images)))
     assert np.asarray(out["features"]).shape == (images, 512)
     batches = -(-images // batch)
     names = [s["name"] for s in spans]
     for per_batch in ("dnn.stack", "dnn.dispatch", "dnn.fetch"):
         assert names.count(per_batch) == batches
-    assert len(spans) == 9 + 3 * batches  # one shape group
+    # one shape group; the resized table is never assembled on the host
+    assert len(spans) == (7 if resize else 4) + 3 * batches
     root, = [s for s in spans if s["name"] == "image.featurize"]
     assert {s["trace_id"] for s in spans} == {root["trace_id"]}
     by_id = {s["span_id"]: s for s in spans}
     parent_of = {s["name"]: by_id[s["parent_id"]]["name"] for s in spans if s is not root}
+    stage_spans = {
+        "image.transform": "image.featurize", "image.stack": "image.transform",
+        "image.apply_fetch": "image.transform",
+    }
     assert parent_of == {
-        "image.transform": "image.featurize", "dnn.transform": "image.featurize",
-        "image.stack": "image.transform", "image.apply_fetch": "image.transform",
-        "image.assemble": "image.transform", "dnn.place_params": "dnn.transform",
+        "dnn.transform": "image.featurize", "dnn.place_params": "dnn.transform",
         "dnn.stack": "dnn.transform", "dnn.dispatch": "dnn.transform",
         "dnn.fetch": "dnn.transform", "dnn.assemble": "dnn.transform",
+        **(stage_spans if resize else {}),
     }
 
     def tags(name):
         return [s["tags"] for s in spans if s["name"] == name]
 
-    uint8, resized = images * 16 * 16 * 3, images * 16 * 16 * 3 * 4
+    uint8 = images * 16 * 16 * 3
     assert root["tags"] == {"rows": images, "batch_size": batch}
     # programs_built: 1 in the module's first call of a definition, then 0
     # (tests/test_dnn.py and tests/test_image.py hold the counts)
-    (stage,), (forward,) = tags("image.transform"), tags("dnn.transform")
-    assert stage.pop("programs_built") in (0, 1) and forward.pop("programs_built") in (0, 1)
-    assert stage == {"rows": images, "groups": 1}
-    assert tags("image.stack") == [{"bytes": uint8}]
-    assert tags("image.apply_fetch") == [{"bytes_up": uint8, "bytes_down": resized}]
-    # float output, one shape group: no clip/round, and the fetched result
-    # is handed over as the column (no copy)
-    assert tags("image.assemble") == [{"bytes": 0}, {"bytes": 0}]
-    assert forward == {"rows": images, "batches": batches}
+    (forward,) = tags("dnn.transform")
+    assert forward.pop("programs_built") in (0, 1)
+    if resize:
+        (stage,) = tags("image.transform")
+        assert stage.pop("programs_built") in (0, 1)
+        assert stage == {"rows": images, "groups": 1}
+        assert tags("image.stack") == [{"bytes": uint8}]
+        # the stage program's result stays on the device for the batch loop
+        assert tags("image.apply_fetch") == [{"bytes_up": uint8, "bytes_down": 0}]
+    assert forward == {"rows": images, "batches": batches,
+                       "device_batches": batches if resize else 0}
     import jax
 
     assert tags("dnn.place_params") == [{"bytes": sum(a.nbytes for a in jax.tree.leaves(params))}]
     fed = batch * 16 * 16 * 3 * 4  # every batch is padded to batchSize
     pads = [0] * (batches - 1) + [batches * batch - images]
-    # what dnn.stack copied: a full batch is a view of the dense resized
-    # column, the padded last batch is written once
-    assert tags("dnn.stack") == [{"pad_rows": p, "bytes": fed if p else 0} for p in pads]
-    assert tags("dnn.dispatch") == [{"bytes": fed}] * batches
+    if resize:
+        # sliced, padded and handed to the forward where the table lives
+        assert tags("dnn.stack") == [{"pad_rows": p, "bytes": 0} for p in pads]
+        assert tags("dnn.dispatch") == [{"bytes": 0}] * batches
+    else:
+        # an object column of uint8 rows: stacked and cast once a batch
+        assert tags("dnn.stack") == [{"pad_rows": p, "bytes": fed} for p in pads]
+        assert tags("dnn.dispatch") == [{"bytes": fed}] * batches
     assert tags("dnn.fetch") == [{"bytes": batch * 512 * 4}] * batches
     assert tags("dnn.assemble") == [{"bytes": images * 512 * 4}]
 
