@@ -3,12 +3,12 @@
 The text sibling of :class:`mmlspark_tpu.image.ImageFeaturizer`: a language
 model applied to whole sequences by :class:`DNNModel` in fixed-shape device
 batches, features and last-position logits out, for a downstream learner.
-``modelConfig["model_type"]`` names the decoder family (:data:`FAMILIES`:
-``afmoe``, the default where the key is absent, :mod:`mmlspark_tpu.models.afmoe`;
-``joyai_llm_flash``, :mod:`mmlspark_tpu.models.mla_moe`). No generation loop
-and no key/value cache: every call runs whole sequences. The program itself
-is built once a process for each decoder configuration (by content) and
-found again by later calls.
+``modelConfig["model_type"]`` names the decoder family, one of
+:data:`FAMILIES` (``afmoe`` where the key is absent); the stage's own
+documentation lists them from that table. No generation loop and no cache
+of keys, values or recurrent state: every call runs whole sequences. The
+program itself is built once a process for each decoder configuration (by
+content) and found again by later calls.
 """
 
 from __future__ import annotations
@@ -28,20 +28,26 @@ from mmlspark_tpu.observability.tracing import get_tracer
 _LOAD = "expert_load"
 
 # model_type -> (the family's module, its ``*_apply(params, tokens, config)``
-# there). The module's ``span_tags(config)`` says what ``lm.featurize`` tells
-# of a configuration of its family, so the key names stay with the family.
+# and its ``init_*(key, config)`` there). The module's ``span_tags(config)``
+# says what ``lm.featurize`` tells of a configuration of its family, so the
+# key names stay with the family. A new family is one line here: the stage's
+# documentation is made from this table.
 FAMILIES = {
-    "afmoe": ("mmlspark_tpu.models.afmoe", "afmoe_apply"),
-    "joyai_llm_flash": ("mmlspark_tpu.models.mla_moe", "mla_moe_apply"),
+    "afmoe": ("mmlspark_tpu.models.afmoe", "afmoe_apply", "init_afmoe"),
+    "joyai_llm_flash": ("mmlspark_tpu.models.mla_moe", "mla_moe_apply", "init_mla_moe"),
+    "nemotron_h": ("mmlspark_tpu.models.nemotron_h", "nemotron_h_apply", "init_nemotron_h"),
 }
+_DEFAULT = "afmoe"
+_MODULES = ", ".join(f"'{model_type}': {module}" for model_type, (module, _, _) in FAMILIES.items())
+_INITS = ", ".join(f"{module}.{init}" for module, _, init in FAMILIES.values())
 
 
 def _family(config: dict):
     """-> (``model_type``, the family's apply function, its span tags)."""
-    model_type = config.get("model_type", "afmoe")
+    model_type = config.get("model_type", _DEFAULT)
     if model_type not in FAMILIES:
         raise ValueError(f"modelConfig['model_type'] {model_type!r}: one of {sorted(FAMILIES)}")
-    module, name = FAMILIES[model_type]
+    module, name, _ = FAMILIES[model_type]
     module = importlib.import_module(module)
     return model_type, getattr(module, name), module.span_tags(config)
 
@@ -62,7 +68,10 @@ def _apply_fn(config: dict):
 
 
 class LMFeaturizer(Model):
-    """Apply a decoder to a column of int32 token rows of one length."""
+    __doc__ = f"""Apply a decoder to a column of int32 token rows of one length.
+
+    The decoder families, by ``modelConfig["model_type"]`` ('{_DEFAULT}' where
+    the key is absent): {_MODULES}."""
 
     inputCol = Param("Column of token-id rows, all of one length", default="tokens", converter=to_str)
     outputCols = Param(
@@ -72,14 +81,12 @@ class LMFeaturizer(Model):
         default={"hidden": "features"},
     )
     modelParams = Param(
-        "Decoder parameter pytree (the family's init_* format: mmlspark_tpu.models.afmoe.init_afmoe, "
-        "mmlspark_tpu.models.mla_moe.init_mla_moe)",
+        f"Decoder parameter pytree (the family's init_* format: {_INITS})",
         default=None, is_complex=True,
     )
     modelConfig = Param(
         "Decoder configuration: the family's published config.json keys and 'layers'; "
-        "'model_type' names the family ('afmoe' where absent: mmlspark_tpu.models.afmoe; "
-        "'joyai_llm_flash': mmlspark_tpu.models.mla_moe)", default=None)
+        f"'model_type' names the family ('{_DEFAULT}' where absent; {_MODULES})", default=None)
     batchSize = Param("Rows per device batch", default=4, converter=to_int, validator=gt(0))
 
     def transform(self, table: Table) -> Table:
@@ -88,7 +95,7 @@ class LMFeaturizer(Model):
         fetched expert loads per dispatch (``observability/tracing``)."""
         params, config = self.getModelParams(), self.getModelConfig()
         if params is None or config is None:
-            raise ValueError("modelParams and modelConfig must be set (see mmlspark_tpu.models.afmoe, .mla_moe)")
+            raise ValueError(f"modelParams and modelConfig must be set (see {_INITS})")
         outputs = dict(self.getOutputCols())
         unknown = set(outputs) - {"hidden", "logits", _LOAD}
         if unknown or not outputs:

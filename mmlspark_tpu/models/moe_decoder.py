@@ -1,7 +1,8 @@
 """What the decoder families with routed experts share (:mod:`afmoe`,
-:mod:`mla_moe`): the stated precision of a norm and of a matrix product,
-SwiGLU, the routed-expert block, the head at a row's last position, and
-seeded weights made on the device a layer at a time.
+:mod:`mla_moe`, :mod:`nemotron_h`): the stated precision of a norm and of a
+matrix product, the feed-forward with a gate (SwiGLU) and without one, the
+routed-expert block, the head at a row's last position, and seeded weights
+made on the device a layer at a time.
 
 Precision, for every family here: weights and the residual stream bfloat16;
 every matrix product takes bfloat16 inputs and sums in float32; norms,
@@ -13,7 +14,7 @@ dtype first (``float8_e4m3fn``, say), for measuring what that costs.
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict
+from typing import Any, Callable, Dict
 
 import jax
 import jax.numpy as jnp
@@ -37,26 +38,45 @@ def dot(x, w, dtype):
     return jnp.dot(rounded(x, dtype), rounded(w, dtype), preferred_element_type=jnp.float32)
 
 
+def relu2(x):
+    return jnp.square(jax.nn.relu(x))
+
+
+def feed_forward(x, w, dt, activation: Callable = jax.nn.silu):
+    """What ``w`` holds: ``gate``, ``up`` and ``down`` are
+    ``down(activation(gate x) * up x)``; ``up`` and ``down`` alone, two
+    matrices and no gate, ``down(activation(up x))``."""
+    if "gate" in w:
+        inner = activation(dot(x, w["gate"], dt)) * dot(x, w["up"], dt)
+    else:
+        inner = activation(dot(x, w["up"], dt))
+    return dot(inner, w["down"], dt)
+
+
 def swiglu(x, gate, up, down, dt):
-    inner = jax.nn.silu(dot(x, gate, dt)) * dot(x, up, dt)
-    return dot(inner, down, dt)
+    return feed_forward(x, {"gate": gate, "up": up, "down": down}, dt)
 
 
-def routed_experts(p, x, k: int, scale: float, dt):
+def routed_experts(p, x, k: int, scale: float, dt, activation: Callable = jax.nn.silu):
     """x: (rows, S, hidden). Sigmoid router scores; a token's ``k`` experts
     are the largest of score + ``router_bias``, weighed by the scores alone
     over their sum times ``scale`` (:func:`moe_topk`); one shared expert
-    beside them. -> (routed + shared (rows, S, hidden) float32, the tokens of
-    each row that each expert received (rows, experts))."""
+    beside them, of whatever width its matrices have. The experts' body is
+    what the tree holds: ``e_gate``, ``e_up``, ``e_down`` (and ``s_*``
+    likewise) a gated feed-forward, ``e_up`` and ``e_down`` alone two
+    matrices with ``activation`` between them (:func:`feed_forward`).
+    -> (routed + shared (rows, S, hidden) float32, the tokens of each row
+    that each expert received (rows, experts))."""
     B, S, D = x.shape
     E = p["router"].shape[-1]
     flat = rounded(x.reshape(B * S, D), dt)
     with jax.named_scope("moe_route"):
         scores = jax.nn.sigmoid(dot(flat, p["router"], dt))
     with jax.named_scope("moe_experts"):
-        experts = {n: rounded(p["e_" + n], dt) for n in ("gate", "up", "down")}
-        routed, chosen = moe_topk(flat, scores + p["router_bias"], scores, experts, k, scale)
-        shared = swiglu(flat, p["s_gate"], p["s_up"], p["s_down"], dt)
+        held = [n for n in ("gate", "up", "down") if "e_" + n in p]
+        experts = {n: rounded(p["e_" + n], dt) for n in held}
+        routed, chosen = moe_topk(flat, scores + p["router_bias"], scores, experts, k, scale, activation)
+        shared = feed_forward(flat, {n: p["s_" + n] for n in held}, dt, activation)
     load = (chosen.reshape(B, S * k, 1) == jnp.arange(E, dtype=jnp.int32)).sum(axis=1)
     return (routed + shared).reshape(B, S, D), load.astype(jnp.int32)
 
@@ -72,7 +92,9 @@ def last_position(params, h, eps, dt):
 def _init_layer(key, shapes):
     out = {}
     for k, (name, (shape, fan_in)) in zip(jax.random.split(key, len(shapes)), sorted(shapes.items())):
-        if name == "router_bias":  # a buffer in float32: it is added to float32 scores
+        if callable(fan_in):  # a leaf with a recipe of its own: (key, shape) -> values
+            out[name] = fan_in(k, shape)
+        elif name == "router_bias":  # a buffer in float32: it is added to float32 scores
             out[name] = 0.1 * jax.random.normal(k, shape, jnp.float32)
         elif fan_in is None:  # a norm's scale, drawn away from 1
             out[name] = jax.random.uniform(k, shape, jnp.float32, 0.5, 1.5).astype(jnp.bfloat16)
@@ -81,17 +103,18 @@ def _init_layer(key, shapes):
     return out
 
 
-def init_decoder(key, config: Dict[str, Any], dense, moe):
-    """Seeded weights, made on the device in bfloat16 (nothing passes through
-    the host). ``dense`` and ``moe`` are each (``{name: (shape, fan-in or
-    None for a norm's scale)}`` of one layer, how many layers): the layers
-    of a kind live stacked on a leading axis, and one jitted call a layer
-    draws that layer and writes it into the donated stack, so nothing is
-    ever held twice. Matrices are normal with variance 1 / fan-in, the
-    embedding and the head 1 / hidden, norm scales uniform in [0.5, 1.5),
-    the router's balancing bias normal x 0.1 in float32."""
+def init_stacks(key, config: Dict[str, Any], stacks: Dict[str, Any]):
+    """Seeded weights, made on the device (nothing passes through the host).
+    ``stacks`` names each kind of layer: (``{name: (shape, fan-in, or None
+    for a norm's scale, or a recipe (key, shape) -> values)}`` of one layer,
+    how many layers): the layers of a kind live stacked on a leading axis,
+    and one jitted call a layer draws that layer and writes it into the
+    donated stack, so nothing is ever held twice. Matrices are normal with
+    variance 1 / fan-in in bfloat16, the embedding and the head 1 / hidden,
+    norm scales uniform in [0.5, 1.5), the router's balancing bias normal x
+    0.1 in float32; a recipe's leaf has the recipe's dtype."""
     D, V = config["hidden_size"], config["vocab_size"]
-    k_embed, k_head, k_norm, k_dense, k_moe = jax.random.split(key, 5)
+    k_embed, k_head, k_norm, *k_stacks = jax.random.split(key, 3 + len(stacks))
     matrix = jax.jit(
         lambda k, shape: (jax.random.normal(k, shape, jnp.float32) * D ** -0.5).astype(jnp.bfloat16),
         static_argnums=1)
@@ -99,16 +122,21 @@ def init_decoder(key, config: Dict[str, Any], dense, moe):
         "embed": matrix(k_embed, (V, D)), "head": matrix(k_head, (D, V)),
         "final_norm": jax.random.uniform(k_norm, (D,), jnp.float32, 0.5, 1.5).astype(jnp.bfloat16),
     }
-    for name, k, (shapes, n) in (("dense", k_dense, dense), ("moe", k_moe, moe)):
+    for k, (name, (shapes, n)) in zip(k_stacks, stacks.items()):
 
         @functools.partial(jax.jit, donate_argnums=0)
         def write(stack, i, kk):
             layer = _init_layer(kk, shapes)
             return {m: lax.dynamic_update_index_in_dim(stack[m], layer[m], i, 0) for m in stack}
 
-        stack = {m: jnp.zeros((n,) + shape, jnp.float32 if m == "router_bias" else jnp.bfloat16)
-                 for m, (shape, _) in shapes.items()}
+        one = jax.eval_shape(lambda kk: _init_layer(kk, shapes), k)
+        stack = {m: jnp.zeros((n,) + a.shape, a.dtype) for m, a in one.items()}
         for i, kk in enumerate(jax.random.split(k, n)):
             stack = write(stack, i, kk)
         params[name] = stack
     return params
+
+
+def init_decoder(key, config: Dict[str, Any], dense, moe):
+    """:func:`init_stacks` of a dense stack and an expert stack."""
+    return init_stacks(key, config, {"dense": dense, "moe": moe})

@@ -18,8 +18,9 @@ formulations, both static-shape:
 And one for many experts on ONE device, inside a model:
 
 - :func:`moe_topk` — top-k by sort: the (token, expert) assignments are
-  sorted by expert, each expert's SwiGLU runs over its own contiguous
-  group (``lax.ragged_dot``), and the results go back weighted. Work is
+  sorted by expert, each expert's feed-forward (three matrices with a
+  gate, or two without) runs over its own contiguous group
+  (``lax.ragged_dot``), and the results go back weighted. Work is
   ∝ tokens x k whatever the imbalance; no capacity, so no token is ever
   dropped, and an expert that got no token is an empty group.
 """
@@ -188,16 +189,19 @@ def moe_apply_a2a(
     )(expert_params, x, assign, chosen_p)
 
 
-def moe_topk(x, scores_to_choose, scores_to_weigh, experts, k: int, scale: float = 1.0):
-    """Top-``k`` mixture of SwiGLU experts held on this device.
+def moe_topk(x, scores_to_choose, scores_to_weigh, experts, k: int, scale: float = 1.0,
+             activation: Callable = jax.nn.silu):
+    """Top-``k`` mixture of the experts held on this device.
 
     ``x`` is (T, D). A token's experts are the ``k`` largest of its row of
     ``scores_to_choose`` (T, E) (a router's scores plus a balancing bias,
     say); their weights are its ``scores_to_weigh`` (T, E) at the chosen,
-    divided by their sum, times ``scale``. ``experts`` holds ``gate`` and
-    ``up`` (E, D, F) and ``down`` (E, F, D); an expert computes
-    ``down(silu(gate x) * up x)``, products in ``x``'s dtype summed in
-    float32. -> (y (T, D) float32, chosen (T, k) int32)."""
+    divided by their sum, times ``scale``. An expert is what ``experts``
+    holds: ``gate`` and ``up`` (E, D, F) and ``down`` (E, F, D) compute
+    ``down(activation(gate x) * up x)`` (SwiGLU as it stands); ``up`` and
+    ``down`` alone, two matrices and no gate, ``down(activation(up x))``.
+    Products in ``x``'s dtype summed in float32.
+    -> (y (T, D) float32, chosen (T, k) int32)."""
     T, D = x.shape
     E = scores_to_choose.shape[1]
     _, chosen = lax.top_k(scores_to_choose, k)
@@ -210,7 +214,11 @@ def moe_topk(x, scores_to_choose, scores_to_weigh, experts, k: int, scale: float
     sizes = sizes.astype(jnp.int32)
     xs = x[order // k]
     dot = lambda a, w: lax.ragged_dot(a, w, sizes, preferred_element_type=jnp.float32)
-    inner = (jax.nn.silu(dot(xs, experts["gate"])) * dot(xs, experts["up"])).astype(x.dtype)
+    if "gate" in experts:
+        inner = activation(dot(xs, experts["gate"])) * dot(xs, experts["up"])
+    else:
+        inner = activation(dot(xs, experts["up"]))
+    inner = inner.astype(x.dtype)
     ys = dot(inner, experts["down"])  # (T*k, D) float32, still sorted by expert
     back = jnp.argsort(order)  # where assignment (token, slot) sits in the sorted rows
     y = (ys[back].reshape(T, k, D) * weights[:, :, None].astype(jnp.float32)).sum(axis=1)

@@ -29,6 +29,26 @@ import numpy as np
 import pytest
 
 
+# A test of the benchmark's own that holds only at the PR that wrote it, with why. ``tests/chipbench/`` is one of
+# the benchmark's ``paths``: a PR that is no ``benchmark`` PR may add files there and edit none, and BENCHMARK.json's
+# lists may only be appended to AT THE END (an entry put in the middle reads to the driver as a change to what was
+# there). PR 32's test asserts that ITS metric is the manifest's last ``per_layer`` entry, which the next PR to append
+# one makes false (PR 33 did). Everything else that test asserts is asserted by name in
+# ``tests/chipbench/test_device_batches_by_name.py``. Strict: the day a ``benchmark`` PR makes the test look its entry
+# up by name it passes again, this line fails, and goes with that file (PERF.md 7, row 15).
+_OUTDATED_BY_AN_APPEND = {
+    "tests/chipbench/test_device_batches.py::test_the_metric_file_is_found_for_its_cell_and_the_manifest_repeats_it":
+        "asserts per_layer[-1] is PR 32's entry; PR 33 appended thirteen after it and may not edit a file of the benchmark",
+}
+
+
+def pytest_collection_modifyitems(config, items):
+    for item in items:
+        reason = _OUTDATED_BY_AN_APPEND.get(item.nodeid)
+        if reason:
+            item.add_marker(pytest.mark.xfail(reason=reason, strict=True))
+
+
 def pytest_configure(config):
     """Build the native library once, before any test file runs.
 

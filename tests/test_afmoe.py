@@ -18,6 +18,7 @@ from mmlspark_tpu.featurize.lm import LMFeaturizer
 from mmlspark_tpu.models.afmoe import afmoe_apply, init_afmoe, layer_kinds
 from mmlspark_tpu.observability.tracing import get_tracer
 from mmlspark_tpu.ops.attention import blocked_attention
+from mmlspark_tpu.models.moe_decoder import relu2
 from mmlspark_tpu.ops.expert_parallel import moe_topk
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -185,11 +186,11 @@ def test_blocked_attention_in_bfloat16():
         np.testing.assert_allclose(np.asarray(got, np.float64), _dense_attention(q, k, v, window), atol=0.03)
 
 
-@pytest.mark.parametrize("group", [1, 3, 4, 7])
+@pytest.mark.parametrize("group", [1, 3, 4, 7, 16])
 def test_blocked_attention_runs_every_head_group_at_the_default_block(group):
     """300 positions pad to a key/value block of 384, three query blocks of
-    128, whatever the group: 28 heads over 4 (7) or 40 over 8 (5) are
-    published shapes."""
+    128, whatever the group: 28 heads over 4 (7), 40 over 8 (5) and 32 over
+    2 (16: a step's score product has 2,048 rows) are published shapes."""
     rng = np.random.default_rng(group)
     q = jnp.asarray(rng.normal(size=(1, 300, 2 * group, 8)), jnp.float32)
     k, v = (jnp.asarray(rng.normal(size=(1, 300, 2, 8)), jnp.float32) for _ in range(2))
@@ -221,29 +222,73 @@ def _every_expert_masked(x, choose, weigh, experts, k, scale):
     return y, chosen
 
 
-@pytest.mark.parametrize("k", [1, 2, 3])
-def test_moe_topk_against_every_expert_under_a_mask(k):
+def _per_token_loop(x, choose, weigh, experts, k, scale, activation):
+    """Two-matrix experts, a token and an expert at a time."""
+    x, choose, weigh = (np.asarray(a, np.float64) for a in (x, choose, weigh))
+    up, down = (np.asarray(experts[n], np.float64) for n in ("up", "down"))
+    y = np.zeros_like(x)
+    for t in range(len(x)):
+        chosen = np.argsort(-choose[t], kind="stable")[:k]
+        weights = weigh[t, chosen] / weigh[t, chosen].sum() * scale
+        for e, w in zip(chosen, weights):
+            y[t] += w * (activation(x[t] @ up[e]) @ down[e])
+    return y
+
+
+@pytest.mark.parametrize("k,gated", [(1, True), (2, True), (3, True), (1, False), (2, False), (3, False)])
+def test_moe_topk_against_every_expert_under_a_mask(k, gated):
     """Expert 5 gets no token, expert 2 gets half of them (every even
-    token's first choice), and nothing is dropped."""
+    token's first choice), and nothing is dropped. ``gated``: the three
+    SwiGLU matrices; not: two matrices with relu(x)^2 between them, against a
+    loop a token and an expert at a time."""
     rng = np.random.default_rng(k)
     T, D, F, E = 64, 16, 8, 6
     x = jnp.asarray(rng.normal(size=(T, D)), jnp.float32)
     experts = {"gate": jnp.asarray(rng.normal(size=(E, D, F)) / 4, jnp.float32),
                "up": jnp.asarray(rng.normal(size=(E, D, F)) / 4, jnp.float32),
                "down": jnp.asarray(rng.normal(size=(E, F, D)) / 3, jnp.float32)}
+    if not gated:
+        del experts["gate"]
     weigh = rng.uniform(0.1, 0.9, size=(T, E))
     bias = np.zeros((T, E))
     bias[:, 5] = -10.0  # never chosen
     bias[::2, 2] = 10.0  # always chosen by every other token
     bias[1::2, 2] = -10.0
     choose = jnp.asarray(weigh + bias, jnp.float32)
-    y, chosen = jax.jit(lambda *a: moe_topk(*a, k, 2.5))(x, choose, jnp.asarray(weigh, jnp.float32), experts)
-    want, want_chosen = _every_expert_masked(x, choose, weigh, experts, k, 2.5)
+    if gated:
+        y, chosen = jax.jit(lambda *a: moe_topk(*a, k, 2.5))(x, choose, jnp.asarray(weigh, jnp.float32), experts)
+        want, want_chosen = _every_expert_masked(x, choose, weigh, experts, k, 2.5)
+    else:
+        y, chosen = jax.jit(lambda *a: moe_topk(*a, k, 2.5, relu2))(x, choose, jnp.asarray(weigh, jnp.float32), experts)
+        want = _per_token_loop(x, choose, weigh, experts, k, 2.5, lambda a: np.maximum(a, 0) ** 2)
+        want_chosen = np.argsort(-np.asarray(choose, np.float64), axis=1, kind="stable")[:, :k]
+        silu_too = jax.jit(lambda *a: moe_topk(*a, k, 2.5))(x, choose, jnp.asarray(weigh, jnp.float32), experts)[0]
+        assert np.abs(np.asarray(silu_too) - want).max() > 0.05  # the activation is the caller's, not a default's
     load = np.bincount(np.asarray(chosen).ravel(), minlength=E)
     assert load[5] == 0 and load[2] == T // 2 and load.sum() == T * k
     assert np.array_equal(np.sort(chosen, axis=1), np.sort(want_chosen, axis=1))
     assert y.dtype == jnp.float32 and chosen.dtype == jnp.int32
     np.testing.assert_allclose(y, want, rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("width", [8, 1856, 768, 1024, 128])
+def test_moe_topk_hands_the_grouped_product_the_matrices_the_tree_holds(width):
+    """One path whatever the inner width: 1,856 = 14.5 x 128, off the lane
+    grid, meets ``ragged_dot`` as 1,856, as 768 and 1,024 on it do. A storage
+    layout that suits the chip better belongs where the weights are made,
+    once, not in a copy of both expert stacks on every call (PERF.md, PR 33)."""
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+
+    for experts in ({"gate": spec(4, 16, width), "up": spec(4, 16, width), "down": spec(4, width, 16)},
+                    {"up": spec(4, 16, width), "down": spec(4, width, 16)}):
+        jaxpr = jax.make_jaxpr(lambda x, s, e: moe_topk(x, s, s, e, 2))(
+            spec(32, 16), jax.ShapeDtypeStruct((32, 4), jnp.float32), experts)
+        products = [eqn for eqn in jaxpr.jaxpr.eqns if eqn.primitive.name == "ragged_dot_general"]
+        assert len(products) == len(experts)
+        assert sorted(eqn.invars[1].aval.shape for eqn in products) == sorted(
+            [(4, width, 16)] + [(4, 16, width)] * (len(experts) - 1))
+        assert not [eqn for eqn in jaxpr.jaxpr.eqns if eqn.primitive.name == "pad"]
 
 
 def test_moe_topk_when_one_expert_takes_every_token():

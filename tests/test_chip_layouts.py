@@ -176,3 +176,81 @@ def test_topk_experts_at_the_published_widths_use_the_grouped_product(one_chip):
     text = compiled.as_text()
     assert text.count("ragged-dot") >= 3 and "tpu_custom_call" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 6 * 2**30
+
+
+def test_the_state_space_scan_at_the_published_widths_compiles_as_one_kernel(one_chip):
+    """One dispatch of the hybrid cell's mixer: 1 row of 16,384 tokens, 64
+    heads of 64 in 8 groups, state 128, chunks of 128. The kernel compiles as
+    written (a ``tpu_custom_call`` named ``ssd_scan``: two heads side by side
+    in 128 lanes, the closing state's product contracting the chunk's
+    positions on both sides), and beside its operands it needs only the two
+    small float32 layouts of the cumulative decays (PERF.md, PR 33)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.ops.ssd import ssd_scan
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    S = 16384
+    compiled = _compile_off(
+        ssd_scan, spec((1, S, 64, 64)), spec((1, S, 64), jnp.float32), spec((64,), jnp.float32),
+        spec((1, S, 8, 128)), spec((1, S, 8, 128)), spec((64,), jnp.float32))
+    memory = compiled.memory_analysis()
+    assert memory.output_size_in_bytes == S * 64 * 64 * 2
+    assert memory.temp_size_in_bytes < 2**28
+    assert len(set(re.findall(r"%(ssd_scan[\w.\-]*) =", compiled.as_text()))) == 1
+
+
+def test_blocked_attention_at_a_group_of_sixteen_holds_in_vmem_as_written(one_chip):
+    """The hybrid cell's one attention block: 1 row of 16,384 tokens, 32 query
+    heads over 2 key/value heads of 128. A grid step's score product has
+    16 x 128 = 2,048 rows, four times Trinity's; its float32 scores, masks
+    and accumulator hold under the kernel's 100 MiB VMEM limit with the
+    query block of 128 unchanged (PERF.md, PR 33)."""
+    import jax
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.ops.attention import blocked_attention
+
+    q = jax.ShapeDtypeStruct((1, 16384, 32, 128), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 16384, 2, 128), jnp.bfloat16, sharding=one_chip)
+    compiled = _compile_off(lambda q, k, v: blocked_attention(q, k, v), q, kv, kv)
+    memory = compiled.memory_analysis()
+    assert memory.output_size_in_bytes == 16384 * 32 * 128 * 2
+    assert memory.temp_size_in_bytes < 2**30 and "tpu_custom_call" in compiled.as_text()
+
+
+def test_the_hybrid_decoder_at_the_published_widths_fits_beside_its_weights(one_chip):
+    """The whole program of ``nemotron-3-nano.score-16k``, one dispatch of
+    16,384 tokens: 11.31 GiB of weights leave 4.4 GiB of a v5e's 15.75 for
+    temporaries, and ``memory_stats`` on the chip does not count them, so the
+    compiler is the one that can say (3.35 GiB: PERF.md, PR 33). One
+    ``lax.scan`` over the four units makes each kernel one HLO name: the scan
+    kernel, the attention kernel under its conditional, and the two grouped
+    expert products, which the three roofline metrics read by."""
+    import json
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.models.nemotron_h import init_nemotron_h, nemotron_h_apply
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs", "nemotron-3-nano.json")) as f:
+        config = json.load(f)["params"]
+    tree = jax.eval_shape(lambda k: init_nemotron_h(k, config), jax.random.PRNGKey(0))
+    tree = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+    tokens = jax.ShapeDtypeStruct((1, 16384), jnp.int32, sharding=one_chip)
+    compiled = _compile_off(lambda p, x: nemotron_h_apply(p, x, config), tree, tokens)
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes >= 12_145_796_608
+    assert memory.temp_size_in_bytes < 3.5 * 2**30
+    text = compiled.as_text()
+    assert len(set(re.findall(r"%(ssd_scan[\w.\-]*) =", text))) == 1
+    assert len(set(re.findall(r"%(attn_full[\w.\-]*) =", text))) == 1
+    assert len(set(re.findall(r"%(ragged-dot-none[\w.\-]*) =", text))) == 2

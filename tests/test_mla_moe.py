@@ -104,7 +104,7 @@ def test_narrower_product_inputs_are_a_different_result(params):
 def test_the_family_is_read_from_model_type_and_afmoe_is_the_default(params):
     from mmlspark_tpu.models import init_afmoe
 
-    assert set(FAMILIES) == {"afmoe", "joyai_llm_flash"}
+    assert set(FAMILIES) == {"afmoe", "joyai_llm_flash", "nemotron_h"}
     afmoe = dict(
         hidden_size=32, num_attention_heads=2, num_key_value_heads=1, head_dim=16,
         intermediate_size=48, moe_intermediate_size=16, num_experts=4, num_experts_per_tok=2,
