@@ -57,7 +57,8 @@ def swiglu(x, gate, up, down, dt):
     return feed_forward(x, {"gate": gate, "up": up, "down": down}, dt)
 
 
-def routed_experts(p, x, k: int, scale: float, dt, activation: Callable = jax.nn.silu):
+def routed_experts(p, x, k: int, scale: float, dt, activation: Callable = jax.nn.silu,
+                   first_group=0, interpret: bool = False):
     """x: (rows, S, hidden). Sigmoid router scores; a token's ``k`` experts
     are the largest of score + ``router_bias``, weighed by the scores alone
     over their sum times ``scale`` (:func:`moe_topk`); one shared expert
@@ -65,6 +66,9 @@ def routed_experts(p, x, k: int, scale: float, dt, activation: Callable = jax.nn
     what the tree holds: ``e_gate``, ``e_up``, ``e_down`` (and ``s_*``
     likewise) a gated feed-forward, ``e_up`` and ``e_down`` alone two
     matrices with ``activation`` between them (:func:`feed_forward`).
+    ``e_*`` may hold several layers' experts one after another, read where
+    they lie: ``first_group`` then says where this layer's stand, and
+    ``interpret`` is the grouped product's (:func:`moe_topk`).
     -> (routed + shared (rows, S, hidden) float32, the tokens of each row
     that each expert received (rows, experts))."""
     B, S, D = x.shape
@@ -75,7 +79,8 @@ def routed_experts(p, x, k: int, scale: float, dt, activation: Callable = jax.nn
     with jax.named_scope("moe_experts"):
         held = [n for n in ("gate", "up", "down") if "e_" + n in p]
         experts = {n: rounded(p["e_" + n], dt) for n in held}
-        routed, chosen = moe_topk(flat, scores + p["router_bias"], scores, experts, k, scale, activation)
+        routed, chosen = moe_topk(flat, scores + p["router_bias"], scores, experts, k, scale, activation,
+                                  first_group, interpret)
         shared = feed_forward(flat, {n: p["s_" + n] for n in held}, dt, activation)
     load = (chosen.reshape(B, S * k, 1) == jnp.arange(E, dtype=jnp.int32)).sum(axis=1)
     return (routed + shared).reshape(B, S, D), load.astype(jnp.int32)

@@ -22,12 +22,15 @@ letter in the published ``hybrid_override_pattern``:
   largest of score + balancing bias, weighed by the scores alone over their
   sum times ``routed_scaling_factor``; an expert is **two** matrices with
   ``relu(x)^2`` between them, no gate; one shared expert of its own width
-  beside them (:func:`moe_decoder.routed_experts`).
+  beside them (:func:`moe_decoder.routed_experts`). The grouped products
+  read every unit's experts where they lie, one stack of ``units x
+  experts`` groups at the published width, told where this unit's stand.
 
 Read as units the pattern is regular: ``M``, then an optional ``*``, then
 ``E``. The blocks of a kind are stacked and the units run under one
 ``lax.scan`` (the attention block under a ``lax.cond``, its weights looked
-up by index, so a unit without one holds nothing), so each kernel is one
+up by index, so a unit without one holds nothing; the routed experts' two
+stacks closed over whole, the unit's number scanned), so each kernel is one
 operation of the program. A pattern that does not end on a whole unit is an
 error. No embedding scale; the final norm and the untied head at each row's
 last position.
@@ -211,19 +214,28 @@ def nemotron_h_apply(params, tokens, config: Dict[str, Any]):
         p = jax.tree.map(lambda a: lax.dynamic_index_in_dim(a, index, keepdims=False), params["attention"])
         return added(h, _attention(p, normed(h, p), c, dt))
 
+    # A unit's two expert matrices sliced out of their stacks by the scan are copies, 1.2 GiB each a unit a dispatch
+    # at the published size, the first transposed on the way. Products that take the matrices as they are stored read
+    # the whole stacks where they lie instead, every unit's groups one after another, told where this unit's stand.
+    # (Inputs narrower than stored are rounded into a copy whatever is done, so those stacks go through the scan.)
+    E = c["n_routed_experts"]
+    whole = {name: a.reshape((-1,) + a.shape[2:])  # a bitcast
+             for name, a in params["experts"].items() if name in ("e_up", "e_down") and a.dtype == dt}
+    scanned = {name: a for name, a in params["experts"].items() if name not in whole}
+
     def unit(h, xs):
-        mixer, experts, attends, index = xs
+        mixer, experts, attends, index, number = xs
         h = added(h, _mixer(mixer, normed(h, mixer), c, dt))
         if any(attention):
             h = lax.cond(attends, attend, lambda h, index: h, h, index)
-        y, load = routed_experts(experts, normed(h, experts), c["num_experts_per_tok"],
-                                 c["routed_scaling_factor"], dt, relu2)
+        y, load = routed_experts({**experts, **whole}, normed(h, experts), c["num_experts_per_tok"],
+                                 c["routed_scaling_factor"], dt, relu2, number * E, bool(c.get("interpret", False)))
         return added(h, y), load
 
     if attention:
         has = jnp.asarray(attention)
         index = jnp.cumsum(has) - has  # an attention block's place in its stack
-        h, loads = lax.scan(unit, h, (params["mixer"], params["experts"], has, index))
+        h, loads = lax.scan(unit, h, (params["mixer"], scanned, has, index, jnp.arange(len(attention))))
         loads = loads.transpose(1, 0, 2)
     else:
         loads = jnp.zeros((tokens.shape[0], 0, c["n_routed_experts"]), jnp.int32)

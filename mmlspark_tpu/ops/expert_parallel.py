@@ -20,9 +20,11 @@ And one for many experts on ONE device, inside a model:
 - :func:`moe_topk` — top-k by sort: the (token, expert) assignments are
   sorted by expert, each expert's feed-forward (three matrices with a
   gate, or two without) runs over its own contiguous group
-  (``lax.ragged_dot``), and the results go back weighted. Work is
-  ∝ tokens x k whatever the imbalance; no capacity, so no token is ever
-  dropped, and an expert that got no token is an empty group.
+  (``lax.ragged_dot``; a Pallas grouped matmul where the matrices are a
+  stack of several layers' experts, read in place), and the results go
+  back weighted. Work is ∝ tokens x k whatever the imbalance; no capacity,
+  so no token is ever dropped, and an expert that got no token is an empty
+  group.
 """
 
 from __future__ import annotations
@@ -32,6 +34,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 from jax.sharding import PartitionSpec as P
 
 from mmlspark_tpu.parallel.mesh import AXIS_EXPERT
@@ -189,8 +192,42 @@ def moe_apply_a2a(
     )(expert_params, x, assign, chosen_p)
 
 
+def _tile(n: int, whole: int) -> int:
+    """A tile of a dimension of ``n``: all of it up to ``whole``, else the
+    largest multiple of 128 up to 1,024 that divides it, else 512 (the
+    kernel masks a contracted edge and clips a written one)."""
+    if n <= whole:
+        return n
+    return max((t for t in range(128, 1025, 128) if n % t == 0), default=512)
+
+
+def _product_in_place(a, w, sizes, interpret: bool):
+    """``lax.ragged_dot(a, w, sizes)`` in float32 by the Pallas grouped
+    matmul (``megablox.gmm``): consecutive groups of ``a``'s rows (M, K),
+    ``sizes`` (G,) long, each times its own matrix of ``w`` (G, K, N). The
+    kernel finds a group's matrix by its number, so ``w`` is read where it
+    lies and a group of size 0 costs no tile; and it tiles a width by what
+    fits and masks the edge, where XLA:TPU's grouped product tiles one by
+    the power of two that divides it (1,856 = 29 x 64 by 128: a tenth of the
+    chip's peak; 2,688 x 1,856 on a v5e 17 -> 91 TFLOP/s, and back 18 ->
+    106: PERF.md, PR 34).
+
+    The chip stores a matrix whose last dimension is off the 128 lanes and
+    whose last but one is on them with that one minor, so such a ``w`` is
+    handed over transposed, which is the same bytes and no copy."""
+    (M, K), N = a.shape, w.shape[2]
+    tm = min(256, -(-M // 8) * 8)
+    a = jnp.pad(a, ((0, -M % tm), (0, 0)))  # rows behind the last group are in no tile
+    as_stored = N % 128 != 0 and K % 128 == 0
+    if as_stored:
+        w = jnp.swapaxes(w, 1, 2)
+    y = gmm(a, w, sizes, jnp.float32, (tm, _tile(K, 3072), _tile(N, 512)),
+            transpose_rhs=as_stored, interpret=interpret)
+    return y[:M]
+
+
 def moe_topk(x, scores_to_choose, scores_to_weigh, experts, k: int, scale: float = 1.0,
-             activation: Callable = jax.nn.silu):
+             activation: Callable = jax.nn.silu, first_group=0, interpret: bool = False):
     """Top-``k`` mixture of the experts held on this device.
 
     ``x`` is (T, D). A token's experts are the ``k`` largest of its row of
@@ -200,7 +237,15 @@ def moe_topk(x, scores_to_choose, scores_to_weigh, experts, k: int, scale: float
     holds: ``gate`` and ``up`` (E, D, F) and ``down`` (E, F, D) compute
     ``down(activation(gate x) * up x)`` (SwiGLU as it stands); ``up`` and
     ``down`` alone, two matrices and no gate, ``down(activation(up x))``.
-    Products in ``x``'s dtype summed in float32.
+    Products in ``x``'s dtype summed in float32 (``lax.ragged_dot``).
+
+    The matrices may hold more groups than the router has experts, a whole
+    multiple: several layers' experts one after another, as a model stores
+    them, read where they lie (:func:`_product_in_place`; ``interpret`` is
+    its Pallas kernel's, for a backend other than the chip). ``first_group``
+    (an integer, traced or not) then says where these ``E`` stand among
+    them: every other group is empty, which costs the product no tile and
+    the program no copy of ``E`` matrices. With ``E`` groups neither is read.
     -> (y (T, D) float32, chosen (T, k) int32)."""
     T, D = x.shape
     E = scores_to_choose.shape[1]
@@ -214,6 +259,12 @@ def moe_topk(x, scores_to_choose, scores_to_weigh, experts, k: int, scale: float
     sizes = sizes.astype(jnp.int32)
     xs = x[order // k]
     dot = lambda a, w: lax.ragged_dot(a, w, sizes, preferred_element_type=jnp.float32)
+    groups = experts["up"].shape[0]
+    if groups != E:  # these experts' sizes at their place, every other group empty
+        assert groups % E == 0, f"{groups} groups of matrices for {E} experts"
+        assert not isinstance(first_group, int) or first_group in range(0, groups, E), first_group  # traced: clamped
+        among = lax.dynamic_update_slice(jnp.zeros(groups, jnp.int32), sizes, (first_group,))
+        dot = lambda a, w: _product_in_place(a, w, among, interpret)
     if "gate" in experts:
         inner = activation(dot(xs, experts["gate"])) * dot(xs, experts["up"])
     else:

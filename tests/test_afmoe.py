@@ -302,3 +302,138 @@ def test_moe_topk_when_one_expert_takes_every_token():
     want, _ = _every_expert_masked(x, scores, scores, experts, 1, 1.0)
     assert (np.asarray(chosen) == 3).all()
     np.testing.assert_allclose(y, want, rtol=2e-4, atol=2e-5)
+
+
+# -- a stack of several layers' experts, read where it lies ------------------
+
+def _moe_topk_as_it_was(x, scores_to_choose, scores_to_weigh, experts, k, scale=1.0, activation=jax.nn.silu):
+    """``moe_topk`` of PR 33, statement for statement: what Trinity's and
+    JoyAI's programs were traced from."""
+    from jax import lax
+
+    T, D = x.shape
+    E = scores_to_choose.shape[1]
+    _, chosen = lax.top_k(scores_to_choose, k)
+    weights = jnp.take_along_axis(scores_to_weigh, chosen, axis=1)
+    weights = weights / weights.sum(axis=1, keepdims=True) * scale
+    expert_of = chosen.reshape(T * k)
+    order = jnp.argsort(expert_of, stable=True)
+    sizes = (expert_of[:, None] == jnp.arange(E, dtype=expert_of.dtype)).sum(axis=0)
+    sizes = sizes.astype(jnp.int32)
+    xs = x[order // k]
+    dot = lambda a, w: lax.ragged_dot(a, w, sizes, preferred_element_type=jnp.float32)
+    if "gate" in experts:
+        inner = activation(dot(xs, experts["gate"])) * dot(xs, experts["up"])
+    else:
+        inner = activation(dot(xs, experts["up"]))
+    inner = inner.astype(x.dtype)
+    ys = dot(inner, experts["down"])
+    back = jnp.argsort(order)
+    y = (ys[back].reshape(T, k, D) * weights[:, :, None].astype(jnp.float32)).sum(axis=1)
+    return y, chosen.astype(jnp.int32)
+
+
+@pytest.mark.parametrize("width", [32, 24], ids=["a_power_of_two", "off_every_grid"])
+@pytest.mark.parametrize("gated", [True, False], ids=["three_matrices", "two_matrices"])
+def test_moe_topk_with_as_many_groups_as_experts_lowers_to_the_program_it_was(gated, width):
+    """Which product runs is read from the matrices' leading length: equal to
+    the router's width, as in every family whose stacks come through a
+    scan's ``xs``, the lowered text is PR 33's, whatever the inner width and
+    whatever ``first_group`` and ``interpret`` say."""
+    def spec(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    experts = {"gate": spec(8, 16, width), "up": spec(8, 16, width), "down": spec(8, width, 16)}
+    if not gated:
+        del experts["gate"]
+    shapes = (spec(64, 16), spec(64, 8, dtype=jnp.float32), spec(64, 8, dtype=jnp.float32), experts)
+
+    def lowered(fn):
+        def program(x, choose, weigh, experts):
+            return fn(x, choose, weigh, experts, 2, 2.5)
+        return jax.jit(program).lower(*shapes).as_text()
+
+    was = lowered(_moe_topk_as_it_was)
+    assert len(was) > 5000 and lowered(moe_topk) == was
+    assert lowered(lambda *a: moe_topk(*a, first_group=24, interpret=True)) == was
+    assert "dynamic_update_slice" not in was and "custom_call" not in was
+
+
+def _a_stack(rng, names, U, E, D, F):
+    stack = {n: rng.normal(size=(U * E, F, D) if n == "down" else (U * E, D, F)) / 4 for n in names}
+    return {n: jnp.asarray(a, jnp.bfloat16) for n, a in stack.items()}
+
+
+@pytest.mark.parametrize("unit", [0, 1, 2])
+@pytest.mark.parametrize("gated", [True, False], ids=["three_matrices", "two_matrices"])
+def test_moe_topk_over_a_stack_of_units_is_each_units_experts_alone(unit, gated):
+    """``units x E`` groups with the unit's place among them, traced as a
+    scan hands it over or not: that unit's ``(E, D, F)`` matrices alone give
+    the same numbers and the same choice, so nothing of the 2 x E other
+    groups' matrices arrives: they are empty. 96 assigned rows are no whole
+    tile of 256, and expert 4 of the unit's own gets no token."""
+    rng = np.random.default_rng(7)
+    T, D, F, E, U, k = 48, 16, 8, 6, 3, 2
+    x = jnp.asarray(rng.normal(size=(T, D)), jnp.bfloat16)
+    stack = _a_stack(rng, ("gate", "up", "down") if gated else ("up", "down"), U, E, D, F)
+    own = {n: a[unit * E:(unit + 1) * E] for n, a in stack.items()}
+    weigh = jnp.asarray(rng.uniform(0.1, 0.9, size=(T, E)), jnp.float32)
+    choose = weigh.at[:, 4].add(-10.0)
+    alone, chosen_alone = jax.jit(lambda *a: moe_topk(*a, k, 2.5, relu2))(x, choose, weigh, own)
+    traced, chosen = jax.jit(lambda x, c, w, e, g: moe_topk(x, c, w, e, k, 2.5, relu2, g, True))(
+        x, choose, weigh, stack, jnp.int32(unit * E))
+    static, _ = jax.jit(lambda *a: moe_topk(*a, k, 2.5, relu2, unit * E, True))(x, choose, weigh, stack)
+    assert np.isfinite(np.asarray(alone)).all() and not (np.asarray(chosen) == 4).any()
+    assert np.array_equal(chosen, chosen_alone)
+    assert np.array_equal(traced, static)
+    np.testing.assert_allclose(traced, alone, rtol=1e-6, atol=1e-6)
+    if unit != 1:  # and another unit's place is another result
+        other, _ = jax.jit(lambda *a: moe_topk(*a, k, 2.5, relu2, E, True))(x, choose, weigh, stack)
+        assert np.abs(np.asarray(other) - np.asarray(alone)).max() > 0.05
+
+
+def test_a_stack_that_is_no_whole_number_of_layers_is_an_error():
+    rng = np.random.default_rng(0)
+    stack = _a_stack(rng, ("up", "down"), 1, 9, 16, 8)
+    scores = jnp.asarray(rng.uniform(size=(8, 6)), jnp.float32)
+    with pytest.raises(AssertionError, match="9 groups of matrices for 6 experts"):
+        moe_topk(jnp.zeros((8, 16), jnp.bfloat16), scores, scores, stack, 2, interpret=True)
+
+
+@pytest.mark.parametrize("M,K,N,transposed", [
+    (96, 128, 72, True),    # written width off the 128 lanes, contracted on them: read as the chip stores it
+    (300, 72, 128, False),  # the way back; 300 rows are padded to 304
+    (520, 24, 40, False),   # a toy's widths, more rows than one tile of 256
+])
+def test_the_product_in_place_is_ragged_dot(M, K, N, transposed):
+    """Groups of every size, empty ones among them, first and last, and the
+    matrices of the groups that get no row full of NaN: they are not read."""
+    from jax import lax
+
+    from mmlspark_tpu.ops import expert_parallel
+
+    rng = np.random.default_rng(M)
+    G = 12
+    sizes = rng.multinomial(M, rng.dirichlet(np.ones(G - 4)))
+    sizes = np.concatenate([[0, 0], sizes, [0, 0]]).astype(np.int32)
+    a = jnp.asarray(rng.normal(size=(M, K)), jnp.bfloat16)
+    w = np.asarray(rng.normal(size=(G, K, N)) / 4, np.float32)
+    w[sizes == 0] = np.nan
+    w = jnp.asarray(w, jnp.bfloat16)
+    text = jax.make_jaxpr(lambda a, w, s: expert_parallel._product_in_place(a, w, s, True))(a, w, jnp.asarray(sizes))
+    assert ("transpose[permutation=(0, 2, 1)]" in str(text)) == transposed
+    got = expert_parallel._product_in_place(a, w, jnp.asarray(sizes), True)
+    want = lax.ragged_dot(a, jnp.nan_to_num(w), jnp.asarray(sizes), preferred_element_type=jnp.float32)
+    assert got.shape == (M, N) and got.dtype == jnp.float32
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+
+
+def test_tiles_of_the_published_hybrid_widths():
+    """Up: all of 2,688 contracted at once, 1,856 written in four tiles of
+    512 (the last clipped). Down: all of 1,856 contracted, 2,688 written in
+    three of 896 (my chip runs, PR 34: 10.8 and 9.3 ms at these, PERF.md 6)."""
+    from mmlspark_tpu.ops.expert_parallel import _tile
+
+    assert (_tile(2688, 3072), _tile(1856, 512)) == (2688, 512)
+    assert (_tile(1856, 3072), _tile(2688, 512)) == (1856, 896)
+    assert _tile(24, 512) == 24 and _tile(4096, 3072) == 1024 and _tile(7168, 3072) == 1024 and _tile(3000, 512) == 512

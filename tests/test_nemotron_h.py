@@ -71,6 +71,71 @@ def test_featurizer_agrees_with_the_reference_on_all_three_outputs(params, seed,
     assert (out["e"].sum(axis=-1) == 50 * 2).all()
 
 
+@pytest.mark.parametrize("width,seed", [(256, 3), (40, 2)], ids=["on_the_256_grid", "another_off_it"])
+def test_featurizer_agrees_with_the_reference_at_another_expert_width(width, seed):
+    """Whatever the published inner width, the stacks are read where they lie
+    at that width: the tree is the published count, and the same limits hold."""
+    config = dict(SMALL, moe_intermediate_size=width)
+    params = init_nemotron_h(jax.random.PRNGKey(11), config)
+    assert params["experts"]["e_up"].shape == (4, 16, 64, width) and params["experts"]["e_down"].shape == (4, 16, width, 64)
+    tokens = _tokens(seed)
+    out = LMFeaturizer(outputCols=ALL_OUTPUTS, modelParams=params, modelConfig=config,
+                       batchSize=3).transform(Table({"tokens": tokens}))
+    want = ref.forward(params, tokens, config)
+    assert np.sort(ref.relative_gaps(out["h"], want["hidden"]))[-2] < 0.04
+    assert np.sort(ref.relative_gaps(out["l"], want["logits"]))[-2] < 0.04
+    assert ref.load_gaps(out["e"], want["expert_load"], 50 * 2).max() <= 0.1
+    assert (out["e"].sum(axis=-1) == 50 * 2).all()
+
+
+def _eqns(jaxpr, name):
+    found = []
+    for eqn in jaxpr.eqns:
+        found += [eqn] if eqn.primitive.name == name else []
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _eqns(sub, name)
+    return found
+
+
+@pytest.mark.parametrize("product_dtype", ["bfloat16", "float8_e4m3fn"])
+def test_the_expert_stacks_are_read_where_they_lie_when_the_products_take_them_as_stored(params, product_dtype):
+    """As stated, the two stacks stay out of the unit scan's ``xs``: the scan
+    closes over all 4 x 16 groups and the Pallas grouped matmul is told where
+    the unit's stand; nothing slices a unit's matrices out. Product inputs
+    narrower than stored are rounded into a copy whatever is done, so then
+    the stacks go through the scan as every other leaf, a unit's at a time,
+    to ``lax.ragged_dot``: the program it was."""
+    config = dict(SMALL, product_dtype=product_dtype)
+    jaxpr = jax.make_jaxpr(lambda p, x: nemotron_h_apply(p, x, config))(params, _tokens(0))
+    (scan,) = [eqn for eqn in jaxpr.jaxpr.eqns if eqn.primitive.name == "scan"]
+    consts = scan.params["num_consts"]
+    closed_over = [v.aval.shape for v in scan.invars[:consts]]
+    carried_or_scanned = [v.aval.shape for v in scan.invars[consts:]]
+    body = scan.params["jaxpr"].jaxpr
+    stacks, in_place = [(4, 16, 64, 24), (4, 16, 24, 64)], [(64, 64, 24), (64, 24, 64)]
+    if product_dtype == "bfloat16":
+        assert all(shape in closed_over for shape in in_place)
+        assert not any(shape in carried_or_scanned for shape in stacks + in_place)
+        assert len(_eqns(body, "pallas_call")) >= 2 and not _eqns(body, "ragged_dot_general")
+        assert not [e for e in _eqns(body, "dynamic_slice") if e.outvars[0].aval.shape[-2:] in ((64, 24), (24, 64))]
+    else:
+        assert all(shape in carried_or_scanned for shape in stacks)
+        assert [e.invars[1].aval.shape for e in _eqns(body, "ragged_dot_general")] == [(16, 64, 24), (16, 24, 64)]
+
+
+def test_reading_the_stacks_in_place_changes_no_routing_and_no_result(params, program):
+    """The same tree with its two expert stacks in float32 (the same values)
+    takes the other way, a unit's matrices through the scan to
+    ``lax.ragged_dot``: every expert receives the same tokens, and the
+    outputs agree to a product's rounding."""
+    wide = dict(params, experts=dict(params["experts"], **{
+        name: params["experts"][name].astype(jnp.float32) for name in ("e_up", "e_down")}))
+    through_the_scan = jax.jit(lambda p, x: nemotron_h_apply(p, x, SMALL))(wide, _tokens(4))
+    assert np.array_equal(through_the_scan["expert_load"], program["expert_load"])
+    assert ref.relative_gaps(through_the_scan["hidden"], program["hidden"]).max() < 1e-3
+    assert ref.relative_gaps(through_the_scan["logits"], program["logits"]).max() < 1e-3
+
+
 @pytest.mark.parametrize("fault", ref.FAULTS)
 def test_each_planted_fault_moves_the_reference_far_from_the_program(params, program, fault):
     tokens = _tokens(4)
