@@ -97,51 +97,34 @@ def make_data(sz: Sizes, seed: int = 0) -> Data:
 
 
 class CompileClock:
-    """Sums JAX's own compile events between ``reset`` calls: seconds in
-    backend compilation (a persistent-cache hit books its load time here),
-    seconds tracing and lowering, and the cache's hit and miss counts."""
+    """What the program's own tracer booked of JAX's compile events between
+    ``reset`` calls (``Tracer.compile_log``, every owner span and
+    ``(no span)`` together): seconds in backend compilation (a
+    persistent-cache hit books its load time there), seconds tracing and
+    lowering, and the cache's hit and miss counts. The log keeps its newest
+    thousand records; the seven phases book a few dozen."""
 
-    _DURATIONS = {
-        "/jax/core/compile/backend_compile_duration": "compile_secs",
-        "/jax/core/compile/jaxpr_trace_duration": "trace_secs",
-        "/jax/core/compile/jaxpr_to_mlir_module_duration": "trace_secs",
-    }
-    _COUNTS = {
-        "/jax/compilation_cache/cache_hits": "cache_hits",
-        "/jax/compilation_cache/cache_misses": "cache_misses",
+    _KEYS = {
+        "compile_secs": "compile_s", "trace_secs": "trace_s",
+        "cache_hits": "cache_hits", "cache_misses": "cache_misses",
     }
 
     def __init__(self) -> None:
-        import jax.monitoring
+        # imported after jax, so the tracer listens from here on at the latest
+        from mmlspark_tpu.observability.tracing import get_tracer
 
-        self._lock = threading.Lock()
-        self._totals = self._zero()
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._count)
+        self._log = get_tracer().compile_log
+        self._seen = self._totals()
 
-    @staticmethod
-    def _zero() -> dict:
-        return {
-            "compile_secs": 0.0, "trace_secs": 0.0,
-            "cache_hits": 0, "cache_misses": 0,
-        }
-
-    def _duration(self, event: str, secs: float, **_) -> None:
-        key = self._DURATIONS.get(event)
-        if key:
-            with self._lock:
-                self._totals[key] += secs
-
-    def _count(self, event: str, **_) -> None:
-        key = self._COUNTS.get(event)
-        if key:
-            with self._lock:
-                self._totals[key] += 1
+    def _totals(self) -> dict:
+        log = self._log()
+        return {out: sum(r[key] for r in log) for out, key in self._KEYS.items()}
 
     def reset(self) -> dict:
         """What accumulated since the last call; starts the next window."""
-        with self._lock:
-            out, self._totals = self._totals, self._zero()
+        now = self._totals()
+        out = {key: now[key] - self._seen[key] for key in now}
+        self._seen = now
         out["compile_secs"] = round(out["compile_secs"], 2)
         out["trace_secs"] = round(out["trace_secs"], 2)
         return out

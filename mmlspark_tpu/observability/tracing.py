@@ -12,6 +12,13 @@ module is that layer:
   call stack and is async/thread-correct); ``start_span``/``finish``
   are the manual form for spans that cross threads (the scheduler's
   attempts, the serving batch loop);
+- **the span that paid for a compile says so**: JAX's own monitoring events
+  (trace, lowering, backend compile or cache load, cache hit and miss) are
+  booked to the innermost ambient span of the global tracer as the tags
+  ``trace_s``, ``compile_s``, ``cache_hits``, ``cache_misses``, and kept
+  in :meth:`Tracer.compile_log`, which :meth:`Tracer.clear` and the ring
+  leave alone; :meth:`Tracer.first_calls` keeps the first finished
+  instance of every root span, a stage's first-call penalty;
 - ids are **deterministic**: process-wide counters, not random — two
   identical single-threaded runs produce identical span ids, which is
   what replay-based tests want;
@@ -42,9 +49,32 @@ import collections
 import contextlib
 import contextvars
 import dataclasses
+import sys
 import threading
 import time
 from typing import Any, Dict, Iterator, List, Optional, Tuple
+
+#: JAX's monitoring events a span is booked for -> the tag each adds to.
+#: ``backend_compile_duration`` holds a persistent-cache hit's load time;
+#: JAX times a jit traced inside another on its own and inside the outer
+#: one's trace again, so ``trace_s`` counts what JAX's events count.
+_BOOKED_SECONDS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "trace_s",
+    "/jax/core/compile/backend_compile_duration": "compile_s",
+}
+_BOOKED_COUNTS = {
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+    "/jax/compilation_cache/cache_misses": "cache_misses",
+}
+#: the tags a span gains where it paid, and a compile-log record's sums
+COMPILE_TAGS = ("trace_s", "compile_s", "cache_hits", "cache_misses")
+#: owner of an event that met no ambient span (a caller's own jits, a
+#: stage that opens no span); its records coalesce a second at a time
+NO_SPAN = "(no span)"
+_NO_SPAN_COALESCE_S = 1.0
+_COMPILE_LOG_SIZE = 1024
+_FIRST_CALLS_SIZE = 256
 
 
 @dataclasses.dataclass
@@ -57,6 +87,11 @@ class Span:
     end: Optional[float] = None
     status: str = "ok"
     tags: Dict[str, Any] = dataclasses.field(default_factory=dict)
+
+    #: this instance's record in the compile log once a JAX event was booked
+    #: to it; a class attribute and no field, so a span that pays nothing
+    #: never sets it
+    booked = None
 
     @property
     def duration(self) -> Optional[float]:
@@ -161,6 +196,13 @@ class Tracer:
             contextvars.ContextVar("mmlspark_tpu_span", default=None)
         )
         self._xprof = xprof
+        # what compiling cost, by the span instance that paid (module
+        # docstring): outlives ``clear`` and the ring
+        self._compile_log: "collections.deque[Dict[str, Any]]" = (
+            collections.deque(maxlen=_COMPILE_LOG_SIZE)
+        )
+        self._unowned: Optional[Dict[str, Any]] = None  # open NO_SPAN record
+        self._first_calls: Dict[str, Dict[str, Any]] = {}
 
     # -- ids (deterministic: counters, not random) ---------------------------
 
@@ -233,8 +275,23 @@ class Tracer:
             span.tags.update(tags)
         with self._lock:
             self._finished.append(span)
+            if span.parent_id is None:
+                self._note_first_call(span)
         self._publish(span)
         return span
+
+    def _note_first_call(self, span: Span) -> None:
+        # under the lock; a root span only
+        if (
+            span.name not in self._first_calls
+            and len(self._first_calls) < _FIRST_CALLS_SIZE
+        ):
+            self._first_calls[span.name] = {
+                "name": span.name,
+                "trace_id": span.trace_id,
+                "start": span.start,
+                "duration": span.duration,
+            }
 
     def _publish(self, span: Span) -> None:
         """Mirror a finished span onto the event bus (SpanRecorded) so the
@@ -339,8 +396,61 @@ class Tracer:
         return {"trace_id": trace_id, "roots": roots}
 
     def clear(self) -> None:
+        """Empty the ring of finished spans. The compile log and the
+        first-call table stay: they describe the process, not a window."""
         with self._lock:
             self._finished.clear()
+
+    # -- what compiling cost, and who paid -----------------------------------
+
+    def _book(self, key: str, amount: float) -> None:
+        """Add one JAX event to the innermost ambient span, as a tag and in
+        its record of the compile log; to ``NO_SPAN`` where there is none.
+        JAX fires its events on the thread that traces or compiles, so the
+        ambient span here is the caller's."""
+        span = self._current.get()
+        with self._lock:
+            if span is None:
+                now = time.monotonic()
+                record = self._unowned
+                if record is None or now - record["t"] > _NO_SPAN_COALESCE_S:
+                    record = self._unowned = _compile_record(now, NO_SPAN, "")
+                    self._compile_log.append(record)
+                record[key] += amount
+                return
+            record = span.booked
+            if record is None:
+                record = span.booked = _compile_record(
+                    span.start, span.name, span.trace_id
+                )
+                self._compile_log.append(record)
+            span.tags[key] = record[key] = record[key] + amount
+
+    def compile_log(self) -> List[Dict[str, Any]]:
+        """What JAX's tracing, lowering, compiling and cache look-ups cost
+        this process, oldest first: one record for each span instance that
+        paid anything, ``{t, span, trace_id, trace_s, compile_s, cache_hits,
+        cache_misses}`` with ``t`` the span's monotonic start, and
+        ``NO_SPAN`` records stamped with their first event's time. Bounded
+        (the newest ~thousand); only the global tracer's is ever filled."""
+        with self._lock:
+            return [dict(record) for record in self._compile_log]
+
+    def first_calls(self) -> List[Dict[str, Any]]:
+        """The first finished instance of every root span name in this
+        process, earliest first: ``{name, trace_id, start, duration}``, a
+        stage's first-call penalty. Its compile share is the compile log's
+        records of that ``trace_id``."""
+        with self._lock:
+            calls = [dict(call) for call in self._first_calls.values()]
+        return sorted(calls, key=lambda call: call["start"])
+
+
+def _compile_record(t: float, span: str, trace_id: str) -> Dict[str, Any]:
+    return {
+        "t": t, "span": span, "trace_id": trace_id,
+        "trace_s": 0.0, "compile_s": 0.0, "cache_hits": 0, "cache_misses": 0,
+    }  # COMPILE_TAGS, seconds as floats and counts as ints
 
 
 _ANNOTATE = None
@@ -355,11 +465,50 @@ def _annotation():
             from mmlspark_tpu.core.profiling import annotate
         except ImportError:  # pragma: no cover - jax is a hard dep in practice
             annotate = contextlib.nullcontext
+        _listen()  # jax is imported by now, if it can be
         _ANNOTATE = annotate
     return _ANNOTATE
 
 
 _TRACER = Tracer()
+
+_LISTENING = False
+_LISTEN_LOCK = threading.Lock()
+
+
+def _on_seconds(event: str, secs: float, **_: Any) -> None:
+    key = _BOOKED_SECONDS.get(event)
+    if key:
+        _TRACER._book(key, secs)
+
+
+def _on_event(event: str, **_: Any) -> None:
+    key = _BOOKED_COUNTS.get(event)
+    if key:
+        _TRACER._book(key, 1)
+
+
+def _listen() -> None:
+    """Register the two ``jax.monitoring`` listeners that book to the global
+    tracer, once a process however often it is called. Importing the package
+    must not import jax, so this runs when the module is imported after jax
+    (every entry point that looks for its device first) and otherwise on the
+    first context-managed span."""
+    global _LISTENING
+    with _LISTEN_LOCK:
+        if _LISTENING:
+            return
+        try:
+            import jax.monitoring
+        except ImportError:  # pragma: no cover - jax is a hard dep in practice
+            return
+        jax.monitoring.register_event_duration_secs_listener(_on_seconds)
+        jax.monitoring.register_event_listener(_on_event)
+        _LISTENING = True
+
+
+if "jax" in sys.modules:
+    _listen()
 
 
 def get_tracer() -> Tracer:

@@ -530,7 +530,7 @@ def test_a_table_without_rows_raises_as_the_forward_always_did():
 
 
 def test_the_stage_alone_still_returns_its_host_column():
-    from mmlspark_tpu.observability.tracing import get_tracer
+    from mmlspark_tpu.observability.tracing import COMPILE_TAGS, get_tracer
 
     table = _handover_table([(9, 7, 3)] * 4)
     tracer = get_tracer()
@@ -540,7 +540,8 @@ def test_the_stage_alone_still_returns_its_host_column():
     column = out["out"]
     assert isinstance(column, np.ndarray) and column.dtype == np.float32
     assert column.shape == (4, 6, 5, 3) and column.flags.c_contiguous
-    assert span == {"bytes_up": 4 * 9 * 7 * 3, "bytes_down": column.nbytes}
+    span = {k: v for k, v in span.items() if k not in COMPILE_TAGS}
+    assert span == {"bytes_up": 4 * 9 * 7 * 3, "bytes_down": column.nbytes}  # less what the first call compiled
 
 
 def test_device_groups_are_the_stage_programs_own_arrays():

@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 
 from mmlspark_tpu.data.table import Table
-from mmlspark_tpu.observability.tracing import get_tracer
+from mmlspark_tpu.observability.tracing import COMPILE_TAGS, get_tracer
 
 FIT_CHILDREN = {
     "lightgbm.prepare", "lightgbm.binning", "lightgbm.upload", "lightgbm.program",
@@ -33,11 +33,17 @@ def _fit_table(rows=2000, features=6, seed=0):
 
 
 def _recorded(job):
-    """Every span the tracer finished while ``job`` ran."""
+    """Every span the tracer finished while ``job`` ran, with the tags the
+    job's shape gives: what a first call paid JAX to trace and compile is
+    booked on the same spans (``tests/test_compile_booking.py``) and
+    depends on what the process compiled before."""
     tracer = get_tracer()
     tracer.clear()
     out = job()
-    return out, tracer.export()
+    spans = tracer.export()
+    for span in spans:
+        span["tags"] = {k: v for k, v in span["tags"].items() if k not in COMPILE_TAGS}
+    return out, spans
 
 
 def _fit_spans(iterations, **params):
