@@ -187,6 +187,7 @@ class TreeArrays(NamedTuple):
     row_leaf: jax.Array  # (N,) final leaf slot of every training row
     cat_node: jax.Array  # (M,) bool: categorical split at this node
     cat_mask: jax.Array  # (M, B) bool left-set bins ((M, 1) placeholder when no cat)
+    passes: jax.Array  # (2,) int32: histogram passes the grower built, and skipped
 
 
 class SplitSearch(NamedTuple):
@@ -206,7 +207,6 @@ class SplitSearch(NamedTuple):
     is_cat: jax.Array  # (k,) bool: categorical split (bin = the prefix-
     # defining BIN id; the left set itself lives in cat_mask)
     cat_mask: jax.Array  # (k, B) bool: bins in the LEFT set (all-False if numeric)
-    value_cat: jax.Array  # (k,) own leaf value under l2+cat_l2 (cat-parent case)
 
 
 def _soft_threshold(g: jax.Array, l1: float) -> jax.Array:
@@ -457,11 +457,9 @@ def _split_search(
             leaf_value_cat(g_tot - glb, h_tot - hlb),
             leaf_value(g_tot - glb, h_tot - hlb),
         )
-        value_cat = leaf_value_cat(g_tot, h_tot)
     else:
         lval = leaf_value(glb, hlb)
         rval = leaf_value(g_tot - glb, h_tot - hlb)
-        value_cat = leaf_value(g_tot, h_tot)
 
     return SplitSearch(
         value=leaf_value(g_tot, h_tot),
@@ -477,7 +475,6 @@ def _split_search(
         rcov=c_tot - clb,
         is_cat=is_cat_best,
         cat_mask=cat_mask,
-        value_cat=value_cat,
     )
 
 
@@ -779,6 +776,7 @@ def _build_tree_depthwise(
             )
             if has_cat else jnp.zeros((internal + leaves, 1), bool)
         ),
+        passes=jnp.array([depth, 0], jnp.int32),  # one a level, none after the last
     )
 
 
@@ -806,18 +804,32 @@ def _build_tree_leafwise(
 ) -> TreeArrays:
     """Best-first growth, ``leaf_batch`` frontier leaves per histogram pass.
 
-    Each pass splits the top-``k`` frontier leaves by cached candidate gain
-    in ONE node-keyed histogram pass — the panel formulation
+    A round splits the top-``k`` frontier leaves by cached candidate gain
+    (``split_round``: rows routed, nodes and leaves recorded), and the next
+    round begins by building its children's histograms in ONE node-keyed
+    pass (``build_round``) — the panel formulation
     (``ops/pallas_histogram.py``) makes a k-node pass cost the same as a
-    1-node pass, so a 31-leaf tree costs ~6 passes instead of 30. ``k = 1``
-    is LightGBM's exact sequential best-first; ``k > 1`` approximates it
-    (the k-th split is committed before the first split's children can
-    compete — ties and near-ties resolve in frontier-gain order, then by
-    lower slot index, matching ``lax.top_k``'s ordering). Slots are
-    allocated densely in split order: the j-th split overall creates slots
-    2j+1 and 2j+2, so the layout is deterministic and static-shaped
-    (M = 2*num_leaves - 1) and ``k = 1`` reproduces the sequential layout
-    bit-for-bit."""
+    1-node pass. A leaf's value and cover come from the split that made it
+    (LightGBM's rule, and the depth-wise grower's), so a pass exists only to
+    find the children's OWN splits, and the round that spends the last of
+    the leaf budget is followed by none. Passes a tree: the root's, plus one
+    for every round but the last when the budget ends the tree (``R``
+    rounds: ``1 + R - 1``; a 31-leaf tree at ``k = 8`` splits 1, 2, 4, 8, 8,
+    7 leaves, so 6 passes), plus one for every round when nothing is left
+    worth splitting or the depth cap ends it (``1 + R``: that the last
+    round's children cannot be split is what its pass finds out).
+    ``TreeArrays.passes`` counts built and skipped. Under ``vmap`` (the
+    boosting step: one tree a class) the loop runs while any class's tree
+    grows, so a pass is saved when every class's tree ends on its budget.
+
+    ``k = 1`` is LightGBM's exact sequential best-first; ``k > 1``
+    approximates it (the k-th split is committed before the first split's
+    children can compete — ties and near-ties resolve in frontier-gain
+    order, then by lower slot index, matching ``lax.top_k``'s ordering).
+    Slots are allocated densely in split order: the j-th split overall
+    creates slots 2j+1 and 2j+2, so the layout is deterministic and
+    static-shaped (M = 2*num_leaves - 1) and ``k = 1`` reproduces the
+    sequential layout bit-for-bit."""
     # Under bundling ``bins`` is (N, C) packed columns while the histogram
     # cache / subtraction / search all live in ORIGINAL feature space —
     # f here sizes those, NOT the packed width.
@@ -893,6 +905,11 @@ def _build_tree_leafwise(
     def at0(template, s_):
         return template.at[0].set(s_[0])
 
+    def kids_of(search):
+        return jnp.stack(
+            [search.lval, search.rval, search.lcov, search.rcov], axis=1
+        )  # (nodes, 4)
+
     has_cat = bool(opts.categorical_slots)
     # Categorical-row view of U, sliced ONCE here (outside the while_loop —
     # XLA does not hoist the gather out of the loop body; left inside it
@@ -933,13 +950,17 @@ def _build_tree_leafwise(
         depth=zi,
         n_splits=jnp.int32(0),
         # frontier candidates (-inf gain = not frontier / not splittable;
-        # NaN sanitized at write so cond's max stays NaN-free)
+        # NaN sanitized at write so top_k's order stays NaN-free)
         c_gain=jnp.full(m, -jnp.inf).at[0].set(
             jnp.where(jnp.isnan(root.gain[0]), -jnp.inf, root.gain[0])
         ),
         c_feat=at0(zi, root.feat),
         c_bin=at0(zi, root.bin),
         c_thr=at0(zf, root.thr),
+        # what the candidate split would leave in its two children: leaf
+        # values (l2 + cat_l2 under a categorical split) and row counts
+        c_kids=at0(jnp.zeros((m, 4), jnp.float32), kids_of(root)),
+        passes=jnp.int32(1),  # histogram passes built: the root's so far
     )
     if has_cat:
         zb = jnp.zeros(m, bool)
@@ -967,17 +988,22 @@ def _build_tree_leafwise(
             root.rcov[0] < root.lcov[0]
         )
 
-    def cond(st):
-        # c_gain is NaN-free by construction; -inf marks non-frontier and
-        # +inf (f32 gain overflow) is a legitimate best split.
-        best = jnp.max(st["c_gain"])
-        return (st["n_splits"] < num_leaves - 1) & (best > opts.min_gain_to_split)
+    def put(arr, slots, values):
+        """Guarded scatter: a disabled lane's slot is m, out of range, and is
+        dropped, never clipped onto a live slot."""
+        return arr.at[slots].set(values, mode="drop")
 
-    def body(st):
-        # Top-k frontier leaves by cached candidate gain (sorted descending,
-        # ties by lower slot index).
-        top_g, top_l = lax.top_k(st["c_gain"], k)
-        j = jnp.arange(k, dtype=jnp.int32)
+    def put_children(arr, lslot, rslot, left, right):
+        return put(put(arr, lslot, left), rslot, right)
+
+    def split_round(st, lanes=k):
+        """Split the top ``lanes`` frontier leaves by cached candidate gain
+        (sorted descending, ties by lower slot index): route their rows,
+        record the nodes and the two leaves each leaves behind, and leave
+        in ``pending`` what a pass over the new children needs. Reads no
+        histogram. (The root's round has a frontier of one: one lane.)"""
+        top_g, top_l = lax.top_k(st["c_gain"], lanes)
+        j = jnp.arange(lanes, dtype=jnp.int32)
         can = (top_g > opts.min_gain_to_split) & (
             st["n_splits"] + j < num_leaves - 1
         )  # monotone in j: gains sorted descending, budget consumed in order
@@ -991,8 +1017,6 @@ def _build_tree_leafwise(
             can = can & ((j == 0) | (top_g >= opts.leaf_batch_ratio * top_g[0]))
         lslot = 2 * (st["n_splits"] + j) + 1
         rslot = lslot + 1
-        # Guarded scatter indices: disabled lanes write out of range (m) and
-        # are dropped, never clipped onto a live slot.
         gparent = jnp.where(can, top_l, m)
         glslot = jnp.where(can, lslot, m)
         grslot = jnp.where(can, rslot, m)
@@ -1006,11 +1030,11 @@ def _build_tree_leafwise(
             sic = st["c_iscat"][top_l]  # (k,)
             scm = st["c_catmask"][top_l]  # (k, B)
 
-        # Route rows and build the pass's node keys in one unrolled sweep:
-        # key = j for rows entering split j's LEFT child (subtraction mode;
-        # 2j + went_right without), k·(invalid) elsewhere — the panel
-        # histogram drops out-of-range keys, so the key IS the in-leaf mask
-        # and grad/hess need no masking pass.
+        # Route rows and build the next pass's node keys in one unrolled
+        # sweep: key = j for rows entering split j's SMALLER child
+        # (subtraction mode; 2j + went_right without), 2k (invalid)
+        # elsewhere — the panel histogram drops out-of-range keys, so the
+        # key IS the in-leaf mask and grad/hess need no masking pass.
         with jax.named_scope("route"):
             node = st["node"]
             new_node = node
@@ -1034,7 +1058,7 @@ def _build_tree_leafwise(
                 cols = _orig_bins(cols, sf, rconsts)
             else:
                 cols = jnp.take(bins, sf, axis=1)  # (N, k)
-            for jj in range(k):
+            for jj in range(lanes):
                 colj = cols[:, jj]
                 in_j = (node == top_l[jj]) & can[jj]
                 right_j = colj > sb[jj]
@@ -1059,11 +1083,62 @@ def _build_tree_leafwise(
                 else:
                     key = jnp.where(in_j, 2 * jj + right_j.astype(jnp.int32), key)
 
+        st = dict(st)
+        st["node"] = new_node
+        st["feat"] = put(st["feat"], gparent, sf)
+        st["bin"] = put(st["bin"], gparent, sb)
+        st["thr"] = put(st["thr"], gparent, sthr)
+        st["left"] = put(st["left"], gparent, lslot)
+        st["right"] = put(st["right"], gparent, rslot)
+        st["is_leaf"] = put_children(
+            put(st["is_leaf"], gparent, False), glslot, grslot, True, True
+        )
+        # A leaf's value and cover come from the split that CREATED it
+        # (native parity; children of categorical splits carry the
+        # l2+cat_l2 output): known when the parent's split was chosen, so
+        # no pass is ever built for their sake.
+        kids = st["c_kids"][top_l]  # (k, 4)
+        st["leaf_val"] = put_children(
+            st["leaf_val"], glslot, grslot, kids[:, 0], kids[:, 1]
+        )
+        st["cover"] = put_children(
+            st["cover"], glslot, grslot, kids[:, 2], kids[:, 3]
+        )
+        st["gain"] = put(st["gain"], gparent, top_g)
+        child_depth = st["depth"][top_l] + 1  # (k,)
+        st["depth"] = put_children(
+            st["depth"], glslot, grslot, child_depth, child_depth
+        )
+        # off the frontier; the children join it if build_round finds
+        # their splits (their fresh slots' gains are -inf until then)
+        st["c_gain"] = put(st["c_gain"], gparent, -jnp.inf)
+        if has_cat:
+            st["cat_node"] = put(st["cat_node"], gparent, sic)
+            st["cat_mask"] = put(st["cat_mask"], gparent, scm)
+        st["n_splits"] = st["n_splits"] + can.sum().astype(jnp.int32)
+        st["grew"] = can[0]  # the round split at least one leaf
+
+        def lane(values, idle):  # the pass has k lanes whatever the round had
+            return jnp.pad(values, (0, k - lanes), constant_values=idle)
+
+        # the new children, which no pass has built yet: every row's node
+        # key (2k = in none of them) and, a lane of the pass, the parent,
+        # its two slots (m = the lane split nothing) and their depth
+        st["pending"] = (
+            key, lane(top_l, 0), lane(glslot, m), lane(grslot, m), lane(child_depth, 0)
+        )
+        return st
+
+    def build_round(st):
+        """One histogram pass over the children the last round created, and
+        the search for each one's own best split: they join the frontier."""
+        key, top_l, glslot, grslot, child_depth = st["pending"]
         if use_sub:
             # Build the smaller child in PACKED space, derive the sibling
             # as parent - smaller (exact integer subtraction on the quant
             # path — the derived sibling is bit-identical to a direct
             # build), then assign built/derived back to left/right.
+            small_r = st["c_subR"][top_l]
             histS, totS = histf.packed(
                 bins, grad, hess, count, key, k, b, feature_mask=feature_mask,
                 u=u, stats=stats,
@@ -1080,102 +1155,61 @@ def _build_tree_leafwise(
                 jnp.concatenate([totL_p, totR_p]),
                 b, stats=stats,
             )
-            histL, histR = hlr[:k], hlr[k:]
-            totL, totR = tlr[:k], tlr[k:]
         else:
             h2, t2 = histf(
                 bins, grad, hess, count, key, 2 * k, b, feature_mask=feature_mask,
                 u=u, stats=stats,
             )
-            h2 = h2.reshape(k, 2, f, b, 3)
-            t2 = t2.reshape(k, 2, 3)
-            histL, histR = h2[:, 0], h2[:, 1]
-            totL, totR = t2[:, 0], t2[:, 1]
+            # (2k,) keyed [2j + went_right] -> [left children | right children]
+            hlr = h2.reshape(k, 2, f, b, 3).swapaxes(0, 1).reshape(2 * k, f, b, 3)
+            tlr = t2.reshape(k, 2, 3).swapaxes(0, 1).reshape(2 * k, 3)
 
-        child_depth = st["depth"][top_l] + 1  # (k,)
         cs = searchk(
-            jnp.concatenate([histL, histR]),
-            jnp.concatenate([totL, totR]),
-            jnp.concatenate([child_depth, child_depth]),
+            hlr, tlr, jnp.concatenate([child_depth, child_depth])
         )  # (2k,) fields: [left children | right children]
 
-        st = dict(st)
-        if use_sub:
-            st["leaf_hist"] = (
-                st["leaf_hist"].at[glslot].set(histL_p, mode="drop")
-                .at[grslot].set(histR_p, mode="drop")
-            )
-            st["leaf_tot"] = (
-                st["leaf_tot"].at[glslot].set(totL_p, mode="drop")
-                .at[grslot].set(totR_p, mode="drop")
-            )
-            sub_r = cs.rcov < cs.lcov  # (2k,) per fresh candidate
-            st["c_subR"] = (
-                st["c_subR"].at[glslot].set(sub_r[:k], mode="drop")
-                .at[grslot].set(sub_r[k:], mode="drop")
-            )
-        st["node"] = new_node
-        st["feat"] = st["feat"].at[gparent].set(sf, mode="drop")
-        st["bin"] = st["bin"].at[gparent].set(sb, mode="drop")
-        st["thr"] = st["thr"].at[gparent].set(sthr, mode="drop")
-        st["left"] = st["left"].at[gparent].set(lslot, mode="drop")
-        st["right"] = st["right"].at[gparent].set(rslot, mode="drop")
-        st["is_leaf"] = (
-            st["is_leaf"].at[gparent].set(False, mode="drop")
-            .at[glslot].set(True, mode="drop")
-            .at[grslot].set(True, mode="drop")
-        )
-        # A final leaf's value comes from the split that CREATED it: children
-        # of categorical splits carry the l2+cat_l2 output (native parity).
-        lv_l, lv_r = cs.value[:k], cs.value[k:]
-        if has_cat:
-            lv_l = jnp.where(sic, cs.value_cat[:k], lv_l)
-            lv_r = jnp.where(sic, cs.value_cat[k:], lv_r)
-        st["leaf_val"] = (
-            st["leaf_val"].at[glslot].set(lv_l, mode="drop")
-            .at[grslot].set(lv_r, mode="drop")
-        )
-        st["cover"] = (
-            st["cover"].at[glslot].set(cs.cover[:k], mode="drop")
-            .at[grslot].set(cs.cover[k:], mode="drop")
-        )
-        st["gain"] = st["gain"].at[gparent].set(top_g, mode="drop")
-        st["depth"] = (
-            st["depth"].at[glslot].set(child_depth, mode="drop")
-            .at[grslot].set(child_depth, mode="drop")
-        )
-        st["c_gain"] = (
-            st["c_gain"].at[gparent].set(-jnp.inf, mode="drop")
-            .at[glslot].set(cs.gain[:k], mode="drop")
-            .at[grslot].set(cs.gain[k:], mode="drop")
-        )
-        st["c_feat"] = (
-            st["c_feat"].at[glslot].set(cs.feat[:k], mode="drop")
-            .at[grslot].set(cs.feat[k:], mode="drop")
-        )
-        st["c_bin"] = (
-            st["c_bin"].at[glslot].set(cs.bin[:k], mode="drop")
-            .at[grslot].set(cs.bin[k:], mode="drop")
-        )
-        st["c_thr"] = (
-            st["c_thr"].at[glslot].set(cs.thr[:k], mode="drop")
-            .at[grslot].set(cs.thr[k:], mode="drop")
-        )
-        if has_cat:
-            st["cat_node"] = st["cat_node"].at[gparent].set(sic, mode="drop")
-            st["cat_mask"] = st["cat_mask"].at[gparent].set(scm, mode="drop")
-            st["c_iscat"] = (
-                st["c_iscat"].at[glslot].set(cs.is_cat[:k], mode="drop")
-                .at[grslot].set(cs.is_cat[k:], mode="drop")
-            )
-            st["c_catmask"] = (
-                st["c_catmask"].at[glslot].set(cs.cat_mask[:k], mode="drop")
-                .at[grslot].set(cs.cat_mask[k:], mode="drop")
-            )
-        st["n_splits"] = st["n_splits"] + can.sum().astype(jnp.int32)
-        return st
+        def children(name, values):
+            return put_children(st[name], glslot, grslot, values[:k], values[k:])
 
-    state = jax.lax.while_loop(cond, body, state)
+        new = dict(
+            c_gain=children("c_gain", cs.gain),
+            c_feat=children("c_feat", cs.feat),
+            c_bin=children("c_bin", cs.bin),
+            c_thr=children("c_thr", cs.thr),
+            c_kids=children("c_kids", kids_of(cs)),
+            passes=st["passes"] + 1,
+        )
+        if has_cat:
+            new["c_iscat"] = children("c_iscat", cs.is_cat)
+            new["c_catmask"] = children("c_catmask", cs.cat_mask)
+        if use_sub:
+            new["leaf_hist"] = put_children(
+                st["leaf_hist"], glslot, grslot, histL_p, histR_p
+            )
+            new["leaf_tot"] = put_children(
+                st["leaf_tot"], glslot, grslot, totL_p, totR_p
+            )
+            new["c_subR"] = children("c_subR", cs.rcov < cs.lcov)
+        return {**st, **new}
+
+    # The root's round, then a pass and a round for as long as the last
+    # round grew the tree and left budget for its children to be split in
+    # turn. The exit follows a round's routing, so the children of the round
+    # that spends the budget are never built: the tree ends there whatever
+    # their histograms would say, and nothing else reads them. A round that
+    # finds no gain worth a split, or meets the depth cap, splits nothing,
+    # and the pass before it was the cost of finding that out. (The exit,
+    # and not a ``lax.cond`` around the pass: the boosting step vmaps the
+    # grower over classes, a single class too, and there a cond is a select
+    # that runs both branches.)
+    def cond(st):
+        return st["grew"] & (st["n_splits"] < num_leaves - 1)
+
+    state = jax.lax.while_loop(
+        cond, lambda st: split_round(build_round(st)), split_round(state, lanes=1)
+    )
+    # at the exit a round that grew the tree can only have spent the budget
+    skipped = state["grew"].astype(jnp.int32)
 
     return TreeArrays(
         feat=state["feat"],
@@ -1190,6 +1224,7 @@ def _build_tree_leafwise(
         row_leaf=state["node"],
         cat_node=state["cat_node"] if has_cat else jnp.zeros(m, bool),
         cat_mask=state["cat_mask"] if has_cat else jnp.zeros((m, 1), bool),
+        passes=jnp.stack([state["passes"], skipped]),
     )
 
 
@@ -2574,7 +2609,11 @@ def train(
             finally:
                 root_logger.setLevel(prev_level)
 
-        fetched = _fetch_trees(trees, stacked_trees, opts, num_classes)
+        # with the trees, how many times the growers streamed the rows for a
+        # histogram and how many rounds' children they left unbuilt
+        fetched, (built, skipped) = _fetch_trees(trees, stacked_trees, opts, num_classes)
+        boost_span.tags["hist_passes_built"] = int(built)
+        boost_span.tags["hist_passes_skipped"] = int(skipped)
     with tracer.span("lightgbm.pack", trees=iters_done * num_classes):
         booster = _assemble_booster(
             fetched, opts, num_classes, init_score, mapper, feature_names,
@@ -2822,7 +2861,7 @@ def _pack_booster(
     its ``lightgbm.boost`` span where the device's work has reached the
     host: :func:`_fetch_trees`, then :func:`_assemble_booster`."""
     return _assemble_booster(
-        _fetch_trees(trees, stacked_trees, opts, num_classes),
+        _fetch_trees(trees, stacked_trees, opts, num_classes)[0],
         opts, num_classes, init_score, mapper, feature_names, best_iteration,
     )
 
@@ -2837,9 +2876,10 @@ def _fetch_trees(
     stacked_trees: Optional[TreeArrays],
     opts: TrainOptions,
     num_classes: int,
-) -> Tuple[np.ndarray, Optional[np.ndarray], Optional[np.ndarray]]:
+) -> Tuple[Tuple[np.ndarray, Optional[np.ndarray], Optional[np.ndarray]], np.ndarray]:
     """Device to host: (packed (9, T*C, M) float32 tree fields, categorical
-    node flags, categorical masks). The first fetch waits for every
+    node flags, categorical masks), and the histogram passes the growers
+    built and skipped over all T*C trees. The first fetch waits for every
     dispatch still in flight."""
     t = opts.num_iterations if stacked_trees is not None else len(trees)
     m = opts.num_nodes
@@ -2854,7 +2894,14 @@ def _fetch_trees(
             dev = jnp.concatenate([getattr(tr, field) for tr in trees], axis=0)
         return dev.reshape(t * num_classes, m).astype(jnp.float32)
 
-    packed = np.asarray(jnp.stack([_field_dev(fld) for fld in _FIELDS]))
+    passes_dev = (
+        stacked_trees.passes if stacked_trees is not None
+        else jnp.stack([tr.passes for tr in trees])
+    ).reshape(-1, 2).sum(axis=0)
+    # the pass counts ride the fetch of the pack: no round trip of their own
+    packed, passes = jax.device_get(
+        (jnp.stack([_field_dev(fld) for fld in _FIELDS]), passes_dev)
+    )
 
     # Categorical split arrays ride separate (small) transfers: the bool
     # mask matrix does not fit the homogeneous f32 pack.
@@ -2872,7 +2919,7 @@ def _fetch_trees(
             )
         cat_nodes_np = np.asarray(cn_dev).astype(bool)
         cat_masks_np = np.asarray(cm_dev.astype(jnp.uint8)).astype(bool)
-    return packed, cat_nodes_np, cat_masks_np
+    return (packed, cat_nodes_np, cat_masks_np), passes
 
 
 def _assemble_booster(

@@ -59,6 +59,21 @@ def _table(X, y):
     return Table({"features": np.asarray(X, np.float64), "label": np.asarray(y, np.float64)})
 
 
+@pytest.fixture(autouse=True)
+def _release_compiled_programs():
+    """This module alone fits some two hundred models of different shapes in
+    one process, and every XLA:CPU executable it keeps holds memory mappings:
+    47,610 of the kernel's 65,530 (``vm.max_map_count``) by the tenth test
+    at PR 35, and past them with a tree program a third larger, where the
+    next compile dies in ``backend_compile_and_load`` (PERF.md, PR 36;
+    ``tests/conftest.py`` bounds the same thing between modules). No test
+    here reuses another's programs, so each releases its own."""
+    yield
+    import mmlspark_tpu
+
+    mmlspark_tpu.clear_compiled_caches()
+
+
 @pytest.fixture(scope="module")
 def class_sets():
     from sklearn.datasets import load_breast_cancer, load_digits, load_wine, make_classification

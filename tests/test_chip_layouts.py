@@ -275,3 +275,54 @@ def test_the_hybrid_decoder_at_the_published_widths_fits_beside_its_weights(one_
         assert memory.temp_size_in_bytes < 3.5 * 2**30
         assert len(products["ragged-dot-none"]) == 2 and not products["gmm"]
         assert a_units_matrix  # the copies, rounded
+
+
+def test_the_leafwise_tree_streams_u_at_the_head_of_a_round_and_leaves_after_the_routing(one_chip):
+    """The fit cell's tree program (1,000,000 x 28 rows of 256 bins, 31 leaves,
+    the resident int8 one-hot ``U`` of 7,168 x 1,000,448): the program holds two
+    ``U`` x panel contractions, the root's before the loop and one in the loop's
+    body, scheduled ahead of everything the body's routing does; the condition
+    reads scalars and there is no conditional. So the round that spends the
+    leaf budget routes its rows, the loop leaves, and no pass is built for
+    children nothing would read: a 31-leaf tree streams ``U`` six times, not
+    seven. ``U`` is an argument read in place: the program's temporaries are
+    megabytes (PERF.md, PR 36)."""
+    import re
+    from functools import partial
+
+    import jax
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.lightgbm.train import TrainOptions, _build_tree_leafwise, _hist_fn
+    from mmlspark_tpu.ops.u_histogram import make_u_spec, u_bytes
+
+    rows, features, bins = 1_000_000, 28, 256
+    opts = TrainOptions(objective="binary", num_leaves=31, max_bin=bins - 1)
+    spec = make_u_spec(bins, features, [bins] * features)
+    assert u_bytes(rows, spec) == 7168 * 1_000_448
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    per_row = shape((rows,), jnp.float32)
+    build = partial(_build_tree_leafwise, num_bins=bins, opts=opts, histf=_hist_fn(opts, None, spec), u_spec=spec)
+    compiled = _compile_off(
+        lambda b, g, h, c, e, m, u: build(b, g, h, c, e, m, u=u),
+        shape((rows, features), jnp.uint8), per_row, per_row, per_row,
+        shape((features, bins - 1), jnp.float32), shape((features,), jnp.float32),
+        shape((7168, 1_000_448), jnp.int8))
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes >= 7168 * 1_000_448 and memory.temp_size_in_bytes < 64 * 2**20
+    lines = compiled.as_text().split("\n")
+    assert not any(" conditional(" in line for line in lines)
+    passes = [i for i, line in enumerate(lines)
+              if re.search(r"= f32\[7168,\d+\]\S* fusion\(.*hist_pass/dot_general", line)]
+    in_body, root = passes  # the entry computation is printed last
+    assert "f32[7168,3]" in lines[root] and "/while/" not in lines[root]
+    assert "f32[7168,24]" in lines[in_body] and "/while/body/hist_pass/" in lines[in_body]
+    # the module is scheduled, so a computation's lines are in the order they run
+    opens = max(i for i in range(in_body) if lines[i].endswith("{") and not lines[i].startswith(" "))
+    closes = min(i for i in range(in_body, len(lines)) if lines[i].startswith("}"))
+    routing = [i for i in range(opens, closes) if "/while/body/route/" in lines[i]]
+    assert routing and in_body < routing[0]
+    assert not any("hist_pass" in line for line in lines if "/while/cond/" in line)

@@ -7,6 +7,8 @@ voting-parallel is ``tree_learner=voting_parallel`` + ``topK``
 (``LightGBMParams.scala:20-24``).
 """
 
+import os
+
 import numpy as np
 import pytest
 
@@ -484,3 +486,259 @@ class TestCategoricalURouting:
             ]
         )
         np.testing.assert_array_equal(in_set, expected)
+
+
+# -- one tree straight from the leaf-wise grower ------------------------------
+#
+# ``_build_tree_leafwise`` on fixed data: the passes it built and skipped, the
+# tree's structure against what the commit before the skip grew
+# (``fixtures/leafwise_parent_trees.npz``, recorded there by ``_record_parent``),
+# and every leaf's value and cover against sums over the rows routed to it.
+
+PARENT_TREES = os.path.join(os.path.dirname(__file__), "fixtures", "leafwise_parent_trees.npz")
+STRUCTURE = ("feat", "bin", "thr", "left", "right", "is_leaf", "gain", "row_leaf")
+
+
+def _tree_data(kind, n=3000, seed=4):
+    """(X, categorical feature ids, bundling?) and per-row gradient pairs of a
+    binary objective part-way through a fit (so gradients are not +-0.5)."""
+    rng = np.random.default_rng(seed)
+    cats = None
+    if kind == "bundled":  # six blocks of five exclusive indicators, three dense columns
+        X = np.zeros((n, 30), np.float64)
+        for block in range(6):
+            X[np.arange(n), block * 5 + rng.integers(0, 5, n)] = rng.uniform(0.5, 2.0, n)
+        X = np.hstack([X, rng.normal(size=(n, 3))])
+        y = (X[:, 0] + 2 * X[:, 7] + X[:, -1] > 1.2).astype(np.float64)
+    else:
+        X, y = _make_binary(n=n, seed=seed)
+        if kind == "categorical":
+            X[:, 5] = rng.integers(0, 12, n)  # sorted-prefix search
+            X[:, 6] = rng.integers(0, 3, n)  # one-vs-rest search
+            y = ((X[:, 5] % 3 == 0) ^ (X[:, 6] == 1) ^ (X[:, 0] > 0.3)).astype(np.float64)
+            cats = [5, 6]
+    p = 1.0 / (1.0 + np.exp(-0.4 * rng.normal(size=n)))
+    return X, cats, (p - y).astype(np.float32), (p * (1.0 - p)).astype(np.float32)
+
+
+def _tree_program(kind="numeric", u_path=False, **options):
+    """``_build_tree_leafwise`` as ``train()`` calls it, unjitted, with its
+    arguments: (build, args, u, grad, hess, the options the grower sees)."""
+    import dataclasses
+    from functools import partial
+
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.lightgbm.train import _build_tree_leafwise, _hist_fn
+
+    X, cats, grad, hess = _tree_data(kind)
+    max_bin = options.pop("max_bin", 63)
+    bins, mapper = bin_dataset(
+        X, max_bin=max_bin, categorical_features=cats, feature_bundling=kind == "bundled")
+    bundle = getattr(mapper, "bundles", None)
+    assert (bundle is not None) == (kind == "bundled")
+    opts = TrainOptions(objective="binary", max_bin=max_bin, min_data_in_leaf=5, **options)
+    if mapper.cat_values:
+        opts = dataclasses.replace(
+            opts, categorical_slots=tuple(sorted(mapper.cat_values)),
+            onehot_slots=tuple(f for f in sorted(mapper.cat_values)
+                               if len(mapper.cat_values[f]) <= opts.max_cat_to_onehot))
+    u = u_spec = None
+    if u_path:
+        from mmlspark_tpu.ops.u_histogram import build_u, make_u_spec
+
+        u_spec = (make_u_spec(bundle.num_bins, bins.shape[1], [int(w) for w in bundle.widths])
+                  if bundle is not None else
+                  make_u_spec(max_bin + 1, bins.shape[1], [int(b) for b in mapper.num_bins]))
+        u = build_u(jnp.asarray(bins), u_spec)
+    features = bundle.num_features if bundle is not None else bins.shape[1]
+    edges = np.where(np.isfinite(mapper.edges), mapper.edges, np.finfo(np.float32).max)
+    build = partial(
+        _build_tree_leafwise, num_bins=max_bin + 1, opts=opts,
+        histf=_hist_fn(opts, None, u_spec, bundle=bundle), u_spec=u_spec, bundle=bundle)
+    args = (jnp.asarray(bins), jnp.asarray(grad), jnp.asarray(hess), jnp.ones(len(grad), jnp.float32),
+            jnp.asarray(edges.astype(np.float32)), jnp.ones(features, jnp.float32))
+    return build, args, u, grad, hess, opts
+
+
+def _grow_tree(**options):
+    """One tree: (tree as numpy, grad, hess, the options the grower saw)."""
+    import jax
+
+    build, args, u, grad, hess, opts = _tree_program(**options)
+    return jax.tree.map(np.asarray, jax.jit(build)(*args, u=u)), grad, hess, opts
+
+
+PARENT_CASES = {
+    "batch1": dict(num_leaves=31, leaf_batch=1),
+    "batch8": dict(num_leaves=31, leaf_batch=8),
+    "batch42": dict(num_leaves=31, leaf_batch=42),
+    "batch8_u": dict(num_leaves=31, leaf_batch=8, u_path=True),
+    "batch8_direct": dict(num_leaves=31, leaf_batch=8, histogram_subtraction=False),
+    "batch8_categorical": dict(kind="categorical", num_leaves=15, leaf_batch=8),
+    "batch8_bundled_u": dict(kind="bundled", num_leaves=15, leaf_batch=8, u_path=True, max_bin=255),
+}
+
+
+FIT_STRUCTURE = ("split_feature", "split_bin", "split_threshold", "left_child", "right_child", "is_leaf")
+
+
+def _fit_five(**options):
+    X, y = _make_binary(n=3000, seed=4)
+    bins, mapper = bin_dataset(X, max_bin=63)
+    opts = TrainOptions(objective="binary", num_iterations=5, num_leaves=31, max_bin=63, **options)
+    return train(bins, y, opts, mapper=mapper).booster
+
+
+FIT_CASES = {"fit5": {}, "fit5_u": {"histogram_method": "u"}}
+
+
+def _record_parent():  # python -c "from tests.test_lightgbm_growth import _record_parent as r; r()"
+    out = {}
+    for case, kwargs in FIT_CASES.items():
+        booster = _fit_five(**kwargs)
+        out.update({f"{case}.{field}": np.asarray(getattr(booster, field))
+                    for field in FIT_STRUCTURE + ("split_gain",)})
+    for case, kwargs in PARENT_CASES.items():
+        tree = _grow_tree(**kwargs)[0]
+        out.update({f"{case}.{field}": getattr(tree, field) for field in STRUCTURE})
+    np.savez_compressed(PARENT_TREES, **out)
+
+
+@pytest.mark.parametrize("options,built,skipped,leaves", [
+    (dict(num_leaves=31, leaf_batch=8), 6, 1, 31),  # root + rounds of 1, 2, 4, 8, 8 | 7
+    (dict(num_leaves=31, leaf_batch=1), 30, 1, 31),  # root + 29 rounds | the 30th
+    (dict(num_leaves=31, leaf_batch=42), 5, 1, 31),  # k = 30: rounds of 1, 2, 4, 8 | 15
+    (dict(num_leaves=2), 1, 1, 2),  # the root's pass finds the one split there is budget for
+    (dict(num_leaves=31, leaf_batch=8, histogram_subtraction=False), 6, 1, 31),
+    (dict(num_leaves=31, leaf_batch=8, u_path=True), 6, 1, 31),
+    # a tree that ends for another reason cannot know it before it has looked: nothing skipped
+    (dict(num_leaves=31, leaf_batch=8, min_gain_to_split=60.0), None, 0, None),
+    (dict(num_leaves=31, leaf_batch=8, max_depth=3), 4, 0, 8),  # root + 3 levels, the last capped
+    (dict(num_leaves=31, leaf_batch=8, min_gain_to_split=1e9), 1, 0, 1),  # the root stays a leaf
+], ids=["batch8", "batch1", "batch42", "two_leaves", "no_subtraction", "u_path", "min_gain", "max_depth",
+        "no_split"])
+def test_a_tree_builds_a_pass_only_where_a_later_round_can_read_it(options, built, skipped, leaves):
+    tree = _grow_tree(**options)[0]
+    grown = int(tree.is_leaf.sum())
+    if built is None:  # stopped by min_gain_to_split part-way: a pass after every round that grew
+        assert 1 < grown < options["num_leaves"] and tree.passes[0] >= 2
+    else:
+        assert (int(tree.passes[0]), grown) == (built, leaves)
+    assert int(tree.passes[1]) == skipped
+    # the slots of a round whose pass was skipped are leaves like any other
+    assert int((~tree.is_leaf & (tree.left > 0)).sum()) == grown - 1 and tree.gain[tree.is_leaf].max() == 0.0
+
+
+@pytest.mark.parametrize("case", sorted(PARENT_CASES))
+def test_tree_structure_is_the_parent_commits_bit_for_bit(case):
+    tree = _grow_tree(**PARENT_CASES[case])[0]
+    with np.load(PARENT_TREES) as parent:
+        for field in STRUCTURE:
+            np.testing.assert_array_equal(getattr(tree, field), parent[f"{case}.{field}"], err_msg=field)
+
+
+@pytest.mark.parametrize("case", sorted(FIT_CASES))
+def test_five_trees_split_where_the_parent_commits_did(case):
+    """From the second tree on the margins carry the float32 rounding the leaf
+    values moved by, so a gain may differ in its last digits; no split does."""
+    booster = _fit_five(**FIT_CASES[case])
+    with np.load(PARENT_TREES) as parent:
+        for field in FIT_STRUCTURE:
+            np.testing.assert_array_equal(getattr(booster, field), parent[f"{case}.{field}"], err_msg=field)
+        np.testing.assert_array_equal(booster.split_gain[0], parent[f"{case}.split_gain"][0])
+        np.testing.assert_allclose(booster.split_gain, parent[f"{case}.split_gain"], rtol=1e-4)
+
+
+@pytest.mark.parametrize("u_path", [False, True], ids=["segment", "u"])
+@pytest.mark.parametrize("subtraction", [True, False], ids=["subtraction", "direct"])
+@pytest.mark.parametrize("kind", ["numeric", "categorical", "bundled"])
+def test_a_leafs_value_and_cover_are_sums_over_the_rows_routed_to_it(kind, subtraction, u_path):
+    """``cover`` counts ``row_leaf`` exactly; ``leaf_val`` is ``-lr G / (H + l2)`` over
+    those rows, ``l2 + cat_l2`` under a categorical split: the statistics a leaf
+    was given by the split that made it are its own."""
+    import jax.numpy as jnp
+
+    tree, grad, hess, opts = _grow_tree(
+        kind=kind, u_path=u_path, num_leaves=15, leaf_batch=4, lambda_l2=1.0,
+        histogram_subtraction=subtraction, max_bin=255 if kind == "bundled" else 63)
+    if u_path:  # the histogram's inputs are bfloat16 there
+        grad, hess = (np.asarray(jnp.asarray(x).astype(jnp.bfloat16).astype(jnp.float32)) for x in (grad, hess))
+    leaves = np.flatnonzero(tree.is_leaf)
+    assert len(leaves) == 15 and tree.passes.tolist() == [5, 1]  # root; rounds of 1, 2, 4, 4 | 3
+    np.testing.assert_array_equal(tree.cover[leaves], np.bincount(tree.row_leaf, minlength=len(tree.feat))[leaves])
+    parent = np.zeros(len(tree.feat), int)
+    for side in (tree.left, tree.right):
+        parent[side[~tree.is_leaf]] = np.flatnonzero(~tree.is_leaf)
+    if kind == "categorical":
+        assert tree.cat_node[parent[leaves]].any() and not tree.cat_node[parent[leaves]].all()
+    l2 = opts.lambda_l2 + opts.cat_l2 * tree.cat_node[parent[leaves]]
+    G = np.bincount(tree.row_leaf, weights=grad.astype(np.float64), minlength=len(tree.feat))[leaves]
+    H = np.bincount(tree.row_leaf, weights=hess.astype(np.float64), minlength=len(tree.feat))[leaves]
+    np.testing.assert_allclose(tree.leaf_val[leaves], -opts.learning_rate * G / (H + l2), rtol=1e-5, atol=1e-7)
+
+
+def _inner_jaxprs(eqn):
+    for value in eqn.params.values():
+        for item in value if isinstance(value, (tuple, list)) else (value,):
+            jaxpr = getattr(item, "jaxpr", item)
+            if hasattr(jaxpr, "eqns"):
+                yield jaxpr
+
+
+def _holds(eqn, found):
+    """Does the equation, or one inside a jaxpr it carries, satisfy ``found``?"""
+    return found(eqn) or any(_holds(e, found) for inner in _inner_jaxprs(eqn) for e in inner.eqns)
+
+
+@pytest.mark.parametrize("subtraction", [True, False], ids=["subtraction", "direct"])
+def test_a_round_begins_with_the_one_contraction_of_u_and_the_loop_leaves_after_the_routing(subtraction):
+    """The traced tree program on the U path: the root's contraction before the
+    loop; in the loop's body one contraction, ahead of the round's choice of
+    leaves (``top_k``) and so of its routing; none in the condition and none
+    after the loop. A round whose splits spend the budget is thus followed by
+    the exit, not by a pass."""
+    import jax
+
+    build, args, u, _, _, _ = _tree_program(
+        u_path=True, num_leaves=31, leaf_batch=8, histogram_subtraction=subtraction)
+    eqns = jax.make_jaxpr(lambda *a: build(*a[:-1], u=a[-1]))(*args, u).jaxpr.eqns
+
+    def streams_u(e):
+        return e.primitive.name == "dot_general" and e.invars[0].aval.shape == u.shape
+
+    def picks_leaves(e):
+        return e.primitive.name == "top_k"
+
+    (at, loop), = [(i, e) for i, e in enumerate(eqns) if e.primitive.name == "while"]
+    outside = [i for i, e in enumerate(eqns) if i != at and _holds(e, streams_u)]
+    assert len(outside) == 1 and outside[0] < at  # the root's
+    body = loop.params["body_jaxpr"].jaxpr.eqns
+    passes = [i for i, e in enumerate(body) if _holds(e, streams_u)]
+    rounds = [i for i, e in enumerate(body) if _holds(e, picks_leaves)]
+    assert len(passes) == 1 and len(rounds) == 1 and passes[0] < rounds[0]
+    assert not any(_holds(e, streams_u) for e in loop.params["cond_jaxpr"].jaxpr.eqns)
+
+
+@pytest.mark.parametrize("classes,objective", [(1, "binary"), (3, "multiclass")])
+def test_the_boosting_step_runs_the_passes_the_counter_counts(classes, objective):
+    """The step vmaps the grower over classes, a single class too, where a
+    conditional would run both its branches: the passes that RUN are counted
+    here by the gang's allreduce hook, a host callback inside every pass.
+    Two 15-leaf trees a class at ``leaf_batch`` 4: the root's pass and rounds
+    of 1, 2, 4, 4 | 3, five passes a tree and none after the last round."""
+    X, y = _make_binary(n=3000, seed=4)
+    if classes > 1:
+        y = (X[:, 0] > 0.4).astype(np.float64) + (X[:, 1] > 0.2)
+    bins, mapper = bin_dataset(X, max_bin=63)
+    ran = []
+
+    def allreduce(hist):
+        ran.append(hist.shape[:2])  # (classes, nodes of the pass)
+        return hist
+
+    opts = TrainOptions(objective=objective, num_class=classes, num_iterations=2, num_leaves=15,
+                        leaf_batch=4, max_bin=63)
+    booster = train(bins, y, opts, mapper=mapper, hist_reduce=allreduce).booster
+    assert (np.asarray(booster.is_leaf).sum(axis=1) == 15).all()
+    assert ran == [(classes, 1), (classes, 4), (classes, 4), (classes, 4), (classes, 4)] * 2

@@ -20,8 +20,12 @@ FIT_CHILDREN = {
     "lightgbm.u_build", "lightgbm.boost", "lightgbm.pack",
 }
 # sha256 of what the commit before the spans (de0f6de) gives for _fit_table()
-# and _image_table() below on this CPU backend: no span may change a result
-PARENT_MODEL_TEXT = "ace44a60238b57820e3e940867bec8e13b7d82f6c8420f61a1eeba16cdfe8792"
+# and _image_table() below on this CPU backend: no span may change a result.
+# The model text is that commit's (ace44a60...) with PR 36's leaf values: a leaf's
+# output comes from the split that made it, which moved `leaf_value` by under
+# 4e-6 relative and, from the second tree on, `split_gain` by under 3e-6; every
+# other line of the text is that commit's.
+PARENT_MODEL_TEXT = "6ee05eedbfadba9d01bbd31cc9665d6112cf328b05dda004d89912ccbb5c1c82"
 PARENT_FEATURES = "317ae1ac426cee2dcf3c7b0201dfccfaf66794287f70c9ffb5f7218d7fd44c9b"
 
 
@@ -129,7 +133,9 @@ def test_train_tags_are_shape_arithmetic(budget, chunks, monkeypatch):
     spec = make_u_spec(16, 4, [int(b) for b in mapper.num_bins])
     want = chunks * 512 * 4 if budget else u_bytes(1000, spec)  # chunked: the bins stack
     assert by_name["lightgbm.u_build"]["tags"] == {"chunks": chunks, "u_bytes": want}
-    assert by_name["lightgbm.boost"]["tags"] == {"iterations": 3, "segments": 1}
+    # a 7-leaf tree at leaf_batch 8: the root's pass, rounds of 1, 2 and 3 splits, no pass after the last
+    assert by_name["lightgbm.boost"]["tags"] == {
+        "iterations": 3, "segments": 1, "hist_passes_built": 3 * 3, "hist_passes_skipped": 3}
     assert by_name["lightgbm.pack"]["tags"] == {"trees": 3}
     assert by_name["lightgbm.program"]["tags"]["cache_hit"] in (True, False)
 
