@@ -36,6 +36,7 @@ FAMILIES = {
     "afmoe": ("mmlspark_tpu.models.afmoe", "afmoe_apply", "init_afmoe"),
     "joyai_llm_flash": ("mmlspark_tpu.models.mla_moe", "mla_moe_apply", "init_mla_moe"),
     "nemotron_h": ("mmlspark_tpu.models.nemotron_h", "nemotron_h_apply", "init_nemotron_h"),
+    "lfm2_moe": ("mmlspark_tpu.models.lfm2_moe", "lfm2_moe_apply", "init_lfm2_moe"),
 }
 _DEFAULT = "afmoe"
 _MODULES = ", ".join(f"'{model_type}': {module}" for model_type, (module, _, _) in FAMILIES.items())

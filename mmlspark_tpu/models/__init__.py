@@ -7,6 +7,7 @@ weights are produced by training or loaded from checkpoints via
 """
 
 from mmlspark_tpu.models.afmoe import afmoe_apply, init_afmoe
+from mmlspark_tpu.models.lfm2_moe import init_lfm2_moe, lfm2_moe_apply
 from mmlspark_tpu.models.mla_moe import init_mla_moe, mla_moe_apply
 from mmlspark_tpu.models.nemotron_h import init_nemotron_h, nemotron_h_apply
 from mmlspark_tpu.models.resnet import init_resnet, resnet_apply
@@ -20,7 +21,7 @@ from mmlspark_tpu.models.zoo import (
 
 __all__ = [
     "init_resnet", "resnet_apply", "init_afmoe", "afmoe_apply", "init_mla_moe", "mla_moe_apply",
-    "init_nemotron_h", "nemotron_h_apply",
+    "init_nemotron_h", "nemotron_h_apply", "init_lfm2_moe", "lfm2_moe_apply",
     "publish_model", "load_zoo_params",
     "params_to_bytes", "params_from_bytes", "train_resnet_classifier",
 ]

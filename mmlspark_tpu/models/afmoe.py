@@ -41,6 +41,7 @@ from mmlspark_tpu.models.moe_decoder import (
     init_decoder,
     last_position,
     norm,
+    rope,
     routed_experts,
     swiglu,
 )
@@ -90,16 +91,6 @@ def init_afmoe(key, config: Dict[str, Any]):
                         (_layer_shapes(config, False), len(moe)))
 
 
-def _rope(x, theta):
-    """x: (rows, S, heads, head), float32. Pairs are (i, i + head/2)."""
-    half = x.shape[-1] // 2
-    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    angle = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * freq
-    cos, sin = jnp.cos(angle)[None, :, None, :], jnp.sin(angle)[None, :, None, :]
-    a, b = x[..., :half], x[..., half:]
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
-
-
 def _attention(p, x, sliding, c, dt):
     """x: (rows, S, hidden), normalised. ``sliding`` is a traced bool."""
     B, S, _ = x.shape
@@ -112,7 +103,7 @@ def _attention(p, x, sliding, c, dt):
 
     def window_layer(q, k, v):
         with jax.named_scope("attn_window"):
-            q, k = (_rope(a, c["rope_theta"]).astype(jnp.bfloat16) for a in (q, k))
+            q, k = (rope(a, c["rope_theta"]).astype(jnp.bfloat16) for a in (q, k))
             return blocked_attention(q, k, v, window=c["sliding_window"], interpret=interpret)
 
     def full_layer(q, k, v):

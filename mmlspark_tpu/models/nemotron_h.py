@@ -64,7 +64,15 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from mmlspark_tpu.models.moe_decoder import dot, init_stacks, last_position, norm, relu2, routed_experts
+from mmlspark_tpu.models.moe_decoder import (
+    causal_conv,
+    dot,
+    init_stacks,
+    last_position,
+    norm,
+    relu2,
+    routed_experts,
+)
 from mmlspark_tpu.ops.attention import blocked_attention
 from mmlspark_tpu.ops.ssd import ssd_scan
 
@@ -160,14 +168,11 @@ def _mixer(p, x, c, dt):
     """x: (rows, S, hidden), normalised."""
     B, S, _ = x.shape
     H, P, G, N = c["mamba_num_heads"], c["mamba_head_dim"], c["n_groups"], c["ssm_state_size"]
-    inner, K = H * P, c["conv_kernel"]
+    inner = H * P
     mixed = dot(x, p["in_proj"], dt)  # [z | xBC | dt]
     z = mixed[..., :inner].astype(jnp.bfloat16)
     with jax.named_scope("ssm_conv"):
-        ahead = jnp.pad(mixed[..., inner:-H], ((0, 0), (K - 1, 0), (0, 0)))  # zeros in front of a row
-        taps = p["conv_w"].astype(jnp.float32)
-        conv = sum(ahead[:, j:j + S] * taps[:, j] for j in range(K)) + p["conv_b"].astype(jnp.float32)
-        xbc = jax.nn.silu(conv).astype(jnp.bfloat16)
+        xbc = jax.nn.silu(causal_conv(mixed[..., inner:-H], p["conv_w"], p["conv_b"])).astype(jnp.bfloat16)
     with jax.named_scope("ssm_scan"):
         step = jax.nn.softplus(mixed[..., -H:] + p["dt_bias"])
         y = ssd_scan(

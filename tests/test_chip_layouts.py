@@ -277,6 +277,62 @@ def test_the_hybrid_decoder_at_the_published_widths_fits_beside_its_weights(one_
         assert a_units_matrix  # the copies, rounded
 
 
+def test_blocked_attention_at_a_key_head_of_64_lowers_as_written(one_chip):
+    """One dispatch of the short-convolution cell's attention layer: 4 rows of
+    8,192 tokens, 32 query heads over 8 key/value heads of **64**. A key/value
+    block is half a lane tile wide, the score product contracts over 64 and
+    the value product writes 64 lanes; Mosaic lowers ``q_ref[...].reshape(G *
+    bq, 64)`` and the ``(padded, 64)`` whole-sequence blocks as written (a
+    ``tpu_custom_call``), with only the transposed copies of its operands
+    beside it (PERF.md, PR 37)."""
+    import jax
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.ops.attention import blocked_attention
+
+    q = jax.ShapeDtypeStruct((4, 8192, 32, 64), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((4, 8192, 8, 64), jnp.bfloat16, sharding=one_chip)
+    compiled = _compile_off(lambda q, k, v: blocked_attention(q, k, v), q, kv, kv)
+    memory = compiled.memory_analysis()
+    assert memory.output_size_in_bytes == 4 * 8192 * 32 * 64 * 2
+    assert memory.temp_size_in_bytes < 2**30 and "tpu_custom_call" in compiled.as_text()
+
+
+def test_the_short_convolution_decoder_at_the_published_widths_fits_beside_its_weights(one_chip):
+    """The whole program of ``lfm2-8b-a1b.score-8k``, one dispatch of 4 x
+    8,192 tokens over 16 layers: 10.06 GiB of weights (the tied head held
+    once) leave 5.7 GiB of a v5e's 15.75 for temporaries, which
+    ``memory_stats`` on the chip does not count, so the compiler is the one
+    that can say (3.17 GiB, 13.23 in all: PERF.md, PR 37). The attention
+    kernel is one HLO name (the dense layers hold none, so only the expert
+    scan calls it, under its conditional) and the three grouped expert
+    products one each, which the two roofline metrics read by."""
+    import json
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.models.lfm2_moe import init_lfm2_moe, lfm2_moe_apply
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs", "lfm2-8b-a1b.json")) as f:
+        config = json.load(f)["params"]
+    tree = jax.eval_shape(lambda k: init_lfm2_moe(k, config), jax.random.PRNGKey(0))
+    assert "head" not in tree and tree["moe"]["e_up"].shape == (14, 32, 2048, 1792)
+    assert tree["conv"]["in_proj"].shape == (12, 2048, 6144) and tree["attention"]["wk"].shape == (4, 2048, 512)
+    tree = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+    tokens = jax.ShapeDtypeStruct((4, 8192), jnp.int32, sharding=one_chip)
+    compiled = _compile_off(lambda p, x: lfm2_moe_apply(p, x, config), tree, tokens)
+    memory = compiled.memory_analysis()
+    assert 10_798_258_944 <= memory.argument_size_in_bytes < 10_798_258_944 + 2**20
+    assert memory.temp_size_in_bytes < 3.6 * 2**30
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes + memory.output_size_in_bytes < 13.7 * 2**30
+    text = compiled.as_text()
+    assert len(set(re.findall(r"%(attn_full[\w.\-]*) =", text))) == 1
+    assert len(set(re.findall(r"%(ragged-dot-none[\w.\-]*) =", text))) == 3
+
+
 def test_the_leafwise_tree_streams_u_at_the_head_of_a_round_and_leaves_after_the_routing(one_chip):
     """The fit cell's tree program (1,000,000 x 28 rows of 256 bins, 31 leaves,
     the resident int8 one-hot ``U`` of 7,168 x 1,000,448): the program holds two

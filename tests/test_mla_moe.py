@@ -104,7 +104,7 @@ def test_narrower_product_inputs_are_a_different_result(params):
 def test_the_family_is_read_from_model_type_and_afmoe_is_the_default(params):
     from mmlspark_tpu.models import init_afmoe
 
-    assert set(FAMILIES) == {"afmoe", "joyai_llm_flash", "nemotron_h"}
+    assert set(FAMILIES) >= {"afmoe", "joyai_llm_flash", "nemotron_h"}
     afmoe = dict(
         hidden_size=32, num_attention_heads=2, num_key_value_heads=1, head_dim=16,
         intermediate_size=48, moe_intermediate_size=16, num_experts=4, num_experts_per_tok=2,
@@ -236,10 +236,12 @@ def test_blocked_attention_refuses_keys_and_values_of_other_lengths_or_heads():
 
 # -- the routing bias ---------------------------------------------------------
 
-def test_experts_are_chosen_by_biased_scores_and_weighed_by_unbiased_ones():
+@pytest.mark.parametrize("shared", [True, False], ids=["one_shared_expert", "no_shared_expert"])
+def test_experts_are_chosen_by_biased_scores_and_weighed_by_unbiased_ones(shared):
     """16 experts, top-2. The bias makes expert 3 every token's first choice
     and keeps experts 10-15 empty; the weights must still be the plain
-    sigmoid scores over their sum times the scale."""
+    sigmoid scores over their sum times the scale. A tree that holds no
+    ``s_*`` leaves gives the routed output alone."""
     rng = np.random.default_rng(0)
     S, D, F, E, k, scale = 24, 16, 8, 16, 2, 2.5
     p = {"router": rng.normal(size=(D, E)) / 4, "router_bias": np.zeros(E),
@@ -248,7 +250,7 @@ def test_experts_are_chosen_by_biased_scores_and_weighed_by_unbiased_ones():
          "s_gate": rng.normal(size=(D, F)) / 4, "s_up": rng.normal(size=(D, F)) / 4,
          "s_down": rng.normal(size=(F, D)) / 3}
     p["router_bias"][3], p["router_bias"][10:] = 10.0, -10.0
-    p = {n: jnp.asarray(a, jnp.float32) for n, a in p.items()}
+    p = {n: jnp.asarray(a, jnp.float32) for n, a in p.items() if shared or not n.startswith("s_")}
     x = jnp.asarray(rng.normal(size=(1, S, D)), jnp.bfloat16)
     y, load = jax.jit(lambda p, x: routed_experts(p, x, k, scale, jnp.bfloat16))(p, x)
     load = np.asarray(load)[0]
@@ -260,7 +262,7 @@ def test_experts_are_chosen_by_biased_scores_and_weighed_by_unbiased_ones():
     xs = bf(x[0])
     scores = 1 / (1 + np.exp(-(xs @ bf(p["router"]))))
     biased = scores + np.asarray(p["router_bias"], np.float64)
-    want = swiglu(xs, p["s_gate"], p["s_up"], p["s_down"])
+    want = swiglu(xs, p["s_gate"], p["s_up"], p["s_down"]) if shared else np.zeros((S, D))
     for t in range(S):
         chosen = np.argsort(-biased[t])[:k]
         assert chosen[0] == 3

@@ -245,7 +245,7 @@ def test_attention_blocks_are_held_where_the_pattern_has_them_and_looked_up_by_i
 # -- the family table, the spans, the scopes ----------------------------------
 
 def test_the_stage_documents_itself_from_the_family_table(params):
-    assert set(FAMILIES) == {"afmoe", "joyai_llm_flash", "nemotron_h"}
+    assert set(FAMILIES) >= {"afmoe", "joyai_llm_flash", "nemotron_h"}
     described = [LMFeaturizer.__doc__, LMFeaturizer._param_specs["modelConfig"].doc]
     for model_type, (module, apply, init) in FAMILIES.items():
         assert all(f"'{model_type}': {module}" in text for text in described)
@@ -254,7 +254,7 @@ def test_the_stage_documents_itself_from_the_family_table(params):
         assert callable(getattr(family, apply)) and callable(getattr(family, init)) and callable(family.span_tags)
     with pytest.raises(ValueError, match="mmlspark_tpu.models.nemotron_h.init_nemotron_h"):
         LMFeaturizer().transform(Table({"tokens": _tokens(0)}))
-    with pytest.raises(ValueError, match="'gpt': one of .'afmoe', 'joyai_llm_flash', 'nemotron_h'"):
+    with pytest.raises(ValueError, match="'gpt': one of .'afmoe', 'joyai_llm_flash', .*'nemotron_h'"):
         LMFeaturizer(modelParams=params, modelConfig=dict(SMALL, model_type="gpt")).transform(
             Table({"tokens": _tokens(0)}))
 

@@ -852,8 +852,8 @@ mt_value_indexer_model <- function(...) {
 #' \itemize{
 #'   \item \code{batchSize}: Rows per device batch (default 4)
 #'   \item \code{inputCol}: Column of token-id rows, all of one length (default 'tokens')
-#'   \item \code{modelConfig}: Decoder configuration: the family's published config.json keys and 'layers'; 'model_type' names the family ('afmoe' where absent; 'afmoe': mmlspark_tpu.models.afmoe, 'joyai_llm_flash': mmlspark_tpu.models.mla_moe, 'nemotron_h': mmlspark_tpu.models.nemotron_h) (default None)
-#'   \item \code{modelParams}: Decoder parameter pytree (the family's init_* format: mmlspark_tpu.models.afmoe.init_afmoe, mmlspark_tpu.models.mla_moe.init_mla_moe, mmlspark_tpu.models.nemotron_h.init_nemotron_h) (default None)
+#'   \item \code{modelConfig}: Decoder configuration: the family's published config.json keys and 'layers'; 'model_type' names the family ('afmoe' where absent; 'afmoe': mmlspark_tpu.models.afmoe, 'joyai_llm_flash': mmlspark_tpu.models.mla_moe, 'nemotron_h': mmlspark_tpu.models.nemotron_h, 'lfm2_moe': mmlspark_tpu.models.lfm2_moe) (default None)
+#'   \item \code{modelParams}: Decoder parameter pytree (the family's init_* format: mmlspark_tpu.models.afmoe.init_afmoe, mmlspark_tpu.models.mla_moe.init_mla_moe, mmlspark_tpu.models.nemotron_h.init_nemotron_h, mmlspark_tpu.models.lfm2_moe.init_lfm2_moe) (default None)
 #'   \item \code{outputCols}: model output ('hidden': last position after the final norm, 'logits': last-position logits, 'expert_load': tokens of the row each expert received, per expert layer) -> output column (default {'hidden': 'features'})
 #' }
 #' @param ... named params forwarded to the Python constructor
