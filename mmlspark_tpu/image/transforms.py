@@ -27,10 +27,6 @@ from mmlspark_tpu.data.table import Table
 from mmlspark_tpu.observability.tracing import get_tracer
 
 
-def _ensure_nhwc(batch: Any) -> Any:
-    return batch if batch.ndim == 4 else batch[..., None]
-
-
 def _op_resize(stage: Dict[str, Any]) -> Callable:
     import jax.image
 
@@ -164,6 +160,8 @@ _OPS: Dict[str, Callable[[Dict[str, Any]], Callable]] = {
 def _build_pipeline(stage_list: List[Dict[str, Any]]):
     """What :meth:`ImageTransformer._pipeline` caches for one stage list."""
     import jax
+    import jax.numpy as jnp
+    from jax import lax
 
     ops = []
     # an op reads its dict when it is traced, and a later trace (another
@@ -181,10 +179,55 @@ def _build_pipeline(stage_list: List[Dict[str, Any]]):
         return x
 
     @functools.partial(jax.jit, static_argnums=1)
-    def run(flat, shape):
-        return stages(flat.reshape(shape)).reshape(shape[0], -1)
+    def run(slabs, shape):
+        # every op is an image's own, so a slab is staged by itself and its
+        # result written where its rows lie in the group's. (Joined first,
+        # the uint8 slabs are a second copy of the table on the device: the
+        # TPU compiler moves the cast behind a concatenation wherever it is
+        # written.)
+        parts = [stages(s.reshape((s.shape[0],) + shape[1:])).reshape(s.shape[0], -1)
+                 for s in slabs]
+        flat = parts[0]
+        if len(parts) > 1:
+            flat, lo = jnp.zeros((shape[0], flat.shape[1]), flat.dtype), 0
+            for part in parts:
+                flat = lax.dynamic_update_slice(flat, part, (lo, 0))
+                lo += part.shape[0]
+        return flat
 
     return stages, run, {}
+
+
+# A shape group of more bytes than this reaches the device a slab at a time
+# (``ImageTransformer._staged``). On the chip's host the first write to a
+# fresh page costs ten times the copy (6,144 rows of 224 x 224 x 3 stacked
+# into a fresh 0.92 GB batch: 0.97-1.10 s; into pages written before: 0.088),
+# so a large group is stacked through two buffers of this size, filled in
+# turn. The wire takes a slab at 5.4-5.6 GB/s, half the rate the host stacks
+# at, so what the stage costs is the upload: 0.14-0.15 s a job of that table
+# at 96 MiB a slab (640 such rows; the pair is 0.2 GB of fresh pages a
+# call), where one batch took 0.96 s and went up afterwards (PERF.md, PR 38).
+_SLAB_BYTES = 96 << 20
+
+
+def _slab_rows(row_bytes: int) -> int:
+    """How many rows of ``row_bytes`` a slab holds: whole tiles of 8 where it
+    holds more than one. The device keeps rows in tiles of 8, and a slab
+    that ends on one is written into the group's result in place; one that
+    ends inside one goes through a temporary of its own size
+    (``tests/test_chip_layouts.py``)."""
+    rows = max(1, _SLAB_BYTES // max(1, row_bytes))
+    return rows - rows % 8 if rows > 8 else rows
+
+
+def _host_is_device() -> bool:
+    """True on the CPU backend: it takes an aligned host buffer as the
+    array's own memory, so nothing is transferred and the array reads the
+    buffer for as long as it lives. A buffer handed over there is never
+    filled again."""
+    import jax
+
+    return jax.default_backend() == "cpu"
 
 
 class ImageTransformer(HasInputCol, HasOutputCol, Transformer):
@@ -240,7 +283,8 @@ class ImageTransformer(HasInputCol, HasOutputCol, Transformer):
         """``(stages, run, shapes)``, built once a process for a stage list's
         content (``core.device.cached_program``; a fresh list of equal dicts
         finds it): the stages as one NHWC -> NHWC function, the jitted
-        program over one shape group, ``run(flat, shape) -> flat result``,
+        program over one shape group, ``run(slabs, shape) -> flat result``
+        (``slabs``: the group's rows in order, cut into one array or more),
         and the result shape ``stages`` gave each batch shape seen so far
         (so ``jax.eval_shape`` traces them once a shape, not once a call).
         The program takes and returns ``(rows, H * W * C)`` and reshapes to
@@ -263,8 +307,20 @@ class ImageTransformer(HasInputCol, HasOutputCol, Transformer):
         (the group's row indices, the shape its result has as a column,
         ``(rows, H, W, C)`` or ``(rows, H, W)`` for gray rows, and the
         result itself as the program returned it, ``(rows, H*W*C)`` float32).
+
+        The rows reach the device a slab of ``_SLAB_BYTES`` at a time: a
+        group of at most one slab is one ``np.stack`` into a fresh batch and
+        one upload, as it always was; a larger one is stacked slab by slab
+        into two staging buffers that the call owns and fills in turn
+        (``image.stack`` a slab), each slab's upload (``image.apply_fetch``,
+        ``bytes_down`` 0) running while the next is stacked, and the stage
+        program is handed all of them. ``whole`` counts ``slabs`` and, of
+        those, the ones stacked into a buffer an earlier slab of the call
+        had used (``staging_reused``: all but two of a group's; none on the
+        CPU backend, :func:`_host_is_device`). The group's last
+        ``image.apply_fetch`` also holds the stage program's enqueue.
         With ``fetch`` the result is brought to the host inside
-        ``image.apply_fetch``, which then owns the wait on the device;
+        that span, which then owns the wait on the device;
         without, it stays the ``jax.Array`` the program returned, the span
         holds the upload's and the program's enqueue and ``bytes_down`` is 0:
         whoever reads the array first waits (``ImageFeaturizer`` hands it to
@@ -283,18 +339,50 @@ class ImageTransformer(HasInputCol, HasOutputCol, Transformer):
         for i, im in enumerate(images):
             by_shape.setdefault(im.shape, []).append(i)
         whole.tags["groups"] = len(by_shape)
+        refill = not _host_is_device()
+        whole.tags["slabs"] = whole.tags["staging_reused"] = 0
         for shape, idxs in by_shape.items():
-            with tracer.span("image.stack") as sp:
-                batch = _ensure_nhwc(np.stack([images[i] for i in idxs]))
-                sp.tags["bytes"] = batch.nbytes
-            with tracer.span("image.apply_fetch", bytes_up=batch.nbytes, bytes_down=0) as sp:
-                out_shape = shapes.get(batch.shape)
-                if out_shape is None:
-                    out_shape = shapes[batch.shape] = jax.eval_shape(stages, batch).shape
-                flat = run(batch.reshape(len(idxs), -1), batch.shape)
-                if fetch:
-                    flat = np.asarray(jax.device_get(flat))
-                    sp.tags["bytes_down"] = flat.nbytes
+            n, lo = len(idxs), 0
+            # NHWC; gray rows come without a channel axis
+            batch_shape = (n,) + shape if len(shape) == 3 else (n,) + shape + (1,)
+            staging: List[np.ndarray] = []
+            slabs: List[Any] = []
+            while lo < n:
+                with tracer.span("image.stack") as sp:
+                    if not slabs:  # what one np.stack of the group works out
+                        rows = [images[i] for i in idxs]
+                        dtype = np.result_type(*{row.dtype for row in rows})
+                        per_slab = _slab_rows(rows[0].size * dtype.itemsize)
+                    hi = min(lo + per_slab, n)
+                    if len(slabs) < 2 or not refill:
+                        staging.append(np.empty((hi - lo,) + shape, dtype))
+                        buf = staging[-1]
+                    else:  # the slab before the last is up (waited for below): its buffer again
+                        buf = staging[len(slabs) % 2][: hi - lo]
+                        whole.tags["staging_reused"] += 1
+                    np.stack(rows[lo:hi], out=buf)
+                    sp.tags["bytes"] = buf.nbytes
+                with tracer.span("image.apply_fetch", bytes_up=buf.nbytes, bytes_down=0) as sp:
+                    slabs.append(jax.device_put(buf.reshape(hi - lo, -1)))
+                    if hi == n:
+                        out_shape = shapes.get(batch_shape)
+                        if out_shape is None:
+                            out_shape = shapes[batch_shape] = jax.eval_shape(
+                                stages, jax.ShapeDtypeStruct(batch_shape, dtype)).shape
+                        flat = run(tuple(slabs), batch_shape)
+                        if fetch:
+                            flat = np.asarray(jax.device_get(flat))
+                            sp.tags["bytes_down"] = flat.nbytes
+                    elif refill and len(slabs) > 1:
+                        # this slab goes up while the next is stacked into
+                        # the buffer the slab before this one came from, and
+                        # the runtime reads a host buffer until its transfer
+                        # is complete: that one has had a whole slab's
+                        # stacking, so this waits only where the wire is
+                        # slower than the stacking
+                        slabs[-2].block_until_ready()
+                lo = hi
+            whole.tags["slabs"] += len(slabs)
             if out_shape[-1] == 1 and len(shape) == 2:
                 out_shape = out_shape[:-1]  # gray rows came without a channel axis
             yield idxs, out_shape, flat
@@ -311,10 +399,11 @@ class ImageTransformer(HasInputCol, HasOutputCol, Transformer):
     def transform(self, table: Table) -> Table:
         """Spans (``observability/tracing``): ``image.transform`` around the
         whole stage (``programs_built``: 1 where this call had to build the
-        stage program, 0 where an earlier call had); per shape group
-        ``image.stack`` (rows to one host
-        batch), ``image.apply_fetch`` (upload, the stage program, download:
-        it owns the wait on the device) and ``image.assemble`` (the fetch
+        stage program, 0 where an earlier call had; ``slabs`` and
+        ``staging_reused``: :meth:`_staged`); per shape group, once a slab,
+        ``image.stack`` (rows to one host batch) and ``image.apply_fetch``
+        (upload, and in the group's last the stage program and the download:
+        it owns the wait on the device), then ``image.assemble`` (the fetch
         as NHWC, clip/round of the uint8 path, gray squeeze); one more
         ``image.assemble`` around the output column (``_image_column``).
         An ``image.assemble``'s ``bytes`` is what it copied. Byte tags come

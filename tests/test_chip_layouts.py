@@ -37,13 +37,52 @@ def test_resize_program_crosses_the_host_boundary_in_row_order(one_chip, side_in
     shape = (512, side_in, side_in, 3)
     _, run, _ = ImageTransformer(toFloat=True).resize(224, 224)._pipeline()
     flat = jax.ShapeDtypeStruct((shape[0], int(np.prod(shape[1:]))), np.uint8, sharding=one_chip)
-    compiled = run.lower(flat, shape).compile()
-    (taken,), _ = compiled.input_formats
+    compiled = run.lower((flat,), shape).compile()
+    ((taken,),), _ = compiled.input_formats
     assert taken.layout.major_to_minor == (0, 1)
     assert compiled.output_formats.layout.major_to_minor == (0, 1)
     memory = compiled.memory_analysis()
     assert memory.output_size_in_bytes == shape[0] * 224 * 224 * 3 * 4
     assert (memory.temp_size_in_bytes > 0) == temporaries
+
+
+@pytest.mark.parametrize("slab,side_in,temporaries", [
+    (512, 224, 0), (664, 224, 0), (661, 224, 661), (512, 256, 512)],
+    ids=["the_cells_twelve", "a_short_last_slab", "slabs_that_end_inside_a_tile", "resized"])
+def test_stage_program_over_slabs_holds_no_second_copy_of_the_table(one_chip, slab, side_in, temporaries):
+    """A shape group of 6,144 images reaches the stage program as uint8
+    slabs (PERF.md, PR 38). Each is staged by itself and written where its
+    rows lie in the group's result: no joined uint8 table (0.86 GiB where
+    the slabs are concatenated first: the TPU compiler moves the cast behind
+    a concatenation wherever it is written), and what comes back is what the
+    one-batch program returned, ``(6144, 150528)`` float32 row-major. A slab
+    ends on a tile of 8 rows (``_staged`` cuts them so; the group's end may
+    not): one that ends inside a tile is cast into a temporary of its own
+    size first. Where a stage changes the shape its temporaries are a
+    slab's, not the table's."""
+    import jax
+
+    from mmlspark_tpu.image import ImageTransformer
+
+    rows = 6144 if side_in == 224 else 2048
+    shape = (rows, side_in, side_in, 3)
+    _, run, _ = ImageTransformer(toFloat=True).resize(224, 224)._pipeline()
+    slabs = tuple(
+        jax.ShapeDtypeStruct((min(slab, rows - lo), int(np.prod(shape[1:]))), np.uint8, sharding=one_chip)
+        for lo in range(0, rows, slab))
+    compiled = run.lower(slabs, shape).compile()
+    (taken,), _ = compiled.input_formats
+    assert len(taken) == -(-rows // slab) and {t.layout.major_to_minor for t in taken} == {(0, 1)}
+    assert compiled.output_formats.layout.major_to_minor == (0, 1)
+    memory = compiled.memory_analysis()
+    assert memory.output_size_in_bytes == rows * 224 * 224 * 3 * 4
+    one = temporaries * 224 * 224 * 3 * 4  # a slab's result
+    if not temporaries:
+        assert memory.temp_size_in_bytes == 0
+    elif side_in == 224:  # one slab's cast, never the uint8 table joined (924,844,032 B)
+        assert one <= memory.temp_size_in_bytes < 1.25 * one
+    else:  # the one-batch program over the same 2,048 images holds 2.7 GiB
+        assert one <= memory.temp_size_in_bytes < 1.75 * 2**30
 
 
 @pytest.mark.parametrize("rows", [512, 300], ids=["full_batch", "short_last_batch"])

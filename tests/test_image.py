@@ -558,3 +558,171 @@ def test_device_groups_are_the_stage_programs_own_arrays():
         assert isinstance(flat, jax.Array) and flat.dtype == np.float32
         assert flat.shape == (2, int(np.prod(shape[1:])))
         np.testing.assert_array_equal(np.asarray(flat).reshape(shape), want)
+
+
+# -- a shape group of more than a slab's bytes goes up slab by slab -------------
+
+_SLAB_TABLES = {
+    "three_channels": [(7, 5, 3)] * 11,
+    "gray": [(7, 5)] * 11,
+    "two_shapes": [(7, 5, 3), (4, 6, 3), (7, 5, 3)] * 5 + [(7, 5, 3)],
+}
+
+
+class _LateSlab:
+    """What ``jax.device_put`` returns from a runtime with memory of its own,
+    at its slowest: the host buffer is read as late as it may be, when the
+    array is waited for or first used, and until then it is not the
+    caller's to write."""
+
+    def __init__(self, view, honours_waits=True):
+        self.view, self.array, self.honours_waits = view, None, honours_waits
+
+    def settle(self):
+        import jax
+
+        if self.array is None:
+            self.array = jax.numpy.asarray(np.array(self.view))
+        return self.array
+
+    def block_until_ready(self):
+        if self.honours_waits:
+            self.settle()
+        return self
+
+
+def _register_late_slab():
+    import jax
+
+    try:
+        jax.tree_util.register_pytree_node(
+            _LateSlab, lambda slab: ((slab.settle(),), None), lambda _, leaves: leaves[0])
+    except ValueError:  # registered by an earlier test of this process
+        pass
+
+
+@pytest.fixture()
+def slabs_of_four_rows(monkeypatch):
+    """Slabs of four 7 x 5 x 3 uint8 rows (five of 4 x 6 x 3, twelve gray)."""
+    from mmlspark_tpu.image import transforms
+
+    monkeypatch.setattr(transforms, "_SLAB_BYTES", 4 * 7 * 5 * 3)
+
+
+@pytest.fixture()
+def a_runtime_that_reads_late(monkeypatch):
+    """The chip's side of the protocol on the CPU: a device with memory of
+    its own (staging buffers are filled again) whose uploads read late."""
+    import jax
+
+    from mmlspark_tpu.image import transforms
+
+    _register_late_slab()
+    monkeypatch.setattr(transforms, "_host_is_device", lambda: False)
+    monkeypatch.setattr(jax, "device_put", _LateSlab)
+
+
+def _staged_results(stage, table, fetch):
+    if fetch:
+        return [np.asarray(row) for row in stage.transform(table)["out"]]
+    return [(idxs, shape, np.asarray(flat)) for idxs, shape, flat in stage._device_groups(table)]
+
+
+def _slab_stage(to_float=True):
+    return ImageTransformer(inputCol="image", outputCol="out", toFloat=to_float).resize(6, 5).flip(1)
+
+
+def _assert_same(got, want):
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        if isinstance(g, tuple):
+            assert g[:2] == w[:2]
+            g, w = g[2], w[2]
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(g, w)
+
+
+@pytest.mark.parametrize("runtime", ["the_cpu_backend", "a_runtime_that_reads_late"])
+@pytest.mark.parametrize("fetch", [True, False], ids=["fetched", "left_on_the_device"])
+@pytest.mark.parametrize("kind", sorted(_SLAB_TABLES))
+def test_slabs_give_what_one_stack_and_one_upload_gave(kind, fetch, runtime, request):
+    """Several slabs a group, the last one short (11 rows in slabs of 4, the
+    gray rows' 11 in slabs of 8, a second shape's 5 in one of 5): bit for bit
+    the result of the one-slab path, which is ``np.stack`` and one upload as
+    it always was."""
+    table = _handover_table(_SLAB_TABLES[kind])
+    want = _staged_results(_slab_stage(), table, fetch)
+    request.getfixturevalue("slabs_of_four_rows")
+    if runtime != "the_cpu_backend":
+        request.getfixturevalue(runtime)
+    _assert_same(_staged_results(_slab_stage(), table, fetch), want)
+
+
+def test_slabs_round_and_clip_to_uint8_as_one_batch_did(slabs_of_four_rows, monkeypatch):
+    from mmlspark_tpu.image import transforms
+
+    table = _handover_table(_SLAB_TABLES["three_channels"])
+    got = _staged_results(_slab_stage(to_float=False), table, True)
+    monkeypatch.setattr(transforms, "_SLAB_BYTES", 1 << 20)
+    _assert_same(got, _staged_results(_slab_stage(to_float=False), table, True))
+    assert got[0].dtype == np.uint8
+
+
+def _slab_tags(table):
+    from mmlspark_tpu.observability.tracing import get_tracer
+
+    tracer = get_tracer()
+    tracer.clear()
+    _slab_stage()._device_groups(table)
+    (tags,) = [s["tags"] for s in tracer.export() if s["name"] == "image.transform"]
+    return tags["slabs"], tags["staging_reused"]
+
+
+@pytest.mark.parametrize("rows,slabs", [(3, 1), (4, 1), (5, 2), (8, 2), (9, 3), (11, 3), (23, 6)])
+def test_a_call_counts_its_slabs_and_the_buffers_it_filled_again(
+        rows, slabs, slabs_of_four_rows, a_runtime_that_reads_late):
+    """Two staging buffers a group however many slabs: every slab after the
+    second is stacked where an earlier one was."""
+    assert _slab_tags(_handover_table([(7, 5, 3)] * rows)) == (slabs, max(0, slabs - 2))
+
+
+def test_every_shape_group_has_a_staging_pair_of_its_own(slabs_of_four_rows, a_runtime_that_reads_late):
+    # 11 rows in slabs of 4 and 5 rows of another shape in one slab of 5
+    assert _slab_tags(_handover_table(_SLAB_TABLES["two_shapes"])) == (3 + 1, 1)
+
+
+def test_the_cpu_backend_is_handed_every_buffer_for_good(slabs_of_four_rows):
+    """It takes an aligned host buffer as the array's own memory, so there
+    no buffer is filled twice (the results above are right either way)."""
+    assert _slab_tags(_handover_table([(7, 5, 3)] * 11)) == (3, 0)
+
+
+def test_a_slab_holds_whole_tiles_of_eight_rows(monkeypatch):
+    from mmlspark_tpu.image import transforms
+
+    monkeypatch.setattr(transforms, "_SLAB_BYTES", 21 * 7 * 5 * 3)
+    assert _slab_tags(_handover_table([(7, 5, 3)] * 33)) == (3, 0)  # 16, 16, 1: not 21, 12
+
+
+@pytest.mark.parametrize("honours_waits", [True, False], ids=["waited_for", "not_waited_for"])
+def test_no_staging_buffer_is_written_while_its_upload_may_be_read(
+        honours_waits, slabs_of_four_rows, monkeypatch):
+    """The wait in front of a refill is what keeps a slab's rows: an upload
+    that reads its buffer only when the stage program is handed the slabs
+    (a wait that settled nothing) finds the rows of a later slab there."""
+    import functools
+
+    import jax
+
+    from mmlspark_tpu.image import transforms
+
+    table = _handover_table([(7, 5, 3)] * 19)
+    want = np.stack(list(table["image"]))[:, :, ::-1].astype(np.float32).reshape(19, -1)
+    _register_late_slab()
+    monkeypatch.setattr(transforms, "_host_is_device", lambda: False)
+    monkeypatch.setattr(jax, "device_put", functools.partial(_LateSlab, honours_waits=honours_waits))
+    flip = ImageTransformer(inputCol="image", outputCol="out", toFloat=True).flip(1)
+    ((_, _, got),) = _staged_results(flip, table, False)
+    assert np.array_equal(got, want) == honours_waits
+    # the last two slabs are never refilled, so their rows are right whatever the wait does
+    np.testing.assert_array_equal(got[12:], want[12:])

@@ -198,7 +198,8 @@ def test_featurize_records_three_spans_a_batch(images, batch, resize):
     if resize:
         (stage,) = tags("image.transform")
         assert stage.pop("programs_built") in (0, 1)
-        assert stage == {"rows": images, "groups": 1}
+        # under a slab's bytes: one slab, stacked once and uploaded once
+        assert stage == {"rows": images, "groups": 1, "slabs": 1, "staging_reused": 0}
         assert tags("image.stack") == [{"bytes": uint8}]
         # the stage program's result stays on the device for the batch loop
         assert tags("image.apply_fetch") == [{"bytes_up": uint8, "bytes_down": 0}]
@@ -235,10 +236,40 @@ def test_mixed_shapes_record_a_stack_fetch_assemble_per_group():
         "image.assemble", "image.transform"]
     whole = spans[-1]
     assert whole["tags"].pop("programs_built") in (0, 1)
-    assert whole["tags"] == {"rows": 5, "groups": 2}
+    assert whole["tags"] == {"rows": 5, "groups": 2, "slabs": 2, "staging_reused": 0}
     # uint8 out: the round trip's clip and cast is a copy; the object column is not
     assert [s["tags"]["bytes"] for s in spans if s["name"] == "image.assemble"] == [
         3 * 8 * 8 * 3, 2 * 12 * 12 * 3, 0]
+
+
+@pytest.mark.parametrize("fetch", [True, False], ids=["fetched", "left_on_the_device"])
+@pytest.mark.parametrize("rows,slab_rows", [(10, [4, 4, 2]), (8, [4, 4]), (4, [4]), (23, [4] * 5 + [3])])
+def test_a_group_of_several_slabs_records_a_stack_and_an_upload_a_slab(rows, slab_rows, fetch, monkeypatch):
+    """One ``image.stack`` then one ``image.apply_fetch`` a slab, their byte
+    tags the slab's own, so the group's bytes are counted once each way
+    however it is cut (``host_copy_gib`` sums every ``bytes*`` tag); the
+    stage program and the fetch sit in the last slab's span, where a
+    one-slab group always had them."""
+    from mmlspark_tpu.image import ImageTransformer, transforms
+
+    row = 16 * 16 * 3
+    monkeypatch.setattr(transforms, "_SLAB_BYTES", 4 * row)
+    stage = ImageTransformer(inputCol="image", outputCol="out", toFloat=True).flip(0)
+    table = _image_table(rows)
+    _, spans = _recorded(lambda: stage.transform(table) if fetch else stage._device_groups(table))
+    names = [s["name"] for s in spans]
+    after = ["image.assemble"] * 2 if fetch else []
+    assert names == ["image.stack", "image.apply_fetch"] * len(slab_rows) + after + ["image.transform"]
+    assert [s["tags"] for s in spans if s["name"] == "image.stack"] == [{"bytes": n * row} for n in slab_rows]
+    down = [0] * (len(slab_rows) - 1) + [rows * row * 4 if fetch else 0]
+    assert [s["tags"] for s in spans if s["name"] == "image.apply_fetch"] == [
+        {"bytes_up": n * row, "bytes_down": d} for n, d in zip(slab_rows, down)]
+    whole = spans[-1]["tags"]
+    assert whole.pop("programs_built") in (0, 1)
+    # on the CPU backend a buffer handed over is the array's own: none is filled again
+    assert whole == {"rows": rows, "groups": 1, "slabs": len(slab_rows), "staging_reused": 0}
+    by_id = {s["span_id"]: s["name"] for s in spans}
+    assert {by_id[s["parent_id"]] for s in spans[:-1]} == {"image.transform"}
 
 
 # -- results are the parent's -------------------------------------------------
