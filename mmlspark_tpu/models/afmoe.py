@@ -23,8 +23,9 @@ takes bfloat16 inputs and sums in float32; norms, softmax, router scores and
 the choice of experts are float32. ``config["product_dtype"]`` rounds every
 projection's, feed-forward's, expert's, router's and head's inputs to a
 narrower dtype first (``float8_e4m3fn``, say), for measuring what that
-costs, and ``config["interpret"]`` runs the attention kernel in the Pallas interpreter
-(a backend that is no TPU has no other way; nothing here chooses it).
+costs, and ``config["interpret"]`` runs the attention kernel and the experts' combine
+in the Pallas interpreter (a backend that is no TPU has no other way; nothing here
+chooses it).
 """
 
 from __future__ import annotations
@@ -141,7 +142,8 @@ def afmoe_apply(params, tokens, config: Dict[str, Any]):
     def moe_layer(h, xs):
         p, sliding = xs
         return layer(h, p, sliding, lambda p, x: routed_experts(
-            p, x, c["num_experts_per_tok"], c["route_scale"], dt))
+            p, x, c["num_experts_per_tok"], c["route_scale"], dt,
+            interpret=bool(c.get("interpret", False))))
 
     if dense_kinds:
         h, _ = lax.scan(dense_layer, h, (params["dense"], jnp.asarray(dense_kinds)))

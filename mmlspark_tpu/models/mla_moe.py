@@ -155,7 +155,8 @@ def mla_moe_apply(params, tokens, config: Dict[str, Any]):
 
     def moe_layer(h, p):
         return layer(h, p, lambda p, x: routed_experts(
-            p, x, c["num_experts_per_tok"], c["routed_scaling_factor"], dt))
+            p, x, c["num_experts_per_tok"], c["routed_scaling_factor"], dt,
+            interpret=bool(c.get("interpret", False))))
 
     if dense:
         h, _ = lax.scan(dense_layer, h, params["dense"])

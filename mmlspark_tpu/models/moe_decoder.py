@@ -93,8 +93,8 @@ def routed_experts(p, x, k: int, scale: float, dt, activation: Callable = jax.nn
     (and ``s_*`` likewise) a gated feed-forward, ``e_up`` and ``e_down`` alone
     two matrices with ``activation`` between them (:func:`feed_forward`).
     ``e_*`` may hold several layers' experts one after another, read where
-    they lie: ``first_group`` then says where this layer's stand, and
-    ``interpret`` is the grouped product's (:func:`moe_topk`).
+    they lie: ``first_group`` then says where this layer's stand.
+    ``interpret`` is :func:`moe_topk`'s, for its Pallas kernels.
     -> (routed (+ shared) (rows, S, hidden) float32, the tokens of each row
     that each expert received (rows, experts))."""
     B, S, D = x.shape

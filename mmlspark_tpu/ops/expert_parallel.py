@@ -22,18 +22,22 @@ And one for many experts on ONE device, inside a model:
   gate, or two without) runs over its own contiguous group
   (``lax.ragged_dot``; a Pallas grouped matmul where the matrices are a
   stack of several layers' experts, read in place), and the results go
-  back weighted. Work is ∝ tokens x k whatever the imbalance; no capacity,
+  back weighted in one Pallas kernel (``moe_combine``, :func:`_combine`).
+  Work is ∝ tokens x k whatever the imbalance; no capacity,
   so no token is ever dropped, and an expert that got no token is an empty
   group.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Callable
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 from jax.sharding import PartitionSpec as P
 
@@ -226,6 +230,165 @@ def _product_in_place(a, w, sizes, interpret: bool):
     return y[:M]
 
 
+_ROWS = 8  # a float32 tile's rows: the least a copy out of HBM may move
+_COMBINE_BLOCK = 256  # tokens a grid step where the buffers fit: within 5% of the best of 64-1,024 (PERF.md)
+# VMEM bytes for both halves of the tile buffer and the output blocks: what a
+# block of 256 at 8 of 128 experts and 2,048 wide needs (PERF.md)
+_COMBINE_VMEM = 68 * 2**20
+
+
+def _combine_tiles(tb: int, k: int, E: int) -> int:
+    """Tiles of 8 rows that hold a block of ``tb`` tokens' ``tb * k`` rows,
+    at most: one run a chosen expert, a run of ``L`` rows touching ``L / 8
+    + 1.75`` tiles at most; never more tiles than rows."""
+    return min(tb * k, -(-tb * k // _ROWS) + 2 * E)
+
+
+def _combine_plan(T: int, k: int, D: int, E: int) -> int:
+    """-> the tokens a grid step of :func:`_combine` takes:
+    ``_COMBINE_BLOCK`` (all of ``T`` where that is fewer), halved until two
+    tile buffers and two output blocks of the whole width ``D`` fit
+    ``_COMBINE_VMEM``."""
+    tb = _COMBINE_BLOCK
+    while tb > _ROWS and 2 * (_combine_tiles(tb, k, E) * _ROWS + tb) * D * 4 > _COMBINE_VMEM:
+        tb //= 2
+    return min(tb, T)
+
+
+def _combine_kernel(first_ref, next_ref, pos_ref, w_ref, ys_hbm, out_ref, buf, count, sem, *, k, tb):
+    """Grid step ``g``, token block ``g``: start the tile copies of block
+    ``g + 1`` into the other half of ``buf``, wait for this block's, and
+    write each of its tokens' ``sum_j w[t, j] * row(t, j)``, slot by slot.
+    ``first_ref`` lists block 0's tiles and ``next_ref`` the next block's
+    (how many, then which); ``pos_ref`` says where in its half each of this
+    block's rows lies and ``w_ref`` weighs it, token-major."""
+    g, blocks = pl.program_id(0), pl.num_programs(0)
+
+    def copy(tiles, q, half):
+        tile = pl.multiple_of(tiles[0, 1 + q] * _ROWS, _ROWS)
+        return pltpu.make_async_copy(ys_hbm.at[pl.ds(tile, _ROWS)],
+                                     buf.at[half, pl.ds(pl.multiple_of(q * _ROWS, _ROWS), _ROWS)], sem.at[half])
+
+    def fetch(tiles, half):
+        count[half] = tiles[0, 0]
+
+        def start(q, carry):
+            copy(tiles, q, half).start()
+            return carry
+        lax.fori_loop(0, tiles[0, 0], start, 0)
+
+    @pl.when(g == 0)
+    def _():
+        fetch(first_ref, 0)
+
+    @pl.when(g + 1 < blocks)
+    def _():
+        fetch(next_ref, (g + 1) % 2)
+
+    half = g % 2
+
+    def wait(q, carry):  # every copy moves one tile's bytes
+        copy(first_ref, 0, half).wait()
+        return carry
+    lax.fori_loop(0, count[half], wait, 0)
+
+    unroll = 8 if tb % 8 == 0 else 1
+
+    def tokens(s, carry):
+        for u in range(unroll):
+            t = s * unroll + u
+            acc = buf[half, pl.ds(pos_ref[0, t * k], 1), :] * w_ref[0, t * k]
+            for j in range(1, k):
+                acc = acc + buf[half, pl.ds(pos_ref[0, t * k + j], 1), :] * w_ref[0, t * k + j]
+            out_ref[pl.ds(t, 1), :] = acc
+        return carry
+    lax.fori_loop(0, tb // unroll, tokens, 0)
+
+
+def _tile_tables(back, expert_of, sizes, k: int, tb: int, most: int):
+    """For each block of ``tb`` tokens: the tiles of 8 rows its rows lie
+    in, ascending (how many, then which, padded to ``most``), and where each
+    of its rows lies once those tiles are side by side, token-major; tokens
+    past the end read place 0 and list nothing. Read off the runs, with no
+    sort and no gather: a block gives expert ``e`` ``count[b, e]`` rows,
+    which start where the earlier blocks' rows of ``e`` end, and a run's
+    first tile is the last run's last where both touch it. ``expert_of``
+    (T*k,) is each row's expert, ``sizes`` (E,) each expert's rows.
+    -> (tiles (blocks, 1, 1 + most), pos (blocks, 1, tb * k)) int32."""
+    E = sizes.shape[0]
+    T = back.shape[0] // k
+    blocks = -(-T // tb)
+    pad = blocks * tb * k - T * k
+    experts = jnp.arange(E, dtype=jnp.int32)
+    mine = jnp.pad(expert_of.astype(jnp.int32), (0, pad), constant_values=E).reshape(blocks, tb * k)
+    rows = jnp.pad(back.astype(jnp.int32), (0, pad)).reshape(blocks, tb * k)
+
+    def pick(table, index):  # table[b, index[b, i]] as a sum over one-hot experts: no gather
+        return jnp.where(index[:, :, None] == experts, table[:, None, :], 0).sum(axis=-1)
+
+    count = (mine[:, :, None] == experts).sum(axis=1, dtype=jnp.int32)  # (blocks, E)
+    row0 = (jnp.cumsum(sizes) - sizes).astype(jnp.int32) + jnp.cumsum(count, axis=0) - count
+    first, last = row0 // _ROWS, (row0 + count - 1) // _ROWS
+    held = count > 0
+    before = lax.cummax(jnp.where(held, last, -1), axis=1)
+    shared = held & (jnp.pad(before[:, :-1], ((0, 0), (1, 0)), constant_values=-1) == first)
+    new = jnp.where(held, last - first + 1, 0) - shared
+    end = jnp.cumsum(new, axis=1)
+    rank0 = end - new - shared  # rank of a run's first tile among its block's
+    q = jnp.arange(most, dtype=jnp.int32)
+    run = (end[:, None, :] <= q[:, None]).sum(axis=-1, dtype=jnp.int32)  # the run that lists slot q
+    tiles = jnp.where(q < end[:, -1:], pick(first - rank0, run) + q, 0)
+    tiles = jnp.concatenate([end[:, -1:], tiles], axis=1)
+    pos = (pick(rank0 - first, mine) + rows // _ROWS) * _ROWS + rows % _ROWS
+    pos = jnp.where(mine < E, pos, 0)
+    return tiles.reshape(blocks, 1, 1 + most), pos.reshape(blocks, 1, tb * k)
+
+
+def _combine(ys, back, expert_of, sizes, weights, interpret: bool):
+    """``sum_j weights[t, j] * ys[back[t * k + j]]`` for every token ``t``,
+    in float32, slot by slot: the routed experts' rows (``ys``, (T*k, D)
+    float32, sorted by expert, a token's rows in token order within each
+    expert's run) weighed back into token order in one pass. A block of
+    tokens copies the 8-row tiles of ``ys`` that hold its rows into VMEM,
+    double-buffered, and reads each row there: a tile is the least a copy
+    out of HBM may move (the chip keeps a float32 matrix in tiles of 8 x 128),
+    and a block's rows lie in at most one run of each expert, so what is
+    read beyond the rows is the runs' ragged ends. ``expert_of`` (T*k,) is
+    each assignment's expert, ``sizes`` (E,) each expert's rows. Nothing of
+    size (T*k, D) is written. -> (T, D) float32."""
+    T, k = weights.shape
+    D, E = ys.shape[1], sizes.shape[0]
+    tb = _combine_plan(T, k, D, E)
+    blocks, most = -(-T // tb), _combine_tiles(tb, k, E)
+    pad = blocks * tb - T
+    tiles, pos = _tile_tables(back, expert_of, sizes, k, tb, most)
+    weights = jnp.pad(weights.astype(jnp.float32), ((0, pad), (0, 0))).reshape(blocks, 1, tb * k)
+    if (T * k) % _ROWS:  # the last tile whole (a model's T * k is a multiple of 8: no copy)
+        ys = jnp.pad(ys, ((0, -(T * k) % _ROWS), (0, 0)))
+    table = functools.partial(pl.BlockSpec, memory_space=pltpu.SMEM)
+    with jax.named_scope("moe_combine"):
+        y = pl.pallas_call(
+            functools.partial(_combine_kernel, k=k, tb=tb),
+            grid=(blocks,),
+            in_specs=[
+                table((None, 1, 1 + most), lambda g: (0, 0, 0)),
+                table((None, 1, 1 + most), lambda g: ((g + 1) % blocks, 0, 0)),
+                table((None, 1, tb * k), lambda g: (g, 0, 0)),
+                table((None, 1, tb * k), lambda g: (g, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((tb, D), lambda g: (g, 0)),
+            out_shape=jax.ShapeDtypeStruct((blocks * tb, D), jnp.float32),
+            scratch_shapes=[pltpu.VMEM((2, most * _ROWS, D), jnp.float32), pltpu.SMEM((2,), jnp.int32),
+                            pltpu.SemaphoreType.DMA((2,))],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",), vmem_limit_bytes=_COMBINE_VMEM + 16 * 2**20),
+            interpret=interpret,
+            name="moe_combine",
+        )(tiles, tiles, pos, weights, ys)
+    return y[:T] if pad else y
+
+
 def moe_topk(x, scores_to_choose, scores_to_weigh, experts, k: int, scale: float = 1.0,
              activation: Callable = jax.nn.silu, first_group=0, interpret: bool = False):
     """Top-``k`` mixture of the experts held on this device.
@@ -237,16 +400,18 @@ def moe_topk(x, scores_to_choose, scores_to_weigh, experts, k: int, scale: float
     holds: ``gate`` and ``up`` (E, D, F) and ``down`` (E, F, D) compute
     ``down(activation(gate x) * up x)`` (SwiGLU as it stands); ``up`` and
     ``down`` alone, two matrices and no gate, ``down(activation(up x))``.
-    Products in ``x``'s dtype summed in float32 (``lax.ragged_dot``).
+    Products in ``x``'s dtype summed in float32 (``lax.ragged_dot``); the
+    weighted sum of a token's ``k`` results in float32, slot by slot
+    (:func:`_combine`, a Pallas kernel on either path).
 
     The matrices may hold more groups than the router has experts, a whole
     multiple: several layers' experts one after another, as a model stores
-    them, read where they lie (:func:`_product_in_place`; ``interpret`` is
-    its Pallas kernel's, for a backend other than the chip). ``first_group``
+    them, read where they lie (:func:`_product_in_place`). ``first_group``
     (an integer, traced or not) then says where these ``E`` stand among
     them: every other group is empty, which costs the product no tile and
-    the program no copy of ``E`` matrices. With ``E`` groups neither is read.
-    -> (y (T, D) float32, chosen (T, k) int32)."""
+    the program no copy of ``E`` matrices. With ``E`` groups it is not read.
+    ``interpret`` runs the Pallas kernels in the interpreter, for a backend
+    other than the chip. -> (y (T, D) float32, chosen (T, k) int32)."""
     T, D = x.shape
     E = scores_to_choose.shape[1]
     _, chosen = lax.top_k(scores_to_choose, k)
@@ -272,5 +437,4 @@ def moe_topk(x, scores_to_choose, scores_to_weigh, experts, k: int, scale: float
     inner = inner.astype(x.dtype)
     ys = dot(inner, experts["down"])  # (T*k, D) float32, still sorted by expert
     back = jnp.argsort(order)  # where assignment (token, slot) sits in the sorted rows
-    y = (ys[back].reshape(T, k, D) * weights[:, :, None].astype(jnp.float32)).sum(axis=1)
-    return y, chosen.astype(jnp.int32)
+    return _combine(ys, back, expert_of, sizes, weights, interpret), chosen.astype(jnp.int32)

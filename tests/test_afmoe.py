@@ -256,13 +256,16 @@ def test_moe_topk_against_every_expert_under_a_mask(k, gated):
     bias[1::2, 2] = -10.0
     choose = jnp.asarray(weigh + bias, jnp.float32)
     if gated:
-        y, chosen = jax.jit(lambda *a: moe_topk(*a, k, 2.5))(x, choose, jnp.asarray(weigh, jnp.float32), experts)
+        y, chosen = jax.jit(lambda *a: moe_topk(*a, k, 2.5, interpret=True))(
+            x, choose, jnp.asarray(weigh, jnp.float32), experts)
         want, want_chosen = _every_expert_masked(x, choose, weigh, experts, k, 2.5)
     else:
-        y, chosen = jax.jit(lambda *a: moe_topk(*a, k, 2.5, relu2))(x, choose, jnp.asarray(weigh, jnp.float32), experts)
+        y, chosen = jax.jit(lambda *a: moe_topk(*a, k, 2.5, relu2, interpret=True))(
+            x, choose, jnp.asarray(weigh, jnp.float32), experts)
         want = _per_token_loop(x, choose, weigh, experts, k, 2.5, lambda a: np.maximum(a, 0) ** 2)
         want_chosen = np.argsort(-np.asarray(choose, np.float64), axis=1, kind="stable")[:, :k]
-        silu_too = jax.jit(lambda *a: moe_topk(*a, k, 2.5))(x, choose, jnp.asarray(weigh, jnp.float32), experts)[0]
+        silu_too = jax.jit(lambda *a: moe_topk(*a, k, 2.5, interpret=True))(
+            x, choose, jnp.asarray(weigh, jnp.float32), experts)[0]
         assert np.abs(np.asarray(silu_too) - want).max() > 0.05  # the activation is the caller's, not a default's
     load = np.bincount(np.asarray(chosen).ravel(), minlength=E)
     assert load[5] == 0 and load[2] == T // 2 and load.sum() == T * k
@@ -298,7 +301,7 @@ def test_moe_topk_when_one_expert_takes_every_token():
     experts = {n: jnp.asarray(rng.normal(size=s), jnp.float32)
                for n, s in (("gate", (E, D, F)), ("up", (E, D, F)), ("down", (E, F, D)))}
     scores = jnp.zeros((T, E)).at[:, 3].set(1.0)
-    y, chosen = moe_topk(x, scores, scores, experts, 1, 1.0)
+    y, chosen = moe_topk(x, scores, scores, experts, 1, 1.0, interpret=True)
     want, _ = _every_expert_masked(x, scores, scores, experts, 1, 1.0)
     assert (np.asarray(chosen) == 3).all()
     np.testing.assert_allclose(y, want, rtol=2e-4, atol=2e-5)
@@ -307,9 +310,13 @@ def test_moe_topk_when_one_expert_takes_every_token():
 # -- a stack of several layers' experts, read where it lies ------------------
 
 def _moe_topk_as_it_was(x, scores_to_choose, scores_to_weigh, experts, k, scale=1.0, activation=jax.nn.silu):
-    """``moe_topk`` of PR 33, statement for statement: what Trinity's and
-    JoyAI's programs were traced from."""
+    """``moe_topk`` as Trinity's and JoyAI's programs were first traced,
+    statement for statement, but for the return of the rows to their
+    tokens, which is the combine kernel's: what those programs are traced
+    from."""
     from jax import lax
+
+    from mmlspark_tpu.ops.expert_parallel import _combine
 
     T, D = x.shape
     E = scores_to_choose.shape[1]
@@ -329,8 +336,7 @@ def _moe_topk_as_it_was(x, scores_to_choose, scores_to_weigh, experts, k, scale=
     inner = inner.astype(x.dtype)
     ys = dot(inner, experts["down"])
     back = jnp.argsort(order)
-    y = (ys[back].reshape(T, k, D) * weights[:, :, None].astype(jnp.float32)).sum(axis=1)
-    return y, chosen.astype(jnp.int32)
+    return _combine(ys, back, expert_of, sizes, weights, True), chosen.astype(jnp.int32)
 
 
 @pytest.mark.parametrize("width", [32, 24], ids=["a_power_of_two", "off_every_grid"])
@@ -338,8 +344,9 @@ def _moe_topk_as_it_was(x, scores_to_choose, scores_to_weigh, experts, k, scale=
 def test_moe_topk_with_as_many_groups_as_experts_lowers_to_the_program_it_was(gated, width):
     """Which product runs is read from the matrices' leading length: equal to
     the router's width, as in every family whose stacks come through a
-    scan's ``xs``, the lowered text is PR 33's, whatever the inner width and
-    whatever ``first_group`` and ``interpret`` say."""
+    scan's ``xs``, the lowered text is that program's products and the combine
+    kernel, whatever the inner width and whatever ``first_group`` says: XLA's
+    grouped products, one a matrix, and one Pallas kernel, ``moe_combine``."""
     def spec(*shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype)
 
@@ -348,15 +355,19 @@ def test_moe_topk_with_as_many_groups_as_experts_lowers_to_the_program_it_was(ga
         del experts["gate"]
     shapes = (spec(64, 16), spec(64, 8, dtype=jnp.float32), spec(64, 8, dtype=jnp.float32), experts)
 
+    def program(fn):
+        return lambda x, choose, weigh, experts: fn(x, choose, weigh, experts, 2, 2.5)
+
     def lowered(fn):
-        def program(x, choose, weigh, experts):
-            return fn(x, choose, weigh, experts, 2, 2.5)
-        return jax.jit(program).lower(*shapes).as_text()
+        return jax.jit(program(fn)).lower(*shapes).as_text()
 
     was = lowered(_moe_topk_as_it_was)
-    assert len(was) > 5000 and lowered(moe_topk) == was
+    assert len(was) > 5000 and lowered(lambda *a: moe_topk(*a, interpret=True)) == was
     assert lowered(lambda *a: moe_topk(*a, first_group=24, interpret=True)) == was
-    assert "dynamic_update_slice" not in was and "custom_call" not in was
+    eqns = jax.make_jaxpr(program(moe_topk))(*shapes).jaxpr.eqns
+    assert len([e for e in eqns if e.primitive.name == "ragged_dot_general"]) == len(experts)
+    kernels = [e.params["name"] for e in eqns if e.primitive.name == "pallas_call"]
+    assert kernels == ["moe_combine"] and not [e for e in eqns if e.primitive.name == "dynamic_update_slice"]
 
 
 def _a_stack(rng, names, U, E, D, F):
@@ -379,7 +390,7 @@ def test_moe_topk_over_a_stack_of_units_is_each_units_experts_alone(unit, gated)
     own = {n: a[unit * E:(unit + 1) * E] for n, a in stack.items()}
     weigh = jnp.asarray(rng.uniform(0.1, 0.9, size=(T, E)), jnp.float32)
     choose = weigh.at[:, 4].add(-10.0)
-    alone, chosen_alone = jax.jit(lambda *a: moe_topk(*a, k, 2.5, relu2))(x, choose, weigh, own)
+    alone, chosen_alone = jax.jit(lambda *a: moe_topk(*a, k, 2.5, relu2, interpret=True))(x, choose, weigh, own)
     traced, chosen = jax.jit(lambda x, c, w, e, g: moe_topk(x, c, w, e, k, 2.5, relu2, g, True))(
         x, choose, weigh, stack, jnp.int32(unit * E))
     static, _ = jax.jit(lambda *a: moe_topk(*a, k, 2.5, relu2, unit * E, True))(x, choose, weigh, stack)

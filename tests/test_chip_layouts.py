@@ -217,6 +217,60 @@ def test_topk_experts_at_the_published_widths_use_the_grouped_product(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 6 * 2**30
 
 
+@pytest.mark.parametrize("shape", ["short_convolution", "hybrid_in_place"])
+def test_topk_experts_return_their_rows_through_the_combine_kernel_and_no_copy(one_chip, shape):
+    """``moe_topk`` at the short-convolution cell's shape (32,768 tokens, 32
+    experts of 1,792, top-4, ``lax.ragged_dot``) and the hybrid cell's
+    (16,384 tokens, 512 groups of 1,856 over 2,688 read in place, top-6, the
+    Pallas grouped matmul): the only float32 ``(T*k, D)`` instruction is the
+    down product's own result, nothing is laid out ``(T, k, D)`` (the
+    parent's was padded to 8 of 4 or 6), and the rows go back through one
+    ``moe_combine`` kernel. The combine alone held 2,048 and 2,352 MiB of
+    temporaries as the parent wrote it and holds none of that size now
+    (PERF.md); the whole of ``moe_topk`` holds 1,370 MiB at the
+    hybrid's shape where it held 2,361, and at the short-convolution cell's
+    no more than before, where the gate and up products' 2.25 GiB are the
+    peak."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.models.moe_decoder import relu2
+    from mmlspark_tpu.ops.expert_parallel import _combine, moe_topk
+
+    def spec(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    if shape == "short_convolution":
+        T, E, F, D, k = 32768, 32, 1792, 2048, 4
+        experts = {n: spec(s) for n, s in (("gate", (E, D, F)), ("up", (E, D, F)), ("down", (E, F, D)))}
+        compiled = _compile_off(lambda x, s, e: moe_topk(x, s, s, e, k), spec((T, D)), spec((T, E), jnp.float32),
+                                experts)
+        products, most = "ragged-dot", 2306 * 2**20
+    else:
+        T, E, F, D, k = 16384, 128, 1856, 2688, 6
+        experts = {"up": spec((4 * E, D, F)), "down": spec((4 * E, F, D))}
+        compiled = _compile_off(lambda x, s, e, g: moe_topk(x, s, s, e, k, 2.5, relu2, g), spec((T, D)),
+                                spec((T, E), jnp.float32), experts, spec((), jnp.int32))
+        products, most = "gmm", 1400 * 2**20
+    text = compiled.as_text()
+    rows = re.findall(r"%([\w.\-]+) = f32\[" + f"{T * k},{D}" + r"\]", text)
+    assert rows and all(name.startswith(products) for name in rows), rows
+    assert f"f32[{T},{k},{D}]" not in text
+    assert len(set(re.findall(r"%(moe_combine[\w.\-]*) = f32\[" + f"{T},{D}" + r"\]", text))) == 1
+    assert compiled.memory_analysis().temp_size_in_bytes <= most
+
+    def parent(ys, back, w):
+        return (ys[back].reshape(T, k, D) * w[:, :, None]).sum(axis=1)
+
+    ys, back, weights = spec((T * k, D), jnp.float32), spec((T * k,), jnp.int32), spec((T, k), jnp.float32)
+    was = _compile_off(parent, ys, back, weights).memory_analysis().temp_size_in_bytes
+    now = _compile_off(lambda *a: _combine(*a, False), ys, back, back, spec((E,), jnp.int32),
+                       weights).memory_analysis().temp_size_in_bytes
+    assert was - now >= T * k * D * 4
+
+
 def test_the_state_space_scan_at_the_published_widths_compiles_as_one_kernel(one_chip):
     """One dispatch of the hybrid cell's mixer: 1 row of 16,384 tokens, 64
     heads of 64 in 8 groups, state 128, chunks of 128. The kernel compiles as
