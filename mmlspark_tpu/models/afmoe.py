@@ -8,7 +8,9 @@ ids and returns each row's last position (normalised hidden state, logits)
 and each expert layer's load. Layers of one kind are stacked and run under
 one ``lax.scan`` (the dense stack, then the expert stack), so the program is
 traced once a kind; within a scan a layer's attention kind, read from the
-configuration's ``layer_types`` by index, picks a ``lax.cond`` branch.
+configuration's ``layer_types`` by index, picks a ``lax.cond`` branch. The
+routed experts' stacks are closed over whole and read where they lie, the
+layer's number scanned (:func:`moe_decoder.experts_in_place`).
 
 ``config`` holds the published ``config.json`` keys (``hidden_size``,
 ``num_attention_heads``, ``num_key_value_heads``, ``head_dim``,
@@ -39,6 +41,7 @@ from jax import lax
 
 from mmlspark_tpu.models.moe_decoder import (
     dot,
+    experts_in_place,
     init_decoder,
     last_position,
     norm,
@@ -139,16 +142,19 @@ def afmoe_apply(params, tokens, config: Dict[str, Any]):
         p, sliding = xs
         return layer(h, p, sliding, lambda p, x: (swiglu(x, p["w_gate"], p["w_up"], p["w_down"], dt), None))
 
+    E = c["num_experts"]
+    whole, scanned = experts_in_place(params["moe"], dt)
+
     def moe_layer(h, xs):
-        p, sliding = xs
+        p, sliding, number = xs
         return layer(h, p, sliding, lambda p, x: routed_experts(
-            p, x, c["num_experts_per_tok"], c["route_scale"], dt,
+            {**p, **whole}, x, c["num_experts_per_tok"], c["route_scale"], dt, first_group=number * E,
             interpret=bool(c.get("interpret", False))))
 
     if dense_kinds:
         h, _ = lax.scan(dense_layer, h, (params["dense"], jnp.asarray(dense_kinds)))
     if moe_kinds:
-        h, loads = lax.scan(moe_layer, h, (params["moe"], jnp.asarray(moe_kinds)))
+        h, loads = lax.scan(moe_layer, h, (scanned, jnp.asarray(moe_kinds), jnp.arange(len(moe_kinds))))
         loads = loads.transpose(1, 0, 2)
     else:
         loads = jnp.zeros((tokens.shape[0], 0, c["num_experts"]), jnp.int32)

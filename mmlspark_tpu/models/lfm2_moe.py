@@ -28,7 +28,9 @@ feed-forward kind changes at another place than the operator kind does. So
 the weights live in four stacks, ``conv`` and ``attention`` (the operators,
 each kind's layers in order) and ``dense`` and ``moe`` (the feed-forwards),
 and the layers run under two ``lax.scan``s, the dense layers then the expert
-layers, as :mod:`afmoe`'s do. A scan's ``xs`` are its feed-forward stack;
+layers, as :mod:`afmoe`'s do. A scan's ``xs`` are its feed-forward stack
+(but for the routed experts' matrices, closed over whole and read where they
+lie: :func:`moe_decoder.experts_in_place`) and the layer's number;
 a layer's operator is looked up by index in its kind's stack, under a
 ``lax.cond`` on the kind where the scan's layers are of both kinds. Any
 list of the two kinds runs (the published tail is not regular); an unknown
@@ -64,6 +66,7 @@ from jax import lax
 from mmlspark_tpu.models.moe_decoder import (
     causal_conv,
     dot,
+    experts_in_place,
     init_stacks,
     last_position,
     norm,
@@ -209,28 +212,33 @@ def lfm2_moe_apply(params, tokens, config: Dict[str, Any]):
     seen = [0, 0]  # conv and attention layers met so far: a layer's place in its kind's stack
 
     def layers(h, stack, kinds, ffn):
-        """The layers of one feed-forward kind under one scan."""
+        """The layers of one feed-forward kind under one scan; ``ffn`` is
+        told a layer's number among them."""
         index = []
         for attends in kinds:
             index.append(seen[attends])
             seen[attends] += 1
 
         def layer(h, xs):
-            p, attends, index = xs
+            p, attends, index, number = xs
             if len(set(kinds)) == 1:  # one kind of operator: no conditional
                 h = (attend if kinds[0] else convolve)(h, index)
             else:
                 h = lax.cond(attends, attend, convolve, h, index)
-            y, load = ffn(p, normed(h, p))
+            y, load = ffn(p, normed(h, p), number)
             return added(h, y), load
 
-        return lax.scan(layer, h, (params[stack], jnp.asarray(kinds), jnp.asarray(index, jnp.int32)))
+        return lax.scan(layer, h, (stack, jnp.asarray(kinds), jnp.asarray(index, jnp.int32), jnp.arange(len(kinds))))
 
+    E = c["num_experts"]
+    whole, scanned = experts_in_place(params["moe"], dt)
     if dense_kinds:
-        h, _ = layers(h, "dense", dense_kinds, lambda p, x: (swiglu(x, p["w_gate"], p["w_up"], p["w_down"], dt), None))
+        h, _ = layers(h, params["dense"], dense_kinds,
+                      lambda p, x, number: (swiglu(x, p["w_gate"], p["w_up"], p["w_down"], dt), None))
     if moe_kinds:
-        h, loads = layers(h, "moe", moe_kinds, lambda p, x: routed_experts(
-            p, x, c["num_experts_per_tok"], c["routed_scaling_factor"], dt, interpret=bool(c.get("interpret", False))))
+        h, loads = layers(h, scanned, moe_kinds, lambda p, x, number: routed_experts(
+            {**p, **whole}, x, c["num_experts_per_tok"], c["routed_scaling_factor"], dt, first_group=number * E,
+            interpret=bool(c.get("interpret", False))))
         loads = loads.transpose(1, 0, 2)
     else:
         loads = jnp.zeros((tokens.shape[0], 0, c["num_experts"]), jnp.int32)

@@ -16,11 +16,13 @@ routed experts (:func:`moe_decoder.routed_experts`).
 Inference only, whole rows in, as :mod:`afmoe`: :func:`mla_moe_apply`
 takes ``(rows, S)`` token ids and returns each row's last position and each
 expert layer's load; the layers of a kind are stacked and run under one
-``lax.scan``. Attention runs in its up-projected form (per-head keys and
-values rebuilt from the latent): the absorbed form, which attends over the
-latent itself, costs ``(kv_lora_rank + rope) + kv_lora_rank`` multiply-adds
-a key where this costs ``(nope + rope) + v``, and pays only where a cache
-is read a token at a time, which nothing here does. The multi-token
+``lax.scan``, the routed experts' stacks closed over whole and read where
+they lie (:func:`moe_decoder.experts_in_place`). Attention runs in its
+up-projected form (per-head keys and values rebuilt from the latent): the
+absorbed form, which attends over the latent itself, costs ``(kv_lora_rank
++ rope) + kv_lora_rank`` multiply-adds a key where this costs ``(nope +
+rope) + v``, and pays only where a cache is read a token at a time, which
+nothing here does. The multi-token
 prediction module (``num_nextn_predict_layers``) feeds a training loss or a
 drafting step and is not built.
 
@@ -45,6 +47,7 @@ from jax import lax
 
 from mmlspark_tpu.models.moe_decoder import (
     dot,
+    experts_in_place,
     init_decoder,
     last_position,
     norm,
@@ -153,15 +156,19 @@ def mla_moe_apply(params, tokens, config: Dict[str, Any]):
     def dense_layer(h, p):
         return layer(h, p, lambda p, x: (swiglu(x, p["w_gate"], p["w_up"], p["w_down"], dt), None))
 
-    def moe_layer(h, p):
+    E = c["n_routed_experts"]
+    whole, scanned = experts_in_place(params["moe"], dt)
+
+    def moe_layer(h, xs):
+        p, number = xs
         return layer(h, p, lambda p, x: routed_experts(
-            p, x, c["num_experts_per_tok"], c["routed_scaling_factor"], dt,
+            {**p, **whole}, x, c["num_experts_per_tok"], c["routed_scaling_factor"], dt, first_group=number * E,
             interpret=bool(c.get("interpret", False))))
 
     if dense:
         h, _ = lax.scan(dense_layer, h, params["dense"])
     if moe:
-        h, loads = lax.scan(moe_layer, h, params["moe"])
+        h, loads = lax.scan(moe_layer, h, (scanned, jnp.arange(moe)))
         loads = loads.transpose(1, 0, 2)
     else:
         loads = jnp.zeros((tokens.shape[0], 0, c["n_routed_experts"]), jnp.int32)

@@ -113,6 +113,21 @@ def routed_experts(p, x, k: int, scale: float, dt, activation: Callable = jax.nn
     return y.reshape(B, S, D), load.astype(jnp.int32)
 
 
+def experts_in_place(stack, dt):
+    """Split a stack of expert layers for a ``lax.scan`` over its layers. A
+    layer's expert matrices sliced out of their stacks by the scan are copies
+    (a layer's worth a layer a dispatch), so each of ``e_gate``, ``e_up``,
+    ``e_down`` stored in ``dt`` is read where it lies instead: reshaped to
+    ``(layers x experts, ., .)`` (a bitcast), every layer's groups one after
+    another, to be closed over and told where a layer's stand
+    (:func:`routed_experts`' ``first_group``). Inputs narrower than stored are
+    rounded into a copy whatever is done, so those stacks stay with the rest.
+    -> (the matrices read in place, what the scan hands over a layer at a time)."""
+    whole = {name: a.reshape((-1,) + a.shape[2:])  # a bitcast
+             for name, a in stack.items() if name in ("e_gate", "e_up", "e_down") and a.dtype == dt}
+    return whole, {name: a for name, a in stack.items() if name not in whole}
+
+
 def last_position(params, h, eps, dt):
     """-> (each row's last position after the final norm (rows, hidden),
     the head applied to it (rows, vocabulary)), float32. The head is

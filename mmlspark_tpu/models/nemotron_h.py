@@ -66,7 +66,15 @@ import jax.numpy as jnp
 from jax import lax
 
 from mmlspark_tpu.models import mamba2
-from mmlspark_tpu.models.moe_decoder import dot, init_stacks, last_position, norm, relu2, routed_experts
+from mmlspark_tpu.models.moe_decoder import (
+    dot,
+    experts_in_place,
+    init_stacks,
+    last_position,
+    norm,
+    relu2,
+    routed_experts,
+)
 from mmlspark_tpu.ops.attention import blocked_attention
 
 _UNITS = re.compile(r"(M\*?E)*")
@@ -176,14 +184,10 @@ def nemotron_h_apply(params, tokens, config: Dict[str, Any]):
         p = jax.tree.map(lambda a: lax.dynamic_index_in_dim(a, index, keepdims=False), params["attention"])
         return added(h, _attention(p, normed(h, p), c, dt))
 
-    # A unit's two expert matrices sliced out of their stacks by the scan are copies, 1.2 GiB each a unit a dispatch
-    # at the published size, the first transposed on the way. Products that take the matrices as they are stored read
-    # the whole stacks where they lie instead, every unit's groups one after another, told where this unit's stand.
-    # (Inputs narrower than stored are rounded into a copy whatever is done, so those stacks go through the scan.)
+    # A unit's two expert matrices sliced out by the scan would be copies, 1.2 GiB each a unit a dispatch at the
+    # published size, the first transposed on the way: the whole stacks are read where they lie instead.
     E = c["n_routed_experts"]
-    whole = {name: a.reshape((-1,) + a.shape[2:])  # a bitcast
-             for name, a in params["experts"].items() if name in ("e_up", "e_down") and a.dtype == dt}
-    scanned = {name: a for name, a in params["experts"].items() if name not in whole}
+    whole, scanned = experts_in_place(params["experts"], dt)
 
     def unit(h, xs):
         mixer, experts, attends, index, number = xs

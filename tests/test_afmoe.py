@@ -310,7 +310,7 @@ def test_moe_topk_when_one_expert_takes_every_token():
 # -- a stack of several layers' experts, read where it lies ------------------
 
 def _moe_topk_as_it_was(x, scores_to_choose, scores_to_weigh, experts, k, scale=1.0, activation=jax.nn.silu):
-    """``moe_topk`` as Trinity's and JoyAI's programs were first traced,
+    """``moe_topk`` as the gated families' programs were first traced,
     statement for statement, but for the return of the rows to their
     tokens, which is the combine kernel's: what those programs are traced
     from."""
@@ -343,10 +343,11 @@ def _moe_topk_as_it_was(x, scores_to_choose, scores_to_weigh, experts, k, scale=
 @pytest.mark.parametrize("gated", [True, False], ids=["three_matrices", "two_matrices"])
 def test_moe_topk_with_as_many_groups_as_experts_lowers_to_the_program_it_was(gated, width):
     """Which product runs is read from the matrices' leading length: equal to
-    the router's width, as in every family whose stacks come through a
-    scan's ``xs``, the lowered text is that program's products and the combine
-    kernel, whatever the inner width and whatever ``first_group`` says: XLA's
-    grouped products, one a matrix, and one Pallas kernel, ``moe_combine``."""
+    the router's width, as where a family's stacks come through a scan's
+    ``xs`` (each float8 control), the lowered text is that program's
+    products and the combine kernel, whatever the inner width and whatever
+    ``first_group`` says: XLA's grouped products, one a matrix, and one
+    Pallas kernel, ``moe_combine``."""
     def spec(*shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype)
 

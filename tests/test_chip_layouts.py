@@ -165,13 +165,31 @@ def test_blocked_attention_at_the_latent_widths_compiles_with_a_group_of_one(one
     assert memory.temp_size_in_bytes < 2**30 and "tpu_custom_call" in compiled.as_text()
 
 
+def _expert_products_read_in_place(text, E, D, F, rows):
+    """The three grouped expert products are Pallas grouped matmuls named
+    ``gmm``, one HLO name each, and XLA's grouped product is gone; no
+    operation makes a layer's ``(E, D, F)`` or ``(E, F, D)`` matrix (a slice
+    of the stacks, a copy, a transposition): the stacks are read where they
+    lie, every layer's groups one after another."""
+    import re
+
+    products = set(re.findall(r"%(gmm[\w.\-]*) = f32\[" + f"{rows}," + f"(?:{D}|{F})" + r"\]", text))
+    a_layers_matrix = re.findall(r"%([\w.\-]+) = bf16\[(?:1,)?" + f"{E},(?:{D},{F}|{F},{D})" + r"\]\S* "
+                                 r"(?!bitcast|parameter|get-tuple-element)", text)
+    assert len(products) == 3 and "ragged-dot" not in text, products
+    assert not a_layers_matrix, a_layers_matrix
+
+
 def test_the_latent_decoder_at_the_published_widths_fits_beside_its_weights(one_chip):
     """The whole program of ``joyai-llm-flash.score-16k``, one dispatch of
     16,384 tokens: 10.35 GiB of weights leave 5.3 GiB of a v5e's 15.75 for
     temporaries, and ``memory_stats`` on the chip does not count them, so
-    the compiler is the one that can say (2.47 GiB: PERF.md, PR 31). The
-    attention kernel and the three grouped expert products are one HLO name
-    a stack each, which the two roofline metrics read by."""
+    the compiler is the one that can say (2.47 GiB with a layer's matrices
+    copied out of the scan, 1.72 with the stacks read in place: PERF.md §4,
+    §6). The attention kernel is one HLO name a stack, which the roofline
+    metric reads by; the three grouped expert products read all 4 x 256
+    groups of the expert stacks where they lie: no layer's 2.4 GB of
+    matrices is copied out of them."""
     import json
     import re
 
@@ -192,7 +210,7 @@ def test_the_latent_decoder_at_the_published_widths_fits_beside_its_weights(one_
     assert memory.temp_size_in_bytes < 3.5 * 2**30
     text = compiled.as_text()
     assert len(set(re.findall(r"%(attn_full[\w.\-]*) =", text))) == 2  # the dense stack's and the expert stack's
-    assert len(set(re.findall(r"%(ragged-dot-none[\w.\-]*) =", text))) == 3
+    _expert_products_read_in_place(text, 256, 2048, 768, 16384 * 8)
 
 
 def test_topk_experts_at_the_published_widths_use_the_grouped_product(one_chip):
@@ -396,10 +414,12 @@ def test_the_short_convolution_decoder_at_the_published_widths_fits_beside_its_w
     8,192 tokens over 16 layers: 10.06 GiB of weights (the tied head held
     once) leave 5.7 GiB of a v5e's 15.75 for temporaries, which
     ``memory_stats`` on the chip does not count, so the compiler is the one
-    that can say (3.17 GiB, 13.23 in all: PERF.md, PR 37). The attention
-    kernel is one HLO name (the dense layers hold none, so only the expert
-    scan calls it, under its conditional) and the three grouped expert
-    products one each, which the two roofline metrics read by."""
+    that can say (3.17 GiB, 13.23 in all, with a layer's matrices copied out
+    of the scan; 2.93 and 12.98 with the stacks read in place: PERF.md §4,
+    §6). The attention kernel is one HLO name (the dense layers hold none,
+    so only the expert scan calls it, under its conditional), which the
+    roofline metric reads by; the three grouped expert products read all
+    14 x 32 groups of the expert stacks where they lie."""
     import json
     import re
 
@@ -423,7 +443,38 @@ def test_the_short_convolution_decoder_at_the_published_widths_fits_beside_its_w
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes + memory.output_size_in_bytes < 13.7 * 2**30
     text = compiled.as_text()
     assert len(set(re.findall(r"%(attn_full[\w.\-]*) =", text))) == 1
-    assert len(set(re.findall(r"%(ragged-dot-none[\w.\-]*) =", text))) == 3
+    _expert_products_read_in_place(text, 32, 2048, 1792, 32768 * 4)
+
+
+def test_the_window_and_full_attention_decoder_at_the_published_widths_fits_beside_its_weights(one_chip):
+    """The whole program of ``trinity-mini.score-8k``, one dispatch of 4 x
+    8,192 tokens over 6 layers: 8.02 GiB of weights, and 3.76 GiB of
+    temporaries with a layer's matrices sliced out of the scan, which the
+    compiler no longer needs (3.60: PERF.md §6). The three grouped
+    expert products read all 4 x 128 groups of the expert stacks where they
+    lie."""
+    import json
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.models.afmoe import afmoe_apply, init_afmoe
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs", "trinity-mini.json")) as f:
+        config = json.load(f)["params"]
+    tree = jax.eval_shape(lambda k: init_afmoe(k, config), jax.random.PRNGKey(0))
+    assert tree["moe"]["e_up"].shape == (4, 128, 2048, 1024)
+    tree = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+    tokens = jax.ShapeDtypeStruct((4, 8192), jnp.int32, sharding=one_chip)
+    compiled = _compile_off(lambda p, x: afmoe_apply(p, x, config), tree, tokens)
+    memory = compiled.memory_analysis()
+    assert 8_613_110_784 <= memory.argument_size_in_bytes < 8_613_110_784 + 2**20
+    assert memory.temp_size_in_bytes <= 3.76 * 2**30
+    text = compiled.as_text()
+    assert len(set(re.findall(r"%(attn_window[\w.\-]*) =", text))) == 2  # the dense stack's and the expert stack's
+    _expert_products_read_in_place(text, 128, 2048, 1024, 32768 * 8)
 
 
 def test_ssd_scan_at_a_state_of_256_and_heads_of_128_lowers(one_chip):
