@@ -35,10 +35,11 @@ reduces only the top-K-voted features' histograms — see ``ops/voting.py``.
 from __future__ import annotations
 
 import dataclasses
+import logging
 import math
-import time
+import os
 from functools import lru_cache, partial
-from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -46,7 +47,21 @@ import numpy as np
 from jax import lax
 
 from mmlspark_tpu.core.device import cached_program, on_tpu, programs_built
+from mmlspark_tpu.core.profiling import get_logger
 from mmlspark_tpu.lightgbm.binning import BinMapper
+from mmlspark_tpu.lightgbm.bundling import expand_maps, route_maps
+from mmlspark_tpu.lightgbm.callbacks import (
+    CallbackEnv,
+    _has_iteration_hooks,
+    _lr_schedule,
+)
+from mmlspark_tpu.observability.events import (
+    HistogramChunked,
+    HistogramDegraded,
+    HistogramSubtracted,
+    MemoryPressure,
+    get_bus,
+)
 from mmlspark_tpu.observability.profiler import get_profiler
 from mmlspark_tpu.observability.tracing import get_tracer
 from mmlspark_tpu.lightgbm.booster import Booster
@@ -57,6 +72,28 @@ from mmlspark_tpu.lightgbm.objectives import (
     metric_higher_is_better,
 )
 from mmlspark_tpu.ops.histogram import build_histograms
+from mmlspark_tpu.ops.u_histogram import (
+    build_histograms_u,
+    build_histograms_u_chunked,
+    build_u,
+    chunked_u_spec,
+    dequant_hist,
+    histogram_acc_dtype,
+    make_u_spec,
+    num_u_chunks,
+    prepare_chunked_bins,
+    stat_rows,
+    stat_rows_quant,
+    u_bytes,
+)
+from mmlspark_tpu.parallel.mesh import (
+    AXIS_MODEL,
+    data_sharding,
+    feature_parallel_sharding,
+    pad_to_multiple,
+    replicated,
+)
+from mmlspark_tpu.runtime.faults import current_faults, is_oom_error
 
 
 @dataclasses.dataclass
@@ -482,8 +519,6 @@ def _bundle_route_consts(bundle):
     """Device views of the per-original-feature routing arrays (col, lo,
     span, skip, dflt) — host lru-cached numpy underneath, so traces close
     over stable constants."""
-    from mmlspark_tpu.lightgbm.bundling import route_maps
-
     return tuple(jnp.asarray(a) for a in route_maps(bundle))
 
 
@@ -512,8 +547,6 @@ def _expand_bundled(h, totals, bundle, num_bins):
     the default bin is recovered by subtraction from the per-node totals
     (LightGBM's most_freq_bin trick) — counts stay exact, grad/hess exact
     up to f32 association order."""
-    from mmlspark_tpu.lightgbm.bundling import expand_maps
-
     cidx, gmask, dmask = expand_maps(bundle, num_bins)
     k = h.shape[0]
     flat = h.reshape(k, -1, 3)  # (k, C*B_b, 3)
@@ -582,22 +615,11 @@ def _hist_fn(opts: TrainOptions, mesh=None, u_spec=None, hist_reduce=None,
         narrow integer accumulator dtype — so parent - child is an exact
         integer subtraction and the allreduce payload stays minimal."""
         if u is not None and u_spec is not None and 3 * num_nodes <= 128:
-            if u_spec.chunk_rows:
-                from mmlspark_tpu.ops.u_histogram import (
-                    build_histograms_u_chunked,
-                )
-
-                h = build_histograms_u_chunked(
-                    u, grad, hess, count, node, num_nodes, u_spec,
-                    stats=stats, dequant=False,
-                )
-            else:
-                from mmlspark_tpu.ops.u_histogram import build_histograms_u
-
-                h = build_histograms_u(
-                    u, grad, hess, count, node, num_nodes, u_spec,
-                    stats=stats, dequant=False,
-                )
+            pass_u = build_histograms_u_chunked if u_spec.chunk_rows else build_histograms_u
+            h = pass_u(
+                u, grad, hess, count, node, num_nodes, u_spec,
+                stats=stats, dequant=False,
+            )
         else:
             h = build_histograms(
                 bins, grad, hess, count, node, num_nodes,
@@ -624,8 +646,6 @@ def _hist_fn(opts: TrainOptions, mesh=None, u_spec=None, hist_reduce=None,
         expand EFB's packed columns back to original feature space
         (``num_bins`` = the ORIGINAL bin width the search expects)."""
         if jnp.issubdtype(h.dtype, jnp.integer):
-            from mmlspark_tpu.ops.u_histogram import dequant_hist
-
             scales = stats[1]
             h = dequant_hist(h, scales)
             totals = dequant_hist(totals, scales)
@@ -849,24 +869,12 @@ def _build_tree_leafwise(
     # where C is the EFB-packed column count and, on the quantized U path,
     # the narrow integer accumulator dtype — so subtraction is an exact
     # integer op before dequantization/expansion and the cache shrinks
-    # with the K-reduction. Gated by a memory budget on that cache — which
-    # the boosting step vmaps over num_class, so the budget multiplies by
-    # the class count — and off under voting-parallel (its histograms only
-    # carry the top-K winner features, so parent - smaller is garbage
-    # elsewhere).
+    # with the K-reduction. :func:`_subtraction` gates it, on the same
+    # shapes the fit's plan reads.
     c_cols = bins.shape[1]  # packed column count (== f without bundling)
     b_pack = bundle.num_bins if bundle is not None else b
     quant = u is not None and qkey is not None
-    from mmlspark_tpu.ops.u_histogram import histogram_acc_dtype
-
-    acc_dtype = histogram_acc_dtype(n, quant)
-    acc_bytes = jnp.dtype(acc_dtype).itemsize
-    use_sub = (
-        opts.histogram_subtraction
-        and max(1, opts.num_class) * m * c_cols * b_pack * 3 * acc_bytes
-        <= (256 << 20)
-        and opts.tree_learner != "voting_parallel"
-    )
+    use_sub = _subtraction(opts, n, c_cols, b_pack, quant).on
     # Panel-pass node budget: 3 stats x nodes must fit one 128-lane group
     # (subtraction keys k left children; without it 2k child nodes).
     cap = 42 if use_sub else 21
@@ -1263,8 +1271,6 @@ def _route_binned(
 
 
 def _tree_stats(grad, hess, count, qkey=None):
-    from mmlspark_tpu.ops.u_histogram import stat_rows, stat_rows_quant
-
     if qkey is not None:
         return stat_rows_quant(grad, hess, count, qkey)
     return stat_rows(grad, hess, count)
@@ -1404,21 +1410,19 @@ def _opts_key(opts: "TrainOptions"):
 
 
 #: TrainOptions fields the many-models plane threads through the compiled
-#: program as TRACED per-candidate data instead of baked constants:
-#: learning_rate rides the scanned (K, iterations) lr stack, and the
-#: bagging/feature-fraction knobs only shape the host-side _mask_schedule
-#: draws (the program consumes the resulting mask stacks, never the
-#: fractions themselves). Everything else — num_leaves, num_iterations,
+#: program as TRACED per-candidate data instead of baked constants, each
+#: with the value a bucket's representative pins it to: learning_rate
+#: rides the scanned (K, iterations) lr stack, and the bagging/feature-
+#: fraction knobs only shape the host-side _mask_schedule draws (the
+#: program consumes the resulting mask stacks, never the fractions
+#: themselves). Everything else — num_leaves, num_iterations,
 #: regularization, objective, seed (GOSS/quantized bake PRNGKey(seed)
 #: statically) — changes the traced program and therefore the bucket.
-MANY_VMAPPED_FIELDS = (
-    "learning_rate",
-    "feature_fraction",
-    "bagging_fraction",
-    "bagging_freq",
-    "pos_bagging_fraction",
-    "neg_bagging_fraction",
+_MANY_PINNED = dict(
+    learning_rate=0.0, feature_fraction=1.0, bagging_fraction=1.0,
+    bagging_freq=0, pos_bagging_fraction=1.0, neg_bagging_fraction=1.0,
 )
+MANY_VMAPPED_FIELDS = tuple(_MANY_PINNED)
 
 
 def normalize_many_opts(opts: "TrainOptions") -> "TrainOptions":
@@ -1426,15 +1430,7 @@ def normalize_many_opts(opts: "TrainOptions") -> "TrainOptions":
     fields pinned to fixed values. Two candidates batch into one compiled
     program iff their normalized options (plus mapper/objective context)
     agree — the shape-bucketing rule documented in docs/automl_sweep.md."""
-    return dataclasses.replace(
-        opts,
-        learning_rate=0.0,
-        feature_fraction=1.0,
-        bagging_fraction=1.0,
-        bagging_freq=0,
-        pos_bagging_fraction=1.0,
-        neg_bagging_fraction=1.0,
-    )
+    return dataclasses.replace(opts, **_MANY_PINNED)
 
 
 def many_bucket_key(opts: "TrainOptions"):
@@ -1442,13 +1438,35 @@ def many_bucket_key(opts: "TrainOptions"):
     return _opts_key(normalize_many_opts(opts))
 
 
-def _scan_steps_run(step, per_iter_bag: bool, per_iter_lr: bool = False,
-                    with_u: bool = False):
-    """The UNJITTED scan-over-iterations program body shared by the
-    single-fit fast path (:func:`_make_scan_steps` jits it directly) and
-    the many-models plane (:func:`_make_scan_steps_many` vmaps it over a
-    stacked candidate axis before jitting). Factored so both paths trace
-    the identical per-iteration semantics."""
+def _scan_program(step, per_iter_bag: bool, per_iter_lr: bool = False,
+                  with_u: bool = False, many: bool = False):
+    """All boosting iterations in ONE device program: ``lax.scan`` over the
+    per-tree step, per-iteration bagging/feature masks as scanned inputs,
+    stacked tree arrays as the scan output. One dispatch and one bulk fetch
+    replace per-iteration host round-trips.
+
+    When bagging never resamples (``per_iter_bag=False``) the single (N,)
+    mask is closed over inside the program rather than scanned, so no
+    (iterations, N) buffer is ever materialized. A dynamic learning-rate
+    schedule (``per_iter_lr``) rides as one more scanned (iterations,)
+    input — schedule callbacks keep the one-dispatch fast path.
+
+    ``with_u`` (U histogram path): the caller builds the fit-resident
+    one-hot ONCE per fit and passes it in — building it inside this program
+    would redo the multi-GB materialization once per SEGMENT when a long
+    fit is split into several dispatches.
+
+    ``many``: the many-models program, the same body vmapped over a leading
+    candidate axis so K same-shaped fits train in ONE compiled dispatch.
+    Data (bins, y, w, edges) is SHARED across candidates (in_axes=None — XLA
+    keeps one copy); margins, the masks and the learning-rate stack carry
+    the candidate axis. lr is always scanned there: it is the vmapped
+    hyperparameter, and a traced f32 scalar is bit-identical to the baked
+    Python float the sequential path closes over (weak f32 typing), so
+    batched and sequential fits agree. When no candidate in the bucket bags
+    the shared (N,) presence mask broadcasts and no (K, iterations, N) mask
+    stack ever materializes."""
+    per_iter_lr = per_iter_lr or many
 
     def run(bins, y, w, margins, edges, bag, fm_all, lr_all, it0, u_arg):
         iters = fm_all.shape[0]
@@ -1476,53 +1494,13 @@ def _scan_steps_run(step, per_iter_bag: bool, per_iter_lr: bool = False,
         margins_out, trees = lax.scan(body, margins, tuple(xs))
         return margins_out, trees
 
-    return run
-
-
-def _make_scan_steps(step, per_iter_bag: bool, per_iter_lr: bool = False,
-                     with_u: bool = False):
-    """All boosting iterations in ONE device program: ``lax.scan`` over the
-    per-tree step, per-iteration bagging/feature masks as scanned inputs,
-    stacked tree arrays as the scan output. One dispatch and one bulk fetch
-    replace per-iteration host round-trips.
-
-    When bagging never resamples (``per_iter_bag=False``) the single (N,)
-    mask is closed over inside the program rather than scanned, so no
-    (iterations, N) buffer is ever materialized. A dynamic learning-rate
-    schedule (``per_iter_lr``) rides as one more scanned (iterations,)
-    input — schedule callbacks keep the one-dispatch fast path.
-
-    ``with_u`` (U histogram path): the caller builds the fit-resident
-    one-hot ONCE per fit and passes it in — building it inside this program
-    would redo the multi-GB materialization once per SEGMENT when a long
-    fit is split into several dispatches."""
-    run = _scan_steps_run(
-        step, per_iter_bag, per_iter_lr=per_iter_lr, with_u=with_u
-    )
+    if many:
+        in_axes = (
+            None, None, None, 0, None, 0 if per_iter_bag else None, 0, 0,
+            None, None,
+        )
+        run = jax.vmap(run, in_axes=in_axes)
     return jax.jit(run, donate_argnums=(3,))
-
-
-def _make_scan_steps_many(step, per_iter_bag: bool):
-    """The many-models program: vmap the scan body over a leading candidate
-    axis so K same-shaped fits train in ONE compiled dispatch. Data (bins,
-    y, w, edges) is SHARED across candidates (in_axes=None — XLA keeps one
-    copy); margins, per-iteration bagging/feature masks, and the
-    per-iteration learning-rate stack carry the candidate axis. lr is
-    always scanned here: it is the vmapped hyperparameter, and a traced f32
-    scalar is bit-identical to the baked Python float the sequential path
-    closes over (weak f32 typing), so batched and sequential fits agree.
-
-    When no candidate in the bucket bags (``per_iter_bag=False``) the
-    shared (N,) presence mask broadcasts (in_axes=None) and no
-    (K, iterations, N) mask stack ever materializes."""
-    run = _scan_steps_run(
-        step, per_iter_bag=per_iter_bag, per_iter_lr=True, with_u=False
-    )
-    in_axes = (
-        None, None, None, 0, None, 0 if per_iter_bag else None, 0, 0,
-        None, None,
-    )
-    return jax.jit(jax.vmap(run, in_axes=in_axes), donate_argnums=(3,))
 
 
 def _bagging_active(opts: "TrainOptions") -> bool:
@@ -1533,14 +1511,17 @@ def _bagging_active(opts: "TrainOptions") -> bool:
     )
 
 
-def _mask_schedule(opts: "TrainOptions", rng, n, pad, num_bag, num_feat, f,
-                   presence, y=None):
+def _mask_schedule(opts: "TrainOptions", n, pad, f, y=None):
     """Per-iteration (bag_mask, bag_changed, feature_mask_or_None) — the ONE
     definition of the bagging/feature-sampling schedule and its rng stream,
     shared by the scan and loop paths so they cannot diverge. Class-
     stratified bagging (pos/neg_bagging_fraction) samples each binary class
     at its own rate, matching native LightGBM's goal-oriented sampling."""
-    bag = presence
+    rng = np.random.default_rng(opts.seed)
+    num_bag = max(1, int(round(n * opts.bagging_fraction)))
+    num_feat = max(1, int(round(f * opts.feature_fraction)))
+    bag = np.ones(n + pad, dtype=np.float32)  # presence: the pad tail is zero
+    bag[n:] = 0.0
     stratified = (
         opts.pos_bagging_fraction < 1.0 or opts.neg_bagging_fraction < 1.0
     ) and y is not None
@@ -1623,6 +1604,884 @@ def _evaluate(
     return fn(y, score, w)
 
 
+# ---------------------------------------------------------------------------
+# The fit's driver: train() calls one function a span.
+# ---------------------------------------------------------------------------
+
+
+def _check_contracts(opts: TrainOptions, valid_sets=None) -> None:
+    """Boosting-type and bagging contracts, with native LightGBM's own errors."""
+    if opts.boosting_type == "rf":
+        if not (opts.bagging_fraction < 1.0 and opts.bagging_freq > 0):
+            raise ValueError(
+                "boosting_type='rf' requires bagging "
+                "(bagging_fraction < 1 and bagging_freq > 0)"
+            )
+        if valid_sets:
+            raise ValueError(
+                "boosting_type='rf' does not support validation sets "
+                "(averaged-ensemble eval is not incremental)"
+            )
+    elif opts.boosting_type == "goss":
+        if opts.bagging_fraction < 1.0:
+            raise ValueError("boosting_type='goss' cannot be combined with bagging")
+        if opts.top_rate + opts.other_rate > 1.0:
+            raise ValueError(
+                "goss requires top_rate + other_rate <= 1 "
+                f"(got {opts.top_rate} + {opts.other_rate})"
+            )
+    elif opts.boosting_type == "dart" and opts.early_stopping_round > 0:
+        raise ValueError("early stopping is not available in dart mode")
+    if (
+        opts.pos_bagging_fraction < 1.0 or opts.neg_bagging_fraction < 1.0
+    ) and opts.objective != "binary":
+        # native LightGBM likewise restricts pos/neg bagging to binary
+        raise ValueError(
+            "posBaggingFraction/negBaggingFraction require the binary "
+            f"objective (got {opts.objective!r})"
+        )
+
+
+def _with_mapper_slots(opts: TrainOptions, mapper: Optional[BinMapper]) -> TrainOptions:
+    """The mapper is the single source of truth for categorical features
+    (LightGBMBase.scala:148-156 likewise resolves slots before training)."""
+    if mapper is None or not mapper.cat_values:
+        return opts
+    return dataclasses.replace(
+        opts,
+        categorical_slots=tuple(sorted(mapper.cat_values)),
+        # native max_cat_to_onehot boundary: features whose SEEN category
+        # count is small use the one-vs-rest search instead of the sort
+        onehot_slots=tuple(
+            f_
+            for f_ in sorted(mapper.cat_values)
+            if len(mapper.cat_values[f_]) <= opts.max_cat_to_onehot
+        ),
+    )
+
+
+class _Prep(NamedTuple):
+    """A fit's host-side set-up, shared by :func:`train` and :func:`train_many`."""
+
+    opts: TrainOptions  # categorical slots resolved; rf's trees full-strength
+    objective: Objective
+    num_classes: int
+    num_bins: int  # max_bin + the missing bin
+    bundle: Any  # the mapper's EFB plan, or None
+    mapper: Optional[BinMapper]
+    bins: Any  # (N, f) as the caller gave them
+    n: int
+    f: int  # columns of ``bins`` (packed under EFB)
+    f_feat: int  # original features: what the feature masks cover
+    y: np.ndarray  # (N,) float32
+    w: np.ndarray  # (N,) float32
+    unit_w: bool  # the caller gave no weights: they are made on the device
+    init_score: np.ndarray  # (C,)
+    margins0: Optional[np.ndarray]  # (N, C) warm start; None: init_score
+
+
+def _prepare(bins, y, w, init_margins, opts: TrainOptions, mapper) -> _Prep:
+    """Objective, classes, bundle, categorical slots, init score, margins."""
+    if opts.boosting_type == "rf":
+        # rf trees are full-strength; averaging happens at the end
+        opts = dataclasses.replace(opts, learning_rate=1.0)
+    objective = get_objective(opts.objective)
+    num_classes = objective.num_outputs_fn(opts.num_class)
+    n, f = bins.shape
+    # EFB: when the mapper carries a bundle plan, ``bins`` is the PACKED
+    # (N, C) matrix. Histograms build in packed space and expand to the
+    # original (k, F, B, 3) before the split search, so everything from the
+    # search down (tree arrays, model text, SHAP) stays in original ids;
+    # f_feat sizes the original-feature surfaces (feature_fraction masks).
+    bundle = getattr(mapper, "bundles", None) if mapper is not None else None
+    if bundle is not None:
+        if f != bundle.num_columns:
+            raise ValueError(
+                f"bundled mapper expects packed bins with {bundle.num_columns} "
+                f"columns, got {f} — bin through apply_bins/bin_dataset with "
+                "this mapper"
+            )
+        if opts.tree_learner == "voting_parallel":
+            raise ValueError(
+                "featureBundling is not supported with tree_learner="
+                "'voting_parallel' (voting's top-K feature exchange needs "
+                "per-feature histograms on the wire)"
+            )
+    opts = _with_mapper_slots(opts, mapper)
+    unit_w = w is None
+    w = np.ones(n, dtype=np.float32) if w is None else np.asarray(w, dtype=np.float32)
+    y = np.asarray(y, dtype=np.float32)
+    margins0 = None
+    if init_margins is not None:
+        # Warm start from provided margins: the booster is a delta model
+        # (LightGBM disables boost_from_average when init_score is given).
+        init_score = np.zeros(num_classes, dtype=np.float32)
+        margins0 = np.asarray(init_margins, dtype=np.float32).reshape(n, num_classes)
+    elif opts.boost_from_average:
+        init_score = objective.init_score(y, num_classes, w)
+    else:
+        init_score = np.zeros(num_classes, dtype=np.float32)
+    return _Prep(
+        opts, objective, num_classes, opts.max_bin + 1, bundle, mapper, bins, n, f,
+        bundle.num_features if bundle is not None else f, y, w, unit_w, init_score,
+        margins0,
+    )
+
+
+class _Placed(NamedTuple):
+    """A fit's arrays on the device, rows padded to the mesh's data axis."""
+
+    bins: Any
+    y: Any
+    w: Any
+    margins: Any
+    edges: Any
+    pad: int
+    rows: int  # n + pad
+    put_rows: Callable  # host array -> device, row-sharded on a mesh
+    put_rep: Callable  # host array -> device, replicated on a mesh
+    dev_rows: Callable  # device array -> the row sharding (no wire traffic)
+    bytes: int  # host bytes handed to the device
+
+
+def _place(prep: _Prep, mesh=None) -> _Placed:
+    """Padding, sharding, uint8 bins and labels: the host's share of
+    placement. Rows are padded to a multiple of the data-axis size; padding
+    rides along with zero weight/count so it never influences histograms or
+    stats — the "empty partition sends ignore" analogue
+    (LightGBMUtils.scala:144-161)."""
+    n, f = prep.n, prep.f
+    bins, y, w, margins0 = prep.bins, prep.y, prep.w, prep.margins0
+    pad = 0
+    sh_bins = None
+    up_bytes = 0
+
+    def host(a):
+        """A host array on its way to the device, counted for the tag."""
+        nonlocal up_bytes
+        up_bytes += a.nbytes
+        return a
+
+    if mesh is not None:
+        _, pad = pad_to_multiple(n, int(mesh.shape["data"]))
+        if pad:
+            bins = np.concatenate([bins, np.zeros((pad, f), dtype=bins.dtype)])
+            y = np.concatenate([y, np.zeros(pad, dtype=np.float32)])
+            w = np.concatenate([w, np.zeros(pad, dtype=np.float32)])
+            if margins0 is not None:
+                margins0 = np.concatenate(
+                    [margins0, np.zeros((pad, prep.num_classes), dtype=margins0.dtype)]
+                )
+        sh_rows = data_sharding(mesh)
+        sh_rep = replicated(mesh)
+        model_size = int(mesh.shape.get(AXIS_MODEL, 1))
+        if model_size > 1 and f % model_size == 0 and prep.bundle is None:
+            # feature parallel: bins vertically partitioned over the model
+            # axis (LightGBM's feature_parallel layout); XLA partitions the
+            # histogram build/split search and inserts the best-split
+            # argmax collectives across model shards itself. (Indivisible
+            # feature counts stay row-sharded/replicated over model.)
+            sh_bins = feature_parallel_sharding(mesh)
+        put_rows = dev_rows = lambda a: jax.device_put(a, sh_rows)
+        put_rep = lambda a: jax.device_put(a, sh_rep)
+    else:
+        put_rows = put_rep = jnp.asarray
+        dev_rows = lambda a: a
+    rows = n + pad
+
+    if prep.mapper is not None:
+        edges = np.where(
+            np.isfinite(prep.mapper.edges), prep.mapper.edges,
+            np.float32(np.finfo(np.float32).max),
+        )
+    else:
+        edges = np.zeros((f, 1))
+    edges_dev = put_rep(host(edges.astype(np.float32)))
+
+    # Ship bins as uint8 when they fit (4x less host->device traffic);
+    # consumers compare/gather fine on uint8 and the histogram kernels
+    # upcast per-tile. Device-RESIDENT bins (bin_dataset_to_device's
+    # overlapped streaming upload) skip the put entirely.
+    put_bins = (lambda a: jax.device_put(a, sh_bins)) if sh_bins is not None else put_rows
+    if isinstance(bins, jax.Array) and mesh is None:
+        bins_dev = bins
+    elif prep.num_bins <= 256:
+        # uint8 inputs (incl. out-of-core memmaps) upload as-is — no host
+        # copy; device_put streams straight from the mapping
+        b8 = np.asarray(bins) if not isinstance(bins, np.ndarray) else bins
+        b8 = b8 if b8.dtype == np.uint8 else b8.astype(np.uint8)
+        bins_dev = put_bins(host(np.ascontiguousarray(b8)))
+    else:
+        bins_dev = put_bins(host(np.asarray(bins, dtype=np.int32)))
+    # Integer-valued labels (binary/multiclass/count targets) ride the wire
+    # as uint8 and upcast on device — 4x less of the per-fit transfer cost.
+    if y.size and np.all(np.mod(y, 1) == 0) and np.all((y >= 0) & (y <= 255)):
+        y_dev = put_rows(host(y.astype(np.uint8))).astype(jnp.float32)
+    else:
+        y_dev = put_rows(host(y))
+    # Constant-valued operands are created ON device instead of uploaded.
+    w_dev = dev_rows(jnp.ones(rows, jnp.float32)) if prep.unit_w else put_rows(host(w))
+    if margins0 is None:
+        margins = dev_rows(
+            jnp.asarray(prep.init_score, dtype=jnp.float32)[None, :]
+            * jnp.ones((rows, 1), jnp.float32)
+        )
+    else:
+        margins = put_rows(host(margins0.astype(np.float32)))
+    return _Placed(
+        bins_dev, y_dev, w_dev, margins, edges_dev, pad, rows, put_rows, put_rep,
+        dev_rows, up_bytes,
+    )
+
+
+class _Subtraction(NamedTuple):
+    """Leaf-wise sibling subtraction's histogram cache on static shapes."""
+
+    on: bool
+    acc_dtype: Any  # dtype of the packed accumulator
+    cache_bytes: int
+    cols: int  # packed columns
+    bins: int  # packed bins
+
+
+def _subtraction(opts: TrainOptions, rows: int, cols: int, bins: int, quant: bool) -> _Subtraction:
+    """Whether the leaf-wise grower subtracts siblings. The cache holds every
+    node's packed histogram (packed columns x packed bins x 3 stats in the
+    accumulator's dtype) once a class, since the boosting step vmaps the
+    grower over classes, and must fit 256 MiB. Voting's histograms carry only
+    the top-K winner features, so parent - smaller is garbage there.
+    :func:`_u_plan` and :func:`_build_tree_leafwise` both ask this."""
+    dt = jnp.dtype(histogram_acc_dtype(rows, quant))
+    cache = max(1, opts.num_class) * (2 * opts.num_leaves - 1) * cols * bins * 3 * dt.itemsize
+    on = (
+        opts.histogram_subtraction
+        and cache <= (256 << 20)
+        and opts.tree_learner != "voting_parallel"
+    )
+    return _Subtraction(bool(on), dt, cache, cols, bins)
+
+
+# Two ceilings on quantized gradients, the tighter (2^24) enforced: s8 x s8
+# sums accumulate in int32 (|sum| <= 127 * rows wraps past 2^31/127 ~= 16.9M
+# rows), and the f32 count channel loses integer exactness above 2^24 — the
+# "counts stay exact" contract in _split_search holds only below it.
+_QUANT_ROW_CAP = min((1 << 31) // 127, 1 << 24)
+
+
+class _UPlan(NamedTuple):
+    """The U histogram path as one fit takes it."""
+
+    spec: Any  # USpec (chunk_rows > 0: streamed in row chunks); None: no U path
+    budget: int  # U's HBM budget in force (0 without U); the OOM ladder halves it
+    quant: bool  # quantized gradients in force
+    quant_off: Optional[str]  # why a quantized request trains on exact stats
+    sub: _Subtraction
+
+
+def _u_plan(opts: TrainOptions, rows: int, cols: int, num_bins: int, bundle=None,
+            mapper=None, mesh=None, budget: Optional[int] = None) -> _UPlan:
+    """The one decision of the U histogram path (ops/u_histogram.py).
+
+    Single-device fits contract each pass against a fit-resident U instead
+    of rebuilding the one-hot (measured 2.1x/pass on v5e); a U over the HBM
+    budget is streamed in row chunks. ``histogram_method='u'`` forces the
+    path (tests exercise it on CPU); ``MMLSPARK_TPU_NO_U=1`` kills it and
+    ``MMLSPARK_TPU_U_BUDGET`` resizes the budget, both read here. Quantized
+    gradients need U and at most ``_QUANT_ROW_CAP`` rows, and sibling
+    subtraction depends on the quantization in force, so both are decided
+    here too. ``budget`` given: a rung of the OOM ladder, on a path already
+    on: the same U, chunked to that budget."""
+    spec = None
+    if budget is not None or (
+        mesh is None
+        and opts.tree_learner != "voting_parallel"
+        and num_bins <= 256
+        and os.environ.get("MMLSPARK_TPU_NO_U") != "1"
+        and (
+            opts.histogram_method == "u"
+            or (opts.histogram_method in (None, "pallas") and on_tpu())
+        )
+    ):
+        if bundle is not None:
+            # U laid out over the PACKED columns — K = Σ bundle widths is
+            # the whole point: fewer one-hot rows to re-stream per pass.
+            spec = make_u_spec(bundle.num_bins, cols, [int(wd) for wd in bundle.widths])
+        else:
+            per_feature = None if mapper is None else [int(x) for x in mapper.num_bins]
+            spec = make_u_spec(num_bins, cols, per_feature)
+        if budget is not None:
+            spec = chunked_u_spec(rows, spec, budget)
+        else:
+            try:
+                budget = int(os.environ.get("MMLSPARK_TPU_U_BUDGET", str(8 << 30)))
+            except ValueError:
+                get_logger("mmlspark_tpu.lightgbm").warning(
+                    "MMLSPARK_TPU_U_BUDGET=%r is not an integer byte count; "
+                    "using the default 8 GB budget",
+                    os.environ["MMLSPARK_TPU_U_BUDGET"],
+                )
+                budget = 8 << 30
+            if u_bytes(rows, spec) > budget:
+                # Over budget: stream the pass in row chunks instead of
+                # abandoning the MXU path wholesale (the pre-chunking behavior
+                # was an all-or-nothing cliff: one row past the budget and the
+                # whole fit fell back to the compare-built kernels).
+                spec = chunked_u_spec(rows, spec, budget)
+    quant_off = None
+    if opts.use_quantized_grad and spec is None:
+        quant_off = (
+            "the precomputed-U histogram path is inactive (non-TPU "
+            "backend without histogram_method='u', mesh/voting "
+            "parallelism, num_bins > 256, or U over the HBM budget)"
+        )
+    elif opts.use_quantized_grad and rows > _QUANT_ROW_CAP:
+        quant_off = (
+            f"{rows} rows exceeds the quantized-path cap "
+            "min(2^31/127, 2^24) = 2^24 (f32 count exactness / int32 "
+            "histogram accumulator)"
+        )
+    quant = bool(opts.use_quantized_grad) and quant_off is None
+    packed_bins = bundle.num_bins if bundle is not None else num_bins
+    return _UPlan(
+        spec, budget if spec is not None else 0, quant, quant_off,
+        _subtraction(opts, rows, cols, packed_bins, quant),
+    )
+
+
+def _publish_plan(plan: _UPlan, opts: TrainOptions, rows: int) -> None:
+    """The plan's log lines and events, once a fit."""
+    log = get_logger("mmlspark_tpu.lightgbm")
+    bus = get_bus()
+    spec, sub = plan.spec, plan.sub
+    if spec is not None and spec.chunk_rows:
+        chunks = num_u_chunks(rows, spec)
+        log.info(
+            "U one-hot (%.1f GB) exceeds MMLSPARK_TPU_U_BUDGET (%.1f GB);"
+            " streaming each histogram pass in %d row chunks of %d",
+            u_bytes(rows, dataclasses.replace(spec, chunk_rows=0)) / 1e9,
+            plan.budget / 1e9, chunks, spec.chunk_rows,
+        )
+        if bus.active:
+            k3 = 3 * max(1, min(opts.leaf_batch, opts.num_leaves - 1))
+            bus.publish(HistogramChunked(
+                rows=rows, k_packed=spec.k_pad, chunk_rows=spec.chunk_rows,
+                num_chunks=chunks, budget_bytes=plan.budget,
+                acc_dtype=sub.acc_dtype.name,
+                bytes_saved=spec.k_pad * k3 * (4 - sub.acc_dtype.itemsize),
+            ))
+    if plan.quant_off is not None:
+        log.warning(
+            "use_quantized_grad requested but %s; training with exact "
+            "bf16 stats instead", plan.quant_off,
+        )
+    if plan.quant and opts.growth == "depthwise" and opts.depth >= 7:
+        # The U panel packs 3 stat planes per frontier node into 128
+        # slots, so levels with > 42 nodes (2^6 = 64 at level 6, reached
+        # once depth >= 7) can't ride the quantized U kernel; _hist_fn
+        # drops those levels to the exact histogram path.
+        log.warning(
+            "use_quantized_grad with depthwise growth and depth %d: levels "
+            "deeper than 5 have > 42 frontier nodes and exceed the 128-slot "
+            "U panel budget (3 stats x nodes), so those levels fall back to "
+            "exact (non-quantized) histograms per level",
+            opts.depth,
+        )
+    if opts.growth == "leafwise" and sub.on and bus.active:
+        bus.publish(HistogramSubtracted(
+            rows=rows, num_leaves=opts.num_leaves,
+            packed_columns=sub.cols, packed_bins=sub.bins,
+            acc_dtype=sub.acc_dtype.name, cache_bytes=sub.cache_bytes,
+            bytes_saved_per_tree=(opts.num_leaves - 1) * sub.cols
+            * sub.bins * 3 * sub.acc_dtype.itemsize,
+        ))
+
+
+class _Programs(NamedTuple):
+    """A fit's programs, under the keys the process-wide cache holds them by."""
+
+    step_raw: Any  # one boosting iteration, unjitted: the scan bodies trace it
+    step: Any  # ``step_raw`` jitted, margins donated: the loop path's dispatch
+    runner: Any  # every iteration in one scan, or None on the loop path
+    u_builder: Any  # lays out U (or the chunked bins stack), or None
+    cache_hit: bool  # the step program came from an earlier fit's build
+
+
+def _programs(opts: TrainOptions, objective: Objective, num_bins: int, n: int,
+              bundle=None, spec=None, mesh=None, hist_reduce=None, scan=None,
+              many: bool = False) -> _Programs:
+    """The step, its jitted dispatch, the scan runner and U's builder. The
+    first build and every rung of the OOM ladder call this.
+
+    ``scan``: :func:`train`'s ``(per_iter_bag, per_iter_lr)``, or with
+    ``many`` :func:`train_many`'s ``per_iter_bag``; None builds no runner.
+    A ``hist_reduce`` hook closes over a live socket group, so a program
+    holding it is never shared across fits. The dispatches book through
+    :meth:`DeviceProfiler.wrap`, which hands them back unwrapped when the
+    profiler is off."""
+    okey = (_opts_key(opts), num_bins, mesh, spec, bundle, objective.cache_token)
+    if opts.boosting_type == "goss":
+        okey = okey + (n,)  # GOSS bakes the unpadded row count into the program
+
+    def make(hook=None):
+        return _make_step(
+            opts, objective, num_bins, mesh, n_real=n, u_spec=spec,
+            hist_reduce=hook, bundle=bundle,
+        )
+
+    prof = get_profiler()
+    if many:
+        step_raw = cached_program(("step_raw_many", okey), make)
+        runner = cached_program(
+            ("scan_many", okey, scan),
+            lambda: _scan_program(step_raw, per_iter_bag=scan, many=True),
+        )
+        return _Programs(
+            step_raw, None, prof.wrap(runner, "gbdt.scan_many", cost_analysis=False),
+            None, False,
+        )
+    built_before = programs_built()
+    if hist_reduce is not None:
+        step_raw = make(hist_reduce)
+        step = jax.jit(step_raw, donate_argnums=(3,))
+    else:
+        step_raw = cached_program(("step_raw", okey), make)
+        step = cached_program(
+            ("step_jit", okey), lambda: jax.jit(step_raw, donate_argnums=(3,))
+        )
+    cache_hit = hist_reduce is None and programs_built() == built_before
+    runner = None
+    if scan is not None:
+        per_iter_bag, per_iter_lr = scan
+        runner = cached_program(
+            ("scan", okey, per_iter_bag, per_iter_lr),
+            lambda: _scan_program(
+                step_raw, per_iter_bag=per_iter_bag, per_iter_lr=per_iter_lr,
+                with_u=spec is not None,
+            ),
+        )
+        runner = prof.wrap(runner, "gbdt.scan", cost_analysis=False)
+    u_builder = None
+    if spec is not None:
+        # the chunked pass consumes a (num_chunks, F, chunk) bins stack laid
+        # out once per fit, not the resident one-hot
+        u_builder = partial(prepare_chunked_bins if spec.chunk_rows else build_u, spec=spec)
+    return _Programs(
+        step_raw, prof.wrap(step, "gbdt.step", cost_analysis=False), runner,
+        u_builder, cache_hit,
+    )
+
+
+_OOM_RETRY_CAP = 8  # ladder rungs one dispatch may walk before its OOM is final
+
+
+@dataclasses.dataclass
+class _Run:
+    """One fit's device side: placed inputs, the U plan, its programs and U.
+
+    The RESOURCE_EXHAUSTED degradation ladder (docs/resilience.md) replaces
+    the last three together. An HBM OOM during a histogram dispatch is
+    retryable at a reduced footprint: halve U's budget (floor 1 MiB), chunk
+    U to it, rebuild the programs and U, and run the SAME dispatch again.
+    Chunked and resident passes are bit-exact, so the final model text
+    matches an undisturbed run byte for byte. The last rung — a smaller
+    ``leaf_batch`` — changes split-scheduling and is left to the caller (it
+    trades reproducibility for survival)."""
+
+    opts: TrainOptions
+    prep: _Prep
+    data: _Placed
+    plan: _UPlan
+    mesh: Any = None
+    hist_reduce: Any = None
+    scan: Any = None  # what _programs builds the runner for
+    faults: Any = None  # the active fault plan: injected OOMs fire pre-dispatch
+    progs: Optional[_Programs] = None
+    u: Any = None
+
+    def build_programs(self) -> None:
+        p = self.prep
+        self.progs = _programs(
+            self.opts, p.objective, p.num_bins, p.n, p.bundle, self.plan.spec,
+            self.mesh, self.hist_reduce, self.scan,
+        )
+
+    def build_u(self) -> None:
+        """The fit-resident U (or the chunked bins stack): one dispatch,
+        again only after an OOM degraded the plan. The build's device time
+        is waited for by whatever reads its result: ``lightgbm.boost``."""
+        spec, rows = self.plan.spec, self.data.rows
+        chunks = num_u_chunks(rows, spec)
+        u_jit = cached_program(("u_build_jit", spec), lambda: jax.jit(self.progs.u_builder))
+        with get_tracer().span(
+            "lightgbm.u_build", chunks=chunks,
+            u_bytes=chunks * spec.chunk_rows * self.prep.f if spec.chunk_rows
+            else u_bytes(rows, spec),
+        ):
+            self.u = u_jit(self.data.bins)
+
+    def dispatch(self, stage: str, iteration: int, attempt: Callable[[int], Any]):
+        """``attempt(retries)`` under the active fault plan; a device OOM
+        walks one rung down the ladder and tries the same dispatch again."""
+        retries = 0
+        while True:
+            try:
+                if self.faults is not None:
+                    self.faults.apply_on_histogram(iteration, retries)
+                return attempt(retries)
+            except Exception as e:  # noqa: BLE001 - OOM-classified below
+                if (
+                    not is_oom_error(e)
+                    or retries >= _OOM_RETRY_CAP
+                    or not self._degrade(e, stage, iteration, retries + 1)
+                ):
+                    raise
+                retries += 1
+                self.build_u()
+
+    def _degrade(self, err, stage: str, iteration: int, retries: int) -> bool:
+        """Walk one rung down the ladder; True when the caller may retry."""
+        plan, p, rows = self.plan, self.prep, self.data.rows
+        if plan.spec is None:
+            return False  # no U path active: nothing to shrink in-loop
+        budget = max(plan.budget // 2, 1 << 20)
+        if budget == plan.budget and plan.spec.chunk_rows:
+            return False  # floor reached; the OOM is genuine scarcity
+        self.plan = _u_plan(
+            self.opts, rows, p.f, p.num_bins, p.bundle, p.mapper, budget=budget
+        )
+        self.build_programs()
+        chunk_rows = self.plan.spec.chunk_rows
+        get_logger("mmlspark_tpu.lightgbm").warning(
+            "histogram %s dispatch hit RESOURCE_EXHAUSTED at iteration %d "
+            "(%s); degrading: U budget -> %d bytes, chunk_rows -> %d, "
+            "retry %d",
+            stage, iteration, str(err)[:120], budget, chunk_rows, retries,
+        )
+        bus = get_bus()
+        if bus.active:
+            bus.publish(MemoryPressure(
+                source="device", level="critical", used_bytes=0.0,
+                limit_bytes=0.0, detail=str(err)[:200],
+            ))
+            bus.publish(HistogramDegraded(
+                rows=rows, budget_bytes=budget, chunk_rows=chunk_rows,
+                stage=stage, iteration=int(iteration), retries=int(retries),
+            ))
+        return True
+
+
+@dataclasses.dataclass
+class _Watch:
+    """What a fit reports between trees and stops on: callbacks, validation
+    sets and their metric history, the training metric, early stopping.
+    The scan path runs only the callbacks' start and end."""
+
+    opts: TrainOptions
+    prep: _Prep
+    callbacks: list
+    lr_all: Optional[np.ndarray]  # (iterations,) schedule; None: constant
+    metric: str
+    higher_better: bool
+    valid: List[dict]
+    evals: Dict[str, Dict[str, List[float]]]
+    update: Any  # the validation sets' margin update program
+    best_score: float
+    best_iter: int = 0
+    stale: int = 0
+
+    @classmethod
+    def start(cls, opts, prep: _Prep, valid_sets, callbacks, lr_all) -> "_Watch":
+        valid = []
+        for name, bv, yv, wv in valid_sets:
+            wv = np.ones(len(yv), dtype=np.float32) if wv is None else np.asarray(wv, np.float32)
+            mv = np.broadcast_to(prep.init_score[None, :], (len(yv), prep.num_classes)).copy()
+            valid.append({
+                "name": name,
+                "bins": jnp.asarray(np.asarray(bv, dtype=np.int32)),
+                "y": np.asarray(yv, dtype=np.float32),
+                "w": wv,
+                "margins": jnp.asarray(mv.astype(np.float32)),
+            })
+        metric = opts.metric or prep.objective.default_metric
+        higher_better = metric_higher_is_better(metric)
+        evals = {vs["name"]: {metric: []} for vs in valid}
+        if opts.provide_training_metric:
+            evals["training"] = {metric: []}
+        update = cached_program(
+            ("valid_update", opts.routing_steps, prep.bundle),
+            lambda: _make_valid_update(opts.routing_steps, prep.bundle),
+        )
+        watch = cls(
+            opts, prep, callbacks, lr_all, metric, higher_better, valid, evals,
+            update, -np.inf if higher_better else np.inf,
+        )
+        for cb in callbacks:
+            cb.before_training(watch.env(0))
+        return watch
+
+    @property
+    def best_iteration(self) -> int:
+        """The booster's: -1 unless early stopping watched a validation set."""
+        return self.best_iter if (self.valid and self.opts.early_stopping_round > 0) else -1
+
+    def env(self, it: int) -> CallbackEnv:
+        lr = self.lr_all
+        return CallbackEnv(
+            iteration=it, num_iterations=self.opts.num_iterations,
+            learning_rate=float(lr[it]) if (lr is not None and it < len(lr))
+            else self.opts.learning_rate,
+            evals=self.evals,
+        )
+
+    def after(self, it: int, tree: TreeArrays, margins) -> bool:
+        """Record iteration ``it``; True when a callback or early stopping
+        ends the fit."""
+        opts, p, metric = self.opts, self.prep, self.metric
+        if opts.provide_training_metric:
+            # isProvideTrainingMetric: train-set metric per iteration (a
+            # device fetch per round — opt-in, loop path only)
+            self.evals["training"][metric].append(_evaluate(
+                metric, opts.objective, p.y, np.asarray(margins)[:p.n], p.w, opts.alpha,
+            ))
+        improved = False
+        for vs in self.valid:
+            if not vs.pop("_updated", False):  # dart applies its own delta
+                vs["margins"] = self.update(vs["bins"], vs["margins"], tree)
+            score = _evaluate(
+                metric, opts.objective, vs["y"], np.asarray(vs["margins"]), vs["w"],
+                opts.alpha,
+            )
+            self.evals[vs["name"]][metric].append(score)
+            # best-so-far from the true score (TrainUtils.scala:276-315);
+            # the first finite eval improves on the ±inf sentinel
+            # naturally, and a NaN score never registers as an improvement.
+            delta = (score - self.best_score) if self.higher_better else (self.best_score - score)
+            if delta > opts.improvement_tolerance:
+                self.best_score, self.best_iter, improved = score, it + 1, True
+        if any([cb.after_iteration(self.env(it)) for cb in self.callbacks]):
+            return True  # every callback still sees the iteration
+        if self.valid and opts.early_stopping_round > 0:
+            self.stale = 0 if improved else self.stale + 1
+            return self.stale >= opts.early_stopping_round
+        return False
+
+    def finish(self, iterations: int) -> None:
+        for cb in self.callbacks:
+            cb.after_training(self.env(max(0, iterations - 1)))
+        if self.opts.verbosity < 1:
+            return
+        logger = get_logger("mmlspark_tpu.lightgbm")
+        # verbosity is an explicit request for output — lift the level floor
+        # for THIS summary only, restoring the configured level after
+        root_logger = logging.getLogger("mmlspark_tpu")
+        prev_level = root_logger.level
+        if root_logger.getEffectiveLevel() > logging.INFO:
+            root_logger.setLevel(logging.INFO)
+        try:
+            for name, metrics in self.evals.items():
+                for mname, scores in metrics.items():
+                    if not scores:
+                        continue
+                    arr = np.asarray(scores, dtype=np.float64)
+                    if np.isnan(arr).all():
+                        logger.info("valid %s %s: all evals NaN", name, mname)
+                        continue
+                    best_i = int(
+                        np.nanargmax(arr) if self.higher_better else np.nanargmin(arr)
+                    )
+                    logger.info(
+                        "valid %s %s: last=%.6f best=%.6f@%d",
+                        name, mname, scores[-1], arr[best_i], best_i + 1,
+                    )
+        finally:
+            root_logger.setLevel(prev_level)
+
+
+def _mask_stacks(schedule, f: int) -> Tuple[List[np.ndarray], np.ndarray]:
+    """Every iteration's masks up front: the bagging masks as drawn, and the
+    (iterations, F) feature masks."""
+    bags, fms = [], []
+    for bag, _, fm in schedule:
+        bags.append(bag)
+        fms.append(fm if fm is not None else np.ones(f, np.float32))
+    return bags, np.stack(fms)
+
+
+def _scan_inputs(schedule, f: int, bagging: bool, presence, lr_all):
+    """The scan's per-iteration operands: (iterations, N) bagging masks
+    (uint8 on the wire, 4x less than f32; cast per scan step) or, when
+    bagging never resamples, the (N,) presence mask closed over in the
+    program; the feature masks; and the learning-rate schedule (the feature
+    masks stand in, unused, when the rate is constant)."""
+    bags, fms = _mask_stacks(schedule, f)
+    bag_arg = jnp.asarray(np.stack(bags).astype(np.uint8)) if bagging else presence
+    fm_all = jnp.asarray(fms)
+    return bag_arg, fm_all, jnp.asarray(lr_all) if lr_all is not None else fm_all
+
+
+def _boost_scan(run: _Run, inputs, span) -> TreeArrays:
+    """Every iteration in one device program a segment: one dispatch and one
+    bulk fetch replace per-iteration host round-trips. Returns the stacked
+    trees."""
+    opts, d = run.opts, run.data
+    bag_arg, fm_all, lr_arg = inputs
+    per_iter_bag, per_iter_lr = run.scan
+    iters = opts.num_iterations
+    # Segment the one-dispatch fit so no single device program runs for
+    # minutes. Observed on an earlier v5e host: a 4M-row x 100-iteration
+    # scan (~90 s on-device) reproducibly killed the TPU worker, while
+    # 4M x 50 and 2M x 100 (~50 s) ran fine. Whether the current machine
+    # still needs this is an open ROADMAP question; the bound is
+    # MMLSPARK_TPU_SCAN_ROW_ITERS. Equal-length segments share one compiled
+    # program; margins thread between dispatches, so results are identical
+    # to the single scan.
+    row_iters = run.prep.n * max(1, iters) * max(1, run.prep.num_classes)
+    budget = int(os.environ.get("MMLSPARK_TPU_SCAN_ROW_ITERS", 200_000_000))
+    nseg = max(1, -(-row_iters // budget))
+    # prefer a divisor of the iteration count close to nseg: equal segment
+    # lengths mean ONE compiled shape instead of two
+    for cand in range(nseg, min(nseg + 3, max(1, iters)) + 1):
+        if iters % cand == 0:
+            nseg = cand
+            break
+    seg = -(-iters // nseg)
+    span.tags["segments"] = nseg
+    no_u = jnp.int32(0)  # unused placeholder when no U path
+    margins, parts = d.margins, []
+    for s0 in range(0, iters, seg):
+        s1 = min(s0 + seg, iters)
+        # margins is donated into the runner; a degraded retry of this
+        # segment needs the pre-dispatch value back, so keep a host snapshot
+        # (segments are rare — usually one per fit)
+        before = np.asarray(margins)
+
+        def attempt(retries, s0=s0, s1=s1, m=margins, before=before):
+            return run.progs.runner(
+                d.bins, d.y, d.w, jnp.asarray(before) if retries else m, d.edges,
+                bag_arg[s0:s1] if per_iter_bag else bag_arg,
+                fm_all[s0:s1],
+                lr_arg[s0:s1] if per_iter_lr else lr_arg,
+                jnp.int32(s0),
+                no_u if run.u is None else run.u,
+            )
+
+        margins, part = run.dispatch("scan", s0, attempt)
+        parts.append(part)
+    if len(parts) == 1:
+        return parts[0]
+    return jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0), *parts)
+
+
+def _boost_loop(run: _Run, watch: _Watch, schedule, bag_dev, iteration_hook=None,
+                start_iteration: int = 0) -> List[TreeArrays]:
+    """A dispatch an iteration, for what needs the host between trees: dart,
+    callbacks, validation sets and early stopping, the mesh, process fits'
+    journal hook. Inputs are re-uploaded only when bagging or feature
+    fraction resamples, and per-tree outputs stay on device until one bulk
+    fetch after the loop."""
+    opts, d, bundle = run.opts, run.data, run.prep.bundle
+    tree_contrib = cached_program(
+        ("tree_contrib", opts.routing_steps, bundle),
+        lambda: _make_tree_contrib(opts.routing_steps, bundle),
+    )
+    dart_rng = np.random.default_rng(opts.seed + 7919)
+    fm_ones_dev = d.put_rep(np.ones(run.prep.f_feat, dtype=np.float32))
+    margins, trees, pending_bag = d.margins, [], None
+
+    def contrib_of(tr, bins_v):
+        return tree_contrib(
+            bins_v, tr.feat, tr.bin, tr.left, tr.right, tr.is_leaf, tr.leaf_val,
+            tr.cat_node, tr.cat_mask,
+        )
+
+    def dropped_contrib(dropped, bins_v):
+        c = contrib_of(trees[dropped[0]], bins_v)
+        for di in dropped[1:]:
+            c = c + contrib_of(trees[di], bins_v)
+        return c
+
+    for it, (bag_np, bag_changed, fm_np) in enumerate(schedule):
+        if it < start_iteration:
+            # journal resume: consume the draw (rng stream stays aligned
+            # with an uninterrupted fit) without boosting
+            if bag_changed:
+                pending_bag = bag_np
+            continue
+        if pending_bag is not None:
+            # the last skipped resample is the mask in force at k
+            if not bag_changed:
+                bag_np, bag_changed = pending_bag, True
+            pending_bag = None
+        if bag_changed:
+            bag_dev = d.put_rows(bag_np)
+        fm_dev = d.put_rep(fm_np) if fm_np is not None else fm_ones_dev
+        for cb in watch.callbacks:
+            cb.before_iteration(watch.env(it))
+        # traced scalar (not a baked constant) so per-iteration LR values
+        # don't each recompile the step program
+        lr_it = jnp.float32(
+            watch.lr_all[it] if watch.lr_all is not None else opts.learning_rate
+        )
+
+        # dart: drop a random subset of existing trees from the margins the
+        # new tree fits against (each with prob drop_rate), then renormalize
+        # — new tree x 1/(k+1), dropped trees x k/(k+1) (the DART
+        # weight-shrinkage rule).
+        dropped = []
+        if opts.boosting_type == "dart" and trees:
+            dropped = list(np.nonzero(dart_rng.random(len(trees)) < opts.drop_rate)[0])
+        if dropped:
+            c_d = dropped_contrib(dropped, d.bins)
+            margins_in = margins - c_d
+        else:
+            margins_in = margins
+
+        # Injected OOM faults fire BEFORE dispatch, so margins_in has not
+        # been donated when the degraded retry re-dispatches. A real device
+        # OOM surfaces after donation; the retry is then best-effort (the
+        # allocator usually fails before consuming the donated buffer, but
+        # that is not contractual).
+        tree, new_margins = run.dispatch("loop", it, lambda _: run.progs.step(
+            d.bins, d.y, d.w, margins_in, d.edges, bag_dev, fm_dev,
+            jnp.int32(it), lr_it, u=run.u,
+        ))
+
+        if dropped:
+            k = len(dropped)
+            scale_new = 1.0 / (k + 1)
+            scale_drop = k / (k + 1)
+            # margins_in was donated into step — recover the unscaled
+            # new-tree contribution from the row->leaf map it computed
+            c_new = jnp.take_along_axis(tree.leaf_val, tree.row_leaf, axis=1).T
+            # valid-set deltas need the PRE-scaled dropped trees
+            for vs in watch.valid:
+                c_dv = dropped_contrib(dropped, vs["bins"])
+                c_newv = contrib_of(tree, vs["bins"])
+                vs["margins"] = vs["margins"] - c_dv * scale_new + c_newv * scale_new
+                vs["_updated"] = True
+            tree = tree._replace(leaf_val=tree.leaf_val * scale_new)
+            for di in dropped:
+                trees[di] = trees[di]._replace(leaf_val=trees[di].leaf_val * scale_drop)
+            margins = margins - c_d * scale_new + c_new * scale_new
+        else:
+            margins = new_margins
+        # Synchronize each iteration on the mesh path: an unbounded async
+        # queue of collective programs can starve a device thread past the
+        # XLA rendezvous timeout (hard abort on the host-platform mesh),
+        # and per-iteration sync is the barrier-execution-mode semantics
+        # of the reference anyway (TrainUtils.scala:477-483).
+        jax.block_until_ready(margins)
+        # drop row_leaf, a (C, N) buffer per tree, before retaining
+        trees.append(tree._replace(row_leaf=None))
+        if iteration_hook is not None:
+            # the commit point: the iteration's tree is final and its
+            # margins applied — procfit journals it here
+            iteration_hook(it, trees[-1])
+        if watch.after(it, tree, margins):
+            break
+    return trees
+
+
 def train(
     bins: np.ndarray,  # (N, F) uint8
     y: np.ndarray,
@@ -1659,94 +2518,10 @@ def train(
     iteration k against the caller-rebuilt ``init_margins``. The returned
     booster then contains only the new trees; a resuming caller packs
     restored + new trees itself via :func:`_pack_booster`."""
-    # Boosting-type contracts (matching native LightGBM's own errors):
-    if opts.boosting_type == "rf":
-        if not (opts.bagging_fraction < 1.0 and opts.bagging_freq > 0):
-            raise ValueError(
-                "boosting_type='rf' requires bagging "
-                "(bagging_fraction < 1 and bagging_freq > 0)"
-            )
-        if valid_sets:
-            raise ValueError(
-                "boosting_type='rf' does not support validation sets "
-                "(averaged-ensemble eval is not incremental)"
-            )
-        # rf trees are full-strength; averaging happens at the end
-        opts = dataclasses.replace(opts, learning_rate=1.0)
-    elif opts.boosting_type == "goss":
-        if opts.bagging_fraction < 1.0:
-            raise ValueError("boosting_type='goss' cannot be combined with bagging")
-        if opts.top_rate + opts.other_rate > 1.0:
-            raise ValueError(
-                "goss requires top_rate + other_rate <= 1 "
-                f"(got {opts.top_rate} + {opts.other_rate})"
-            )
-    elif opts.boosting_type == "dart":
-        if opts.early_stopping_round > 0:
-            raise ValueError("early stopping is not available in dart mode")
-    if (
-        opts.pos_bagging_fraction < 1.0 or opts.neg_bagging_fraction < 1.0
-    ) and opts.objective != "binary":
-        # native LightGBM likewise restricts pos/neg bagging to binary
-        raise ValueError(
-            "posBaggingFraction/negBaggingFraction require the binary "
-            f"objective (got {opts.objective!r})"
-        )
-    objective = get_objective(opts.objective)
-    num_classes = objective.num_outputs_fn(opts.num_class)
-    n, f = bins.shape
-    num_bins = opts.max_bin + 1  # + missing bin
-    # EFB: when the mapper carries a bundle plan, ``bins`` is the PACKED
-    # (N, C) matrix. Histograms build in packed space and expand to the
-    # original (k, F, B, 3) before the split search, so everything from the
-    # search down (tree arrays, model text, SHAP) stays in original ids;
-    # f_feat sizes the original-feature surfaces (feature_fraction masks).
-    bundle = getattr(mapper, "bundles", None) if mapper is not None else None
-    if bundle is not None:
-        if f != bundle.num_columns:
-            raise ValueError(
-                f"bundled mapper expects packed bins with {bundle.num_columns} "
-                f"columns, got {f} — bin through apply_bins/bin_dataset with "
-                "this mapper"
-            )
-        if opts.tree_learner == "voting_parallel":
-            raise ValueError(
-                "featureBundling is not supported with tree_learner="
-                "'voting_parallel' (voting's top-K feature exchange needs "
-                "per-feature histograms on the wire)"
-            )
-    f_feat = bundle.num_features if bundle is not None else f
-    # The mapper is the single source of truth for categorical features
-    # (LightGBMBase.scala:148-156 likewise resolves slots before training).
-    if mapper is not None and mapper.cat_values:
-        opts = dataclasses.replace(
-            opts,
-            categorical_slots=tuple(sorted(mapper.cat_values)),
-            # native max_cat_to_onehot boundary: features whose SEEN category
-            # count is small use the one-vs-rest search instead of the sort
-            onehot_slots=tuple(
-                f_
-                for f_ in sorted(mapper.cat_values)
-                if len(mapper.cat_values[f_]) <= opts.max_cat_to_onehot
-            ),
-        )
-
-    w_is_default = w is None
-    w = np.ones(n, dtype=np.float32) if w is None else np.asarray(w, dtype=np.float32)
-    y_np = np.asarray(y, dtype=np.float32)
-
-    if init_margins is None:
-        if opts.boost_from_average:
-            init_score = objective.init_score(y_np, num_classes, w)
-        else:
-            init_score = np.zeros(num_classes, dtype=np.float32)
-        margins0 = np.broadcast_to(init_score[None, :], (n, num_classes)).copy()
-    else:
-        # Warm start from provided margins: the booster is a delta model
-        # (LightGBM disables boost_from_average when init_score is given).
-        init_score = np.zeros(num_classes, dtype=np.float32)
-        margins0 = np.asarray(init_margins, dtype=np.float32).reshape(n, num_classes)
-
+    _check_contracts(opts, valid_sets)
+    valid_sets, callbacks = list(valid_sets or []), list(callbacks or [])
+    prep = _prepare(bins, y, w, init_margins, opts, mapper)
+    opts = prep.opts
     tracer = get_tracer()
     # ``lightgbm.upload`` is the host's share of placement: padding, casts
     # and the hand-off of each host array. The transfers themselves run on
@@ -1754,504 +2529,24 @@ def train(
     # v5e, PERF.md) and are waited for by the first program that reads
     # them, inside ``lightgbm.boost``.
     with tracer.span("lightgbm.upload") as up_span:
-        # Device placement; shard rows over the mesh data axis when given.
-        # Rows are padded to a multiple of the data-axis size; padding rides along
-        # with zero weight/count so it never influences histograms or stats — the
-        # "empty partition sends ignore" analogue (LightGBMUtils.scala:144-161).
-        pad = 0
-        sh_bins = None
-        up_bytes = 0
-
-        def host(a):
-            """A host array on its way to the device, counted for the tag."""
-            nonlocal up_bytes
-            up_bytes += a.nbytes
-            return a
-
-        if mesh is not None:
-            from mmlspark_tpu.parallel.mesh import (
-                AXIS_MODEL,
-                data_sharding,
-                feature_parallel_sharding,
-                pad_to_multiple,
-                replicated,
-            )
-
-            shard_n = int(mesh.shape["data"])
-            padded_n, pad = pad_to_multiple(n, shard_n)
-            if pad:
-                bins = np.concatenate([bins, np.zeros((pad, f), dtype=bins.dtype)])
-                y_np = np.concatenate([y_np, np.zeros(pad, dtype=np.float32)])
-                w = np.concatenate([w, np.zeros(pad, dtype=np.float32)])
-                margins0 = np.concatenate(
-                    [margins0, np.zeros((pad, num_classes), dtype=margins0.dtype)]
-                )
-            sh_rows = data_sharding(mesh)
-            sh_rep = replicated(mesh)
-            model_size = int(mesh.shape.get(AXIS_MODEL, 1))
-            if model_size > 1 and f % model_size == 0 and bundle is None:
-                # feature parallel: bins vertically partitioned over the model
-                # axis (LightGBM's feature_parallel layout); XLA partitions the
-                # histogram build/split search and inserts the best-split
-                # argmax collectives across model shards itself. (Indivisible
-                # feature counts stay row-sharded/replicated over model.)
-                sh_bins = feature_parallel_sharding(mesh)
-            put_rows = lambda a: jax.device_put(a, sh_rows)
-            put_rep = lambda a: jax.device_put(a, sh_rep)
-        else:
-            put_rows = put_rep = jnp.asarray
-        presence = np.ones(n + pad, dtype=np.float32)
-        if pad:
-            presence[n:] = 0.0
-
-        if mapper is not None:
-            edges = np.where(np.isfinite(mapper.edges), mapper.edges, np.float32(np.finfo(np.float32).max))
-        else:
-            edges = np.zeros((f, 1))
-        edges_dev = put_rep(host(edges.astype(np.float32)))
-
-        def dev_rows(a):
-            """Re-shard a device-created array onto the row sharding (device-to-
-            device; no host wire traffic)."""
-            return jax.device_put(a, sh_rows) if mesh is not None else a
-
-        # Ship bins as uint8 when they fit (4x less host->device traffic);
-        # consumers compare/gather fine on uint8 and the histogram kernels
-        # upcast per-tile. Device-RESIDENT bins (bin_dataset_to_device's
-        # overlapped streaming upload) skip the put entirely.
-        put_bins = (lambda a: jax.device_put(a, sh_bins)) if sh_bins is not None else put_rows
-        if isinstance(bins, jax.Array) and mesh is None:
-            bins_dev = bins
-        elif num_bins <= 256:
-            # uint8 inputs (incl. out-of-core memmaps) upload as-is — no host
-            # copy; device_put streams straight from the mapping
-            b8 = np.asarray(bins) if not isinstance(bins, np.ndarray) else bins
-            b8 = b8 if b8.dtype == np.uint8 else b8.astype(np.uint8)
-            bins_dev = put_bins(host(np.ascontiguousarray(b8)))
-        else:
-            bins_dev = put_bins(host(np.asarray(bins, dtype=np.int32)))
-        # Integer-valued labels (binary/multiclass/count targets) ride the wire
-        # as uint8 and upcast on device — 4x less of the per-fit transfer cost.
-        if y_np.size and np.all(np.mod(y_np, 1) == 0) and np.all((y_np >= 0) & (y_np <= 255)):
-            y_dev = put_rows(host(y_np.astype(np.uint8))).astype(jnp.float32)
-        else:
-            y_dev = put_rows(host(y_np))
-        # Constant-valued operands are created ON device instead of uploaded.
-        if w_is_default:
-            w_dev = dev_rows(jnp.ones(n + pad, jnp.float32))
-        else:
-            w_dev = put_rows(host(w))
-        if init_margins is None:
-            margins = dev_rows(
-                jnp.asarray(init_score, dtype=jnp.float32)[None, :]
-                * jnp.ones((n + pad, 1), jnp.float32)
-            )
-        else:
-            margins = put_rows(host(margins0.astype(np.float32)))
-        up_span.tags["bytes"] = up_bytes
+        data = _place(prep, mesh)
+        up_span.tags["bytes"] = data.bytes
 
     with tracer.span("lightgbm.program") as prog_span:
-        # U histogram path (ops/u_histogram.py): single-device fits whose packed
-        # one-hot fits the HBM budget contract each pass against a fit-resident
-        # U instead of rebuilding the one-hot (measured 2.1x/pass on v5e).
-        # histogram_method='u' forces it (tests exercise it on CPU); the env
-        # knobs kill it or resize the budget without code changes.
-        import os as _os
-
-        u_spec = None
-        u_budget = 0  # the in-force U HBM budget; the OOM ladder halves it
-        if (
-            mesh is None
-            and opts.tree_learner != "voting_parallel"
-            and num_bins <= 256
-            and _os.environ.get("MMLSPARK_TPU_NO_U") != "1"
-            and (
-                opts.histogram_method == "u"
-                or (
-                    opts.histogram_method in (None, "pallas")
-                    and on_tpu()
-                )
-            )
-        ):
-            from mmlspark_tpu.ops.u_histogram import (
-                chunked_u_spec,
-                make_u_spec,
-                num_u_chunks,
-                u_bytes,
-            )
-
-            if bundle is not None:
-                # U laid out over the PACKED columns — K = Σ bundle widths is
-                # the whole point: fewer one-hot rows to re-stream per pass.
-                cand = make_u_spec(
-                    bundle.num_bins, f, [int(wd) for wd in bundle.widths]
-                )
-            else:
-                per_feature = None if mapper is None else [int(x) for x in mapper.num_bins]
-                cand = make_u_spec(num_bins, f, per_feature)
-            try:
-                budget = int(_os.environ.get("MMLSPARK_TPU_U_BUDGET", str(8 << 30)))
-            except ValueError:
-                from mmlspark_tpu.core.profiling import get_logger
-
-                get_logger("mmlspark_tpu.lightgbm").warning(
-                    "MMLSPARK_TPU_U_BUDGET=%r is not an integer byte count; "
-                    "using the default 8 GB budget",
-                    _os.environ["MMLSPARK_TPU_U_BUDGET"],
-                )
-                budget = 8 << 30
-            if u_bytes(n + pad, cand) > budget:
-                # Over budget: stream the pass in row chunks instead of
-                # abandoning the MXU path wholesale (the pre-chunking behavior
-                # was an all-or-nothing cliff: one row past the budget and the
-                # whole fit fell back to the compare-built kernels).
-                cand = chunked_u_spec(n + pad, cand, budget)
-            u_spec = cand
-            u_budget = budget
-            if u_spec.chunk_rows:
-                chunks = num_u_chunks(n + pad, u_spec)
-                from mmlspark_tpu.core.profiling import get_logger
-
-                get_logger("mmlspark_tpu.lightgbm").info(
-                    "U one-hot (%.1f GB) exceeds MMLSPARK_TPU_U_BUDGET (%.1f GB);"
-                    " streaming each histogram pass in %d row chunks of %d",
-                    u_bytes(n + pad, dataclasses.replace(u_spec, chunk_rows=0))
-                    / 1e9,
-                    budget / 1e9, chunks, u_spec.chunk_rows,
-                )
-                from mmlspark_tpu.observability.events import (
-                    HistogramChunked,
-                    get_bus,
-                )
-
-                bus = get_bus()
-                if bus.active:
-                    from mmlspark_tpu.ops.u_histogram import histogram_acc_dtype
-
-                    # quant may still fall back below (row cap); mirror that
-                    # predicate so the event records the dtype actually used
-                    _ck_quant = opts.use_quantized_grad and (
-                        n + pad <= min((1 << 31) // 127, 1 << 24)
-                    )
-                    _ck_dt = jnp.dtype(histogram_acc_dtype(n + pad, _ck_quant))
-                    _ck_3k = 3 * max(1, min(opts.leaf_batch, opts.num_leaves - 1))
-                    bus.publish(HistogramChunked(
-                        rows=n + pad, k_packed=u_spec.k_pad,
-                        chunk_rows=u_spec.chunk_rows, num_chunks=chunks,
-                        budget_bytes=budget,
-                        acc_dtype=_ck_dt.name,
-                        bytes_saved=u_spec.k_pad * _ck_3k
-                        * (4 - _ck_dt.itemsize),
-                    ))
-
-        if opts.use_quantized_grad:
-            reason = None
-            if u_spec is None:
-                reason = (
-                    "the precomputed-U histogram path is inactive (non-TPU "
-                    "backend without histogram_method='u', mesh/voting "
-                    "parallelism, num_bins > 256, or U over the HBM budget)"
-                )
-            elif n + pad > min((1 << 31) // 127, 1 << 24):
-                # Two ceilings, enforce the tighter (2^24): s8 x s8 sums
-                # accumulate in int32 (|sum| <= 127 * rows wraps past
-                # 2^31/127 ~= 16.9M rows), and the f32 count channel loses
-                # integer exactness above 2^24 — the "counts stay exact"
-                # contract in _split_search holds only below it.
-                reason = (
-                    f"{n + pad} rows exceeds the quantized-path cap "
-                    "min(2^31/127, 2^24) = 2^24 (f32 count exactness / int32 "
-                    "histogram accumulator)"
-                )
-            if reason is not None:
-                from mmlspark_tpu.core.profiling import get_logger
-
-                get_logger("mmlspark_tpu.lightgbm").warning(
-                    "use_quantized_grad requested but %s; training with exact "
-                    "bf16 stats instead", reason,
-                )
-                opts = dataclasses.replace(opts, use_quantized_grad=False)
-
-        if (
-            opts.use_quantized_grad
-            and u_spec is not None
-            and opts.growth == "depthwise"
-            and opts.depth >= 7
-        ):
-            # The U panel packs 3 stat planes per frontier node into 128
-            # slots, so levels with > 42 nodes (2^6 = 64 at level 6, reached
-            # once depth >= 7) can't ride the quantized U kernel; _hist_fn
-            # drops those levels to the exact histogram path. Surface the
-            # per-level degrade once per fit instead of silently.
-            from mmlspark_tpu.core.profiling import get_logger
-
-            get_logger("mmlspark_tpu.lightgbm").warning(
-                "use_quantized_grad with depthwise growth and depth %d: levels "
-                "deeper than 5 have > 42 frontier nodes and exceed the 128-slot "
-                "U panel budget (3 stats x nodes), so those levels fall back to "
-                "exact (non-quantized) histograms per level",
-                opts.depth,
-            )
-
-        if opts.growth == "leafwise" and opts.histogram_subtraction:
-            # Mirror _build_tree_leafwise's use_sub gate so the event reports
-            # the path the trace will actually take (static predicate).
-            from mmlspark_tpu.observability.events import (
-                HistogramSubtracted,
-                get_bus,
-            )
-            from mmlspark_tpu.ops.u_histogram import histogram_acc_dtype
-
-            _sb_cols = len(bundle.widths) if bundle is not None else f
-            _sb_bins = bundle.num_bins if bundle is not None else num_bins
-            _sb_quant = opts.use_quantized_grad and u_spec is not None
-            _sb_dt = jnp.dtype(histogram_acc_dtype(n + pad, _sb_quant))
-            _sb_m = 2 * opts.num_leaves - 1
-            _sb_cache = (
-                max(1, opts.num_class) * _sb_m * _sb_cols * _sb_bins * 3
-                * _sb_dt.itemsize
-            )
-            bus = get_bus()
-            if (
-                bus.active
-                and _sb_cache <= (256 << 20)
-                and opts.tree_learner != "voting_parallel"
-            ):
-                bus.publish(HistogramSubtracted(
-                    rows=n + pad, num_leaves=opts.num_leaves,
-                    packed_columns=_sb_cols, packed_bins=_sb_bins,
-                    acc_dtype=_sb_dt.name, cache_bytes=_sb_cache,
-                    bytes_saved_per_tree=(opts.num_leaves - 1) * _sb_cols
-                    * _sb_bins * 3 * _sb_dt.itemsize,
-                ))
-
-        okey = (_opts_key(opts), num_bins, mesh, u_spec, bundle, objective.cache_token)
-        if opts.boosting_type == "goss":
-            okey = okey + (n,)  # GOSS bakes the unpadded row count into the program
-        _prof = get_profiler()
-        _prof_on = _prof.active
-        built_before = programs_built()
-        if hist_reduce is not None:
-            # the reduce hook closes over a live socket group — never share a
-            # compiled program holding it across fits. The profiler wrap times
-            # the host-side collective per call, splitting each iteration into
-            # histogram-build (device) vs allreduce (wire) time.
-            if _prof_on:
-                hist_reduce = _prof.wrap_host(hist_reduce, "gbdt.hist_allreduce")
-            step_raw = _make_step(
-                opts, objective, num_bins, mesh, n_real=n, u_spec=u_spec,
-                hist_reduce=hist_reduce, bundle=bundle,
-            )
-            step = jax.jit(step_raw, donate_argnums=(3,))
-        else:
-            step_raw = cached_program(
-                ("step_raw", okey),
-                lambda: _make_step(
-                    opts, objective, num_bins, mesh, n_real=n, u_spec=u_spec,
-                    bundle=bundle,
-                ),
-            )
-            step = cached_program(
-                ("step_jit", okey), lambda: jax.jit(step_raw, donate_argnums=(3,))
-            )
-        # whether this fit's step program was already built by an earlier fit
-        prog_span.tags["cache_hit"] = (
-            hist_reduce is None and programs_built() == built_before
-        )
-        u_builder = None
-        if u_spec is not None:
-            if u_spec.chunk_rows:
-                # chunked pass consumes a (num_chunks, F, chunk) bins stack
-                # laid out once per fit, not the resident one-hot
-                from mmlspark_tpu.ops.u_histogram import prepare_chunked_bins
-
-                u_builder = partial(prepare_chunked_bins, spec=u_spec)
-            else:
-                from mmlspark_tpu.ops.u_histogram import build_u
-
-                u_builder = partial(build_u, spec=u_spec)
-        valid_update = cached_program(
-            ("valid_update", opts.routing_steps, bundle),
-            lambda: _make_valid_update(opts.routing_steps, bundle),
-        )
-
-        # -- RESOURCE_EXHAUSTED degradation ladder (docs/resilience.md) ----------
-        # An HBM OOM during a histogram dispatch is retryable at a reduced
-        # footprint: halve the in-memory U budget (floor 1 MiB), re-derive the
-        # chunked-U spec, rebuild the step program, and re-run the SAME
-        # iteration. Chunked and resident passes are bit-exact, so the final
-        # model text matches an undisturbed run byte for byte. The last rung —
-        # a smaller ``leaf_batch`` — changes split-scheduling and is left to
-        # the caller (it trades reproducibility for survival).
-        from mmlspark_tpu.runtime.faults import (
-            current_faults as _current_faults,
-            is_oom_error as _is_oom,
-        )
-
-        _fault_plan = _current_faults()
-        _oom_retry_cap = 8
-
-        def _degrade_for_oom(err, stage, iteration, retries) -> bool:
-            """Walk one rung down the ladder; True when the caller may retry."""
-            nonlocal u_spec, u_budget, okey, step_raw, step, u_builder
-            if u_spec is None:
-                return False  # no U path active: nothing to shrink in-loop
-            new_budget = max(u_budget // 2, 1 << 20)
-            if new_budget == u_budget and u_spec.chunk_rows:
-                return False  # floor reached; the OOM is genuine scarcity
-            u_budget = new_budget
-            from mmlspark_tpu.ops.u_histogram import (
-                build_u,
-                chunked_u_spec,
-                prepare_chunked_bins,
-            )
-
-            u_spec = chunked_u_spec(
-                n + pad, dataclasses.replace(u_spec, chunk_rows=0), u_budget
-            )
-            okey = (
-                _opts_key(opts), num_bins, mesh, u_spec, bundle,
-                objective.cache_token,
-            )
-            if opts.boosting_type == "goss":
-                okey = okey + (n,)
-            if hist_reduce is not None:
-                step_raw = _make_step(
-                    opts, objective, num_bins, mesh, n_real=n, u_spec=u_spec,
-                    hist_reduce=hist_reduce, bundle=bundle,
-                )
-                step = jax.jit(step_raw, donate_argnums=(3,))
-            else:
-                step_raw = cached_program(
-                    ("step_raw", okey),
-                    lambda: _make_step(
-                        opts, objective, num_bins, mesh, n_real=n, u_spec=u_spec,
-                        bundle=bundle,
-                    ),
-                )
-                step = cached_program(
-                    ("step_jit", okey),
-                    lambda: jax.jit(step_raw, donate_argnums=(3,)),
-                )
-            u_builder = (
-                partial(prepare_chunked_bins, spec=u_spec) if u_spec.chunk_rows
-                else partial(build_u, spec=u_spec)
-            )
-            from mmlspark_tpu.core.profiling import get_logger
-
-            get_logger("mmlspark_tpu.lightgbm").warning(
-                "histogram %s dispatch hit RESOURCE_EXHAUSTED at iteration %d "
-                "(%s); degrading: U budget -> %d bytes, chunk_rows -> %d, "
-                "retry %d",
-                stage, iteration, str(err)[:120], u_budget, u_spec.chunk_rows,
-                retries,
-            )
-            from mmlspark_tpu.observability.events import (
-                HistogramDegraded,
-                MemoryPressure,
-                get_bus,
-            )
-
-            bus = get_bus()
-            if bus.active:
-                bus.publish(MemoryPressure(
-                    source="device", level="critical", used_bytes=0.0,
-                    limit_bytes=0.0, detail=str(err)[:200],
-                ))
-                bus.publish(HistogramDegraded(
-                    rows=n + pad, budget_bytes=u_budget,
-                    chunk_rows=u_spec.chunk_rows, stage=stage,
-                    iteration=int(iteration), retries=int(retries),
-                ))
-            return True
-
-        valid_sets = list(valid_sets or [])
-        valid_state = []
-        for name, bv, yv, wv in valid_sets:
-            wv = np.ones(len(yv), dtype=np.float32) if wv is None else np.asarray(wv, np.float32)
-            mv = np.broadcast_to(init_score[None, :], (len(yv), num_classes)).copy()
-            valid_state.append(
-                {
-                    "name": name,
-                    "bins": jnp.asarray(np.asarray(bv, dtype=np.int32)),
-                    "y": np.asarray(yv, dtype=np.float32),
-                    "w": wv,
-                    "margins": jnp.asarray(mv.astype(np.float32)),
-                }
-            )
-
-        metric = opts.metric or objective.default_metric
-        higher_better = metric_higher_is_better(metric)
-        evals: Dict[str, Dict[str, List[float]]] = {
-            vs["name"]: {metric: []} for vs in valid_state
-        }
-        if opts.provide_training_metric:
-            evals["training"] = {metric: []}
-
-        rng = np.random.default_rng(opts.seed)
-        num_bag = max(1, int(round(n * opts.bagging_fraction)))
-        num_feat = max(1, int(round(f_feat * opts.feature_fraction)))
-
-        from mmlspark_tpu.lightgbm.callbacks import (
-            CallbackEnv,
-            _has_iteration_hooks,
-            _lr_schedule,
-        )
-
-        callbacks = list(callbacks or [])
+        plan = _u_plan(opts, data.rows, prep.f, prep.num_bins, prep.bundle, mapper, mesh)
+        _publish_plan(plan, opts, data.rows)
+        opts = dataclasses.replace(opts, use_quantized_grad=plan.quant)
         lr_all = _lr_schedule(callbacks, opts.learning_rate, opts.num_iterations)
-        iteration_hooks = _has_iteration_hooks(callbacks)
-
-        def _cb_env(it: int) -> "CallbackEnv":
-            lr_it = float(lr_all[it]) if (lr_all is not None and it < len(lr_all)) \
-                else opts.learning_rate
-            return CallbackEnv(
-                iteration=it, num_iterations=opts.num_iterations,
-                learning_rate=lr_it, evals=evals,
-            )
-
-        for cb in callbacks:
-            cb.before_training(_cb_env(0))
-
-        trees: List[TreeArrays] = []
-        best_score = -np.inf if higher_better else np.inf
-        best_iter = 0
-        stale = 0
-
-        # Device-resident inputs are uploaded once and only re-uploaded when
-        # bagging/feature-fraction actually resamples, and per-tree outputs stay
-        # on device until one bulk fetch after the loop, so an iteration costs
-        # no host<->device round-trip of its own.
-        # presence mask built on device (zeroed pad tail) — no upload
-        bag_dev = dev_rows(
-            jnp.ones(n + pad, jnp.float32)
-            if pad == 0
-            else jnp.ones(n + pad, jnp.float32).at[n:].set(0.0)
-        )
-        fm_ones_dev = put_rep(np.ones(f_feat, dtype=np.float32))
-
-        # Fast path: no per-iteration host decisions (no valid-set metrics, no
-        # mesh special-casing) — run every boosting iteration in ONE device
-        # program via lax.scan. Per-iteration masks come from the same
-        # _mask_schedule as the loop path, so semantics (bagging schedule,
-        # feature sampling, rng stream order) are identical.
-        stacked_trees = None
-        schedule = _mask_schedule(
-            opts, rng, n, pad, num_bag, num_feat, f_feat, presence, y=y_np
-        )
-        bag_resampling = _bagging_active(opts)
-        # The scan path materializes an (iterations, N) uint8 bagging-mask array
-        # on device when bagging resamples; gate it so a huge fit (e.g. 10M rows
-        # x 1000 iters = 10 GB) falls back to the loop path, which re-uploads
-        # only on resample.
-        bag_stack_ok = (
-            not bag_resampling or opts.num_iterations * (n + pad) <= (512 << 20)
-        )
+        bagging = _bagging_active(opts)
+        # The scan path: no per-iteration host decisions, every iteration in
+        # ONE device program. Its (iterations, N) bagging masks are gated so
+        # a huge fit (10M rows x 1000 iterations = 10 GB) takes the loop path,
+        # which re-uploads only on resample.
         scan_path = (
             mesh is None
-            and not valid_state
-            and not iteration_hooks  # per-iteration delegates need the loop path
-            and bag_stack_ok
+            and not valid_sets
+            and not _has_iteration_hooks(callbacks)
+            and (not bagging or opts.num_iterations * data.rows <= (512 << 20))
             and opts.num_iterations > 0
             and opts.boosting_type != "dart"  # dart drops trees per host decision
             and not opts.provide_training_metric  # needs per-iteration margins
@@ -2259,59 +2554,28 @@ def train(
             and iteration_hook is None
             and start_iteration == 0
         )
-        if scan_path:
-            bag_list, fm_list = [], []
-            for bag_np, _, fm_np in schedule:
-                bag_list.append(bag_np)
-                fm_list.append(fm_np if fm_np is not None else np.ones(f_feat, np.float32))
-            if bag_resampling:
-                # uint8 on the wire (masks are 0/1; 4x less than f32); cast per
-                # scan step
-                bag_arg = jnp.asarray(np.stack(bag_list).astype(np.uint8))
-            else:
-                bag_arg = bag_dev  # (N,) closed over inside the program
-            fm_all = jnp.asarray(np.stack(fm_list))
-            per_iter_lr = lr_all is not None
-            lr_arg = jnp.asarray(lr_all) if per_iter_lr else fm_all  # unused placeholder
-            runner = cached_program(
-                ("scan", okey, bag_resampling, per_iter_lr),
-                lambda: _make_scan_steps(
-                    step_raw, per_iter_bag=bag_resampling, per_iter_lr=per_iter_lr,
-                    with_u=u_builder is not None,
-                ),
-            )
-        else:
-            dart_rng = np.random.default_rng(opts.seed + 7919)
-            tree_contrib = cached_program(
-                ("tree_contrib", opts.routing_steps, bundle),
-                lambda: _make_tree_contrib(opts.routing_steps, bundle),
-            )
-
-            def contrib_of(tr, bins_v):
-                return tree_contrib(
-                    bins_v, tr.feat, tr.bin, tr.left, tr.right, tr.is_leaf,
-                    tr.leaf_val, tr.cat_node, tr.cat_mask,
-                )
-
-    def build_u_dev():
-        """The fit-resident U (or the chunked bins stack): one dispatch,
-        again only after an OOM degraded the spec. The build's device time
-        is waited for by whatever reads its result: ``lightgbm.boost``."""
-        from mmlspark_tpu.ops.u_histogram import num_u_chunks, u_bytes
-
-        chunks = num_u_chunks(n + pad, u_spec)
-        u_jit = cached_program(
-            ("u_build_jit", u_spec), lambda: jax.jit(u_builder)
+        if hist_reduce is not None:
+            # times the host-side collective per call: histogram build
+            # (device) vs allreduce (wire) time
+            hist_reduce = get_profiler().wrap_host(hist_reduce, "gbdt.hist_allreduce")
+        run = _Run(
+            opts, prep, data, plan, mesh=mesh, hist_reduce=hist_reduce,
+            scan=(bagging, lr_all is not None) if scan_path else None,
+            faults=current_faults(),
         )
-        with tracer.span(
-            "lightgbm.u_build", chunks=chunks,
-            u_bytes=chunks * u_spec.chunk_rows * f if u_spec.chunk_rows
-            else u_bytes(n + pad, u_spec),
-        ):
-            return u_jit(bins_dev)
+        run.build_programs()
+        # whether this fit's step program was already built by an earlier fit
+        prog_span.tags["cache_hit"] = run.progs.cache_hit
+        watch = _Watch.start(opts, prep, valid_sets, callbacks, lr_all)
+        schedule = _mask_schedule(opts, prep.n, data.pad, prep.f_feat, y=prep.y)
+        # presence mask built on device (zeroed pad tail) — no upload
+        ones = jnp.ones(data.rows, jnp.float32)
+        bag_dev = data.dev_rows(ones if data.pad == 0 else ones.at[prep.n:].set(0.0))
+        if scan_path:
+            inputs = _scan_inputs(schedule, prep.f_feat, bagging, bag_dev, lr_all)
 
-    # built ONCE per fit, shared by every segment or iteration below
-    u_dev = build_u_dev() if u_builder is not None else None
+    if plan.spec is not None:
+        run.build_u()  # once a fit, shared by every segment or iteration
     # ``lightgbm.boost`` runs from the first dispatch until the trees are on
     # the host: the dispatches return at once, so the span's time is the
     # device's, waited for in _fetch_trees (and per iteration on the loop
@@ -2319,308 +2583,25 @@ def train(
     with tracer.span(
         "lightgbm.boost", iterations=opts.num_iterations - start_iteration
     ) as boost_span:
+        trees = stacked = None
         if scan_path:
-            no_u = jnp.int32(0)  # unused placeholder when no U path
-            # Segment the one-dispatch fit so no single device program runs for
-            # minutes. Observed on an earlier v5e host: a 4M-row x 100-iteration
-            # scan (~90 s on-device) reproducibly killed the TPU worker, while
-            # 4M x 50 and 2M x 100 (~50 s) ran fine. Whether the current machine
-            # still needs this is an open ROADMAP question; the bound is
-            # MMLSPARK_TPU_SCAN_ROW_ITERS. Equal-length segments share one compiled program; margins thread
-            # between dispatches, so results are identical to the single scan.
-            row_iters = n * max(1, opts.num_iterations) * max(1, num_classes)
-            budget = int(_os.environ.get("MMLSPARK_TPU_SCAN_ROW_ITERS", 200_000_000))
-            nseg = max(1, -(-row_iters // budget))
-            # prefer a divisor of the iteration count close to nseg: equal
-            # segment lengths mean ONE compiled shape instead of two
-            for cand in range(nseg, min(nseg + 3, max(1, opts.num_iterations)) + 1):
-                if opts.num_iterations % cand == 0:
-                    nseg = cand
-                    break
-            seg = -(-opts.num_iterations // nseg)
-            boost_span.tags["segments"] = nseg
-            parts = []
-            for s0 in range(0, opts.num_iterations, seg):
-                s1 = min(s0 + seg, opts.num_iterations)
-                # margins is donated into the runner; a degraded retry of
-                # this segment needs the pre-dispatch value back, so keep a
-                # host snapshot (segments are rare — usually one per fit)
-                margins_before = np.asarray(margins)
-                oom_retries = 0
-                while True:
-                    try:
-                        # injected OOM fires pre-dispatch (margins not donated
-                        # yet), so the degraded retry re-dispatches cleanly
-                        if _fault_plan is not None:
-                            _fault_plan.apply_on_histogram(s0, oom_retries)
-                        # profiling forces a per-segment sync (an honest device
-                        # window needs block_until_ready); the unprofiled fit
-                        # keeps the async dispatch pipeline.
-                        t_seg = time.perf_counter() if _prof_on else 0.0
-                        cache_before = (
-                            runner._cache_size() if _prof_on
-                            and hasattr(runner, "_cache_size") else None
-                        )
-                        margins, part = runner(
-                            bins_dev, y_dev, w_dev, margins, edges_dev,
-                            bag_arg[s0:s1] if bag_resampling else bag_arg,
-                            fm_all[s0:s1],
-                            lr_arg[s0:s1] if per_iter_lr else lr_arg,
-                            jnp.int32(s0),
-                            no_u if u_dev is None else u_dev,
-                        )
-                        if _prof_on:
-                            jax.block_until_ready((margins, part))
-                            dt = time.perf_counter() - t_seg
-                            compiled = (
-                                cache_before is not None
-                                and hasattr(runner, "_cache_size")
-                                and runner._cache_size() > cache_before
-                            )
-                            if compiled:
-                                _prof.note_compile("gbdt.scan", dt)
-                            else:
-                                _prof.note_cache_hit("gbdt.scan")
-                            _prof.note_execute("gbdt.scan", dt)
-                        break
-                    except Exception as e:  # noqa: BLE001 - OOM-classified below
-                        if (
-                            not _is_oom(e)
-                            or oom_retries >= _oom_retry_cap
-                            or not _degrade_for_oom(e, "scan", s0, oom_retries + 1)
-                        ):
-                            raise
-                        oom_retries += 1
-                        # recreate the donated margins buffer and rebuild the
-                        # scan program + fit-resident U under the new spec
-                        margins = jnp.asarray(margins_before)
-                        runner = cached_program(
-                            ("scan", okey, bag_resampling, per_iter_lr),
-                            lambda: _make_scan_steps(
-                                step_raw, per_iter_bag=bag_resampling,
-                                per_iter_lr=per_iter_lr,
-                                with_u=u_builder is not None,
-                            ),
-                        )
-                        if u_builder is not None:
-                            u_dev = build_u_dev()
-                parts.append(part)
-            stacked_trees = (
-                parts[0]
-                if len(parts) == 1
-                else jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0), *parts)
-            )
+            stacked = _boost_scan(run, inputs, boost_span)
         else:
             boost_span.tags["segments"] = 0  # the loop path: a dispatch an iteration
-            pending_bag = None
-            for it, (bag_np, bag_changed, fm_np) in enumerate(schedule):
-                if it < start_iteration:
-                    # journal resume: consume the draw (rng stream stays
-                    # aligned with an uninterrupted fit) without boosting
-                    if bag_changed:
-                        pending_bag = bag_np
-                    continue
-                if pending_bag is not None:
-                    # the last skipped resample is the mask in force at k
-                    if not bag_changed:
-                        bag_np, bag_changed = pending_bag, True
-                    pending_bag = None
-                if bag_changed:
-                    bag_dev = put_rows(bag_np)
-                fm_dev = put_rep(fm_np) if fm_np is not None else fm_ones_dev
-                for cb in callbacks:
-                    cb.before_iteration(_cb_env(it))
-                # traced scalar (not a baked constant) so per-iteration LR values
-                # don't each recompile the step program
-                lr_it = jnp.float32(
-                    lr_all[it] if lr_all is not None else opts.learning_rate
-                )
-
-                # dart: drop a random subset of existing trees from the margins
-                # the new tree fits against (each with prob drop_rate), then
-                # renormalize — new tree x 1/(k+1), dropped trees x k/(k+1)
-                # (the DART weight-shrinkage rule).
-                dropped = []
-                if opts.boosting_type == "dart" and trees:
-                    dropped = list(np.nonzero(
-                        dart_rng.random(len(trees)) < opts.drop_rate
-                    )[0])
-                if dropped:
-                    c_d = contrib_of(trees[dropped[0]], bins_dev)
-                    for di in dropped[1:]:
-                        c_d = c_d + contrib_of(trees[di], bins_dev)
-                    margins_in = margins - c_d
-                else:
-                    margins_in = margins
-
-                # Injected OOM faults fire here, BEFORE dispatch, so margins_in
-                # has not been donated when the degraded retry re-dispatches.
-                # A real device OOM surfaces after donation; the retry is then
-                # best-effort (the allocator usually fails before consuming the
-                # donated buffer, but that is not contractual).
-                oom_retries = 0
-                while True:
-                    try:
-                        if _fault_plan is not None:
-                            _fault_plan.apply_on_histogram(it, oom_retries)
-                        t_step = time.perf_counter() if _prof_on else 0.0
-                        step_cache_before = (
-                            step._cache_size() if _prof_on
-                            and hasattr(step, "_cache_size") else None
-                        )
-                        tree, new_margins = step(
-                            bins_dev, y_dev, w_dev, margins_in, edges_dev,
-                            bag_dev, fm_dev, jnp.int32(it), lr_it, u=u_dev,
-                        )
-                        break
-                    except Exception as e:  # noqa: BLE001 - OOM-classified below
-                        if (
-                            not _is_oom(e)
-                            or oom_retries >= _oom_retry_cap
-                            or not _degrade_for_oom(e, "loop", it, oom_retries + 1)
-                        ):
-                            raise
-                        oom_retries += 1
-                        if u_builder is not None:
-                            u_dev = build_u_dev()
-
-                if dropped:
-                    k = len(dropped)
-                    scale_new = 1.0 / (k + 1)
-                    scale_drop = k / (k + 1)
-                    # margins_in was donated into step — recover the unscaled
-                    # new-tree contribution from the row->leaf map it computed
-                    c_new = jnp.take_along_axis(tree.leaf_val, tree.row_leaf, axis=1).T
-                    # valid-set deltas need the PRE-scaled dropped trees
-                    for vs in valid_state:
-                        c_dv = contrib_of(trees[dropped[0]], vs["bins"])
-                        for di in dropped[1:]:
-                            c_dv = c_dv + contrib_of(trees[di], vs["bins"])
-                        c_newv = contrib_of(tree, vs["bins"])
-                        vs["margins"] = (
-                            vs["margins"] - c_dv * scale_new + c_newv * scale_new
-                        )
-                        vs["_updated"] = True
-                    tree = tree._replace(leaf_val=tree.leaf_val * scale_new)
-                    for di in dropped:
-                        trees[di] = trees[di]._replace(
-                            leaf_val=trees[di].leaf_val * scale_drop
-                        )
-                    margins = margins - c_d * scale_new + c_new * scale_new
-                else:
-                    margins = new_margins
-                # Synchronize each iteration on the mesh path: an unbounded async
-                # queue of collective programs can starve a device thread past the
-                # XLA rendezvous timeout (hard abort on the host-platform mesh),
-                # and per-iteration sync is the barrier-execution-mode semantics
-                # of the reference anyway (TrainUtils.scala:477-483).
-                jax.block_until_ready(margins)
-                if _prof_on:
-                    # the per-iteration device window: step dispatch through
-                    # the mesh sync above (dart host work rides along on the
-                    # rare dropped-tree iterations)
-                    dt = time.perf_counter() - t_step
-                    compiled = (
-                        step_cache_before is not None
-                        and hasattr(step, "_cache_size")
-                        and step._cache_size() > step_cache_before
-                    )
-                    if compiled:
-                        _prof.note_compile("gbdt.step", dt)
-                    else:
-                        _prof.note_cache_hit("gbdt.step")
-                    _prof.note_execute("gbdt.step", dt)
-                # drop row_leaf, a (C, N) buffer per tree, before retaining
-                trees.append(tree._replace(row_leaf=None))
-                if iteration_hook is not None:
-                    # the commit point: the iteration's tree is final and its
-                    # margins applied — procfit journals it here
-                    iteration_hook(it, trees[-1])
-
-                if opts.provide_training_metric:
-                    # isProvideTrainingMetric: train-set metric per iteration
-                    # (a device fetch per round — opt-in, loop path only)
-                    evals["training"][metric].append(_evaluate(
-                        metric, opts.objective, y_np[:n], np.asarray(margins)[:n],
-                        w[:n], opts.alpha,
-                    ))
-
-                improved_any = False
-                for vs in valid_state:
-                    if vs.pop("_updated", False):
-                        pass  # dart already applied this round's delta
-                    else:
-                        vs["margins"] = valid_update(vs["bins"], vs["margins"], tree)
-                    score = _evaluate(
-                        metric, opts.objective, vs["y"], np.asarray(vs["margins"]),
-                        vs["w"], opts.alpha,
-                    )
-                    evals[vs["name"]][metric].append(score)
-                    # best-so-far from the true score (TrainUtils.scala:276-315);
-                    # the first finite eval improves on the ±inf sentinel
-                    # naturally, and a NaN score never registers as an improvement.
-                    delta = (score - best_score) if higher_better else (best_score - score)
-                    if delta > opts.improvement_tolerance:
-                        best_score, best_iter, improved_any = score, it + 1, True
-                stop_requested = False
-                for cb in callbacks:
-                    if cb.after_iteration(_cb_env(it)):
-                        stop_requested = True
-                if stop_requested:
-                    break
-                if valid_state and opts.early_stopping_round > 0:
-                    stale = 0 if improved_any else stale + 1
-                    if stale >= opts.early_stopping_round:
-                        break
-
-        # scan path: all iterations ran inside one program (trees list unused)
-        iters_done = opts.num_iterations if stacked_trees is not None else len(trees)
-        for cb in callbacks:
-            cb.after_training(_cb_env(max(0, iters_done - 1)))
-
-        if opts.verbosity >= 1:
-            import logging as _logging
-
-            from mmlspark_tpu.core.profiling import get_logger
-
-            logger = get_logger("mmlspark_tpu.lightgbm")
-            # verbosity is an explicit request for output — lift the level floor
-            # for THIS summary only, restoring the configured level after
-            root_logger = _logging.getLogger("mmlspark_tpu")
-            prev_level = root_logger.level
-            if root_logger.getEffectiveLevel() > _logging.INFO:
-                root_logger.setLevel(_logging.INFO)
-            try:
-                for name, metrics in evals.items():
-                    for mname, scores in metrics.items():
-                        if not scores:
-                            continue
-                        arr = np.asarray(scores, dtype=np.float64)
-                        if np.isnan(arr).all():
-                            logger.info("valid %s %s: all evals NaN", name, mname)
-                            continue
-                        best_i = int(
-                            np.nanargmax(arr) if higher_better else np.nanargmin(arr)
-                        )
-                        logger.info(
-                            "valid %s %s: last=%.6f best=%.6f@%d",
-                            name, mname, scores[-1], arr[best_i], best_i + 1,
-                        )
-            finally:
-                root_logger.setLevel(prev_level)
-
+            trees = _boost_loop(run, watch, schedule, bag_dev, iteration_hook, start_iteration)
+        iters_done = opts.num_iterations if stacked is not None else len(trees)
+        watch.finish(iters_done)
         # with the trees, how many times the growers streamed the rows for a
         # histogram and how many rounds' children they left unbuilt
-        fetched, (built, skipped) = _fetch_trees(trees, stacked_trees, opts, num_classes)
+        fetched, (built, skipped) = _fetch_trees(trees, stacked, opts, prep.num_classes)
         boost_span.tags["hist_passes_built"] = int(built)
         boost_span.tags["hist_passes_skipped"] = int(skipped)
-    with tracer.span("lightgbm.pack", trees=iters_done * num_classes):
+    with tracer.span("lightgbm.pack", trees=iters_done * prep.num_classes):
         booster = _assemble_booster(
-            fetched, opts, num_classes, init_score, mapper, feature_names,
-            best_iteration=best_iter
-            if (valid_state and opts.early_stopping_round > 0) else -1,
+            fetched, opts, prep.num_classes, prep.init_score, mapper, feature_names,
+            best_iteration=watch.best_iteration,
         )
-    return TrainResult(booster=booster, evals=evals, best_iteration=best_iter)
+    return TrainResult(booster=booster, evals=watch.evals, best_iteration=watch.best_iter)
 
 
 def train_many(
@@ -2636,7 +2617,7 @@ def train_many(
     The many-models plane: every candidate must share
     :func:`many_bucket_key` (callers bucket heterogeneous grids first and
     call once per bucket). The per-iteration step is vmapped over a leading
-    candidate axis (:func:`_make_scan_steps_many`), so the whole sweep
+    candidate axis (:func:`_scan_program`), so the whole sweep
     bucket is one dispatch and one compile — the per-candidate
     hyperparameters ride as traced data: learning_rate as a scanned
     (K, iterations) stack, bagging/feature-fraction as host-drawn mask
@@ -2669,177 +2650,49 @@ def train_many(
     if opts_list[0].num_iterations <= 0:
         raise ValueError("train_many requires num_iterations > 0")
     for o in opts_list:
-        if o.boosting_type == "goss" and o.bagging_fraction < 1.0:
-            raise ValueError(
-                "boosting_type='goss' cannot be combined with bagging"
-            )
-        if o.boosting_type == "goss" and o.top_rate + o.other_rate > 1.0:
-            raise ValueError(
-                "goss requires top_rate + other_rate <= 1 "
-                f"(got {o.top_rate} + {o.other_rate})"
-            )
-        if (
-            o.pos_bagging_fraction < 1.0 or o.neg_bagging_fraction < 1.0
-        ) and o.objective != "binary":
-            raise ValueError(
-                "posBaggingFraction/negBaggingFraction require the binary "
-                f"objective (got {o.objective!r})"
-            )
-
-    objective = get_objective(opts_list[0].objective)
-    num_classes = objective.num_outputs_fn(opts_list[0].num_class)
-    n, f = bins.shape
-    num_bins = opts_list[0].max_bin + 1
-    bundle = getattr(mapper, "bundles", None) if mapper is not None else None
-    if bundle is not None and f != bundle.num_columns:
-        raise ValueError(
-            f"bundled mapper expects packed bins with {bundle.num_columns} "
-            f"columns, got {f}"
-        )
-    f_feat = bundle.num_features if bundle is not None else f
-    if mapper is not None and mapper.cat_values:
-        # same mapper → same slot resolution for every candidate (the
-        # bucket key already agrees on categorical/onehot slots)
-        cat_kw = dict(
-            categorical_slots=tuple(sorted(mapper.cat_values)),
-            onehot_slots=tuple(
-                f_
-                for f_ in sorted(mapper.cat_values)
-                if len(mapper.cat_values[f_])
-                <= opts_list[0].max_cat_to_onehot
-            ),
-        )
-        opts_list = [dataclasses.replace(o, **cat_kw) for o in opts_list]
-    base = normalize_many_opts(opts_list[0])
-    K = len(opts_list)
-    iters = base.num_iterations
-
-    w_is_default = w is None
-    w = (
-        np.ones(n, dtype=np.float32)
-        if w is None
-        else np.asarray(w, dtype=np.float32)
-    )
-    y_np = np.asarray(y, dtype=np.float32)
-    # boost_from_average is static (outside MANY_VMAPPED_FIELDS), so one
-    # init_score serves the whole bucket
-    if base.boost_from_average:
-        init_score = objective.init_score(y_np, num_classes, w)
-    else:
-        init_score = np.zeros(num_classes, dtype=np.float32)
-    margins0 = np.broadcast_to(init_score[None, :], (n, num_classes)).copy()
-    presence = np.ones(n, dtype=np.float32)
-
-    if mapper is not None:
-        edges = np.where(
-            np.isfinite(mapper.edges), mapper.edges,
-            np.float32(np.finfo(np.float32).max),
-        )
-    else:
-        edges = np.zeros((f, 1))
-    edges_dev = jnp.asarray(edges.astype(np.float32))
-    if num_bins <= 256:
-        b8 = np.asarray(bins)
-        b8 = b8 if b8.dtype == np.uint8 else b8.astype(np.uint8)
-        bins_dev = jnp.asarray(np.ascontiguousarray(b8))
-    else:
-        bins_dev = jnp.asarray(np.asarray(bins, dtype=np.int32))
-    if (
-        y_np.size
-        and np.all(np.mod(y_np, 1) == 0)
-        and np.all((y_np >= 0) & (y_np <= 255))
-    ):
-        y_dev = jnp.asarray(y_np.astype(np.uint8)).astype(jnp.float32)
-    else:
-        y_dev = jnp.asarray(y_np)
-    w_dev = jnp.ones(n, jnp.float32) if w_is_default else jnp.asarray(w)
+        _check_contracts(o)
+    # same mapper → same slot resolution for every candidate (the bucket key
+    # already agrees on categorical/onehot slots); boost_from_average is
+    # static (outside MANY_VMAPPED_FIELDS), so one init_score serves all
+    opts_list = [_with_mapper_slots(o, mapper) for o in opts_list]
+    prep = _prepare(bins, y, w, None, normalize_many_opts(opts_list[0]), mapper)
+    data = _place(prep)
+    n, K = prep.n, len(opts_list)
 
     # Per-candidate host-side schedules: each candidate draws its own
     # bagging/feature masks from ITS seed and fractions — the exact
     # sequential-path stream — and its constant learning rate becomes an
     # (iterations,) lane of the scanned lr stack.
     any_bag = any(_bagging_active(o) for o in opts_list)
-    bag_stacks: List[np.ndarray] = []
-    fm_stacks: List[np.ndarray] = []
-    lr_stacks: List[np.ndarray] = []
+    bag_stacks, fm_stacks = [], []
     for o in opts_list:
-        rng = np.random.default_rng(o.seed)
-        num_bag = max(1, int(round(n * o.bagging_fraction)))
-        num_feat = max(1, int(round(f_feat * o.feature_fraction)))
-        bag_l, fm_l = [], []
-        for bag_np, _, fm_np in _mask_schedule(
-            o, rng, n, 0, num_bag, num_feat, f_feat, presence, y=y_np
-        ):
-            bag_l.append(bag_np)
-            fm_l.append(
-                fm_np if fm_np is not None else np.ones(f_feat, np.float32)
-            )
+        bags, fms = _mask_stacks(_mask_schedule(o, n, 0, prep.f_feat, y=prep.y), prep.f_feat)
         if any_bag:
-            bag_stacks.append(np.stack(bag_l).astype(np.uint8))
-        fm_stacks.append(np.stack(fm_l))
-        lr_stacks.append(np.full(iters, o.learning_rate, dtype=np.float32))
-    margins_many = jnp.asarray(
-        np.broadcast_to(margins0[None], (K, n, num_classes)).copy()
+            bag_stacks.append(np.stack(bags).astype(np.uint8))
+        fm_stacks.append(fms)
+    lr_all = np.stack([
+        np.full(prep.opts.num_iterations, o.learning_rate, dtype=np.float32)
+        for o in opts_list
+    ])
+    progs = _programs(
+        prep.opts, prep.objective, prep.num_bins, n, prep.bundle, scan=any_bag, many=True
     )
-    fm_all = jnp.asarray(np.stack(fm_stacks))  # (K, iters, F)
-    lr_all = jnp.asarray(np.stack(lr_stacks))  # (K, iters)
-    bag_arg = (
-        jnp.asarray(np.stack(bag_stacks))  # (K, iters, N) uint8
-        if any_bag
-        else jnp.ones(n, jnp.float32)  # shared presence, broadcast
+    _, stacked = progs.runner(
+        data.bins, data.y, data.w,
+        jnp.broadcast_to(data.margins, (K, *data.margins.shape)), data.edges,
+        jnp.asarray(np.stack(bag_stacks)) if any_bag else jnp.ones(n, jnp.float32),
+        jnp.asarray(np.stack(fm_stacks)), jnp.asarray(lr_all),
+        jnp.int32(0), jnp.int32(0),
     )
-
-    okey = (many_bucket_key(opts_list[0]), num_bins, None, None, bundle,
-            objective.cache_token)
-    if base.boosting_type == "goss":
-        okey = okey + (n,)  # GOSS bakes the unpadded row count
-    step_raw = cached_program(
-        ("step_raw_many", okey),
-        lambda: _make_step(
-            base, objective, num_bins, None, n_real=n, u_spec=None,
-            bundle=bundle,
-        ),
-    )
-    runner = cached_program(
-        ("scan_many", okey, any_bag),
-        lambda: _make_scan_steps_many(step_raw, per_iter_bag=any_bag),
-    )
-
-    _prof = get_profiler()
-    _prof_on = _prof.active
-    t0 = time.perf_counter() if _prof_on else 0.0
-    cache_before = (
-        runner._cache_size()
-        if _prof_on and hasattr(runner, "_cache_size") else None
-    )
-    margins_out, stacked = runner(
-        bins_dev, y_dev, w_dev, margins_many, edges_dev, bag_arg, fm_all,
-        lr_all, jnp.int32(0), jnp.int32(0),
-    )
-    if _prof_on:
-        jax.block_until_ready((margins_out, stacked))
-        dt = time.perf_counter() - t0
-        compiled = (
-            cache_before is not None
-            and hasattr(runner, "_cache_size")
-            and runner._cache_size() > cache_before
-        )
-        if compiled:
-            _prof.note_compile("gbdt.scan_many", dt)
-        else:
-            _prof.note_cache_hit("gbdt.scan_many")
-        _prof.note_execute("gbdt.scan_many", dt)
 
     results: List[TrainResult] = []
     for ki, o in enumerate(opts_list):
         cand = jax.tree.map(lambda x, _ki=ki: x[_ki], stacked)
         booster = _pack_booster(
-            None, cand, o, num_classes, init_score, mapper, feature_names,
+            None, cand, o, prep.num_classes, prep.init_score, mapper, feature_names,
             best_iteration=-1,
         )
-        results.append(
-            TrainResult(booster=booster, evals={}, best_iteration=0)
-        )
+        results.append(TrainResult(booster=booster, evals={}, best_iteration=0))
     return results
 
 
@@ -2884,41 +2737,30 @@ def _fetch_trees(
     t = opts.num_iterations if stacked_trees is not None else len(trees)
     m = opts.num_nodes
 
+    def flat(field, *tail):
+        """``field`` of every tree, (T*C, *tail): scan-stacked (T, C, ...) or
+        a list of (C, ...) per-iteration arrays."""
+        if stacked_trees is not None:
+            dev = getattr(stacked_trees, field)
+        else:
+            dev = jnp.concatenate([getattr(tr, field) for tr in trees], axis=0)
+        return dev.reshape(t * num_classes, *tail)
+
     # ONE device-side pack + ONE fetch for all tree fields: every int/bool
     # field's values fit float32 exactly (slot ids < 2^24), so the 9 fields
     # ride a single (9, T*C, M) f32 wire transfer instead of 9 round-trips.
-    def _field_dev(field):
-        if stacked_trees is not None:
-            dev = getattr(stacked_trees, field)  # (T, C, M)
-        else:
-            dev = jnp.concatenate([getattr(tr, field) for tr in trees], axis=0)
-        return dev.reshape(t * num_classes, m).astype(jnp.float32)
-
-    passes_dev = (
-        stacked_trees.passes if stacked_trees is not None
-        else jnp.stack([tr.passes for tr in trees])
-    ).reshape(-1, 2).sum(axis=0)
-    # the pass counts ride the fetch of the pack: no round trip of their own
-    packed, passes = jax.device_get(
-        (jnp.stack([_field_dev(fld) for fld in _FIELDS]), passes_dev)
-    )
+    # The pass counts ride the same fetch: no round trip of their own.
+    packed, passes = jax.device_get((
+        jnp.stack([flat(fld, m).astype(jnp.float32) for fld in _FIELDS]),
+        flat("passes", 2).sum(axis=0),
+    ))
 
     # Categorical split arrays ride separate (small) transfers: the bool
     # mask matrix does not fit the homogeneous f32 pack.
     cat_nodes_np = cat_masks_np = None
     if opts.categorical_slots:
-        if stacked_trees is not None:
-            cn_dev = stacked_trees.cat_node.reshape(t * num_classes, m)
-            cm_dev = stacked_trees.cat_mask.reshape(t * num_classes, m, -1)
-        else:
-            cn_dev = jnp.concatenate([tr.cat_node for tr in trees]).reshape(
-                t * num_classes, m
-            )
-            cm_dev = jnp.concatenate([tr.cat_mask for tr in trees], axis=0).reshape(
-                t * num_classes, m, -1
-            )
-        cat_nodes_np = np.asarray(cn_dev).astype(bool)
-        cat_masks_np = np.asarray(cm_dev.astype(jnp.uint8)).astype(bool)
+        cat_nodes_np = np.asarray(flat("cat_node", m)).astype(bool)
+        cat_masks_np = np.asarray(flat("cat_mask", m, -1).astype(jnp.uint8)).astype(bool)
     return (packed, cat_nodes_np, cat_masks_np), passes
 
 

@@ -12,7 +12,6 @@ averaging at each pass boundary inside one ``shard_map`` over the mesh
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Optional, Tuple
 
 import numpy as np
@@ -611,14 +610,8 @@ def train_linear_many(
     weights0 = jnp.asarray(np.broadcast_to(w0[None], (K, dim)).copy())
     acc0 = jnp.zeros((K, dim), jnp.float32)
 
-    _prof = get_profiler()
-    _prof_on = _prof.active
+    fit = get_profiler().wrap(fit, "vw.fit_many", cost_analysis=False)
     with sw.measure():
-        t0 = time.perf_counter() if _prof_on else 0.0
-        cache_before = (
-            fit._cache_size()
-            if _prof_on and hasattr(fit, "_cache_size") else None
-        )
         fitted = fit(
             bidx, bval, by, bw, weights0, acc0,
             jnp.asarray(np.asarray(learning_rates, np.float32)),
@@ -627,18 +620,6 @@ def train_linear_many(
             jnp.asarray(np.asarray(l2s, np.float32)),
         )
         fitted = np.asarray(jax.block_until_ready(fitted))
-        if _prof_on:
-            dt = time.perf_counter() - t0
-            compiled = (
-                cache_before is not None
-                and hasattr(fit, "_cache_size")
-                and fit._cache_size() > cache_before
-            )
-            if compiled:
-                _prof.note_compile("vw.fit_many", dt)
-            else:
-                _prof.note_cache_hit("vw.fit_many")
-            _prof.note_execute("vw.fit_many", dt)
 
     results = []
     for ki in range(K):

@@ -350,6 +350,30 @@ class TestTrainProfilerWiring:
             prof.disable()
             prof.clear()
 
+    @pytest.mark.parametrize("kind,name", [("gbdt", "gbdt.scan_many"), ("vw", "vw.fit_many")])
+    def test_many_models_book_one_dispatch(self, data, kind, name):
+        from mmlspark_tpu.data.table import Table
+        from mmlspark_tpu.lightgbm import LightGBMClassifier
+        from mmlspark_tpu.sweep import bucket_candidates, fit_bucket
+        from mmlspark_tpu.vw import VowpalWabbitClassifier
+
+        X, y = data
+        table = Table({"features": X[:200].astype(np.float64), "label": y[:200].astype(np.float64)})
+        valid = Table({"features": X[200:].astype(np.float64), "label": y[200:].astype(np.float64)})
+        est = (LightGBMClassifier(numIterations=3, numLeaves=7) if kind == "gbdt"
+               else VowpalWabbitClassifier(numPasses=2))
+        (bucket,) = bucket_candidates([(est, {"learningRate": lr}) for lr in (0.1, 0.3)])
+        prof = get_profiler().enable()
+        prof.clear()
+        try:
+            fit_bucket(bucket, table, valid, "label", "accuracy")
+            p = prof.snapshot()["functions"][name]
+            assert p["executions"] == 1
+            assert p["device_seconds"] > 0
+        finally:
+            prof.disable()
+            prof.clear()
+
     def test_disabled_profiler_books_nothing(self, data):
         X, y = data
         prof = get_profiler()

@@ -117,6 +117,36 @@ class TestBatchedParity:
             ref_metric = _evaluate(ref.transform(valid), "label", "AUC")
             assert np.isclose(metric, ref_metric, rtol=1e-5), (m, metric)
 
+    @pytest.mark.parametrize("bundled", [False, True])
+    @pytest.mark.parametrize("boosting,bagging", [
+        ("gbdt", False), ("gbdt", True), ("goss", False),
+    ])
+    def test_train_many_of_one_is_train(self, boosting, bagging, bundled):
+        """``train_many([o])`` shares ``train(o)``'s set-up and step: the
+        model texts are byte-equal."""
+        from mmlspark_tpu.lightgbm.binning import bin_dataset
+        from mmlspark_tpu.lightgbm.train import TrainOptions, train, train_many
+
+        rng = np.random.default_rng(0)
+        if bundled:  # one non-zero column a row: the columns bundle
+            X = np.zeros((600, 10))
+            X[np.arange(600), rng.integers(0, 10, 600)] = rng.normal(size=600) + 2
+            y = (X[:, 0] + X[:, 1] - X[:, 2] > 0.5).astype(np.float64)
+        else:
+            X = rng.normal(size=(600, 6))
+            y = (X[:, 0] + X[:, 1] > 0).astype(np.float64)
+        bins, mapper = bin_dataset(X, max_bin=31, feature_bundling=bundled)
+        assert (mapper.bundles is not None) == bundled
+        bag = dict(bagging_fraction=0.7, bagging_freq=1) if bagging else {}
+        o = TrainOptions(
+            objective="binary", num_iterations=5, num_leaves=7, max_bin=31,
+            boosting_type=boosting, learning_rate=0.15, feature_fraction=0.8,
+            **bag,
+        )
+        (many,) = train_many(bins, y, [o], mapper=mapper)
+        one = train(bins, y, o, mapper=mapper)
+        assert many.booster.model_to_string() == one.booster.model_to_string()
+
     def test_vw_batched_scores_match_sequential(self, clf_table, monkeypatch):
         # the vmapped core is single-device; the sequential reference must
         # run mesh-free too (row sharding reorders SGD accumulation)

@@ -693,3 +693,126 @@ class TestSiblingSubtraction:
         assert model_texts_close(
             r_on.model_text, serial.booster.model_to_string()
         )
+
+
+# -- the U-path plan: one decision, and the events it publishes ---------------
+
+_CAP = 1 << 24  # the quantized path's row cap
+# a 31-leaf cache at 256 bins: 61 nodes x 256 bins x 3 stats x 4 bytes a column
+_COL_BYTES = 61 * 256 * 3 * 4
+
+
+@pytest.mark.parametrize("case,kw,want", [
+    ("mesh", dict(opts=dict(histogram_method="u"), mesh=object()), dict(spec=None)),
+    ("voting", dict(opts=dict(histogram_method="u", tree_learner="voting_parallel")),
+     dict(spec=None, sub=False)),
+    ("num_bins_over_256", dict(opts=dict(histogram_method="u", max_bin=511), num_bins=512),
+     dict(spec=None)),
+    ("no_u_env", dict(opts=dict(histogram_method="u"), env={"MMLSPARK_TPU_NO_U": "1"}),
+     dict(spec=None)),
+    ("auto_off_the_chip", dict(opts=dict()), dict(spec=None)),
+    ("forced_u_off_the_chip", dict(opts=dict(histogram_method="u")),
+     dict(chunk_rows=0, budget=8 << 30)),
+    ("over_budget_chunked", dict(opts=dict(histogram_method="u"),
+                                 env={"MMLSPARK_TPU_U_BUDGET": str(64 << 10)}),
+     dict(chunked=True, budget=64 << 10)),
+    ("quantized", dict(opts=dict(histogram_method="u", use_quantized_grad=True)),
+     dict(quant=True, acc="int32")),
+    ("quantized_without_u", dict(opts=dict(use_quantized_grad=True)),
+     dict(spec=None, quant=False, quant_off="inactive", acc="float32")),
+    ("quantized_over_row_cap", dict(opts=dict(histogram_method="u", use_quantized_grad=True),
+                                    rows=_CAP + 1),
+     dict(quant=False, quant_off="2^24", acc="float32")),
+    ("quantized_at_row_cap", dict(opts=dict(histogram_method="u", use_quantized_grad=True),
+                                  rows=_CAP), dict(quant=True, acc="int32")),
+    ("subtraction_at_256MiB", dict(opts=dict(num_leaves=31, max_bin=255), num_bins=256,
+                                   cols=(256 << 20) // _COL_BYTES), dict(spec=None, sub=True)),
+    ("subtraction_past_256MiB", dict(opts=dict(num_leaves=31, max_bin=255), num_bins=256,
+                                     cols=(256 << 20) // _COL_BYTES + 1),
+     dict(spec=None, sub=False)),
+    ("subtraction_off", dict(opts=dict(histogram_subtraction=False)),
+     dict(spec=None, sub=False)),
+])
+def test_u_plan(case, kw, want, monkeypatch):
+    """Each branch of ``_u_plan``, and the HistogramChunked /
+    HistogramSubtracted events ``_publish_plan`` derives from the plan."""
+    from mmlspark_tpu.lightgbm.train import _publish_plan, _u_plan
+    from mmlspark_tpu.observability import HistogramChunked, HistogramSubtracted, get_bus
+
+    for name, value in kw.get("env", {}).items():
+        monkeypatch.setenv(name, value)
+    opts = TrainOptions(**{"num_leaves": 7, "max_bin": 15, **kw["opts"]})
+    rows, cols, num_bins = kw.get("rows", 1000), kw.get("cols", 4), kw.get("num_bins", 16)
+    plan = _u_plan(opts, rows, cols, num_bins, mesh=kw.get("mesh"))
+
+    if "spec" in want:
+        assert plan.spec is None and plan.budget == 0
+    else:
+        assert plan.spec is not None
+        assert plan.budget == want.get("budget", 8 << 30)
+        assert bool(plan.spec.chunk_rows) == want.get("chunked", False)
+    assert plan.quant == want.get("quant", False)
+    assert (plan.quant_off is None) == ("quant_off" not in want)
+    if "quant_off" in want:
+        assert want["quant_off"] in plan.quant_off
+    if "acc" in want:
+        assert plan.sub.acc_dtype.name == want["acc"]
+    if "sub" in want:
+        assert plan.sub.on == want["sub"]
+    cache = max(1, opts.num_class) * (2 * opts.num_leaves - 1) * cols * num_bins * 3 \
+        * plan.sub.acc_dtype.itemsize
+    assert plan.sub.cache_bytes == cache and plan.sub.cols == cols
+
+    seen = []
+    bus = get_bus()
+    bus.add_listener(seen.append)
+    try:
+        _publish_plan(plan, opts, rows)
+    finally:
+        bus.remove_listener(seen.append)
+    chunked = [e for e in seen if isinstance(e, HistogramChunked)]
+    subtracted = [e for e in seen if isinstance(e, HistogramSubtracted)]
+    if plan.spec is not None and plan.spec.chunk_rows:
+        (ev,) = chunked
+        assert (ev.rows, ev.chunk_rows, ev.budget_bytes, ev.k_packed, ev.acc_dtype) == (
+            rows, plan.spec.chunk_rows, plan.budget, plan.spec.k_pad, plan.sub.acc_dtype.name)
+        assert ev.num_chunks == -(-rows // plan.spec.chunk_rows)
+    else:
+        assert not chunked
+    if plan.sub.on:
+        (ev,) = subtracted
+        assert (ev.rows, ev.packed_columns, ev.packed_bins, ev.acc_dtype, ev.cache_bytes) == (
+            rows, cols, num_bins, plan.sub.acc_dtype.name, plan.sub.cache_bytes)
+    else:
+        assert not subtracted
+
+
+@pytest.mark.parametrize("quant", [False, True])
+def test_grower_subtracts_where_the_plan_says(quant, monkeypatch):
+    """``_build_tree_leafwise`` asks the plan's own predicate: a fit whose
+    plan says no subtraction keys its passes on both children (21 nodes a
+    pass at most), one whose plan says yes on the smaller (42)."""
+    import mmlspark_tpu
+    import mmlspark_tpu.lightgbm.train as train_mod
+
+    mmlspark_tpu.clear_compiled_caches()  # the grower must be traced again
+    calls = []
+    inner = train_mod._subtraction
+
+    def spy(opts, rows, cols, bins, q):
+        out = inner(opts, rows, cols, bins, q)
+        calls.append((rows, cols, bins, q, out.on))
+        return out
+
+    monkeypatch.setattr(train_mod, "_subtraction", spy)
+    rng = np.random.default_rng(3)
+    X = rng.normal(size=(600, 4))
+    y = (X[:, 0] > 0).astype(np.float64)
+    bins, mp = bin_dataset(X, max_bin=15)
+    opts = TrainOptions(num_iterations=2, num_leaves=7, max_bin=15, histogram_method="u",
+                        use_quantized_grad=quant)
+    train(bins, y, opts, mapper=mp)
+    assert len(calls) >= 2
+    plan_call, grower_call = calls[0], calls[-1]
+    assert plan_call == grower_call  # same shapes, same quantization, same answer
+    assert grower_call[3] == quant
