@@ -25,8 +25,6 @@ from mmlspark_tpu.data.table import Table
 from mmlspark_tpu.dnn.model import DNNModel
 from mmlspark_tpu.observability.tracing import get_tracer
 
-_LOAD = "expert_load"
-
 # model_type -> (the family's module, its ``*_apply(params, tokens, config)``
 # and its ``init_*(key, config)`` there). The module's ``span_tags(config)``
 # says what ``lm.featurize`` tells of a configuration of its family, so the
@@ -38,17 +36,47 @@ FAMILIES = {
     "nemotron_h": ("mmlspark_tpu.models.nemotron_h", "nemotron_h_apply", "init_nemotron_h"),
     "lfm2_moe": ("mmlspark_tpu.models.lfm2_moe", "lfm2_moe_apply", "init_lfm2_moe"),
     "falcon_h1": ("mmlspark_tpu.models.falcon_h1", "falcon_h1_apply", "init_falcon_h1"),
+    "KeyeVL2": ("mmlspark_tpu.models.keye_vl2", "keye_vl2_apply", "init_keye_vl2"),
 }
 _DEFAULT = "afmoe"
 _MODULES = ", ".join(f"'{model_type}': {module}" for model_type, (module, _, _) in FAMILIES.items())
 _INITS = ", ".join(f"{module}.{init}" for module, _, init in FAMILIES.values())
 
 
+def _route_tags(load, batch: int, tokens: int) -> dict:
+    """``expert_load`` (rows, expert layers, experts) -> ``lm.route_stats``'
+    tags, summed per dispatch (the rows of one batch)."""
+    load = np.asarray(load, np.int64)
+    per_dispatch = np.add.reduceat(load, np.arange(0, len(load), batch), axis=0)
+    return {"load_peak": int(per_dispatch.max(axis=-1).sum()), "load_mean": float(per_dispatch.mean(axis=-1).sum()),
+            "tokens_routed": int(load.sum()), "experts_empty": int((per_dispatch == 0).sum()),
+            "expert_groups": int(per_dispatch.size)}
+
+
+def _select_tags(selection, batch: int, tokens: int) -> dict:
+    """``key_selection`` (rows, layers, 1 + words), the keys each layer kept
+    over a row's queries first -> ``lm.select_stats``' tags: the keys kept,
+    the keys the same queries see (``j <= i``: causal) and the queries."""
+    kept = np.asarray(selection[..., 0], np.int64)
+    return {"keys_kept": int(kept.sum()), "keys_visible": kept.size * tokens * (tokens + 1) // 2,
+            "queries": kept.size * tokens}
+
+
+# A statistic a family returns -> (the ``span_tags`` key that says the family
+# returns it, what the stage says where it does not, the span that books it,
+# its tags from the fetched column). The stage fetches each statistic its
+# family has, asked for as an output column or not, and books it.
+STATISTICS = {
+    "expert_load": ("experts", "no expert layers", "lm.route_stats", _route_tags),
+    "key_selection": ("sparse_topk", "no sparse attention", "lm.select_stats", _select_tags),
+}
+
+
 def _family(config: dict):
     """-> (``model_type``, the family's apply function, its span tags)."""
     model_type = config.get("model_type", _DEFAULT)
     if model_type not in FAMILIES:
-        raise ValueError(f"modelConfig['model_type'] {model_type!r}: one of {sorted(FAMILIES)}")
+        raise ValueError(f"modelConfig['model_type'] {model_type!r}: one of {sorted(FAMILIES, key=str.lower)}")
     module, name, _ = FAMILIES[model_type]
     module = importlib.import_module(module)
     return model_type, getattr(module, name), module.span_tags(config)
@@ -79,7 +107,9 @@ class LMFeaturizer(Model):
     outputCols = Param(
         "model output ('hidden': last position after the final norm, 'logits': "
         "last-position logits, 'expert_load': tokens of the row each expert "
-        "received, per expert layer, for a family with experts) -> output column",
+        "received, per expert layer, for a family with experts; 'key_selection': "
+        "keys kept over the row's queries and the last position's kept keys, per "
+        "layer, for a family with sparse attention) -> output column",
         default={"hidden": "features"},
     )
     modelParams = Param(
@@ -93,49 +123,45 @@ class LMFeaturizer(Model):
 
     def transform(self, table: Table) -> Table:
         """One ``lm.featurize`` span roots the call's trace, the batched
-        forward's ``dnn.*`` spans beneath it; for a family with expert layers
-        (its ``span_tags`` count ``experts``) ``lm.route_stats`` then sums the
-        fetched expert loads per dispatch (``observability/tracing``). A
-        dense family returns no ``expert_load``: none is fetched and there
-        are no route statistics."""
+        forward's ``dnn.*`` spans beneath it; then a span for each statistic
+        the family returns (:data:`STATISTICS`: ``lm.route_stats`` for a
+        family with expert layers, ``lm.select_stats`` for one with sparse
+        attention), booked from the fetched column
+        (``observability/tracing``). A family without a statistic returns
+        none: none is fetched and none is booked."""
         params, config = self.getModelParams(), self.getModelConfig()
         if params is None or config is None:
             raise ValueError(f"modelParams and modelConfig must be set (see {_INITS})")
         outputs = dict(self.getOutputCols())
-        unknown = set(outputs) - {"hidden", "logits", _LOAD}
+        unknown = set(outputs) - {"hidden", "logits", *STATISTICS}
         if unknown or not outputs:
-            raise ValueError(f"outputCols maps hidden / logits / expert_load to columns (got {sorted(outputs)})")
+            raise ValueError(f"outputCols maps hidden / logits / {' / '.join(STATISTICS)} to columns "
+                             f"(got {sorted(outputs)})")
         batch = self.getBatchSize()
         tokens = len(table.column(self.getInputCol())[0])
         model_type, _, tags = _family(config)
-        routed = bool(tags.get("experts"))
-        if _LOAD in outputs and not routed:
-            raise ValueError(f"model_type {model_type!r} has no expert layers: no expert_load to map to a column")
+        for stat, (flag, lacking, _, _) in STATISTICS.items():
+            if stat in outputs and not tags.get(flag):
+                raise ValueError(f"model_type {model_type!r} has {lacking}: no {stat} to map to a column")
+        # fetched always where the family has it: its span reads it
+        stats = {stat: outputs.get(stat, f"__{stat}__") for stat, (flag, *_) in STATISTICS.items() if tags.get(flag)}
         with get_tracer().span(
             "lm.featurize", rows=table.num_rows, tokens=tokens, batch_size=batch,
             model_type=model_type, **tags,
         ):
-            load_col = outputs.get(_LOAD, "__expert_load__")  # fetched always where routed: route_stats reads it
             dnn = DNNModel(
                 applyFn=_apply_fn(config),
                 modelParams=params,
                 feedDict={"input": self.getInputCol()},
-                fetchDict={**{col: out for out, col in outputs.items()}, **({load_col: _LOAD} if routed else {})},
+                fetchDict={**{col: out for out, col in outputs.items()}, **{col: stat for stat, col in stats.items()}},
                 batchSize=batch,
                 inputDtype="int32",
             )
             out = dnn.transform(table)
-            if not routed:
-                return out
-            with get_tracer().span("lm.route_stats") as sp:
-                # (rows, expert layers, experts) -> per dispatch (the rows of one batch)
-                load = np.asarray(out[load_col], np.int64)
-                per_dispatch = np.add.reduceat(load, np.arange(0, len(load), batch), axis=0)
-                sp.tags["load_peak"] = int(per_dispatch.max(axis=-1).sum())
-                sp.tags["load_mean"] = float(per_dispatch.mean(axis=-1).sum())
-                sp.tags["tokens_routed"] = int(load.sum())
-                sp.tags["experts_empty"] = int((per_dispatch == 0).sum())
-                sp.tags["expert_groups"] = int(per_dispatch.size)
-            if _LOAD not in outputs:
-                out = out.drop(load_col)
+            for stat, col in stats.items():
+                _, _, span, book = STATISTICS[stat]
+                with get_tracer().span(span) as sp:
+                    sp.tags.update(book(out[col], batch, tokens))
+                if stat not in outputs:
+                    out = out.drop(col)
             return out

@@ -1,5 +1,5 @@
 """What the decoder families with routed experts share (:mod:`afmoe`,
-:mod:`mla_moe`, :mod:`nemotron_h`, :mod:`lfm2_moe`): the stated precision of
+:mod:`mla_moe`, :mod:`nemotron_h`, :mod:`lfm2_moe`, :mod:`keye_vl2`): the stated precision of
 a norm and of a matrix product, rotary positions on half-split pairs, the
 depthwise causal convolution, the feed-forward with a gate (SwiGLU) and
 without one, the routed-expert block with a shared expert or none, the head
@@ -83,9 +83,11 @@ def swiglu(x, gate, up, down, dt):
 
 
 def routed_experts(p, x, k: int, scale: float, dt, activation: Callable = jax.nn.silu,
-                   first_group=0, interpret: bool = False):
-    """x: (rows, S, hidden). Sigmoid router scores; a token's ``k`` experts
-    are the largest of score + ``router_bias``, weighed by the scores alone
+                   first_group=0, interpret: bool = False, gate: Callable = jax.nn.sigmoid):
+    """x: (rows, S, hidden). Router scores ``gate`` of the router's product
+    (sigmoid; a softmax over the experts, say); a token's ``k`` experts
+    are the largest of score + ``router_bias`` (the scores alone where the
+    tree holds no bias), weighed by the scores alone
     over their sum times ``scale`` (:func:`moe_topk`); one shared expert
     beside them, of whatever width its matrices have, where the tree holds
     ``s_*`` leaves, and the routed output alone where it holds none. The
@@ -101,12 +103,12 @@ def routed_experts(p, x, k: int, scale: float, dt, activation: Callable = jax.nn
     E = p["router"].shape[-1]
     flat = rounded(x.reshape(B * S, D), dt)
     with jax.named_scope("moe_route"):
-        scores = jax.nn.sigmoid(dot(flat, p["router"], dt))
+        scores = gate(dot(flat, p["router"], dt))
     with jax.named_scope("moe_experts"):
         held = [n for n in ("gate", "up", "down") if "e_" + n in p]
         experts = {n: rounded(p["e_" + n], dt) for n in held}
-        routed, chosen = moe_topk(flat, scores + p["router_bias"], scores, experts, k, scale, activation,
-                                  first_group, interpret)
+        choose = scores + p["router_bias"] if "router_bias" in p else scores
+        routed, chosen = moe_topk(flat, choose, scores, experts, k, scale, activation, first_group, interpret)
         shared = feed_forward(flat, {n: p["s_" + n] for n in held}, dt, activation) if "s_down" in p else None
     load = (chosen.reshape(B, S * k, 1) == jnp.arange(E, dtype=jnp.int32)).sum(axis=1)
     y = routed if shared is None else routed + shared

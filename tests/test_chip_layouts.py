@@ -601,3 +601,65 @@ def test_the_leafwise_tree_streams_u_at_the_head_of_a_round_and_leaves_after_the
     routing = [i for i in range(opens, closes) if "/while/body/route/" in lines[i]]
     assert routing and in_body < routing[0]
     assert not any("hist_pass" in line for line in lines if "/while/cond/" in line)
+
+
+def test_the_sparse_attention_kernels_at_the_cells_shapes_lower_as_written(one_chip):
+    """One dispatch of ``keye-vl2-30b-a3b.score-32k``: 1 x 32,768 tokens, an
+    indexer of 16 heads of 64 keeping 2,048 keys a query, 32 query heads over
+    4 of 128. ``dsa_index`` holds a block's scores over every key in VMEM as
+    order keys (128 x 32,768 x 4 B) and writes only the bitmask (32,768 x
+    1,024 int32, 128 MiB): no (T, T) scores in HBM, no sort. ``dsa_attn``
+    reads the bitmask and the key blocks its scalar table names."""
+    import jax
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.ops.sparse_attention import dsa_attn, dsa_index, words_for
+
+    S = 32768
+    shape = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    index = _compile_off(lambda qi, w, ki: dsa_index(qi, w, ki, 2048), shape((1, S, 16, 64), jnp.bfloat16),
+                         shape((1, S, 16), jnp.float32), shape((1, S, 64), jnp.bfloat16))
+    memory = index.memory_analysis()
+    assert words_for(S) == 1024 and memory.output_size_in_bytes == S * 1024 * 4
+    assert memory.temp_size_in_bytes < 2**27 and "sort" not in index.as_text()
+    assert "tpu_custom_call" in index.as_text()
+    attn = _compile_off(dsa_attn, shape((1, S, 32, 128), jnp.bfloat16), shape((1, S, 4, 128), jnp.bfloat16),
+                        shape((1, S, 4, 128), jnp.bfloat16), shape((1, S, 1024), jnp.int32))
+    memory = attn.memory_analysis()
+    assert memory.output_size_in_bytes == S * 32 * 128 * 2 and memory.temp_size_in_bytes < 2**29
+    assert "tpu_custom_call" in attn.as_text()
+
+
+@pytest.mark.parametrize("product_dtype", ["bfloat16", "float8_e4m3fn"], ids=["as_stated", "the_float8_control"])
+def test_the_sparse_attention_decoder_at_the_published_widths_fits_beside_its_weights(one_chip, product_dtype):
+    """The whole program of ``keye-vl2-30b-a3b.score-32k``, one dispatch of 1
+    x 32,768 tokens over 6 layers: 8.15 GiB of weights and 3.13 GiB of
+    temporaries (11.28 in all, of a v5e's 15.75). One stack
+    under one scan: each kernel is one HLO name, which the roofline metrics
+    read by; as stated the three grouped expert products read all 6 x 128
+    groups where they lie."""
+    import json
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.models.keye_vl2 import init_keye_vl2, keye_vl2_apply
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs", "keye-vl2-30b-a3b.json")) as f:
+        config = dict(json.load(f)["params"], product_dtype=product_dtype)
+    tree = jax.eval_shape(lambda k: init_keye_vl2(k, config), jax.random.PRNGKey(0))
+    assert tree["layers"]["e_up"].shape == (6, 128, 2048, 768) and tree["head"].shape == (2048, 151936)
+    tree = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+    tokens = jax.ShapeDtypeStruct((1, 32768), jnp.int32, sharding=one_chip)
+    compiled = _compile_off(lambda p, x: keye_vl2_apply(p, x, config), tree, tokens)
+    memory = compiled.memory_analysis()
+    assert 8_749_244_928 <= memory.argument_size_in_bytes < 8_749_244_928 + 2**20
+    assert memory.temp_size_in_bytes < 3.4 * 2**30
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes + memory.output_size_in_bytes < 11.6 * 2**30
+    text = compiled.as_text()
+    for kernel in ("dsa_index", "dsa_attn", "moe_combine"):
+        assert len(set(re.findall(r"%(" + kernel + r"[\w.\-]*) =", text))) == 1, kernel
+    if product_dtype == "bfloat16":
+        _expert_products_read_in_place(text, 128, 2048, 768, 32768 * 8)

@@ -852,9 +852,9 @@ mt_value_indexer_model <- function(...) {
 #' \itemize{
 #'   \item \code{batchSize}: Rows per device batch (default 4)
 #'   \item \code{inputCol}: Column of token-id rows, all of one length (default 'tokens')
-#'   \item \code{modelConfig}: Decoder configuration: the family's published config.json keys and 'layers'; 'model_type' names the family ('afmoe' where absent; 'afmoe': mmlspark_tpu.models.afmoe, 'joyai_llm_flash': mmlspark_tpu.models.mla_moe, 'nemotron_h': mmlspark_tpu.models.nemotron_h, 'lfm2_moe': mmlspark_tpu.models.lfm2_moe, 'falcon_h1': mmlspark_tpu.models.falcon_h1) (default None)
-#'   \item \code{modelParams}: Decoder parameter pytree (the family's init_* format: mmlspark_tpu.models.afmoe.init_afmoe, mmlspark_tpu.models.mla_moe.init_mla_moe, mmlspark_tpu.models.nemotron_h.init_nemotron_h, mmlspark_tpu.models.lfm2_moe.init_lfm2_moe, mmlspark_tpu.models.falcon_h1.init_falcon_h1) (default None)
-#'   \item \code{outputCols}: model output ('hidden': last position after the final norm, 'logits': last-position logits, 'expert_load': tokens of the row each expert received, per expert layer, for a family with experts) -> output column (default {'hidden': 'features'})
+#'   \item \code{modelConfig}: Decoder configuration: the family's published config.json keys and 'layers'; 'model_type' names the family ('afmoe' where absent; 'afmoe': mmlspark_tpu.models.afmoe, 'joyai_llm_flash': mmlspark_tpu.models.mla_moe, 'nemotron_h': mmlspark_tpu.models.nemotron_h, 'lfm2_moe': mmlspark_tpu.models.lfm2_moe, 'falcon_h1': mmlspark_tpu.models.falcon_h1, 'KeyeVL2': mmlspark_tpu.models.keye_vl2) (default None)
+#'   \item \code{modelParams}: Decoder parameter pytree (the family's init_* format: mmlspark_tpu.models.afmoe.init_afmoe, mmlspark_tpu.models.mla_moe.init_mla_moe, mmlspark_tpu.models.nemotron_h.init_nemotron_h, mmlspark_tpu.models.lfm2_moe.init_lfm2_moe, mmlspark_tpu.models.falcon_h1.init_falcon_h1, mmlspark_tpu.models.keye_vl2.init_keye_vl2) (default None)
+#'   \item \code{outputCols}: model output ('hidden': last position after the final norm, 'logits': last-position logits, 'expert_load': tokens of the row each expert received, per expert layer, for a family with experts; 'key_selection': keys kept over the row's queries and the last position's kept keys, per layer, for a family with sparse attention) -> output column (default {'hidden': 'features'})
 #' }
 #' @param ... named params forwarded to the Python constructor
 #' @export
