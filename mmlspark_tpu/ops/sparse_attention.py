@@ -15,9 +15,11 @@ VMEM scratch as integers of the same order (:func:`order_keys`), and finds
 each query's cut there by a search over the bits (a count of the keys at or
 above a candidate, a pass over the scratch a bit, until exactly ``topk``
 lie above it; a tie at the cut is then split by a search over positions).
-Scores never reach HBM and nothing is sorted. What it writes is each
-query's kept set as a bitmask (:func:`unpack_keys`): a word column holds 32
-bit planes of 128 keys, so a row of 32,768 keys is 1,024 int32 words.
+Scores never reach HBM and nothing is sorted. A count crosses lanes once a
+pass, not once a key block: it is added 128 lanes wide through the pass and
+summed across the lanes at its end. What it writes is each query's kept set
+as a bitmask (:func:`unpack_keys`): a word column holds 32 bit planes of
+128 keys, so a row of 32,768 keys is 1,024 int32 words.
 
 ``dsa_attn``: :mod:`ops.attention`'s blocked loop (a block of queries of the
 heads of one key/value head, the head's whole key and value sequence in
@@ -107,13 +109,16 @@ def _index_kernel(qi_ref, w_ref, ki_ref, words_ref, keys_ref, *, topk, bq, bk):
     lax.fori_loop(0, blocks, fill, 0)
 
     def count(at):
-        """(bq, 1) float32: keys of each query for which ``at(keys, positions)``."""
+        """(bq, 1) float32: keys of each query for which ``at(keys, positions)``,
+        added lane by lane into a (bq, 128) carry over the sweep (exact: a
+        count is at most S), then summed across the lanes once."""
         def step(j, acc):
             start = pl.multiple_of(j * bk, bk)
-            hit = at(keys_ref[:, pl.ds(start, bk)], lanes + start)
-            return acc + jnp.sum(jnp.where(hit, 1.0, 0.0), axis=1, keepdims=True)
+            hit = jnp.where(at(keys_ref[:, pl.ds(start, bk)], lanes + start), 1.0, 0.0)
+            return functools.reduce(lambda a, c: a + hit[:, c:c + _LANES], range(0, bk, _LANES), acc)
 
-        return lax.fori_loop(0, blocks, step, jnp.zeros((bq, 1), jnp.float32))
+        wide = lax.fori_loop(0, blocks, step, jnp.zeros((bq, _LANES), jnp.float32))
+        return jnp.sum(wide, axis=1, keepdims=True)
 
     def any_row(rows):
         return jnp.max(jnp.where(rows, 1.0, 0.0)) > 0.0

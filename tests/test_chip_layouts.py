@@ -607,9 +607,13 @@ def test_the_sparse_attention_kernels_at_the_cells_shapes_lower_as_written(one_c
     """One dispatch of ``keye-vl2-30b-a3b.score-32k``: 1 x 32,768 tokens, an
     indexer of 16 heads of 64 keeping 2,048 keys a query, 32 query heads over
     4 of 128. ``dsa_index`` holds a block's scores over every key in VMEM as
-    order keys (128 x 32,768 x 4 B) and writes only the bitmask (32,768 x
-    1,024 int32, 128 MiB): no (T, T) scores in HBM, no sort. ``dsa_attn``
-    reads the bitmask and the key blocks its scalar table names."""
+    order keys (128 x 32,768 x 4 B = 16 MiB) beside its blocks (the indexer
+    key sequence 4 MiB, queries and bitmask 0.5 MiB each); a count of the
+    search is a (128, 128) float32 carry held in registers through its sweep.
+    The compile, which enforces the kernel's limit of 100 MiB, fits it in
+    18.5 MiB and refuses 18. It writes only the bitmask (32,768 x 1,024
+    int32, 128 MiB): no (T, T) scores in HBM, no sort. ``dsa_attn`` reads the
+    bitmask and the key blocks its scalar table names."""
     import jax
     import jax.numpy as jnp
 

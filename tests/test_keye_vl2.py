@@ -92,12 +92,21 @@ def test_order_keys_keep_the_order_of_the_scores():
     assert ((ka < kb) == (a < b)).all() and ((ka == kb) == (a == b)).all()
 
 
-@pytest.mark.parametrize("S,topk", [(1100, 300), (50, 16), (600, 1), (257, 600)],
-                         ids=["cut_across_key_blocks", "the_cells_dry_size", "one_key", "topk_past_the_row"])
-def test_the_indexer_keeps_the_topk_of_the_scores_a_query_sees(S, topk):
+@pytest.mark.parametrize("S,topk,head_scale", [
+    (1100, 300, None), (50, 16, None), (600, 1, None), (257, 600, None),
+    (1600, 700, None), (1100, 300, (1.0, -2.0, 0.0, 3.0))],
+    ids=["cut_across_key_blocks", "the_cells_dry_size", "one_key", "topk_past_the_row",
+         "cut_inside_a_lane_tile", "heads_of_mixed_sign_and_zero"])
+def test_the_indexer_keeps_the_topk_of_the_scores_a_query_sees(S, topk, head_scale):
     """Every key while ``t + 1 <= topk``, exactly ``topk`` after, and the
-    set is the ``lax.top_k`` of the plain scores over the keys ``s <= t``."""
+    set is the ``lax.top_k`` of the plain scores over the keys ``s <= t``.
+    At 1,600 keys the query blocks see one to four key blocks and a query's
+    last key lies anywhere in a 128-lane tile; with each head's weight given
+    one sign and one head zeroed, a weight read for another head or another
+    query changes the kept set."""
     qi, w, ki = _indexer_inputs(S, S=S)
+    if head_scale is not None:
+        w = jnp.abs(w) * jnp.asarray(head_scale)
     words = dsa_index(qi, w, ki, topk, interpret=True)
     assert words.shape == (2, S, words_for(S)) and words.dtype == jnp.int32
     got = unpack_keys(words, S)
@@ -106,6 +115,8 @@ def test_the_indexer_keeps_the_topk_of_the_scores_a_query_sees(S, topk):
     t = np.arange(S)
     np.testing.assert_array_equal(got.sum(-1), np.broadcast_to(np.minimum(t + 1, topk), (2, S)))
     assert not (got & (t[None, :] > t[:, None])).any()  # no key after its query
+    if head_scale is not None:  # the heads' weights decide the set: in another order they choose others
+        assert (got != _selection(_scores(qi, w[..., ::-1], ki), topk)).sum(-1)[:, -1].min() > 4
 
 
 def test_ties_go_to_the_lower_position():
